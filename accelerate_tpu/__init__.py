@@ -1,19 +1,5 @@
 __version__ = "0.1.0"
 
-# Honor JAX_PLATFORMS via jax.config as well as the env var: some TPU PJRT plugins
-# hook get_backend and ignore the env var, reaching (slowly, serialized) for real
-# hardware even in CPU-only child processes. The config path bypasses the hook.
-import os as _os
-
-if _os.environ.get("JAX_PLATFORMS"):
-    try:
-        import jax as _jax
-
-        _jax.config.update("jax_platforms", _os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-del _os
-
 from .accelerator import Accelerator
 from .state import AcceleratorState, GradientState, PartialState
 from .logging import get_logger

@@ -303,9 +303,9 @@ class Accelerator:
         )
 
         if self.compilation_config.cache_dir:
-            import jax
+            from .utils.environment import configure_compile_cache
 
-            jax.config.update("jax_compilation_cache_dir", self.compilation_config.cache_dir)
+            configure_compile_cache(self.compilation_config.cache_dir)
 
     # ------------------------------------------------------------------ state passthrough
     @property
@@ -866,7 +866,8 @@ class Accelerator:
         `steps_per_call=K > 1` additionally scans K FULL optimizer steps inside
         the one program (pass a batch stacking K step-batches along dim 0); host
         dispatch cost is paid once per K steps — the device-training-loop mode
-        for small-step configs and high-latency (tunneled) hosts.
+        for small-step configs, whose device step is short beside the host's
+        per-call dispatch.
 
         This is the TPU performance path; `backward()`/`optimizer.step()` remain as
         the eager-feel compatibility surface (reference accelerator.py:2093-2121).

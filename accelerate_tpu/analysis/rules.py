@@ -180,10 +180,11 @@ RULES = (
         "forced into interpret mode outside test code",
         fixit='pass attention_impl="pallas_paged" for paged serving engines (the '
         "XLA gather path materializes the whole logical cache per decode "
-        "dispatch and exists as the parity oracle, not the hot path) — or "
-        "suppress where the oracle is deliberate; interpret=True is the "
-        "CPU-test shim, production call sites must let the kernel compile "
-        "(interpret=None auto-selects)",
+        "dispatch and is the parity oracle; the kernels compile for the chip "
+        "and run on it, and which read is faster there is not measured yet — "
+        "ROADMAP S4) — or suppress where the oracle is deliberate; "
+        "interpret=True is the CPU-test shim, production call sites must let "
+        "the kernel compile (interpret=None auto-selects)",
     ),
     Rule(
         id="TPU116",

@@ -12,10 +12,14 @@ discipline actually holds on a live step.
   - **arms ``jax.transfer_guard``** (default ``"disallow"``) so accidental
     *implicit* transfers — raw numpy leaking into a jitted call, an implicit
     ``bool()`` on a device value — raise at the offending line, while the
-    sanctioned explicit step-boundary pattern (``jnp.asarray`` operand pushes,
-    ``np.asarray``/``.item()`` drains) passes untouched. That asymmetry is the
-    whole point: the guard encodes the repo's host discipline, not "no
-    transfers ever".
+    sanctioned explicit step-boundary pattern (``jnp.asarray(np_array)`` /
+    ``jax.device_put`` operand pushes, ``jax.device_get`` drains) passes
+    untouched. On a TPU ``np.asarray(x)``, ``int(x)``, ``float(x)`` and
+    ``x.item()`` on a device value are IMPLICIT device-to-host reads and are
+    rejected; XLA:CPU lets them through (there is no copy to guard), so a
+    "0 host transfers" count taken on CPU says nothing about them. That
+    asymmetry is the whole point: the guard encodes the repo's host
+    discipline, not "no transfers ever".
 
 On exit, ``on_violation="raise"`` turns any observed cache miss into a
 `TraceGuardViolation` naming the recompiled executables; ``"record"`` just
@@ -36,7 +40,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
-_COMPILE_LOG_RE = re.compile(r"Compiling ([^\s]+) with global shapes")
+# jax 0.9 logs the executable as "jit(<name>)"; the ledger is keyed by <name>.
+_COMPILE_LOG_RE = re.compile(r"Compiling (?:jit\()?([^\s()]+)\)? with global shapes")
 _TRANSFER_RE = re.compile(
     r"Disallowed (host-to-device|device-to-host|device-to-device) transfer"
 )

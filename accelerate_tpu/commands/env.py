@@ -27,18 +27,11 @@ def env_command(args):
         "Device kind": jax.devices()[0].device_kind,
         "Process count": jax.process_count(),
     }
-    try:
-        import flax
+    import flax
+    import optax
 
-        info["Flax version"] = flax.__version__
-    except ImportError:
-        pass
-    try:
-        import optax
-
-        info["Optax version"] = optax.__version__
-    except ImportError:
-        pass
+    info["Flax version"] = flax.__version__
+    info["Optax version"] = optax.__version__
     accelerate_env = {k: v for k, v in os.environ.items() if k.startswith("ACCELERATE_TPU_")}
     print("\nCopy-and-paste the text below in your GitHub issue\n")
     print("\n".join([f"- {prop}: {val}" for prop, val in info.items()]))

@@ -23,9 +23,11 @@ from typing import Optional
 import numpy as np
 
 # Trace-time record of the implementation the last dispatch chose ("xla" | "flash"
-# | "ring" | "allgather" | "pallas_paged"). Benchmarks read it to PROVE the kernel
-# they claim to measure actually ran (round-2 verdict weak #5: flash was dead code
-# on every benchmarked path and nothing would have noticed).
+# | "ring" | "allgather" | "pallas_paged"). Benchmarks and chip_smoke.py read it to
+# PROVE the kernel they claim to run actually ran (flash was once dead code on
+# every benchmarked path and nothing noticed). Together with a `tpu_custom_call`
+# in the lowered program it says the COMPILED kernel ran: both Pallas families
+# compile for the chip (tests/test_tpu_compile.py) and run on it (chip_smoke.py).
 LAST_DISPATCH: Optional[str] = None
 
 #: The serving-decode attention implementations `slot_cache_attention` accepts.
@@ -270,9 +272,8 @@ def _tp_paged_attention(fn, q, pool_k, pool_v, table, positions, k_scale, v_scal
     heads [i*Hq/tp, (i+1)*Hq/tp) and their kv heads [i*Hkv/tp, (i+1)*Hkv/tp),
     so every local query head's kv head is local too. Page tables, positions
     and the output's batch dims stay replicated traced operands."""
+    import jax
     from jax.sharding import PartitionSpec as P
-
-    from ..parallel.sharding import compat_shard_map
 
     head = P(None, None, "model", None)  # q/pools: [.., heads, head_dim]
     repl = P(None, None)  # page tables / positions: replicated operands
@@ -292,7 +293,7 @@ def _tp_paged_attention(fn, q, pool_k, pool_v, table, positions, k_scale, v_scal
     # Replication checking off: pallas_call can't annotate its outputs (the
     # same dispensation ring_attention's flash path uses); numerics are
     # covered by the tp-parity pins.
-    wrapped = compat_shard_map(
+    wrapped = jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs, out_specs=head, check_vma=False
     )
     return wrapped(*args)

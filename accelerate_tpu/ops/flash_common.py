@@ -35,7 +35,7 @@ def init_softmax_state(acc, m_scr, l_scr):
     l_scr[:] = jnp.zeros_like(l_scr)
 
 
-def online_softmax_update(s, v, acc, m_scr, l_scr):
+def online_softmax_update(s, v, acc, m_scr, l_scr, idx=()):
     """Fold one K/V block into the running softmax state.
 
     Args:
@@ -43,28 +43,35 @@ def online_softmax_update(s, v, acc, m_scr, l_scr):
             masked (masked lanes at `NEG_INF`).
         v: [block_k, D] fp32 value block.
         acc / m_scr / l_scr: scratch refs as in `init_softmax_state`.
+        idx: leading index into the scratch refs, e.g. ``(h,)`` for the paged
+            kernel's per-KV-head ``[Hkv, rows, ...]`` scratch. Indexed, not
+            `ref.at[h]`: Mosaic refuses a sub-view of a scratch whose last
+            dim is under a lane tile (head_dim 64).
     """
-    m_prev = m_scr[:, 0:1]  # [rows, 1] (lane dim is broadcast)
-    l_prev = l_scr[:, 0:1]
+    at = (*idx, slice(None))
+    m_prev = m_scr[at][:, 0:1]  # [rows, 1] (lane dim is broadcast)
+    l_prev = l_scr[at][:, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)  # [rows, block_k]
     correction = jnp.exp(m_prev - m_new)  # [rows, 1]
     l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-    acc[:] = acc[:] * correction + jax.lax.dot_general(
+    acc[at] = acc[at] * correction + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    stat_shape = m_scr.shape[len(idx):]
+    m_scr[at] = jnp.broadcast_to(m_new, stat_shape)
+    l_scr[at] = jnp.broadcast_to(l_new, stat_shape)
 
 
-def finalize_softmax(acc, m_scr, l_scr):
+def finalize_softmax(acc, m_scr, l_scr, idx=()):
     """(normalized output [rows, D], logsumexp [rows, 1]) after the last block.
 
     Rows whose every lane was masked (l == 0) normalize against a tiny floor
     instead of dividing by zero — they come out ~0, never NaN, which is what
     lets inactive serving slots ride the same dispatch as live ones.
     """
-    l = l_scr[:, 0:1]
+    at = (*idx, slice(None))
+    l = l_scr[at][:, 0:1]
     safe_l = jnp.maximum(l, 1e-30)
-    lse = (m_scr[:, 0:1] + jnp.log(safe_l)).astype(jnp.float32)
-    return acc[:] / safe_l, lse
+    lse = (m_scr[at][:, 0:1] + jnp.log(safe_l)).astype(jnp.float32)
+    return acc[at] / safe_l, lse

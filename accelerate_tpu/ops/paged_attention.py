@@ -27,14 +27,18 @@ Page-walk contract (mirrors the engine's host-side conventions, paging.py):
   - Rows whose every lane is masked normalize against a tiny floor
     (`finalize_softmax`), never NaN — inactive slots ride the same dispatch.
 
-Both kernels are single-program-multiple-rows: grid ``(B, Hkv, pages)``, GQA
-handled by grouping the ``G = Hq // Hkv`` query heads of each KV head into the
-kernel's row axis (the pool is shared per KV head; repeating it like the XLA
-path does would multiply the very HBM traffic this kernel exists to remove).
+Decode and verify are ONE kernel (decode is the ``s == 1`` block): grid
+``(B, pages)``, each step streaming one whole pool page — every KV head in one
+DMA — and looping the heads in VMEM. GQA is handled by grouping the
+``G = Hq // Hkv`` query heads of each KV head into the kernel's row axis (the
+pool is shared per KV head; repeating it like the XLA path does would multiply
+the very HBM traffic this kernel exists to remove). Every block's last two
+dims are the operand's own, which is what the chip's compiler requires
+(`tests/test_tpu_compile.py` compiles the kernel for a described v5e).
 
 QUANTIZED pools (``k_scale``/``v_scale`` operands, `ops/quantization.py`):
 int8/fp8 pages stream through the same BlockSpec walk at 1 byte/value, their
-per-page-per-head scales ride (1, 1) SMEM blocks picked by the SAME
+per-page-per-head scales ride ``[1, Hkv]`` blocks picked by the SAME
 ``tbl[b, p]`` index map, and the dequant is one fused multiply on the
 VMEM-resident block before the score dot — the cache crosses HBM quantized,
 fp32 exists only inside the accumulator. Token-identical to the XLA
@@ -64,114 +68,77 @@ from .flash_common import (
 )
 
 
-def _decode_kernel(
-    tbl_ref, q_ref, k_ref, v_ref, *rest,
-    scale, page_size, quantized,
+def _paged_kernel(
+    tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+    scale, page_size, hkv, quantized,
 ):
-    """Single-query paged decode: one [G, D] query group per (batch, kv head),
-    streaming that row's pages through the online-softmax accumulator.
+    """One (slot, page) step of the page walk, all KV heads at once.
 
-    Quantized pools (`quantized=True`) thread two extra refs — the page's
-    per-head K/V scales ((1, 1) SMEM scalars picked by the same
-    ``tbl[b, p]`` index map that streams the page) — and the dequant is one
-    fused multiply on the VMEM-resident block: the page crosses HBM at
-    int8/fp8 width, fp32 exists only inside the accumulator."""
+    The page arrives as one ``[page_size, Hkv, D]`` block — the pool's own
+    trailing dims, so the block satisfies the Mosaic tiling rule at any head
+    count or page size and the pool needs no layout change; head ``h`` is a
+    strided sublane read of it. Rows are the ``s*G`` (query position, GQA
+    group) pairs of a KV head; row ``r`` attends ``cols <= limit[r]``, which
+    covers single-query decode (``s == 1``) and the speculative verify block
+    alike, so the speculative accept loop sees the XLA verify path's greedy
+    tokens. Quantized pools thread the page's ``[1, Hkv]`` K/V scales, picked
+    by the same ``tbl[b, p]`` index map that streams the page; the dequant is
+    one multiply on the VMEM-resident block, so the page crosses HBM at
+    int8/fp8 width and fp32 exists only inside the accumulator."""
     from jax.experimental import pallas as pl
 
     if quantized:
-        ks_ref, vs_ref, pos_ref, len_ref, o_ref, acc, m_scr, l_scr = rest
+        ks_ref, vs_ref, lim_ref, o_ref, acc, m_scr, l_scr = rest
     else:
-        pos_ref, len_ref, o_ref, acc, m_scr, l_scr = rest
+        lim_ref, o_ref, acc, m_scr, l_scr = rest
 
-    pi = pl.program_id(2)
-    n_pages = pl.num_programs(2)
+    bi = pl.program_id(0)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
         init_softmax_state(acc, m_scr, l_scr)
 
-    length = len_ref[0, 0]  # row's valid cache length (pos + 1)
+    length = len_ref[bi]  # max attend limit + 1: pages past it hold no query's keys
     base = pi * page_size
 
     @pl.when(base < length)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page_size, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [G, page_size]
-        cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < length, s, NEG_INF)
-        online_softmax_update(s, v, acc, m_scr, l_scr)
+        limit = lim_ref[0]  # [rows, 1] int32 per-row attend limits
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32)  # [rows, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # [page_size, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = k * ks_ref[0, :, h : h + 1]
+                v = v * vs_ref[0, :, h : h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # [rows, page_size]
+            cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(cols <= limit, s, NEG_INF)
+            online_softmax_update(s, v, acc, m_scr, l_scr, idx=(h,))
 
-    @pl.when(pi == n_pages - 1)
+    @pl.when(pi == pl.num_programs(1) - 1)
     def _finish():
-        out, _ = finalize_softmax(acc, m_scr, l_scr)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def _verify_kernel(
-    tbl_ref, q_ref, k_ref, v_ref, *rest,
-    scale, page_size, s_block, gsize, quantized,
-):
-    """Block-verify paged attention: the [B, s] multi-token twin. Rows are the
-    s*G (query position, GQA group) pairs of one (batch, kv head); query j
-    attends ``cols <= positions[b, j]`` — the accepted prefix plus the block
-    tokens at or before it, exactly the per-query mask of the XLA verify
-    path, so the speculative accept loop sees identical greedy tokens.
-    Quantized pools dequant the streamed page in VMEM exactly like
-    `_decode_kernel`."""
-    from jax.experimental import pallas as pl
-
-    if quantized:
-        ks_ref, vs_ref, pos_ref, len_ref, o_ref, acc, m_scr, l_scr = rest
-    else:
-        pos_ref, len_ref, o_ref, acc, m_scr, l_scr = rest
-
-    pi = pl.program_id(2)
-    n_pages = pl.num_programs(2)
-
-    @pl.when(pi == 0)
-    def _init():
-        init_softmax_state(acc, m_scr, l_scr)
-
-    length = len_ref[0, 0]  # max block position + 1: pages past it hold no query's keys
-    base = pi * page_size
-
-    @pl.when(base < length)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)  # [s*G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page_size, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [s*G, page_size]
-        pos = pos_ref[0]  # [s] int32 per-query attend limits
-        s3 = s.reshape(s_block, gsize, page_size)
-        cols = base + jax.lax.broadcasted_iota(jnp.int32, s3.shape, 2)
-        s3 = jnp.where(cols <= pos[:, None, None], s3, NEG_INF)
-        online_softmax_update(s3.reshape(s_block * gsize, page_size), v, acc, m_scr, l_scr)
-
-    @pl.when(pi == n_pages - 1)
-    def _finish():
-        out, _ = finalize_softmax(acc, m_scr, l_scr)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        for h in range(hkv):
+            out, _ = finalize_softmax(acc, m_scr, l_scr, idx=(h,))
+            o_ref[0, h] = out.astype(o_ref.dtype)
 
 
 def _paged_call(
-    q, k_pool, v_pool, page_table, positions, scale, interpret, kernel_for,
+    q, k_pool, v_pool, page_table, positions, scale, interpret,
     k_scale=None, v_scale=None,
 ):
     """Shared wrapper: layout transforms, prefetch grid spec, pallas_call.
     `k_scale`/`v_scale` ([num_pages, Hkv] f32 traced operands, never Python
-    scalars — TPU117) switch the kernels into fused-dequant mode."""
+    scalars — TPU117) switch the kernel into fused-dequant mode.
+
+    Every block's last two dims equal the operand's own (the Mosaic tiling
+    rule), so the kernel compiles for the chip at any page size / head
+    count: per-slot scalars (page table, page-skip bound) ride SMEM as
+    scalar-prefetch operands, everything else is a whole-trailing-dims VMEM
+    block."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -194,7 +161,7 @@ def _paged_call(
     pages_per_slot = page_table.shape[-1]
 
     # [B, s, Hq, D] -> [B, Hkv, s*G, D]: query head h*G+g rides kv head h's
-    # walk (the row ordering the kernels' reshape masks assume).
+    # walk; row j*G+g of a head carries query position j's attend limit.
     qt = (
         q.reshape(b, s, hkv, gsize, d)
         .transpose(0, 2, 1, 3, 4)
@@ -202,45 +169,40 @@ def _paged_call(
     )
     table = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, n_pages_pool - 1)
     pos = jnp.asarray(positions, jnp.int32).reshape(b, s)
-    # Scalar page-skip bound per row, SMEM-friendly [B, 1].
-    lengths = (jnp.max(pos, axis=1, keepdims=True) + 1).astype(jnp.int32)
+    limits = jnp.repeat(pos, gsize, axis=1)[:, :, None]  # [B, rows, 1]
+    lengths = jnp.max(pos, axis=1) + 1  # [B] scalar page-skip bound per slot
 
-    kernel = kernel_for(
-        scale=float(scale), page_size=page_size, s_block=s, gsize=gsize,
+    kernel = functools.partial(
+        _paged_kernel, scale=float(scale), page_size=page_size, hkv=hkv,
         quantized=quantized,
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, rows, d), lambda bi, hi, pi, tbl: (bi, hi, 0, 0)),  # q
-        # THE fused page-table gather: grid step (b, h, p) streams pool page
-        # table[b, p] for kv head h. Table entries past a slot's reservation
-        # are the scratch page — identical consecutive block indices, which
-        # the Pallas pipeline fetches once, not P times.
-        pl.BlockSpec((1, page_size, 1, d), lambda bi, hi, pi, tbl: (tbl[bi, pi], 0, hi, 0)),
-        pl.BlockSpec((1, page_size, 1, d), lambda bi, hi, pi, tbl: (tbl[bi, pi], 0, hi, 0)),
-    ]
+    q_spec = pl.BlockSpec((1, hkv, rows, d), lambda bi, pi, tbl, ln: (bi, 0, 0, 0))
+    # THE fused page-table gather: grid step (b, p) streams pool page
+    # table[b, p], every KV head in one DMA. Table entries past a slot's
+    # reservation are the scratch page — identical consecutive block indices,
+    # which the Pallas pipeline fetches once, not P times.
+    page_spec = pl.BlockSpec(
+        (1, page_size, hkv, d), lambda bi, pi, tbl, ln: (tbl[bi, pi], 0, 0, 0)
+    )
+    in_specs = [q_spec, page_spec, page_spec]
     operands = [qt, k_pool, v_pool]
     if quantized:
         # The streamed page's per-head scales ride the SAME tbl[b, p] walk as
         # the page itself — the dequant is fused, not a second gather.
-        scale_spec = pl.BlockSpec(
-            (1, 1), lambda bi, hi, pi, tbl: (tbl[bi, pi], hi), memory_space=pltpu.SMEM
-        )
+        scale_spec = pl.BlockSpec((1, 1, hkv), lambda bi, pi, tbl, ln: (tbl[bi, pi], 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-    in_specs += [
-        pl.BlockSpec((1, s), lambda bi, hi, pi, tbl: (bi, 0)),  # per-query limits
-        pl.BlockSpec((1, 1), lambda bi, hi, pi, tbl: (bi, 0), memory_space=pltpu.SMEM),
-    ]
-    operands += [pos, lengths]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
+    in_specs.append(pl.BlockSpec((1, rows, 1), lambda bi, pi, tbl, ln: (bi, 0, 0)))
+    operands.append(limits)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, pages_per_slot),
+        num_scalar_prefetch=2,
+        grid=(b, pages_per_slot),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, d), lambda bi, hi, pi, tbl: (bi, hi, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, LANE), jnp.float32),
-            pltpu.VMEM((rows, LANE), jnp.float32),
+            pltpu.VMEM((hkv, rows, d), jnp.float32),
+            pltpu.VMEM((hkv, rows, LANE), jnp.float32),
+            pltpu.VMEM((hkv, rows, LANE), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -248,7 +210,8 @@ def _paged_call(
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(table, *operands)
+        name="paged_attention",
+    )(table, lengths, *operands)
     return (
         out.reshape(b, hkv, s, gsize, d).transpose(0, 2, 1, 3, 4).reshape(b, s, hq, d)
     )
@@ -284,20 +247,10 @@ def paged_decode_attention(
     Returns [B, 1, Hq, D], token-identical to the XLA gather oracle
     (dequantize-on-read for quantized pools).
     """
-    b = q.shape[0]
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(f"paged_decode_attention takes [B, 1, Hq, D] queries, got {q.shape}")
-    if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
-    pos = jnp.asarray(positions, jnp.int32).reshape(b, 1)
-
-    def kernel_for(scale, page_size, s_block, gsize, quantized):
-        return functools.partial(
-            _decode_kernel, scale=scale, page_size=page_size, quantized=quantized
-        )
-
-    return _paged_call(
-        q, k_pool, v_pool, page_table, pos, scale, _auto_interpret(interpret), kernel_for,
+    return paged_verify_attention(
+        q, k_pool, v_pool, page_table, positions, scale=scale, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale,
     )
 
@@ -325,13 +278,7 @@ def paged_verify_attention(
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
 
-    def kernel_for(scale, page_size, s_block, gsize, quantized):
-        return functools.partial(
-            _verify_kernel, scale=scale, page_size=page_size, s_block=s_block,
-            gsize=gsize, quantized=quantized,
-        )
-
     return _paged_call(
-        q, k_pool, v_pool, page_table, positions, scale, _auto_interpret(interpret), kernel_for,
+        q, k_pool, v_pool, page_table, positions, scale, _auto_interpret(interpret),
         k_scale=k_scale, v_scale=v_scale,
     )

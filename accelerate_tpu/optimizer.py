@@ -923,7 +923,9 @@ class AcceleratedOptimizer:
             # aliased executable — the multi-device pipeline tests hit it
             # deterministically). Donation is a memory optimization, not a
             # semantics change, so drop it on CPU; TPU/GPU keep the aliasing.
-            donate = () if jax.default_backend() == "cpu" else (0, 1, 2)
+            # The grads are NOT donated: no output aliases them, and on the chip
+            # XLA answers "Some donated buffers were not usable" for every leaf.
+            donate = () if jax.default_backend() == "cpu" else (0, 1)
             self._jit_cache["update"] = jax.jit(_update, donate_argnums=donate)
         return self._jit_cache["update"]
 

@@ -36,12 +36,6 @@ import numpy as np
 PIPELINE_SHARDING_RULES = [(r"(^|/)(enc_|dec_)?layers(/|$)", ("stage",))]
 
 
-def _shard_map():
-    from .sharding import compat_shard_map
-
-    return compat_shard_map
-
-
 def stack_layer_params(layers):
     """Stack a list of per-layer param pytrees into one pytree with leading [L] axes."""
     import jax
@@ -61,8 +55,9 @@ def stack_layer_params_sharded(layers, sharding_tree):
     assembling each device's [L/S, ...] slice individually so the full stacked model
     never materializes on one device.
 
-    Deliberately NOT `jit(stack_layer_params, out_shardings=...)`: on jax 0.4.37's
-    forced-host-device CPU backend the GSPMD-partitioned concatenate reads its input
+    Deliberately NOT `jit(stack_layer_params, out_shardings=...)`: on the
+    forced-host-device CPU backend (seen under jax 0.4.37, not re-checked since)
+    the GSPMD-partitioned concatenate reads its input
     with a stride equal to the size of the replicated mesh axes (out.flat[k] ==
     ref.flat[data_size * k]), silently corrupting every stacked buffer — the root
     cause of the pipeline parity drift."""
@@ -296,9 +291,7 @@ def _build_local_fns(
         out_mb, out_i, valid)` folds the last stage's finished carry into an
         accumulator. The scan carry is (streams_tuple, acc)."""
         prelude_p, tail_p = params["prelude"], params["tail"]
-        from .ring_attention import _axis_size
-
-        S = _axis_size("stage")
+        S = lax.axis_size("stage")
         idx = lax.axis_index("stage")
         mbs = _split_microbatches(batch, M)
         mb0 = _index_mb(mbs, jnp.int32(0))
@@ -367,9 +360,10 @@ def _build_local_fns(
         returns give exact token-weighted parity with the unpipelined loss.
 
         Both entries are shape (1,), NOT 0-d: every float scalar in this body risks
-        becoming a 0-d residual of the differentiated shard_map, and jax 0.4.37's
-        partial-eval misses scalar-residual promotion for forwarded residuals — the
-        transpose then fails _check_names (leading-axis sharding on a 0-d aval)."""
+        becoming a 0-d residual of the differentiated shard_map, whose partial-eval
+        missed scalar-residual promotion for forwarded residuals when this was
+        written (jax 0.4.37) — the transpose then failed _check_names
+        (leading-axis sharding on a 0-d aval). Shape (1,) is right on every version."""
         out = spec.loss_on_logits(spec.tail(tail_p, carry), mb)
         if isinstance(out, tuple):
             s, w = out
@@ -400,9 +394,8 @@ def _build_local_fns(
         weight = lax.psum(weight, axes)
         # Return the unreduced (loss_sum, weight) pair; the caller divides OUTSIDE the
         # shard_map. Keeping the division inside makes `weight` a 0-d float residual of
-        # the differentiated body, and jax 0.4.37's shard_map partial-eval under remat
-        # skips its scalar-residual promotion — the transpose then dies with a
-        # _SpecError (leading-axis names on a 0-d aval).
+        # the differentiated body — see _loss_pair for why no float scalar may
+        # become a residual of the differentiated shard_map.
         return loss_sum, weight
 
     def local_forward(params, batch):
@@ -601,7 +594,8 @@ class PipelinedModel:
         )
         from .sharding import data_spec as _data_spec
 
-        shard_map = _shard_map()
+        from jax import shard_map
+
         data_spec = _data_spec(mesh)
         param_specs = {
             "prelude": P(),

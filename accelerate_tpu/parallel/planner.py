@@ -48,6 +48,7 @@ __all__ = [
     "ShardingPlan",
     "StagePlan",
     "CHIPS",
+    "chip_for_device_kind",
     "default_chip",
     "candidate_specs",
     "emit_rules",
@@ -96,13 +97,38 @@ CHIPS: Dict[str, ChipSpec] = {
 }
 
 
+#: `jax.Device.device_kind` -> `CHIPS` key. "TPU v5 lite" is what a v5e chip
+#: reports. A kind missing here is an error, never a default: pricing a 16 GB
+#: chip with another generation's HBM accepts plans that do not fit.
+CHIP_BY_DEVICE_KIND: Dict[str, str] = {
+    "cpu": "cpu-smoke",
+    "TPU v4": "tpu-v4",
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v5e": "tpu-v5e",
+    "TPU v5": "tpu-v5p",
+    "TPU v5p": "tpu-v5p",
+}
+
+
+def chip_for_device_kind(device_kind: str) -> ChipSpec:
+    """The `ChipSpec` a device of `device_kind` is priced with."""
+    key = CHIP_BY_DEVICE_KIND.get(device_kind)
+    if key is None:
+        raise ValueError(
+            f"no ChipSpec for device_kind {device_kind!r} (known: "
+            f"{sorted(CHIP_BY_DEVICE_KIND)}); add it to CHIPS/CHIP_BY_DEVICE_KIND "
+            "or pass chip= explicitly"
+        )
+    return CHIPS[key]
+
+
 def default_chip() -> ChipSpec:
-    """Chip constants for the CURRENT backend: real TPU generations price as
-    tpu-v4 unless overridden; the CPU interpret/smoke backend gets CPU-ish
-    constants so bench predictions are comparable to measurements."""
+    """Chip constants for the CURRENT backend, resolved from the first
+    device's `device_kind` (the CPU interpret/smoke backend gets CPU-ish
+    constants so bench predictions are comparable to measurements)."""
     import jax
 
-    return CHIPS["cpu-smoke"] if jax.default_backend() == "cpu" else CHIPS["tpu-v4"]
+    return chip_for_device_kind(jax.devices()[0].device_kind)
 
 
 @dataclass(frozen=True)

@@ -24,20 +24,6 @@ from typing import Optional
 import numpy as np
 
 
-def _axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside `shard_map`.
-
-    `lax.psum(1, axis)` constant-folds to a Python int (no collective is
-    emitted), which the ring loops need for `range()` unrolling. Newer jax
-    exposes `lax.axis_size`; this works on every version in support."""
-    from jax import lax
-
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def segment_mask(q_seg, kv_seg):
     """Packed-sequence attention mask: [B, Sq] x [B, Skv] ids -> [B, 1, Sq, Skv]
     boolean, True where the ids match. The ONE definition of segment semantics —
@@ -105,7 +91,7 @@ def ring_attention(
     import jax.numpy as jnp
     from jax import lax
 
-    axis_size = _axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     axis_index = lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     skv = k.shape[1]
@@ -157,7 +143,7 @@ def _ring_flash_fwd_impl(qt, kt, vt, axis_name, causal, scale, block_q, block_k,
 
     from ..ops.flash_attention import LANE, NEG_INF, _fwd_call
 
-    axis_size = _axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     BH, S, D = qt.shape
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
@@ -220,7 +206,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, block_q, block_k, interpret, r
     from ..ops.flash_attention import LANE, _bwd_call
 
     qt, kt, vt, out, lse = res
-    axis_size = _axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     BH, S, D = qt.shape
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
@@ -384,9 +370,8 @@ def sequence_parallel_attention(
     forces the einsum block path, `True` asserts flash eligibility.
     """
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from .sharding import compat_shard_map as shard_map
 
     if mesh is None:
         from ..state import AcceleratorState
@@ -436,8 +421,7 @@ def sequence_parallel_attention(
 
     if mode == "ring" and use_flash:
         # Varying-mesh-axes checking off: pallas_call inside shard_map can't
-        # annotate its outputs; correctness is covered by the parity tests
-        # (compat_shard_map handles the check_vma/check_rep rename).
+        # annotate its outputs; correctness is covered by the parity tests.
         inner_flash = functools.partial(
             ring_flash_attention, axis_name=seq_axis, causal=causal, scale=scale
         )

@@ -263,7 +263,7 @@ def with_memory_kind(shardings, memory_kind: str):
 
 #: Memory kinds that live in host RAM, preferred order. Accelerator backends
 #: expose a distinct "pinned_host" space next to device HBM; CPU backends
-#: (jax >= 0.4.3x) expose only "unpinned_host", which IS their default memory
+#: expose only "unpinned_host", which IS their default memory
 #: — offload placement there is a no-op by construction, which keeps the
 #: offload code paths (kind-stamped shardings, streaming device_puts, chunked
 #: group programs) fully exercisable on the CPU test tier.
@@ -394,28 +394,6 @@ def constrain_activation(x):
 # layout the model families' rule tables already describe. Everything here is
 # spec derivation — XLA/GSPMD inserts the collectives once params, KV pools
 # and scale pools are placed with these NamedShardings.
-
-
-def compat_shard_map(fn, **kwargs):
-    """`shard_map` across jax versions — the ONE compat shim (pipeline, ring
-    flash, and the TP paged-attention wrap all route here): current jax
-    exposes `jax.shard_map`, older versions `jax.experimental.shard_map`;
-    the replication-checking kwarg renamed `check_rep` -> `check_vma` along
-    the way. Callers pass the current spelling (`check_vma`); exactly one
-    retry swaps the kwarg on TypeError, so an unrelated TypeError from the
-    wrapped call still propagates."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax keeps it under experimental
-        from jax.experimental.shard_map import shard_map
-
-    if "check_vma" in kwargs:
-        try:
-            return shard_map(fn, **kwargs)
-        except TypeError:
-            kwargs = dict(kwargs)
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return shard_map(fn, **kwargs)
 
 
 def serving_tp_mesh(tp: int, devices=None, group: int = 0):

@@ -285,17 +285,16 @@ class ReplicaSet:
 
     # ------------------------------------------------------------------ build
     def _engine_kwargs_for(self, index: int) -> Dict[str, Any]:
-        """Per-replica engine kwargs: a tensor-parallel fleet (`engine_kwargs`
-        ``tp=N``) gives each replica its OWN N-device submesh — replica r
-        spans devices ``[r*N, (r+1)*N)`` when the topology has that many,
-        wrapping around otherwise (`parallel.sharding.serving_tp_mesh`
-        resolves the group; CPU smoke meshes oversubscribe harmlessly). A
-        mesh-spanning engine is just one replica, so replication over TP
-        groups composes with health routing, retries, hedging and rolling
-        swaps for free."""
+        """Per-replica engine kwargs: each replica gets its OWN device group
+        (`tp_group=index`) — a tensor-parallel fleet (`engine_kwargs`
+        ``tp=N``) spans devices ``[r*N, (r+1)*N)``, a tp=1 fleet puts replica
+        r on device r — when the topology has that many, wrapping around
+        otherwise (`parallel.sharding.serving_tp_mesh` resolves the group;
+        CPU smoke meshes oversubscribe harmlessly). A mesh-spanning engine is
+        just one replica, so replication over device groups composes with
+        health routing, retries, hedging and rolling swaps for free."""
         kwargs = dict(self.engine_kwargs)
-        tp = int(kwargs.get("tp", 1) or 1)
-        if tp > 1 and kwargs.get("tp_devices") is None:
+        if kwargs.get("tp_devices") is None:
             kwargs.setdefault("tp_group", index)
         return kwargs
 

@@ -308,6 +308,14 @@ class ContinuousBatcher:
                     "\"model\" axis"
                 )
             self.mesh = serving_tp_mesh(self.tp, devices=tp_devices, group=tp_group)
+        elif tp_devices is not None or int(tp_group) % jax.device_count():
+            # A tp=1 replica of an in-process fleet (the router hands replica
+            # r `tp_group=r`): pin it to its own device through a 1-device
+            # submesh, so N replicas on an N-chip host do not pile onto chip
+            # 0. Group 0 stays mesh-free — the plain single-device engine.
+            from .parallel.sharding import serving_tp_mesh
+
+            self.mesh = serving_tp_mesh(1, devices=tp_devices, group=tp_group)
         self.num_slots = int(num_slots)
         self.max_length = int(max_length or base.max_position_embeddings)
         self.chunk_size = int(chunk_size)
@@ -1562,7 +1570,9 @@ class ContinuousBatcher:
                         _operand(req.repetition_penalty, np.float32),
                         self._rng,
                     )
-                token = int(token)
+                # jax.device_get, not int(token): on a TPU only device_get is an
+                # EXPLICIT device-to-host read an armed transfer guard admits.
+                token = int(jax.device_get(token))
                 ispan.end()
             except Exception as exc:  # noqa: BLE001 — isolate, report, keep serving
                 ispan.annotate(error=repr(exc)).end()
@@ -1647,6 +1657,35 @@ class ContinuousBatcher:
         del self.results[request_id]
         return result
 
+    def _chunk_operands(self) -> List[Any]:
+        """The decode-chunk dispatch's operand list: device-resident state
+        (params, donated cache/presence, rng) plus this cycle's push of the
+        small per-slot host mirrors."""
+        args = [
+            self.params,
+            self._cache,
+            self._presence,
+            jnp.asarray(self._token),
+            jnp.asarray(self._pos),
+            jnp.asarray(self._active),
+            jnp.asarray(self._rem),
+            jnp.asarray(self._eos),
+            jnp.asarray(self._temp),
+            jnp.asarray(self._pen),
+            jnp.asarray(self._page_table),
+            self._rng,
+        ]
+        if self.speculative:
+            args.append(jnp.asarray(self._history))
+        return args
+
+    def lower_decode_chunk(self):
+        """`jax.stages.Lowered` of THE decode executable at the engine's live
+        operand signature, without dispatching it — how chip_smoke.py proves
+        from the program text which attention read is inside it
+        (a `tpu_custom_call` for the compiled Pallas kernels)."""
+        return self._chunk_fn.lower(*self._chunk_operands())
+
     def step(self) -> List[Tuple[int, List[int]]]:
         """One serving cycle: expire deadlines → admit → one decode-chunk
         dispatch → drain the packed stream. Returns `(request_id, new_tokens)`
@@ -1670,33 +1709,20 @@ class ContinuousBatcher:
         )
         pos_before = self._pos.copy()  # spec: where each slot's drained tokens append
         try:
-            args = [
-                self.params,
-                self._cache,
-                self._presence,
-                jnp.asarray(self._token),
-                jnp.asarray(self._pos),
-                jnp.asarray(self._active),
-                jnp.asarray(self._rem),
-                jnp.asarray(self._eos),
-                jnp.asarray(self._temp),
-                jnp.asarray(self._pen),
-                jnp.asarray(self._page_table),
-                self._rng,
-            ]
-            if self.speculative:
-                args.append(jnp.asarray(self._history))
-            out = self._chunk_fn(*args)
-            # np.array (copy): np.asarray of a jax buffer is a READ-ONLY view,
-            # and these mirrors are written in-place at the next admission.
-            # The readback sits INSIDE the try: on accelerators the dispatch
-            # is async, so a device-side failure surfaces here rather than at
-            # the enqueue above — it is the same blast radius.
+            out = self._chunk_fn(*self._chunk_operands())
+            # ONE explicit drain of everything the host needs (jax.device_get:
+            # np.asarray / int() on a device value are IMPLICIT reads, which an
+            # armed transfer guard rejects on a TPU). The readback sits INSIDE
+            # the try: on accelerators the dispatch is async, so a device-side
+            # failure surfaces here rather than at the enqueue above — it is
+            # the same blast radius.
             new_cache, new_presence = out[0], out[1]
-            token, pos, active, rem = (np.array(x) for x in out[2:6])
-            packed, count = np.asarray(out[7]), int(out[8])
-            spec_emitted = np.asarray(out[9]) if self.speculative else None
-            spec_proposed = np.asarray(out[10]) if self.speculative else None
+            host = jax.device_get(out[2:6] + out[7:])
+            # np.array (copy): these mirrors are written in-place at the next
+            # admission, and a drained buffer may be a read-only view.
+            token, pos, active, rem = (np.array(x) for x in host[:4])
+            packed, count = host[4], int(host[5])
+            spec_emitted, spec_proposed = host[6:8] if self.speculative else (None, None)
         except Exception as exc:  # noqa: BLE001
             if self.trace_guard is not None:
                 self.trace_guard.observe(exc)

@@ -7,7 +7,7 @@ two ad-hoc context managers you had to wrap around code *in advance*.
 outside at the moment something looks wrong:
 
   - **touch-file trigger**: `touch <log_dir>/CAPTURE` on the host (over ssh,
-    from a watchdog script like tpu_watch_r05.sh) — the next `poll()` at a step
+    from a watchdog script) — the next `poll()` at a step
     boundary consumes the file and opens a fixed-duration trace window;
   - **signal trigger**: SIGUSR2 latches a capture request (same degrade-to-warn
     off the main thread as `fault_tolerance.PreemptionHandler`);

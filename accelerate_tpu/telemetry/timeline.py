@@ -255,13 +255,13 @@ class StepTimeline:
             and unaccounted >= self.unaccounted_warn_s
             and not self._unaccounted_warned
         ):
-            # Once per accounting window: the r05-hang signature surfacing at
-            # RUNTIME instead of waiting for a postmortem to read the ledger.
+            # Once per accounting window: a stalled host surfacing at RUNTIME
+            # instead of waiting for a postmortem to read the ledger.
             self._unaccounted_warned = True
             logger.warning(
                 "goodput: %.1fs of wall clock is unaccounted (total %.1fs, productive "
                 "%.1fs, lost %.1fs) — the host is stalling outside the instrumented "
-                "loop (backend init, a dead tunnel, or an opaque hang)",
+                "loop (backend init or an opaque hang)",
                 unaccounted, total, productive, lost_total,
             )
             if self.tracer is not None:

@@ -138,7 +138,8 @@ DEVICE_PEAK_FLOPS = {
 
 
 def get_device_peak_flops(device_kind: str, dtype: str = "bf16") -> float:
-    """Best-effort peak FLOP/s for a device kind; 0.0 when unknown (MFU then unreported).
+    """Peak FLOP/s for a device kind. An unknown kind raises: a silent 0.0
+    used to make MFU quietly go unreported on the measurement path.
 
     Longest name first, so "TPU v5 lite" matches its own entry rather than "TPU v5".
     """
@@ -146,7 +147,42 @@ def get_device_peak_flops(device_kind: str, dtype: str = "bf16") -> float:
     for k in sorted(DEVICE_PEAK_FLOPS, key=len, reverse=True):
         if kind.startswith(k.lower()) or k.lower() in kind:
             return DEVICE_PEAK_FLOPS[k]
-    return 0.0
+    raise ValueError(
+        f"no peak FLOP/s for device_kind {device_kind!r} (known: "
+        f"{sorted(DEVICE_PEAK_FLOPS)}); add it to DEVICE_PEAK_FLOPS with its source"
+    )
+
+
+#: JAX's own variable for the persistent compile cache. When it is set, JAX reads
+#: it and this package sets nothing: whoever runs the program places the cache.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: The one fixed cache directory otherwise, inside the checkout (`.gitignore`
+#: lists it). The directory is part of the cache key's lookup, so it is never a
+#: temp name, a pid or a timestamp.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
+
+
+def configure_compile_cache(cache_dir: str | None = None) -> str:
+    """Resolve the persistent compile cache directory — the ONE place that
+    decides it (chip_smoke.py, bench.py, the benchmarks, the worker entry,
+    `Accelerator(compilation_config=...)` and tests/conftest.py all call this).
+
+    `JAX_COMPILATION_CACHE_DIR` set: nothing is set in code, and neither
+    `cache_dir` nor any default overrides it. Unset: `cache_dir` when the
+    caller passed one, else `DEFAULT_COMPILE_CACHE_DIR`. Returns the directory
+    in use."""
+    from_env = os.environ.get(COMPILE_CACHE_ENV)
+    if from_env:
+        return from_env
+    import jax
+
+    path = cache_dir or DEFAULT_COMPILE_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def set_host_device_count_flag(flags: str, num_devices: int, override: bool = True) -> str:

@@ -18,10 +18,7 @@ def extract_model_from_parallel(model, keep_fp32_wrapper: bool = True):
     utils/other.py:56 which unwraps DDP/FSDP/DeepSpeed/compiled wrappers).
 
     Under GSPMD there is exactly one wrapper type: `PreparedModel`."""
-    try:
-        from ..modeling import PreparedModel
-    except ImportError:
-        return model
+    from ..modeling import PreparedModel
 
     if isinstance(model, PreparedModel):
         return model.module if model.module is not None else model

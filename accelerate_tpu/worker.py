@@ -832,7 +832,9 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     from .serving import ContinuousBatcher
     from .telemetry.tracing import default_tracer
+    from .utils.environment import configure_compile_cache
 
+    configure_compile_cache()
     tracer = default_tracer()
     spec = json.loads(args.spec_json)
     engine_kwargs = json.loads(args.engine_json)
@@ -1107,6 +1109,27 @@ class _TransportDown(WorkerGone):
     `step()` catches it specifically to drive the reconnect loop."""
 
 
+def _refuse_spawn_beside_held_tpu(worker_id: int) -> None:
+    """A TPU belongs to one process at a time. Once THIS process has initialised
+    a TPU backend it holds the chip(s), and a worker spawned from it inherits
+    the environment, needs the same chip, and fails or hangs at start-up — so
+    refuse at once, with the reason. Nothing pins a worker to one chip of a
+    multi-chip host yet (ROADMAP D8/R5); in-process replicas (`Router(
+    out_of_process=False)`, one device each) are the multi-replica path on one
+    host, and `connect=` adopts workers launched where they own their chips."""
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"cannot spawn serving worker {worker_id}: this process already holds "
+            "the TPU (a chip belongs to one process at a time), so a child that "
+            "needs it would fail or hang. Use in-process replicas "
+            "(out_of_process=False), or launch the workers first — from a parent "
+            "that has not touched JAX — and adopt them with connect=HOST:PORT."
+        )
+
+
 class SubprocessEngine:
     """Client proxy for one out-of-process engine worker, exposing the exact
     `ContinuousBatcher` surface so `Router` needs no routing changes.
@@ -1203,6 +1226,7 @@ class SubprocessEngine:
                 _parse_hostport(connect), proc=None, worker_id=self.worker_id
             )
         else:
+            _refuse_spawn_beside_held_tpu(self.worker_id)
             run_env = dict(os.environ if env is None else env)
             run_env[WORKER_ID_ENV] = str(self.worker_id)
             pkg_parent = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

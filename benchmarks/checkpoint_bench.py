@@ -156,6 +156,9 @@ def main(argv=None):
             f"--steps {args.steps} < --save-every {args.save_every}: the run would never save"
         )
 
+    from accelerate_tpu.utils.environment import configure_compile_cache
+
+    configure_compile_cache()
     scratch = args.base_dir or tempfile.mkdtemp(prefix="accelerate_tpu_ckpt_bench_")
     try:
         results = {}

@@ -7,7 +7,7 @@ closed vocabulary with a known generative process, so a from-scratch bert-tiny
 can provably learn it — and a *mis-trained* one provably cannot (the mutation
 audit in tests/test_integration_gates.py).
 
-Task design (all constraints found empirically — see MEASUREMENTS_r04.md):
+Task design (all constraints found empirically on the CPU test tier):
 - A sentence is 5 active-voice slots: `adj noun verb adj noun`
   ("big dog chases small cat").
 - Every word has exactly one synonym partner. A POSITIVE pair rewrites each
