@@ -935,10 +935,14 @@ class Accelerator:
         arithmetic, no device sync), wrapped in a `train.step` span, heartbeats
         the hang watchdog, and polls the ProfilerManager + flight recorder so
         touch-file / SIGUSR2 capture and trace-dump requests are served at
-        step boundaries. Exceptions (including TraceGuardViolation from
-        analyze mode) propagate untouched."""
+        step boundaries. The wait for the step's batch, which the loader whose
+        pass the step runs in has stamped, becomes the timeline's "data_wait"
+        phase afterwards: a loader opens no step, so a pass that feeds no
+        `train_step()` (an evaluation) is nobody's step. Exceptions (including
+        TraceGuardViolation from analyze mode) propagate untouched."""
         timeline, profiler = self.timeline, self.profiler
         tracer, recorder = self.tracer, self.tracer.recorder
+        gradient_state = self.gradient_state
         counter = {"step": 0}
 
         def instrumented(*args, **kwargs):
@@ -948,6 +952,11 @@ class Accelerator:
             ):
                 out = step_fn(*args, **kwargs)
             timeline.step_done(out)
+            loader = gradient_state.active_dataloader
+            waited = getattr(loader, "data_wait_s", None)
+            if waited is not None:
+                loader.data_wait_s = None  # one step a batch takes it
+                timeline.record_phase("data_wait", waited)
             recorder.heartbeat()
             profiler.poll()
             recorder.poll()

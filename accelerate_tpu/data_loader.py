@@ -24,6 +24,7 @@ fetches the global batch and broadcasts; other hosts slice their shard.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import math
 import queue
@@ -34,6 +35,7 @@ import numpy as np
 
 from .logging import get_logger
 from .state import AcceleratorState, GradientState, PartialState
+from .telemetry.tracing import default_tracer
 from .utils.imports import is_torch_available
 from .utils.operations import recursively_apply, send_to_device
 from .utils.random import synchronize_rng_states
@@ -471,8 +473,37 @@ def batch_to_global_array(batch, sharding):
     return recursively_apply(_make, batch, test_type=_is_leaf)
 
 
+def _stamps_wait(iter_fn):
+    """A loader's `__iter__` whose every `next()` leaves in `self.data_wait_s`
+    how long the consumer waited for that batch, under a `train.data_wait`
+    annotation in a profiler capture. The loader keeps no timeline: whoever
+    does takes the stamp from the loader whose pass it is in
+    (`Accelerator.train_step`, after its dispatch)."""
+
+    @functools.wraps(iter_fn)
+    def __iter__(self):
+        batches = iter_fn(self)
+        tracer = default_tracer()
+        try:
+            while True:
+                with tracer.span("train.data_wait", category="train", record=False) as wait:
+                    try:
+                        batch = next(batches)
+                    except StopIteration:
+                        return
+                self.data_wait_s = wait.duration_s
+                yield batch
+        finally:
+            batches.close()
+
+    return __iter__
+
+
 class DataLoaderStateMixin:
     """begin/end hooks registering with GradientState (reference data_loader.py:355-388)."""
+
+    #: Seconds the consumer waited for the newest batch (see `_stamps_wait`).
+    data_wait_s: Optional[float] = None
 
     def __init_subclass__(cls, **kwargs):
         cls.end_of_dataloader = False
@@ -603,6 +634,7 @@ class DataLoaderShard(DataLoaderStateMixin):
                 continue
             yield batch
 
+    @_stamps_wait
     def __iter__(self):
         if self.rng_types is not None:
             synchronize_rng_states(self.rng_types, self.synchronized_generator)
@@ -825,6 +857,7 @@ class DataLoaderDispatcher(DataLoaderStateMixin):
             return self.slice_fn(batch, slice(start, start + per_proc), self.state.process_index, self.state.num_processes)
         return slice_tensors(batch, slice(start, start + per_proc))
 
+    @_stamps_wait
     def __iter__(self):
         self.set_epoch(self.iteration)
         self.begin()

@@ -163,6 +163,7 @@ def update_slot_cache(
 
     Returns `(k_full, v_full, decode_mask)` like `update_decode_cache`.
     """
+    import jax
     import jax.numpy as jnp
 
     b, s, h, d = k.shape
@@ -183,17 +184,18 @@ def update_slot_cache(
         # attention as the contiguous layout — pool order never leaks. This
         # materialized gather is the HBM cost `slot_cache_attention`'s
         # "pallas_paged" path exists to remove; it stays as the parity oracle.
-        k_pages = jnp.take(pool_k, table, axis=0)  # [B, P, ps, h, d]
-        v_pages = jnp.take(pool_v, table, axis=0)
-        if scales is not None:
-            # Dequantize-on-read: scale[table] broadcasts per page per head.
-            from .quantization import dequantize_kv_pages
+        with jax.named_scope("kv_read"):
+            k_pages = jnp.take(pool_k, table, axis=0)  # [B, P, ps, h, d]
+            v_pages = jnp.take(pool_v, table, axis=0)
+            if scales is not None:
+                # Dequantize-on-read: scale[table] broadcasts per page per head.
+                from .quantization import dequantize_kv_pages
 
-            k_scale, v_scale = scales
-            k_pages = dequantize_kv_pages(k_pages, jnp.take(k_scale, table, axis=0), k.dtype)
-            v_pages = dequantize_kv_pages(v_pages, jnp.take(v_scale, table, axis=0), v.dtype)
-        k_full = k_pages.reshape(b, L, h, d)
-        v_full = v_pages.reshape(b, L, h, d)
+                k_scale, v_scale = scales
+                k_pages = dequantize_kv_pages(k_pages, jnp.take(k_scale, table, axis=0), k.dtype)
+                v_pages = dequantize_kv_pages(v_pages, jnp.take(v_scale, table, axis=0), v.dtype)
+            k_full = k_pages.reshape(b, L, h, d)
+            v_full = v_pages.reshape(b, L, h, d)
         cols = jnp.arange(L)[None, None, :]
         decode_mask = (cols <= pos[:, :, None])[:, None, :, :]  # [B, 1, s, L]
         return k_full, v_full, decode_mask
@@ -207,8 +209,9 @@ def update_slot_cache(
     cached_v = module.variable("cache", "cached_value", jnp.zeros, (b, L, h, d), v.dtype)
     pos = jnp.clip(positions, 0, L - 1).astype(jnp.int32)  # [B, s]
     rows = jnp.arange(b)[:, None]
-    cached_k.value = cached_k.value.at[rows, pos].set(k)
-    cached_v.value = cached_v.value.at[rows, pos].set(v)
+    with jax.named_scope("kv_write"):
+        cached_k.value = cached_k.value.at[rows, pos].set(k)
+        cached_v.value = cached_v.value.at[rows, pos].set(v)
     cols = jnp.arange(L)[None, None, :]
     decode_mask = (cols <= pos[:, :, None])[:, None, :, :]  # [B, 1, s, L]
     return cached_k.value, cached_v.value, decode_mask
@@ -225,6 +228,7 @@ def _write_slot_pool(
     path (`update_slot_cache`) and the fused kernel path
     (`slot_cache_attention`) so the two implementations can never disagree
     about where K/V lives — or what scale it was stored under."""
+    import jax
     import jax.numpy as jnp
 
     from .quantization import kv_quant_spec, quantized_pool_write
@@ -248,17 +252,19 @@ def _write_slot_pool(
     pid = jnp.take_along_axis(table, page_slot, axis=1)  # [B, s]
     off = pos % page_size
     if spec is None:
-        pool_k.value = pool_k.value.at[pid, off].set(k)
-        pool_v.value = pool_v.value.at[pid, off].set(v)
+        with jax.named_scope("kv_write"):
+            pool_k.value = pool_k.value.at[pid, off].set(k)
+            pool_v.value = pool_v.value.at[pid, off].set(v)
         return pool_k.value, pool_v.value, pos, table, None
     k_scale = module.variable("cache", "key_scale", jnp.zeros, (num_pages, h), jnp.float32)
     v_scale = module.variable("cache", "value_scale", jnp.zeros, (num_pages, h), jnp.float32)
-    pool_k.value, k_scale.value = quantized_pool_write(
-        pool_k.value, k_scale.value, k, pid, off, spec
-    )
-    pool_v.value, v_scale.value = quantized_pool_write(
-        pool_v.value, v_scale.value, v, pid, off, spec
-    )
+    with jax.named_scope("kv_write"):
+        pool_k.value, k_scale.value = quantized_pool_write(
+            pool_k.value, k_scale.value, k, pid, off, spec
+        )
+        pool_v.value, v_scale.value = quantized_pool_write(
+            pool_v.value, v_scale.value, v, pid, off, spec
+        )
     return pool_k.value, pool_v.value, pos, table, (k_scale.value, v_scale.value)
 
 

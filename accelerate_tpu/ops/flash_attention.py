@@ -118,6 +118,7 @@ def _fwd_call(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -249,6 +250,7 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     dq_kernel = functools.partial(
@@ -269,6 +271,7 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

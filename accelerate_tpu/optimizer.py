@@ -74,18 +74,19 @@ def unscale_and_clip(grads, inv_scale, max_norm: Optional[float], use_scaler: bo
     # Preserve the gradient dtype: inv_scale is a strong fp32 scalar and would
     # silently promote bf16 grads (and through them the whole update + params)
     # to fp32, breaking param_dtype storage.
-    grads = jax.tree_util.tree_map(lambda g: (g * inv_scale).astype(g.dtype), grads)
-    finite = jnp.array(True)
-    if use_scaler:
-        finite = jnp.all(
-            jnp.stack([jnp.all(jnp.isfinite(g)) for g in jax.tree_util.tree_leaves(grads)])
-        )
-    if max_norm is not None:
-        norm = jnp.sqrt(
-            sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree_util.tree_leaves(grads))
-        )
-        factor = jnp.minimum(1.0, max_norm / (norm + 1e-6))
-        grads = jax.tree_util.tree_map(lambda g: (g * factor).astype(g.dtype), grads)
+    with jax.named_scope("clip"):
+        grads = jax.tree_util.tree_map(lambda g: (g * inv_scale).astype(g.dtype), grads)
+        finite = jnp.array(True)
+        if use_scaler:
+            finite = jnp.all(
+                jnp.stack([jnp.all(jnp.isfinite(g)) for g in jax.tree_util.tree_leaves(grads)])
+            )
+        if max_norm is not None:
+            norm = jnp.sqrt(
+                sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree_util.tree_leaves(grads))
+            )
+            factor = jnp.minimum(1.0, max_norm / (norm + 1e-6))
+            grads = jax.tree_util.tree_map(lambda g: (g * factor).astype(g.dtype), grads)
     return grads, finite
 
 
@@ -98,18 +99,19 @@ def update_and_revert(tx, params, opt_state, grads, lr_override, finite, use_sca
 
     if lr_override is not None and hasattr(opt_state, "hyperparams"):
         opt_state = opt_state._replace(hyperparams={**opt_state.hyperparams, "learning_rate": lr_override})
-    updates, new_opt_state = tx.update(grads, opt_state, params)
-    new_params = jax.tree_util.tree_map(lambda p, u: (p + u).astype(p.dtype), params, updates)
-    if use_scaler:
-        # Skipped step on non-finite grads: keep the old state untouched.
-        new_params = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(finite, new, old), new_params, params
-        )
-        new_opt_state = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(finite, new, old) if hasattr(new, "shape") else new,
-            new_opt_state,
-            opt_state,
-        )
+    with jax.named_scope("optimizer_update"):
+        updates, new_opt_state = tx.update(grads, opt_state, params)
+        new_params = jax.tree_util.tree_map(lambda p, u: (p + u).astype(p.dtype), params, updates)
+        if use_scaler:
+            # Skipped step on non-finite grads: keep the old state untouched.
+            new_params = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(finite, new, old), new_params, params
+            )
+            new_opt_state = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(finite, new, old) if hasattr(new, "shape") else new,
+                new_opt_state,
+                opt_state,
+            )
     return new_params, new_opt_state
 
 

@@ -162,11 +162,15 @@ class RequestResult:
     request_id: int
     tokens: List[int] = field(default_factory=list)
     arrival_time: float = 0.0
-    first_token_time: Optional[float] = None  # host perf_counter at insert return
+    # Host perf_counter when the first token was ON THE HOST (its insert's
+    # readback returned). The client gets it when that step() returns, a decode
+    # chunk later: the request span's `handed_back` event carries the difference.
+    first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     finished: bool = False
     finish_reason: Optional[str] = None  # one of FINISH_REASONS
     error: Optional[str] = None  # repr of the exception when finish_reason == "error"
+    submit_time: Optional[float] = None  # host perf_counter when submit() accepted it
 
 
 class ContinuousBatcher:
@@ -609,26 +613,29 @@ class ContinuousBatcher:
             "serving_slot_utilization", help="slots_in_use / num_slots"
         )
         self._m_ttft = self.metrics.histogram(
-            "serving_ttft_seconds", help="submit() -> first token (host wall clock)"
+            "serving_ttft_seconds",
+            help="submit() -> the step() that carries the first token returns (host wall clock)",
         )
         self._m_inter_token = self.metrics.histogram(
             "serving_inter_token_seconds",
             help="per-token gap between stream drains for an in-flight slot",
         )
         self._m_chunk_latency = self.metrics.histogram(
-            "serving_chunk_seconds", help="decode-chunk dispatch+drain wall clock"
+            "serving_chunk_seconds",
+            help="decode-chunk wall clock: operand push, dispatch and readback (the `serve.decode_chunk` span)",
         )
-        self._submit_times: Dict[int, float] = {}  # request_id -> submit() perf_counter
         self._slot_last_event = np.zeros(S, np.float64)  # last drain time per slot
 
-        # Request-scoped tracing (telemetry.tracing): one `serve.request` span
-        # per accepted request from submit() to its terminal finish_reason,
-        # child `serve.insert` spans per admission dispatch, and batched
-        # `serve.decode_chunk` spans with slot annotations. Everything is
-        # host-clock arithmetic — the spans ride the same zero-device-sync
-        # discipline as the metrics (and TPU112 lints the annotations).
+        # Tracing (telemetry.tracing): one `serve.request` span per accepted
+        # request from submit() to its terminal finish_reason, and one
+        # `serve.step` tree per step() — see step(). Everything is host-clock
+        # arithmetic — the spans ride the same zero-device-sync discipline as
+        # the metrics (and TPU112 lints the annotations).
         self.tracer = tracer if tracer is not None else default_tracer()
         self._request_spans: Dict[int, Any] = {}
+        # Requests whose first token reached the host in the step() now
+        # running: handed back, and timed, when it returns (_hand_back).
+        self._first_tokens: List[RequestResult] = []
 
         # Page-pool + prefix-cache telemetry and the host allocator itself
         # (paged engines only; all updates are host-scalar arithmetic).
@@ -869,21 +876,23 @@ class ContinuousBatcher:
             self.trace_counts["insert"] += 1
             positions = jnp.broadcast_to(jnp.arange(bucket)[None, :], (1, bucket))
             logits, small = prefill(params, input_ids, positions)
-            cache = constrain_tp_cache(tree_scatter_rows(cache, small, slot), mesh)
+            with jax.named_scope("kv_write"):
+                cache = constrain_tp_cache(tree_scatter_rows(cache, small, slot), mesh)
             # Logits at the REAL last prompt token (right-bucket pads sit above
             # it and, being causal, never influenced it).
             last = jax.lax.dynamic_slice_in_dim(logits, real_len - 1, 1, axis=1)[:, 0, :]
             row = None
-            if use_pen:
-                valid = jnp.arange(bucket) < real_len
-                row = jnp.zeros((V,), bool).at[input_ids[0]].max(valid)
-                last = _apply_repetition_penalty(last, row[None, :], penalty)
-            token, rng = _sample(last, config, rng, temperature)
-            if use_pen:
-                row = row.at[token[0]].set(True)
-                presence = jax.lax.dynamic_update_slice(
-                    presence, row[None, :], (jnp.asarray(slot, jnp.int32), jnp.int32(0))
-                )
+            with jax.named_scope("sample"):
+                if use_pen:
+                    valid = jnp.arange(bucket) < real_len
+                    row = jnp.zeros((V,), bool).at[input_ids[0]].max(valid)
+                    last = _apply_repetition_penalty(last, row[None, :], penalty)
+                token, rng = _sample(last, config, rng, temperature)
+                if use_pen:
+                    row = row.at[token[0]].set(True)
+                    presence = jax.lax.dynamic_update_slice(
+                        presence, row[None, :], (jnp.asarray(slot, jnp.int32), jnp.int32(0))
+                    )
             return token[0], cache, presence, rng
 
         donate = (1, 2) if use_pen else (1,)
@@ -916,37 +925,40 @@ class ContinuousBatcher:
             matched_pages, page_row, slot, temperature, penalty, rng,
         ):
             self.trace_counts["insert"] += 1
-            dense = tree_gather_pages(pool_cache, dense_struct, page_row, matched_len)
+            with jax.named_scope("kv_read"):
+                dense = tree_gather_pages(pool_cache, dense_struct, page_row, matched_len)
             positions = matched_len + jnp.broadcast_to(jnp.arange(bucket)[None, :], (1, bucket))
             logits, dense = cached_prefill(params, dense, suffix_ids, positions)
             # Zero rows past the prompt before the write-back: the gather
             # resurrects a recycled page's stale content (never attended, but
             # a QUANTIZED scatter folds it into the boundary page's amax
             # scale, coarsening the real rows; tree_zero_cache_tail).
-            dense = tree_zero_cache_tail(dense, matched_len + real_len)
-            write_row = jnp.where(
-                jnp.arange(P) < matched_pages, jnp.int32(SCRATCH_PAGE), page_row
-            )
-            pool_cache = constrain_tp_cache(
-                tree_scatter_pages(pool_cache, dense, write_row), mesh
-            )
+            with jax.named_scope("kv_write"):
+                dense = tree_zero_cache_tail(dense, matched_len + real_len)
+                write_row = jnp.where(
+                    jnp.arange(P) < matched_pages, jnp.int32(SCRATCH_PAGE), page_row
+                )
+                pool_cache = constrain_tp_cache(
+                    tree_scatter_pages(pool_cache, dense, write_row), mesh
+                )
             # Logits at the REAL last suffix token (bucket pads sit above it
             # and, being causal, never influenced it).
             last = jax.lax.dynamic_slice_in_dim(logits, real_len - 1, 1, axis=1)[:, 0, :]
             row = None
-            if use_pen:
-                # Penalty engines run with the prefix cache OFF (matched_len is
-                # always 0), so the "suffix" here is the whole prompt and the
-                # presence row seeds exactly as on the contiguous path.
-                valid = jnp.arange(bucket) < real_len
-                row = jnp.zeros((V,), bool).at[suffix_ids[0]].max(valid)
-                last = _apply_repetition_penalty(last, row[None, :], penalty)
-            token, rng = _sample(last, config, rng, temperature)
-            if use_pen:
-                row = row.at[token[0]].set(True)
-                presence = jax.lax.dynamic_update_slice(
-                    presence, row[None, :], (jnp.asarray(slot, jnp.int32), jnp.int32(0))
-                )
+            with jax.named_scope("sample"):
+                if use_pen:
+                    # Penalty engines run with the prefix cache OFF (matched_len is
+                    # always 0), so the "suffix" here is the whole prompt and the
+                    # presence row seeds exactly as on the contiguous path.
+                    valid = jnp.arange(bucket) < real_len
+                    row = jnp.zeros((V,), bool).at[suffix_ids[0]].max(valid)
+                    last = _apply_repetition_penalty(last, row[None, :], penalty)
+                token, rng = _sample(last, config, rng, temperature)
+                if use_pen:
+                    row = row.at[token[0]].set(True)
+                    presence = jax.lax.dynamic_update_slice(
+                        presence, row[None, :], (jnp.asarray(slot, jnp.int32), jnp.int32(0))
+                    )
             return token[0], pool_cache, presence, rng
 
         donate = (1, 2) if use_pen else (1,)
@@ -976,12 +988,13 @@ class ContinuousBatcher:
                     logits, cache = step_inner(params, cache, token, pos, page_table)
                 else:
                     logits, cache = step_inner(params, cache, token, pos)
-                if use_pen:
-                    logits = _apply_repetition_penalty(logits, presence, penalty[:, None])
-                nxt, rng = _sample(logits, config, rng, temperature[:, None])
-                nxt = jnp.where(active, nxt, jnp.int32(0))
-                if use_pen:
-                    presence = presence.at[jnp.arange(S), nxt].max(active)
+                with jax.named_scope("sample"):
+                    if use_pen:
+                        logits = _apply_repetition_penalty(logits, presence, penalty[:, None])
+                    nxt, rng = _sample(logits, config, rng, temperature[:, None])
+                    nxt = jnp.where(active, nxt, jnp.int32(0))
+                    if use_pen:
+                        presence = presence.at[jnp.arange(S), nxt].max(active)
                 emitted = active  # every active slot streams exactly one token
                 new_rem = jnp.where(active, rem - 1, rem)
                 hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
@@ -996,17 +1009,18 @@ class ContinuousBatcher:
             # Pack the [chunk, S] stream TIME-major so each slot's tokens stay in
             # order, valid entries first: composite sort key = invalid*N + time.
             n = chunk * S
-            flat_tok = toks.reshape(n)
-            flat_valid = valids.reshape(n)
-            flat_slot = jnp.broadcast_to(jnp.arange(S)[None, :], (chunk, S)).reshape(n)
-            order = jnp.argsort(jnp.where(flat_valid, 0, n) + jnp.arange(n))
-            packed = jnp.stack(
-                [
-                    jnp.where(flat_valid[order], flat_slot[order], -1),
-                    jnp.where(flat_valid[order], flat_tok[order], -1),
-                ],
-                axis=-1,
-            ).astype(jnp.int32)
+            with jax.named_scope("pack_stream"):
+                flat_tok = toks.reshape(n)
+                flat_valid = valids.reshape(n)
+                flat_slot = jnp.broadcast_to(jnp.arange(S)[None, :], (chunk, S)).reshape(n)
+                order = jnp.argsort(jnp.where(flat_valid, 0, n) + jnp.arange(n))
+                packed = jnp.stack(
+                    [
+                        jnp.where(flat_valid[order], flat_slot[order], -1),
+                        jnp.where(flat_valid[order], flat_tok[order], -1),
+                    ],
+                    axis=-1,
+                ).astype(jnp.int32)
             return cache, presence, token, pos, active, rem, rng, packed, flat_valid.sum()
 
         donate = (1, 2) if use_pen else (1,)
@@ -1100,17 +1114,18 @@ class ContinuousBatcher:
             # (row-major flatten keeps (iteration, block-index) order within a
             # slot), valid entries first — same composite key as the plain chunk.
             n = chunk * S * (k_draft + 1)
-            flat_tok = toks.reshape(n)
-            flat_valid = valids.reshape(n)
-            flat_slot = jnp.broadcast_to(rows[None, :, None], (chunk, S, k_draft + 1)).reshape(n)
-            order = jnp.argsort(jnp.where(flat_valid, 0, n) + jnp.arange(n))
-            packed = jnp.stack(
-                [
-                    jnp.where(flat_valid[order], flat_slot[order], -1),
-                    jnp.where(flat_valid[order], flat_tok[order], -1),
-                ],
-                axis=-1,
-            ).astype(jnp.int32)
+            with jax.named_scope("pack_stream"):
+                flat_tok = toks.reshape(n)
+                flat_valid = valids.reshape(n)
+                flat_slot = jnp.broadcast_to(rows[None, :, None], (chunk, S, k_draft + 1)).reshape(n)
+                order = jnp.argsort(jnp.where(flat_valid, 0, n) + jnp.arange(n))
+                packed = jnp.stack(
+                    [
+                        jnp.where(flat_valid[order], flat_slot[order], -1),
+                        jnp.where(flat_valid[order], flat_tok[order], -1),
+                    ],
+                    axis=-1,
+                ).astype(jnp.int32)
             return (
                 cache, presence, token, pos, active, rem, rng, packed, flat_valid.sum(),
                 emitted_mat, proposed_mat,
@@ -1302,12 +1317,12 @@ class ContinuousBatcher:
             raise QueueFull(
                 f"wait queue is at max_queue={self.max_queue}; shed load or retry later"
             )
+        now = time.perf_counter()
         self.results[request.request_id] = RequestResult(
-            request.request_id, arrival_time=request.arrival_time
+            request.request_id, arrival_time=request.arrival_time, submit_time=now
         )
         if request.deadline_s is not None:
-            self._deadlines[request.request_id] = time.perf_counter() + float(request.deadline_s)
-        self._submit_times[request.request_id] = time.perf_counter()
+            self._deadlines[request.request_id] = now + float(request.deadline_s)
         self._queue.append(dataclasses.replace(request, input_ids=ids))
         self._m_submitted.inc()
         span = self.tracer.start_span(
@@ -1397,15 +1412,18 @@ class ContinuousBatcher:
         result.finish_reason = reason
         if error is not None:
             result.error = error
-        span = self._request_spans.pop(result.request_id, None)
+        span = self._request_spans.get(result.request_id)
         if span is not None:
             span.annotate(finish_reason=reason, tokens=len(result.tokens))
             if error is not None:
                 span.annotate(error=error)
-            span.end()
+            # A request that ends in the step() that admitted it keeps its span
+            # open for the `handed_back` event: _hand_back() ends it.
+            if not any(r is result for r in self._first_tokens):
+                del self._request_spans[result.request_id]
+                span.end()
         self._m_finish[reason].inc()
         self._deadlines.pop(result.request_id, None)
-        self._submit_times.pop(result.request_id, None)
         if slot is not None:
             self._slot_request[slot] = None
             self._active[slot] = False
@@ -1468,8 +1486,12 @@ class ContinuousBatcher:
         Error isolation: an exception from ONE request's insert (transient device
         error, a prompt the compiled program rejects) finishes only that request
         with `finish_reason="error"` — the queue keeps draining and every other
-        slot keeps serving."""
+        slot keeps serving.
+
+        Returns the events and the seconds spent waiting for the device (the
+        inserts' first-token readbacks)."""
         events: List[Tuple[int, List[int]]] = []
+        device_wait_s = 0.0
         while self._queue and self.free_slots:
             req = self._queue.popleft()
             slot = self._slot_request.index(None)
@@ -1528,54 +1550,56 @@ class ContinuousBatcher:
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :p] = ids
             rspan = self._request_spans.get(req.request_id)
-            admit_t0 = time.perf_counter()
             if rspan is not None:
-                submitted_at = self._submit_times.get(req.request_id)
                 rspan.event(
                     "admitted", slot=slot, bucket=int(bucket),
-                    queue_wait_s=round(admit_t0 - submitted_at, 6) if submitted_at is not None else None,
+                    queue_wait_s=round(time.perf_counter() - result.submit_time, 6),
                     prefix_hit_pages=int(matched_pages), pages_reserved=len(pages),
                 )
-            ispan = self.tracer.start_span(
-                "serve.insert", category="serve", parent=rspan,
-                request_id=int(req.request_id), slot=slot, bucket=int(bucket),
-                suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
-            )
             try:
-                fn = self._insert_fn(bucket)
-                if self.paged:
-                    token, self._cache, self._presence, self._rng = fn(
-                        self.params,
-                        self._cache,
-                        self._presence,
-                        jnp.asarray(padded),
-                        _operand(p - matched_len, np.int32),
-                        _operand(matched_len, np.int32),
-                        _operand(matched_pages, np.int32),
-                        jnp.asarray(page_row),
-                        _operand(slot, np.int32),
-                        _operand(req.temperature, np.float32),
-                        _operand(req.repetition_penalty, np.float32),
-                        self._rng,
-                    )
-                else:
-                    token, self._cache, self._presence, self._rng = fn(
-                        self.params,
-                        self._cache,
-                        self._presence,
-                        jnp.asarray(padded),
-                        _operand(p, np.int32),
-                        _operand(slot, np.int32),
-                        _operand(req.temperature, np.float32),
-                        _operand(req.repetition_penalty, np.float32),
-                        self._rng,
-                    )
-                # jax.device_get, not int(token): on a TPU only device_get is an
-                # EXPLICIT device-to-host read an armed transfer guard admits.
-                token = int(jax.device_get(token))
-                ispan.end()
+                # A child of this step's `serve.step`; `request_id` ties it to
+                # its `serve.request` span. A failure is on it as `error`.
+                with self.tracer.span(
+                    "serve.insert", category="serve",
+                    request_id=int(req.request_id), slot=slot, bucket=int(bucket),
+                    suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
+                ) as ispan:
+                    fn = self._insert_fn(bucket)
+                    if self.paged:
+                        on_device, self._cache, self._presence, self._rng = fn(
+                            self.params,
+                            self._cache,
+                            self._presence,
+                            jnp.asarray(padded),
+                            _operand(p - matched_len, np.int32),
+                            _operand(matched_len, np.int32),
+                            _operand(matched_pages, np.int32),
+                            jnp.asarray(page_row),
+                            _operand(slot, np.int32),
+                            _operand(req.temperature, np.float32),
+                            _operand(req.repetition_penalty, np.float32),
+                            self._rng,
+                        )
+                    else:
+                        on_device, self._cache, self._presence, self._rng = fn(
+                            self.params,
+                            self._cache,
+                            self._presence,
+                            jnp.asarray(padded),
+                            _operand(p, np.int32),
+                            _operand(slot, np.int32),
+                            _operand(req.temperature, np.float32),
+                            _operand(req.repetition_penalty, np.float32),
+                            self._rng,
+                        )
+                    # jax.device_get, not int(token): on a TPU only device_get is an
+                    # EXPLICIT device-to-host read an armed transfer guard admits.
+                    with self.tracer.span("serve.insert.wait", category="serve", record=False) as wait:
+                        on_host = jax.device_get(on_device)
+                    ispan.annotate(device_wait_s=round(wait.duration_s, 6))
+                    device_wait_s += wait.duration_s
+                token = int(on_host)
             except Exception as exc:  # noqa: BLE001 — isolate, report, keep serving
-                ispan.annotate(error=repr(exc)).end()
                 if pages:
                     self.pool.release(pages)
                 if self.trace_guard is not None:
@@ -1605,14 +1629,12 @@ class ContinuousBatcher:
                 self.pool.register_prefix(hashes[: p // self.page_size], pages, start=matched_pages)
             now = time.perf_counter()
             self._m_inserts.inc()
-            submitted_at = self._submit_times.get(req.request_id)
-            if submitted_at is not None:
-                self._m_ttft.observe(now - submitted_at)
             if rspan is not None:
                 rspan.event("first_token")
             self._slot_last_event[slot] = now
             result.tokens.append(token)
             result.first_token_time = now
+            self._first_tokens.append(result)
             events.append((req.request_id, [token]))
 
             eos = -1 if req.eos_token_id is None else int(req.eos_token_id)
@@ -1644,7 +1666,27 @@ class ContinuousBatcher:
                     self.pool.release(pages)
                 self._finish(result, "eos" if token == eos else "length", now=now)
         self._update_occupancy_gauges()
-        return events
+        return events, device_wait_s
+
+    def _hand_back(self):
+        """Runs as step() returns, which is when a client gets the first token
+        of every request admitted in it: observes TTFT there, and marks the
+        request's span with `handed_back` (`held_s`: how long the engine sat on
+        a token it had; `ttft_s`: what the client waited since submit())."""
+        if not self._first_tokens:
+            return
+        now = time.perf_counter()
+        for result in self._first_tokens:
+            ttft_s = round(now - result.submit_time, 6)
+            self._m_ttft.observe(ttft_s)
+            span = self._request_spans.get(result.request_id)
+            if span is not None:
+                span.event("handed_back", held_s=round(now - result.first_token_time, 6),
+                           ttft_s=ttft_s)
+                if result.finished:  # _finish() left the span open for this event
+                    del self._request_spans[result.request_id]
+                    span.end()
+        self._first_tokens.clear()
 
     def release(self, request_id: int) -> RequestResult:
         """Drop a FINISHED request's result and free its id for reuse. `results`
@@ -1689,40 +1731,77 @@ class ContinuousBatcher:
     def step(self) -> List[Tuple[int, List[int]]]:
         """One serving cycle: expire deadlines → admit → one decode-chunk
         dispatch → drain the packed stream. Returns `(request_id, new_tokens)`
-        events in stream order (admissions' first tokens included)."""
+        events in stream order (admissions' first tokens included).
+
+        One span tree a step, each also a profiler annotation of its name:
+
+            serve.step ⊃ serve.admit ⊃ serve.insert (one an admission) ⊃ serve.insert.wait
+                       ⊃ serve.decode_chunk ⊃ serve.chunk.push, .dispatch, .wait
+                       ⊃ serve.drain
+
+        `serve.step`, `serve.insert` and `serve.decode_chunk` are recorded (the
+        flight recorder's ring stays proportional to dispatches); the others
+        are annotations whose seconds ride `serve.step` as `admit_s`, `push_s`,
+        `dispatch_s`, `drain_s` and `device_wait_s` (the inserts' and the
+        chunk's readbacks together). `host_s` is the rest of the step: its own
+        time, with the device's taken out."""
         if self._closed:
             return []
-        self._expire_deadlines()
-        events = self._admit()
-        if not self._active.any():
-            return events
+        tracer = self.tracer
+        with tracer.span("serve.step", category="serve") as step_span:
+            self._expire_deadlines()
+            with tracer.span("serve.admit", category="serve", record=False) as admit_span:
+                events, device_wait_s = self._admit()
+            step_span.annotate(inserts=len(self._first_tokens),
+                               admit_s=round(admit_span.duration_s, 6), push_s=0.0, dispatch_s=0.0)
+            drained = None
+            if self._active.any():
+                drained, chunk_wait_s = self._decode_chunk(step_span)
+                device_wait_s += chunk_wait_s
+            with tracer.span("serve.drain", category="serve", record=False) as drain_span:
+                if drained is not None:
+                    self._drain(events, *drained)
+                self._hand_back()
+            step_span.annotate(
+                drain_s=round(drain_span.duration_s, 6),
+                device_wait_s=round(device_wait_s, 6),
+                host_s=round(step_span.duration_s - device_wait_s, 6),
+            )
+        return events
+
+    def _decode_chunk(self, step_span):
+        """Push the slot mirrors, dispatch the decode chunk and read its
+        outputs back, under `serve.decode_chunk`; `push_s` and `dispatch_s` go
+        on `step_span`. Returns what _drain() takes — None when the dispatch
+        failed (every in-flight request then errored) — and the seconds the
+        readback waited for the device."""
+        tracer = self.tracer
         chunk_t0 = time.perf_counter()
-        # One batched span per chunk dispatch: every active request rides it,
-        # so the slot annotation (not N per-request spans) is what keeps the
-        # flight recorder's ring proportional to dispatches, not tokens.
-        chunk_span = self.tracer.start_span(
-            "serve.decode_chunk", category="serve",
-            chunk_size=self.chunk_size,
-            active_slots=int(self._active.sum()),
-            slots=",".join(str(i) for i in np.nonzero(self._active)[0]),
-            pages_in_use=self.pool.pages_in_use if self.paged else None,
-        )
         pos_before = self._pos.copy()  # spec: where each slot's drained tokens append
         try:
-            out = self._chunk_fn(*self._chunk_operands())
-            # ONE explicit drain of everything the host needs (jax.device_get:
-            # np.asarray / int() on a device value are IMPLICIT reads, which an
-            # armed transfer guard rejects on a TPU). The readback sits INSIDE
-            # the try: on accelerators the dispatch is async, so a device-side
-            # failure surfaces here rather than at the enqueue above — it is
-            # the same blast radius.
-            new_cache, new_presence = out[0], out[1]
-            host = jax.device_get(out[2:6] + out[7:])
-            # np.array (copy): these mirrors are written in-place at the next
-            # admission, and a drained buffer may be a read-only view.
-            token, pos, active, rem = (np.array(x) for x in host[:4])
-            packed, count = host[4], int(host[5])
-            spec_emitted, spec_proposed = host[6:8] if self.speculative else (None, None)
+            # One batched span per chunk dispatch: every active request rides
+            # it (not N per-request spans).
+            with tracer.span(
+                "serve.decode_chunk", category="serve",
+                chunk_size=self.chunk_size,
+                active_slots=int(self._active.sum()),
+                pages_in_use=self.pool.pages_in_use if self.paged else None,
+            ) as chunk_span:
+                with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:
+                    operands = self._chunk_operands()
+                with tracer.span("serve.chunk.dispatch", category="serve", record=False) as dispatch_span:
+                    out = self._chunk_fn(*operands)
+                # ONE explicit drain of everything the host needs (jax.device_get:
+                # np.asarray / int() on a device value are IMPLICIT reads, which an
+                # armed transfer guard rejects on a TPU). The readback sits INSIDE
+                # the try: on accelerators the dispatch is async, so a device-side
+                # failure surfaces here rather than at the enqueue above — it is
+                # the same blast radius.
+                with tracer.span("serve.chunk.wait", category="serve", record=False) as wait_span:
+                    host = jax.device_get(out[2:6] + out[7:])
+                step_span.annotate(push_s=round(push_span.duration_s, 6),
+                                   dispatch_s=round(dispatch_span.duration_s, 6))
+                chunk_span.annotate(**self._chunk_counts(host))
         except Exception as exc:  # noqa: BLE001
             if self.trace_guard is not None:
                 self.trace_guard.observe(exc)
@@ -1734,16 +1813,28 @@ class ContinuousBatcher:
             in_flight = sum(r is not None for r in self._slot_request)
             logger.warning("decode chunk dispatch failed; erroring %d in-flight request(s): %r",
                            in_flight, exc)
-            chunk_span.annotate(error=repr(exc)).end()
             self._abort_in_flight(exc)
-            return events
-        self._cache, self._presence = new_cache, new_presence
+            return None, 0.0
+        self._cache, self._presence = out[0], out[1]
         self._rng = out[6]
         self._m_chunks.inc()
         self._m_decode_steps.inc(self.chunk_size)
+        # The chunk's wall clock, through the readback: real device work, not
+        # just the async enqueue.
+        self._m_chunk_latency.observe(max(time.perf_counter() - chunk_t0, 0.0))
+        # np.array (copy): these mirrors are written in-place at the next
+        # admission, and a drained buffer may be a read-only view.
+        mirrors = tuple(np.array(x) for x in host[:4])
+        return (mirrors, host[4][: int(host[5])], pos_before), wait_span.duration_s
+
+    def _chunk_counts(self, host) -> Dict[str, int]:
+        """What a chunk's readback counts, for its span: the tokens streamed
+        and — speculative engines — the fold of the per-(iteration, slot)
+        emit/propose matrices into the spec ledger. Every count is a host
+        scalar off the readback."""
+        counts = {"tokens_streamed": int(host[5])}
         if self.speculative:
-            # Fold the chunk's per-(iteration, slot) emit/propose matrices into
-            # the spec ledger. Every count is a host scalar off the readback.
+            spec_emitted, spec_proposed = host[6:8]
             steps = int((spec_emitted > 0).sum())
             emitted_total = int(spec_emitted.sum())
             proposed_total = int(spec_proposed.sum())
@@ -1754,22 +1845,21 @@ class ContinuousBatcher:
             self._m_spec_rejected.inc(proposed_total - accepted)
             for v in spec_emitted[spec_emitted > 0]:
                 self._m_spec_hist.observe(float(v))
-            chunk_span.annotate(
+            counts.update(
                 spec_verify_steps=steps,
                 spec_tokens_emitted=emitted_total,
                 spec_drafts_accepted=accepted,
                 spec_drafts_proposed=proposed_total,
             )
+        return counts
 
+    def _drain(self, events: List[Tuple[int, List[int]]], mirrors, packed, pos_before):
+        """Hand the chunk's packed `(slot, token)` stream to its requests,
+        adopt the device's slot state, and finish what ended."""
         per_slot: Dict[int, List[int]] = {}
-        for slot, tok in packed[:count]:
+        for slot, tok in packed:
             per_slot.setdefault(int(slot), []).append(int(tok))
         now = time.perf_counter()
-        # The chunk's wall clock (dispatch + packed-stream drain) — measured
-        # AFTER the np.asarray readback above, so it covers real device work,
-        # not just the async enqueue.
-        self._m_chunk_latency.observe(max(now - chunk_t0, 0.0))
-        chunk_span.annotate(tokens_streamed=count).end()
         self.tracer.recorder.poll()  # serve the `trace dump` touch file
         for slot, toks in per_slot.items():
             result = self._slot_request[slot]
@@ -1785,26 +1875,21 @@ class ContinuousBatcher:
             events.append((result.request_id, toks))
             # Inter-token latency: the host drains a slot's tokens once per
             # chunk, so the per-token gap is the drain gap amortized over the
-            # tokens it delivered (one observation per token keeps histogram
-            # weights proportional to tokens served).
+            # tokens it delivered, weighted by them.
             last = self._slot_last_event[slot]
             if last > 0.0 and toks:
-                gap = max(now - last, 0.0) / len(toks)
-                for _ in toks:
-                    self._m_inter_token.observe(gap)
+                self._m_inter_token.observe(max(now - last, 0.0) / len(toks), count=len(toks))
             self._slot_last_event[slot] = now
 
         was_active = self._active
-        self._token, self._pos, self._rem = token, pos, rem
-        self._active = active
-        for slot in np.nonzero(was_active & ~active)[0]:
+        self._token, self._pos, self._active, self._rem = mirrors
+        for slot in np.nonzero(was_active & ~self._active)[0]:
             result = self._slot_request[slot]
             if result is not None:
                 reason = (
                     "eos" if result.tokens and result.tokens[-1] == self._eos[slot] else "length"
                 )
                 self._finish(result, reason, now=now, slot=slot)
-        return events
 
     def run(self, requests: Optional[List[Request]] = None) -> Dict[int, np.ndarray]:
         """Drive to completion: submit `requests` (if given), loop `step()` until

@@ -167,13 +167,15 @@ class Histogram(_Instrument):
         self._sum = 0.0
         self._count = 0
 
-    def observe(self, value: float):
+    def observe(self, value: float, count: int = 1):
+        """Record `value`, `count` times over in one update (a chunk's tokens
+        all share one per-token gap)."""
         value = _check_scalar(value)
         idx = bisect_left(self.bucket_bounds, value)
         with self._lock:
-            self._counts[idx] += 1
-            self._sum += value
-            self._count += 1
+            self._counts[idx] += count
+            self._sum += value * count
+            self._count += count
 
     @property
     def count(self) -> int:

@@ -17,6 +17,10 @@ train step loops:
   - **no jax import**: this module is pure stdlib, so host-side tools (the
     `accelerate-tpu trace` CLI, the chaos runner's invariant checks) can read
     and stitch traces without an accelerator stack.
+  - **one clock with the profiler**: where jax is ALREADY loaded, every scoped
+    span (`Tracer.span`) also enters a `jax.profiler.TraceAnnotation` of its
+    name, so the program's spans sit on the host plane of any profiler
+    capture, on the profiler's timestamps, beside the device's operations.
   - **bounded memory**: the tracer itself holds only the active-span stack;
     completed spans go to the recorder's fixed-capacity ring.
 
@@ -40,6 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -67,6 +72,15 @@ def _check_attrs(attrs: Dict[str, Any]) -> Dict[str, Any]:
                 "conversion here would hide a device sync"
             )
     return dict(attrs)
+
+
+def _profiler_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` named as the span — a null context in
+    a process that has not loaded jax (this module never imports it). The
+    annotation carries the name alone: attributes stay in the recorded span.
+    Outside a capture, entering one costs a flag test."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return contextlib.nullcontext() if profiler is None else profiler.TraceAnnotation(name)
 
 
 def new_id() -> str:
@@ -107,10 +121,16 @@ class Span:
         """Record an instant event inside this span (serialized with it)."""
         self.events.append({
             "name": name,
-            "t_unix": self._tracer._anchor + self._tracer._clock(),
+            "t_unix": self._tracer.now(),
             "attrs": _check_attrs(attrs),
         })
         return self
+
+    @property
+    def duration_s(self) -> float:
+        """Seconds from start to end (to now, while the span is open)."""
+        end = self.end_s if self.end_s is not None else self._tracer._clock()
+        return end - self.start_s
 
     def end(self):
         """Close the span and hand it to the recorder. Idempotent — a span
@@ -210,6 +230,13 @@ class Tracer:
         self._local = threading.local()
         self._compile_listener_installed = False
 
+    def now(self) -> float:
+        """This tracer's clock, on the timeline of every record it writes
+        (`start_unix`, `end_unix`, an event's `t_unix`). A reader that holds
+        instants of another clock reads both clocks once and shifts by the
+        difference."""
+        return self._anchor + self._clock()
+
     # ------------------------------------------------------------------ context
     def _stack(self) -> List[Span]:
         stack = getattr(self._local, "stack", None)
@@ -240,20 +267,33 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, category: Optional[str] = None,
-             parent: Optional[Span] = None, **attrs):
+             parent: Optional[Span] = None, record: bool = True, **attrs):
         """Scoped span: pushed on this thread's stack (children nest under it),
-        always ended — exceptions mark the span failed and propagate."""
-        span = self.start_span(name, category=category, parent=parent, **attrs)
-        stack = self._stack()
-        stack.append(span)
+        always ended — exceptions mark the span failed and propagate. Also a
+        profiler annotation of the same name (see `_profiler_annotation`).
+
+        `record=False` keeps the span out of the recorder and off the stack:
+        an annotation in a capture and a `duration_s` for the caller, who
+        carries the seconds as an attribute of the recorded parent. A span
+        opened inside it is a child of the nearest recorded span."""
+        if record:
+            span = self.start_span(name, category=category, parent=parent, **attrs)
+            stack = self._stack()
+            stack.append(span)
+        else:
+            span = Span(self, name, category or self.category, self._parent_id(parent), attrs)
         try:
-            yield span
+            with _profiler_annotation(name):
+                yield span
         except BaseException as exc:
             span.attrs.setdefault("error", repr(exc))
             raise
         finally:
-            stack.pop()
-            span.end()
+            if record:
+                stack.pop()
+                span.end()
+            else:
+                span.end_s = self._clock()
 
     @contextlib.contextmanager
     def activate(self, span: Span):
@@ -279,7 +319,7 @@ class Tracer:
             "parent_id": self._parent_id(None),
             "pid": self.pid,
             "tid": threading.get_ident(),
-            "t_unix": self._anchor + self._clock(),
+            "t_unix": self.now(),
             "attrs": _check_attrs(attrs),
         }
         if self.enabled:

@@ -107,7 +107,8 @@ class FusedTrainStep:
                 loss, aux = out if isinstance(out, tuple) else (out, None)
                 return loss * scale, (loss, aux)
 
-            return jax.grad(scaled, has_aux=True)(params)
+            with jax.named_scope("forward_backward"):
+                return jax.grad(scaled, has_aux=True)(params)
 
         def split_leading(batch, n, what):
             def _split(x):

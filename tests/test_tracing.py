@@ -5,9 +5,11 @@ step, cross-process trace-id propagation through a real Supervisor child, the
 serving engine's submit->finish span coverage, the goodput unaccounted-time
 alarm, and the chaos smoke-serve dump carrying injected faults as events."""
 
+import contextlib
 import json
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -272,14 +274,308 @@ def test_serving_request_lifecycle_spans():
     for record in requests.values():
         assert record["attrs"]["finish_reason"] == "length"
         assert record["attrs"]["tokens"] == 5
-        assert [e["name"] for e in record["events"]] == ["submitted", "admitted", "first_token"]
+        assert [e["name"] for e in record["events"]] == [
+            "submitted", "admitted", "first_token", "handed_back"]
         admitted = record["events"][1]["attrs"]
         assert admitted["queue_wait_s"] >= 0 and "pages_reserved" in admitted
     inserts = [r for r in records if r["name"] == "serve.insert"]
-    assert len(inserts) == 4
-    assert all(r["parent_id"] in {q["span_id"] for q in requests.values()} for r in inserts)
+    assert sorted(r["attrs"]["request_id"] for r in inserts) == [0, 1, 2, 3]
     chunks = [r for r in records if r["name"] == "serve.decode_chunk"]
-    assert chunks and all("slots" in r["attrs"] for r in chunks)
+    assert chunks and all(r["attrs"]["active_slots"] >= 1 for r in chunks)
+
+
+def test_serving_step_span_tree_and_hand_back():
+    """One `serve.step` a step() over its inserts and its decode chunk; the
+    step's host and device-wait seconds add up to it; every request is handed
+    back once, and TTFT is observed there (a one-token request and one that
+    ends in its first chunk included: their spans wait for the event)."""
+    from accelerate_tpu.serving import ContinuousBatcher, Request
+
+    recorder = FlightRecorder()
+    tracer = Tracer(recorder=recorder, category="serve")
+    engine = ContinuousBatcher(_tiny_llama(), num_slots=2, max_length=64, chunk_size=4,
+                               tracer=tracer)
+    rng = np.random.default_rng(1)
+    lengths = [9, 1, 3, 6, 12]
+    for i, n in enumerate(lengths):
+        engine.submit(Request(i, rng.integers(1, 128, (6,)).astype(np.int32), max_new_tokens=n))
+    stepped = 0
+    while engine.pending:
+        engine.step()
+        stepped += 1
+    results = dict(engine.results)
+    engine.close()
+
+    records = recorder.records()
+    steps = [r for r in records if r["name"] == "serve.step"]
+    assert len(steps) == stepped
+    by_parent = {}
+    for r in records:
+        if r["name"] in ("serve.insert", "serve.decode_chunk"):
+            by_parent.setdefault(r["parent_id"], []).append(r)
+    assert set(by_parent) <= {s["span_id"] for s in steps}  # nothing outside a step
+    assert all(s["parent_id"] is None for s in steps)
+    for step in steps:
+        attrs = step["attrs"]
+        children = by_parent.get(step["span_id"], [])
+        inserts = [c for c in children if c["name"] == "serve.insert"]
+        chunks = [c for c in children if c["name"] == "serve.decode_chunk"]
+        assert len(inserts) == attrs["inserts"] and len(chunks) <= 1
+        assert attrs["host_s"] + attrs["device_wait_s"] == pytest.approx(step["duration_s"], abs=2e-4)
+        parts = (attrs["admit_s"] + attrs["push_s"] + attrs["dispatch_s"] + attrs["drain_s"]
+                 + (chunks[0]["duration_s"] - attrs["push_s"] - attrs["dispatch_s"] if chunks else 0.0))
+        assert parts <= step["duration_s"] + 2e-4
+        assert attrs["device_wait_s"] >= sum(c["attrs"]["device_wait_s"] for c in inserts) - 1e-5
+        for child in children:
+            assert step["start_unix"] <= child["start_unix"] and child["end_unix"] <= step["end_unix"]
+    assert sum(s["attrs"]["inserts"] for s in steps) == len(lengths)
+    assert not [r for r in records if r["name"] in (
+        "serve.admit", "serve.insert.wait", "serve.chunk.push", "serve.chunk.dispatch",
+        "serve.chunk.wait", "serve.drain")]  # annotations only
+
+    requests = {r["attrs"]["request_id"]: r for r in records if r["name"] == "serve.request"}
+    ttft_sum = 0.0
+    for rid, n in enumerate(lengths):
+        events = {e["name"]: e for e in requests[rid]["events"]}
+        assert [e["name"] for e in requests[rid]["events"]].count("handed_back") == 1
+        handed = events["handed_back"]["attrs"]
+        assert handed["held_s"] >= 0 and handed["ttft_s"] >= handed["held_s"]
+        assert events["admitted"]["attrs"]["queue_wait_s"] <= handed["ttft_s"]
+        assert requests[rid]["attrs"]["tokens"] == n
+        ttft_sum += handed["ttft_s"]
+        result = results[rid]
+        assert result.submit_time <= result.first_token_time <= result.finish_time
+        # the token was on the host `held_s` before the step returned
+        assert handed["ttft_s"] == pytest.approx(
+            result.first_token_time - result.submit_time + handed["held_s"], abs=1e-5)
+    ttft = engine.metrics.get("serving_ttft_seconds")
+    assert ttft.count == len(lengths)
+    assert ttft.sum == pytest.approx(ttft_sum, abs=1e-9)
+
+
+def test_engine_ttft_is_the_routers_on_one_replica():
+    """Both histograms are observed where the first token is handed back, so
+    on one replica they agree to well within one insert (they used to differ
+    by a decode chunk)."""
+    from accelerate_tpu.router import Router
+    from accelerate_tpu.serving import Request
+
+    recorder = FlightRecorder()
+    tracer = Tracer(recorder=recorder, category="serve")
+    router = Router(_tiny_llama(), replicas=1, num_slots=2, max_length=64, chunk_size=4,
+                    tracer=tracer)
+    rng = np.random.default_rng(2)
+    for i in range(5):
+        router.submit(Request(i, rng.integers(1, 128, (6,)).astype(np.int32), max_new_tokens=6))
+    router.drain()
+    engine = router.replica_set.replicas[0].engine
+    mine = engine.metrics.get("serving_ttft_seconds")
+    routers = router.metrics.get("serving_ttft_seconds")
+    records = recorder.records()
+    router.close()
+    assert mine.count == routers.count == 5
+    inserts = [r["duration_s"] for r in records if r["name"] == "serve.insert"]
+    chunks = [r["duration_s"] for r in records if r["name"] == "serve.decode_chunk"]
+    assert abs(routers.sum - mine.sum) / 5 < min(min(inserts), min(chunks))
+
+
+# ------------------------------------------------------------------ profiler clock
+def test_scoped_span_enters_a_profiler_annotation_of_its_name(monkeypatch):
+    import jax
+
+    entered, exited = [], []
+
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            assert not kwargs  # the name alone: attributes stay in the span
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            exited.append(self.name)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    recorder = FlightRecorder()
+    tracer = Tracer(recorder=recorder)
+    with tracer.span("outer", a=1) as outer:
+        with tracer.span("quiet", record=False) as quiet:
+            with tracer.span("inner") as inner:
+                assert inner.parent_id == outer.span_id  # the nearest recorded span
+        assert quiet.duration_s >= inner.duration_s > 0
+    tracer.start_span("lifecycle").end()  # unscoped: no annotation
+    assert entered == ["outer", "quiet", "inner"]
+    assert exited == ["inner", "quiet", "outer"]
+    assert [r["name"] for r in recorder.records()] == ["inner", "outer", "lifecycle"]
+    with pytest.raises(RuntimeError):
+        with tracer.span("doomed"):
+            raise RuntimeError("boom")
+    assert exited[-1] == "doomed"
+
+    # now(): the timeline of the records, for mapping another clock onto it
+    before = tracer.now()
+    with tracer.span("timed"):
+        pass
+    record = recorder.records()[-1]
+    assert before <= record["start_unix"] <= record["end_unix"] <= tracer.now()
+
+
+def test_tracing_runs_where_jax_is_not_loaded():
+    """`tracing.py`, the flight recorder and the metrics are stdlib alone: a
+    scoped span works, without its annotation, in a process that never loads
+    jax. (The package's parent imports jax, so the three are loaded here under
+    a bare stand-in for it.)"""
+    import subprocess
+
+    code = f"""
+import logging, os, sys, types
+root = {os.path.join(REPO, "accelerate_tpu")!r}
+for name, path in (("accelerate_tpu", root), ("accelerate_tpu.telemetry", os.path.join(root, "telemetry"))):
+    package = types.ModuleType(name)
+    package.__path__ = [path]
+    sys.modules[name] = package
+stand_in = types.ModuleType("accelerate_tpu.logging")
+stand_in.get_logger = logging.getLogger
+sys.modules["accelerate_tpu.logging"] = stand_in
+from accelerate_tpu.telemetry.tracing import Tracer
+tracer = Tracer()
+with tracer.span("outer"):
+    with tracer.span("quiet", record=False):
+        pass
+assert [r["name"] for r in tracer.recorder.records()] == ["outer"]
+assert "jax" not in sys.modules and "numpy" not in sys.modules
+"""
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+# ------------------------------------------------------------------ device scopes
+def _has_scope(program_text: str, scope: str) -> bool:
+    """`scope` as a whole component of some location's name path (the text
+    nests a loop body's locations, so a path may start or end at it)."""
+    import re
+
+    return re.search(r'loc\("([^"]*/)?' + re.escape(scope) + r'(/[^"]*)?"', program_text) is not None
+
+
+def test_decode_chunk_and_insert_programs_carry_named_scopes():
+    """The device side of a capture reads by phase: the scope names are in
+    the programs' debug locations (flax names the layers, these the rest)."""
+    from accelerate_tpu.serving import ContinuousBatcher, Request
+
+    engine = ContinuousBatcher(_tiny_llama(), num_slots=2, max_length=64, chunk_size=4,
+                               tracer=Tracer(recorder=FlightRecorder()))
+    text = engine.lower_decode_chunk().as_text(debug_info=True)
+    for scope in ("kv_write", "kv_read", "sample", "pack_stream"):
+        assert _has_scope(text, scope), scope
+
+    engine.submit(Request(0, np.arange(1, 7, dtype=np.int32), max_new_tokens=2))
+    engine.run()
+    (bucket, insert), = engine._insert_fns.items()
+    lowered = insert.lower(
+        engine.params, engine._cache, engine._presence, np.zeros((1, bucket), np.int32),
+        np.int32(6), np.int32(0), np.int32(0), np.zeros((engine.pages_per_slot,), np.int32),
+        np.int32(0), np.float32(1.0), np.float32(1.0), engine._rng)
+    text = lowered.as_text(debug_info=True)
+    for scope in ("kv_read", "kv_write", "sample"):
+        assert _has_scope(text, scope), scope
+    engine.close()
+
+
+def _regression_job(accelerator, n_train=32, n_eval=None):
+    """A prepared regression model, optimizer and loaders of batch 8."""
+    import optax
+
+    from accelerate_tpu import SimpleDataLoader
+    from accelerate_tpu.data_loader import BatchSampler
+
+    from test_training import make_regression_data, make_regression_model
+
+    loaders = [
+        SimpleDataLoader(data, BatchSampler(range(len(data)), 8))
+        for data in (make_regression_data(n=n) for n in (n_train, n_eval) if n)
+    ]
+    return accelerator.prepare(make_regression_model(seed=0), optax.sgd(0.05), *loaders)
+
+
+def test_fused_step_carries_named_scopes_and_the_loader_feeds_data_wait(monkeypatch):
+    """`forward_backward`, `clip` and `optimizer_update` are in the fused
+    step's program; the prepared loader stamps each wait for a batch under a
+    `train.data_wait` annotation, and `train_step()` folds the stamp of its
+    batch into the timeline's "data_wait" phase, beside `train.step`."""
+    import jax
+
+    from accelerate_tpu import Accelerator
+
+    entered = []
+
+    def annotation(name):
+        entered.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    recorder = FlightRecorder()
+    accelerator = Accelerator(tracer=Tracer(recorder=recorder, category="train"))
+    pmodel, popt, ploader = _regression_job(accelerator)
+    step_fn = accelerator.train_step(max_grad_norm=1.0)
+    for _epoch in range(2):
+        for batch in ploader:
+            assert ploader.data_wait_s > 0
+            step_fn(batch)
+            assert ploader.data_wait_s is None  # taken once
+
+    fused = step_fn.__wrapped__
+    while not hasattr(fused, "_jitted"):
+        fused = fused.__wrapped__
+    (program,) = fused._jitted.values()
+    text = program.lower(pmodel.params, popt.opt_state, *fused._scalar_bufs, batch).as_text(debug_info=True)
+    for scope in ("forward_backward", "clip", "optimizer_update"):
+        assert _has_scope(text, scope), scope
+
+    report = accelerator.timeline.goodput()
+    assert report["steps"] == 8
+    assert set(report["phase_s"]) >= {"data_wait", "dispatch"}
+    registry = accelerator.telemetry
+    for phase in ("data_wait", "dispatch", "step"):
+        assert registry.get(f"train_{phase}_seconds").count == 8, phase
+    # in a capture: a wait a `next()` (the one that ends a pass too), a step a call
+    assert entered.count("train.data_wait") == 10 and entered.count("train.step") == 8
+    # in the ring: the steps alone
+    names = [r["name"] for r in recorder.records()]
+    assert names.count("train.step") == 8 and "train.data_wait" not in names
+
+
+def test_an_evaluation_pass_is_nobodys_train_step():
+    """A prepared loader opens no step: a pass that feeds no `train_step()`
+    stays out of the productive time and of the "data_wait" phase, and shows
+    as unaccounted time."""
+    from accelerate_tpu import Accelerator
+
+    accelerator = Accelerator(tracer=Tracer(recorder=FlightRecorder(), category="train"))
+    _, _, train_loader, eval_loader = _regression_job(accelerator, n_eval=24)
+    step_fn = accelerator.train_step()
+    for batch in train_loader:  # compiles
+        step_fn(batch)
+    timeline = accelerator.timeline
+    timeline.reset()
+    waits_before = accelerator.telemetry.get("train_data_wait_seconds").count
+
+    for batch in train_loader:
+        step_fn(batch)
+    t0 = time.perf_counter()
+    for _batch in eval_loader:
+        time.sleep(0.05)
+    eval_s = time.perf_counter() - t0
+    for batch in train_loader:
+        step_fn(batch)
+
+    report = timeline.goodput()
+    assert report["steps"] == 8 and eval_s >= 0.15
+    assert timeline._step_open_since is None
+    assert report["productive_s"] + eval_s <= report["total_s"]
+    assert report["unaccounted_s"] >= eval_s - 1e-3
+    assert accelerator.telemetry.get("train_data_wait_seconds").count - waits_before == 8
 
 
 # ------------------------------------------------------------------ goodput alarm
