@@ -18,6 +18,7 @@ Shapes follow the [batch, seq, heads, head_dim] convention (BSHD) throughout.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import numpy as np
@@ -143,7 +144,10 @@ def update_slot_cache(
     `cols <= pos` mask, so decode is token-identical to the contiguous layout.
     Page 0 is the engine's reserved scratch page: the host points inactive
     slots' table rows at it, so their (discarded) writes can never land in a
-    page owned by a live request or a shared read-only prefix page.
+    page owned by a live request or a shared read-only prefix page. Every
+    table entry must be a pool page id in `[0, num_pages)`: the read gathers
+    without an out-of-bounds fill (an id outside the pool is clamped into it,
+    not read as NaN).
 
     QUANTIZED pool (`kv_cache_dtype` "int8" / "fp8_e4m3", paged only): pages
     are stored in the quantized dtype with per-page-per-head scales in
@@ -181,19 +185,32 @@ def update_slot_cache(
         pages_per_slot = table.shape[-1]
         L = pages_per_slot * page_size
         # Logical-order read: [B, P, ps, h, d] -> [B, P*ps, h, d]. Same masked
-        # attention as the contiguous layout — pool order never leaks. This
-        # materialized gather is the HBM cost `slot_cache_attention`'s
-        # "pallas_paged" path exists to remove; it stays as the parity oracle.
+        # attention as the contiguous layout — pool order never leaks. The
+        # gathered window is written once here and read once by each of the
+        # attention's two reductions, whatever the slots' live lengths: the
+        # HBM cost `slot_cache_attention`'s "pallas_paged" path exists to remove.
+        # mode="clip": jnp.take's default "fill" is a select(id in range, page,
+        # NaN) over the whole window, one more read and write of it than the
+        # gather itself (PERF.md §6, PR 25), against ids that cannot occur: the
+        # table holds the engine's PagePool ids with scratch page 0 in every
+        # unused entry (pinned in tests/test_paging.py), and the kernel path
+        # clips the same table.
+        gather = functools.partial(jnp.take, indices=table, axis=0, mode="clip")
         with jax.named_scope("kv_read"):
-            k_pages = jnp.take(pool_k, table, axis=0)  # [B, P, ps, h, d]
-            v_pages = jnp.take(pool_v, table, axis=0)
+            k_pages, v_pages = gather(pool_k), gather(pool_v)  # [B, P, ps, h, d]
             if scales is not None:
                 # Dequantize-on-read: scale[table] broadcasts per page per head.
+                # The barrier keeps the quantized -> f32 convert inside the
+                # dequantize fusion (1 byte a value read, 2 written); without
+                # it the TPU compiler hoists the convert up to the gather and
+                # materializes both windows in f32 for the reductions to read.
+                # One barrier a tensor: a joint one keeps both windows live.
                 from .quantization import dequantize_kv_pages
 
+                pin = jax.lax.optimization_barrier
                 k_scale, v_scale = scales
-                k_pages = dequantize_kv_pages(k_pages, jnp.take(k_scale, table, axis=0), k.dtype)
-                v_pages = dequantize_kv_pages(v_pages, jnp.take(v_scale, table, axis=0), v.dtype)
+                k_pages = dequantize_kv_pages(pin(k_pages), gather(k_scale), k.dtype)
+                v_pages = dequantize_kv_pages(pin(v_pages), gather(v_scale), v.dtype)
             k_full = k_pages.reshape(b, L, h, d)
             v_full = v_pages.reshape(b, L, h, d)
         cols = jnp.arange(L)[None, None, :]
@@ -317,9 +334,12 @@ def slot_cache_attention(
 
       - ``"xla"`` (default, and the only option for the contiguous layout):
         `update_slot_cache`'s gather-then-mask read + `dot_product_attention`.
-        Paged mode pays a full materialized copy of the logical cache per
-        dispatch — this path is the PARITY ORACLE the kernels are pinned
-        against, not the serving hot path.
+        Paged mode touches every slot's whole logical window three times a
+        layer per dispatch, live or not: the gather writes it (reading as
+        many pool pages), q·K reads K's, probs·V reads V's — nothing else
+        does (`tests/test_tpu_compile.py` holds the compiled program to it).
+        The engine's default, and the PARITY ORACLE the kernels are pinned
+        against.
       - ``"pallas_paged"`` (paged mode only): the pool write plus the
         `ops/paged_attention` kernels, which walk each slot's page table
         directly and never materialize the gathered cache. Greedy decode is

@@ -3,15 +3,18 @@ speculative block-verify variant, with the page-table gather FUSED into the
 attention walk.
 
 The XLA paged path (`ops/attention.update_slot_cache`) gathers every slot's
-pages back into a logical ``[B, L, h, d]`` K/V buffer before attending — a
-full materialized copy of the cache per decode dispatch, which is exactly the
-HBM traffic that bounds decode throughput. These kernels never materialize
-that buffer: the grid walks each slot's ``page_table`` directly (the table
-rides as a SCALAR-PREFETCH operand, so the BlockSpec index maps pick which
-pool page to stream into VMEM for each grid step) and folds every page into
-the shared online-softmax accumulator (`ops/flash_common.py`). HBM traffic
-per dispatch drops from "the whole logical cache, written then read" to "each
-live page, read once".
+pages back into a logical ``[B, L, h, d]`` K/V buffer before attending: per
+layer and dispatch it reads ``B * pages_per_slot`` pool pages, writes them as
+the buffer, and reads the buffer once in q·K and once in probs·V — three
+passes over every slot's WHOLE window, live or not, which is the HBM traffic
+that bounds decode throughput (measured on the v5e: PERF.md §5). These
+kernels never materialize that buffer: the grid walks each slot's
+``page_table`` directly (the table rides as a SCALAR-PREFETCH operand, so the
+BlockSpec index maps pick which pool page to stream into VMEM for each grid
+step) and folds every page into the shared online-softmax accumulator
+(`ops/flash_common.py`). HBM traffic per dispatch drops from "the whole
+logical window, read, written, then read again" to "each live page, read
+once".
 
 Page-walk contract (mirrors the engine's host-side conventions, paging.py):
 

@@ -569,19 +569,23 @@ def tree_gather_pages(pool, dense_struct, page_ids, cache_index):
         if leaf is None:
             raise ValueError(f"pool cache has no leaf at {'/'.join(names)}")
         axis = leaf.ndim - axis_back
-        pages = jnp.take(leaf, jnp.asarray(page_ids, jnp.int32), axis=axis)
+        # mode="clip": the default "fill" passes every gathered page through a
+        # select against NaN; the engine's ids are the pool's own (PagePool).
+        pages = jnp.take(leaf, jnp.asarray(page_ids, jnp.int32), axis=axis, mode="clip")
         scale_leaf = pool_leaves.get(names[:-1] + (_SCALE_OF.get(names[-1], ""),))
         if scale_leaf is not None:
             # Quantized pool: dequantize the gathered pages with their
             # per-page-per-head scales so the dense prefill sees real values.
             scale_axis = scale_leaf.ndim - _SCALE_AXIS_FROM_BACK[_SCALE_OF[names[-1]]]
             pages_scale = jnp.take(
-                scale_leaf, jnp.asarray(page_ids, jnp.int32), axis=scale_axis
+                scale_leaf, jnp.asarray(page_ids, jnp.int32), axis=scale_axis, mode="clip"
             )
             # Insert the page_size axis after the page axis and the head_dim
             # axis at the end, then broadcast-multiply in fp32.
+            # The barrier keeps the quantized -> f32 convert in this fusion (as in
+            # `update_slot_cache`): hoisted to the gather it writes the pages in f32.
             scale_b = jnp.expand_dims(pages_scale, axis + 1)[..., None]
-            pages = pages.astype(jnp.float32) * scale_b
+            pages = jax.lax.optimization_barrier(pages).astype(jnp.float32) * scale_b
         merged = pages.reshape(
             pages.shape[:axis]
             + (pages.shape[axis] * pages.shape[axis + 1],)

@@ -166,19 +166,41 @@ def test_paged_slot_write_crosses_page_boundaries():
 # ------------------------------------------------------------------ parity
 
 
-def test_paged_contiguous_and_static_parity_with_slot_reuse():
-    """Acceptance pin: greedy decode is token-identical between the paged and
-    contiguous cache paths across a slot-reuse workload, and both match the
-    static Generator."""
-    model = _model()
-    rng = np.random.default_rng(3)
+def _slot_reuse_workload(rng):
     lengths = [5, 9, 3, 12, 7, 4]
     budgets = [6, 4, 8, 3, 5, 7]
-    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in lengths]
+    return [rng.integers(1, 128, (n,)).astype(np.int32) for n in lengths], budgets
+
+
+def _shared_prefix_churn_workload(rng):
+    """Two system prompts of two full pages each (page_size 8), interleaved, more
+    requests than slots: shared pages, private pages, frees and slot reuse all
+    pass through the page tables the "xla" read gathers from."""
+    systems = [rng.integers(1, 128, (17,)).astype(np.int32) for _ in range(2)]
+    tails = [2, 5, 1, 4, 3, 6, 2]
+    prompts = [
+        np.concatenate([systems[i % 2], rng.integers(1, 128, (n,)).astype(np.int32)])
+        for i, n in enumerate(tails)
+    ]
+    return prompts, [4, 7, 3, 6, 5, 2, 8]
+
+
+@pytest.mark.parametrize(
+    "workload", [_slot_reuse_workload, _shared_prefix_churn_workload],
+    ids=["slot_reuse", "shared_prefix_churn"],
+)
+def test_paged_contiguous_and_static_parity_with_slot_reuse(workload):
+    """Acceptance pin: greedy decode is token-identical between the paged "xla"
+    read and the contiguous layout across a slot-reuse workload and a
+    shared-prefix one, and both match the static Generator."""
+    model = _model()
+    prompts, budgets = workload(np.random.default_rng(3))
     requests = lambda: [  # noqa: E731 — fresh Request objects per engine
         Request(i, p, max_new_tokens=m) for i, (p, m) in enumerate(zip(prompts, budgets))
     ]
-    paged = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=4, page_size=8)
+    paged = ContinuousBatcher(
+        model, num_slots=2, max_length=32, chunk_size=4, page_size=8, attention_impl="xla"
+    )
     contiguous = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=4, paged=False)
     out_p = paged.run(requests())
     out_c = contiguous.run(requests())
@@ -188,6 +210,60 @@ def test_paged_contiguous_and_static_parity_with_slot_reuse():
     assert paged.trace_counts["decode_chunk"] == 1
     assert paged.pool.pages_in_use == 0
     assert paged.pool.check_consistency() == []
+
+
+def _assert_page_table_in_pool(engine):
+    """What the "xla" read's unguarded gather (`mode="clip"`) relies on: every
+    page-table entry is one of the pool's own ids, a live slot's row is its
+    pages in order, and every unused entry is the scratch page."""
+    table = engine._page_table
+    assert table.dtype == np.int32
+    assert table.min() >= 0 and table.max() < engine.pool.num_pages, table
+    for slot, pages in enumerate(engine._slot_pages):
+        assert list(table[slot, : len(pages)]) == list(pages), (slot, table[slot], pages)
+        assert SCRATCH_PAGE not in pages
+        assert (table[slot, len(pages):] == SCRATCH_PAGE).all(), (slot, table[slot], pages)
+        if engine._slot_request[slot] is None:
+            assert pages == []
+
+
+def test_page_table_entries_stay_in_the_pool_through_churn():
+    """Admissions, prefix sharing, eviction under a tight pool, a cancel, frees
+    and slot reuse: after every step the page table the decode chunk is about to
+    gather from holds only in-range ids, with scratch in every unused entry."""
+    model = _model()
+    prompts, budgets = _shared_prefix_churn_workload(np.random.default_rng(8))
+    rng = np.random.default_rng(9)
+    prompts += [rng.integers(1, 128, (n,)).astype(np.int32) for n in (3, 11, 6)]
+    budgets += [9, 2, 5]
+    # 9 usable pages of 8 tokens for 3 slots of up to 4 pages: the pool, not the
+    # slots, bounds admission, so cached prefix pages are evicted and re-minted.
+    engine = ContinuousBatcher(
+        model, num_slots=3, max_length=32, chunk_size=2, page_size=8, num_pages=10,
+        attention_impl="xla",
+    )
+    _assert_page_table_in_pool(engine)
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        engine.submit(Request(i, p, max_new_tokens=m))
+    steps = 0
+    while engine.pending:
+        engine.step()
+        steps += 1
+        if steps == 2:
+            # an in-flight request (a slot's row drops to scratch mid-run), or a
+            # queued one (never admitted): whichever request 1 is by now
+            engine.cancel(1)
+        _assert_page_table_in_pool(engine)
+        assert engine.pool.check_consistency() == []
+        assert steps < 200
+    assert (engine._page_table == SCRATCH_PAGE).all()
+    assert engine.pool.pages_in_use == 0
+    assert engine.stats["prefix_cache"]["hits"] > 0 and engine.pool.evictions > 0
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        if i != 1:
+            np.testing.assert_array_equal(
+                np.asarray(engine.results[i].tokens), _static_reference(model, p, m)
+            )
 
 
 def test_shared_prefix_parity_and_tokens_saved():

@@ -1,4 +1,6 @@
-"""The main-path Pallas kernels, compiled for a DESCRIBED TPU v5e at llama-1b widths.
+"""The main-path Pallas kernels, compiled for a DESCRIBED TPU v5e at llama-1b widths,
+and the XLA paged read at the benchmark's serving shapes (which passes over the
+gathered window its optimized program may make).
 
 Interpret mode (every other kernel test) cannot see what the chip's compiler
 refuses: block shapes off the (8, 128) tiling, sub-tile scratch views, too much
@@ -10,7 +12,9 @@ tests/test_flash_attention.py hold the kernels to their XLA oracles in interpret
 mode; chip_smoke.py runs them on the chip).
 """
 
+import math
 import os
+import re
 
 import pytest
 
@@ -97,6 +101,69 @@ def test_paged_attention_compiles_for_v5e(v5e, s_block, pool, batch, page_size):
             return kernel(q, k, v, table, pos, interpret=False)
 
     _compile(fn, *args)
+
+
+# One layer of the benchmark's serving cells (chipbench/workloads/pythia-1.4b.*.json):
+# 32 slots x 88 pages of 16 tokens, 16 heads of 128, a pool of 2,816 pages + scratch.
+CELL_SLOTS, CELL_PAGES_PER_SLOT, CELL_PAGE_SIZE, CELL_HEADS, CELL_D = 32, 88, 16, 16, 128
+CELL_WINDOW = CELL_SLOTS * CELL_PAGES_PER_SLOT * CELL_PAGE_SIZE * CELL_HEADS * CELL_D
+_HLO_SELECT = re.compile(r" = \(?[a-z]+[0-9]*\[([0-9,]+)\]\S* select\(")
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("s_block", [1, 5], ids=["decode", "verify5"])
+def test_xla_paged_read_has_no_window_sized_select(v5e, s_block, pool):
+    """`slot_cache_attention(attention_impl="xla")` — `update_slot_cache`'s pool
+    write and gather, then `dot_product_attention` — as the v5e's compiler leaves
+    it. The gathered window ([32, 88, 16, 16, 128], 185 MB a tensor in bf16) is
+    written by the two gathers and read by the two reductions; a `jnp.take` left at
+    its default `mode="fill"` puts a `select(page id in range, page, NaN)` over
+    both windows between them (`broadcast_select_fusion`: 369 MB read + 369 MB
+    written a layer, 27 of a 64 ms decode step on the chip — PERF.md §6, PR 25)."""
+    import flax.linen as nn
+
+    from accelerate_tpu.ops.attention import slot_cache_attention
+
+    num_pages = CELL_SLOTS * CELL_PAGES_PER_SLOT + 1
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v, positions, table):
+            return slot_cache_attention(
+                self, q, k, v, CELL_PAGES_PER_SLOT * CELL_PAGE_SIZE, positions,
+                page_table=table, page_size=CELL_PAGE_SIZE, num_pages=num_pages,
+                attention_impl="xla", kv_cache_dtype=pool,
+            )
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    layer = Layer()
+    x = spec((CELL_SLOTS, s_block, CELL_HEADS, CELL_D), jnp.bfloat16)
+    operands = (x, x, x, spec((CELL_SLOTS, s_block), jnp.int32),
+                spec((CELL_SLOTS, CELL_PAGES_PER_SLOT), jnp.int32))
+    cache = jax.eval_shape(lambda *a: layer.init(jax.random.key(0), *a), *operands)["cache"]
+    cache = jax.tree_util.tree_map(lambda leaf: spec(leaf.shape, leaf.dtype), cache)
+
+    def step(cache, *args):
+        out, mutated = layer.apply({"cache": cache}, *args, mutable=["cache"])
+        return out, mutated["cache"]
+
+    compiled = jax.jit(step, donate_argnums=0).lower(cache, *operands).compile()
+    window_selects = [
+        line.strip()[:160]
+        for line in compiled.as_text().splitlines()
+        if (m := _HLO_SELECT.search(line))
+        and math.prod(int(n) for n in m.group(1).split(",")) >= CELL_WINDOW
+    ]
+    assert not window_selects, window_selects
+    # Decode, bf16: 1.895 GB with the fill, 1.156 GB without (the analysis counts
+    # each in-place pool scatter as a pass over its pool besides). int8: 1.423 GB
+    # with the fill, 1.417 GB without — and 2.257 GB if the int8 -> f32 convert
+    # leaves the dequantize fusion (`update_slot_cache`'s barrier).
+    bound = {("bf16", 1): 1.25e9, ("int8", 1): 1.5e9}.get((pool, s_block))
+    if bound is not None:
+        assert compiled.cost_analysis()["bytes accessed"] <= bound
 
 
 @pytest.mark.parametrize("seq", [1024, 2048])
