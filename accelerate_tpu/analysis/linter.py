@@ -1002,7 +1002,7 @@ class _ModuleChecker:
                         node,
                         "TPU115",
                         'attention_impl="xla" pins this decode/verify program to the '
-                        "gather oracle (a full materialized cache copy per dispatch) "
+                        "XLA oracle (three passes over every live page per dispatch) "
                         'where the Pallas paged kernel applies — pass "pallas_paged", '
                         "or suppress where the oracle is deliberate",
                     )

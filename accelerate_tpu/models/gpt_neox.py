@@ -116,7 +116,7 @@ class GPTNeoXAttention(nn.Module):
                 # Continuous-batching decode: per-row scatter writes at each
                 # slot's own position (serving.ContinuousBatcher). Paged mode
                 # reads `mask` as the [B, pages_per_slot] int32 page table;
-                # decode_attention_impl picks the gather oracle or the fused
+                # decode_attention_impl picks the XLA live-page read or the fused
                 # Pallas page-walk kernels.
                 out = slot_cache_attention(
                     self, q, k, v, L, positions,

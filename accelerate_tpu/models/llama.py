@@ -140,7 +140,7 @@ class LlamaAttention(nn.Module):
                 # position (per-row scatter) and attends its written prefix
                 # only. Paged mode reads `mask` as the slot page table ([B,
                 # pages_per_slot] int32) mapping positions onto pool pages;
-                # decode_attention_impl picks the XLA gather oracle or the
+                # decode_attention_impl picks the XLA live-page read or the
                 # fused Pallas page-walk kernels.
                 out = slot_cache_attention(
                     self, q, k, v, cfg.decode_cache_length, positions,

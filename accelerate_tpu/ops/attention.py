@@ -9,9 +9,9 @@
 
 `slot_cache_attention` is the SERVING twin: the fused cache-write + attend seam
 for slot-batched decode, with its own `attention_impl` dispatch — the XLA
-gather oracle, or the Pallas paged-decode / block-verify kernels
-(ops/paged_attention.py) that walk the page table without materializing the
-gathered cache.
+oracle (paged: blocks of live pages gathered and reduced in two loops), or the
+Pallas paged-decode / block-verify kernels (ops/paged_attention.py) that walk
+the page table without materializing any gathered page.
 
 Shapes follow the [batch, seq, heads, head_dim] convention (BSHD) throughout.
 """
@@ -102,18 +102,16 @@ def update_decode_cache(module, k, v, cache_length: int, pad_mask=None):
     return cached_k.value, cached_v.value, decode_mask
 
 
-def update_slot_cache(
-    module, k, v, cache_length: int, positions, page_table=None, page_size: int = 0,
-    num_pages: int = 0, kv_cache_dtype: str = "bf16",
-):
-    """Per-ROW cache writes for slot-based continuous batching (serving.py):
-    every batch row is an independent request slot with its OWN running position,
-    so the new K/V of row i lands at `positions[i]` instead of a shared
-    scalar `cache_index`. The scatter (`.at[rows, pos].set`) is the per-slot twin
-    of `update_decode_cache`'s `dynamic_update_slice`; the returned mask lets each
-    query attend exactly to its written prefix `cols <= its position` — stale K/V
-    from a previous slot occupant above the current position is never visible,
-    which is what makes slot reuse sound without ever clearing the cache.
+def update_slot_cache(module, k, v, cache_length: int, positions):
+    """Per-ROW cache writes for slot-based continuous batching (serving.py),
+    CONTIGUOUS layout: every batch row is an independent request slot with its
+    OWN running position, so the new K/V of row i lands at `positions[i]`
+    instead of a shared scalar `cache_index`. The scatter (`.at[rows, pos].set`)
+    is the per-slot twin of `update_decode_cache`'s `dynamic_update_slice`; the
+    returned mask lets each query attend exactly to its written prefix
+    `cols <= its position` — stale K/V from a previous slot occupant above the
+    current position is never visible, which is what makes slot reuse sound
+    without ever clearing the cache.
 
     Decode (s == 1) and speculative VERIFY BLOCKS (s == draft_tokens + 1,
     positions[i] = pos_i + [0..s)): the s > 1 path writes every block token's
@@ -134,36 +132,15 @@ def update_slot_cache(
     pages (tree_scatter_pages) — so one attention code path covers both
     programs.
 
-    PAGED mode (`page_size > 0`): the cache collection holds one POOL of
-    `num_pages` fixed-size pages ([num_pages, page_size, h, d]) instead of one
-    `cache_length` row per slot, and `page_table` ([B, pages_per_slot] int32, a
-    traced operand — admissions never recompile) maps each slot's logical
-    positions onto pool pages. Row i's new K/V lands at
-    `pool[page_table[i, pos_i // page_size], pos_i % page_size]`; the read
-    gathers the row's pages back into logical order and applies the same
-    `cols <= pos` mask, so decode is token-identical to the contiguous layout.
-    Page 0 is the engine's reserved scratch page: the host points inactive
-    slots' table rows at it, so their (discarded) writes can never land in a
-    page owned by a live request or a shared read-only prefix page. Every
-    table entry must be a pool page id in `[0, num_pages)`: the read gathers
-    without an out-of-bounds fill (an id outside the pool is clamped into it,
-    not read as NaN).
-
-    QUANTIZED pool (`kv_cache_dtype` "int8" / "fp8_e4m3", paged only): pages
-    are stored in the quantized dtype with per-page-per-head scales in
-    parallel `key_scale`/`value_scale` pool arrays ([num_pages, h] f32, same
-    cache collection — traced operands, never Python scalars), maintained by
-    `ops.quantization.quantized_pool_write` (offset-0 scale reset, scatter-max
-    growth, in-dispatch requant of touched pages). This XLA read path
-    dequantizes the gathered pages — the parity oracle the fused-dequant
-    Pallas kernels are pinned against.
+    The PAGED layout (`slot_cache_attention(page_size > 0)`) keeps these
+    semantics — the same positions, the same `cols <= pos` mask, the same
+    tokens — but has no gathered view to return: `_write_slot_pool` writes
+    through the page table and `_live_page_attention` reads the live pages
+    alone. The read that gathered every slot's whole window here is now the
+    oracle of tests/test_paging.py.
 
     Args:
         positions: [B, s] int32 — each token's absolute write/attend position.
-        page_table: [B, pages_per_slot] int32 pool-page ids per slot (paged only).
-        page_size / num_pages: static pool geometry (paged only).
-        kv_cache_dtype: "bf16" (unquantized, the model compute dtype) |
-            "int8" | "fp8_e4m3" — pool storage dtype (paged only).
 
     Returns `(k_full, v_full, decode_mask)` like `update_decode_cache`.
     """
@@ -171,56 +148,7 @@ def update_slot_cache(
     import jax.numpy as jnp
 
     b, s, h, d = k.shape
-    if positions.shape != (b, s):
-        raise ValueError(
-            f"update_slot_cache needs per-token positions [B, S] = {(b, s)}, "
-            f"got {positions.shape}; slot prefill goes through "
-            "update_decode_cache on a batch-1 cache (tree_scatter_rows)"
-        )
-    if page_size:
-        pool_k, pool_v, pos, table, scales = _write_slot_pool(
-            module, k, v, positions, page_table, page_size, num_pages,
-            kv_cache_dtype=kv_cache_dtype,
-        )
-        pages_per_slot = table.shape[-1]
-        L = pages_per_slot * page_size
-        # Logical-order read: [B, P, ps, h, d] -> [B, P*ps, h, d]. Same masked
-        # attention as the contiguous layout — pool order never leaks. The
-        # gathered window is written once here and read once by each of the
-        # attention's two reductions, whatever the slots' live lengths: the
-        # HBM cost `slot_cache_attention`'s "pallas_paged" path exists to remove.
-        # mode="clip": jnp.take's default "fill" is a select(id in range, page,
-        # NaN) over the whole window, one more read and write of it than the
-        # gather itself (PERF.md §6, PR 25), against ids that cannot occur: the
-        # table holds the engine's PagePool ids with scratch page 0 in every
-        # unused entry (pinned in tests/test_paging.py), and the kernel path
-        # clips the same table.
-        gather = functools.partial(jnp.take, indices=table, axis=0, mode="clip")
-        with jax.named_scope("kv_read"):
-            k_pages, v_pages = gather(pool_k), gather(pool_v)  # [B, P, ps, h, d]
-            if scales is not None:
-                # Dequantize-on-read: scale[table] broadcasts per page per head.
-                # The barrier keeps the quantized -> f32 convert inside the
-                # dequantize fusion (1 byte a value read, 2 written); without
-                # it the TPU compiler hoists the convert up to the gather and
-                # materializes both windows in f32 for the reductions to read.
-                # One barrier a tensor: a joint one keeps both windows live.
-                from .quantization import dequantize_kv_pages
-
-                pin = jax.lax.optimization_barrier
-                k_scale, v_scale = scales
-                k_pages = dequantize_kv_pages(pin(k_pages), gather(k_scale), k.dtype)
-                v_pages = dequantize_kv_pages(pin(v_pages), gather(v_scale), v.dtype)
-            k_full = k_pages.reshape(b, L, h, d)
-            v_full = v_pages.reshape(b, L, h, d)
-        cols = jnp.arange(L)[None, None, :]
-        decode_mask = (cols <= pos[:, :, None])[:, None, :, :]  # [B, 1, s, L]
-        return k_full, v_full, decode_mask
-    if kv_cache_dtype != "bf16":
-        raise ValueError(
-            f"kv_cache_dtype={kv_cache_dtype!r} requires the paged slot cache "
-            "(page_size > 0); the contiguous layout has no page-scale pool"
-        )
+    _check_slot_positions(positions, b, s)
     L = cache_length
     cached_k = module.variable("cache", "cached_key", jnp.zeros, (b, L, h, d), k.dtype)
     cached_v = module.variable("cache", "cached_value", jnp.zeros, (b, L, h, d), v.dtype)
@@ -234,6 +162,15 @@ def update_slot_cache(
     return cached_k.value, cached_v.value, decode_mask
 
 
+def _check_slot_positions(positions, b: int, s: int):
+    if positions.shape != (b, s):
+        raise ValueError(
+            f"the slot cache needs per-token positions [B, S] = {(b, s)}, "
+            f"got {positions.shape}; slot prefill goes through "
+            "update_decode_cache on a batch-1 cache (tree_scatter_rows)"
+        )
+
+
 def _write_slot_pool(
     module, k, v, positions, page_table, page_size: int, num_pages: int,
     kv_cache_dtype: str = "bf16",
@@ -241,10 +178,29 @@ def _write_slot_pool(
     """The paged slot cache's WRITE half: scatter this dispatch's [B, s] K/V
     into the page pool through the slot page tables, and return the updated
     pools plus the clipped positions/table and (quantized pools only) the
-    `(key_scale, value_scale)` parallel scale pools. Shared by the XLA gather
-    path (`update_slot_cache`) and the fused kernel path
-    (`slot_cache_attention`) so the two implementations can never disagree
-    about where K/V lives — or what scale it was stored under."""
+    `(key_scale, value_scale)` parallel scale pools. Shared by both of
+    `slot_cache_attention`'s reads (`_live_page_attention` and the fused
+    kernels) so the two can never disagree about where K/V lives — or what
+    scale it was stored under.
+
+    The cache collection holds one POOL of `num_pages` fixed-size pages
+    ([num_pages, page_size, h, d]) instead of one `cache_length` row per slot,
+    and `page_table` ([B, pages_per_slot] int32, a traced operand — admissions
+    never recompile) maps each slot's logical positions onto pool pages. Row
+    i's new K/V lands at `pool[page_table[i, pos_i // page_size], pos_i %
+    page_size]`. Page 0 is the engine's reserved scratch page: the host points
+    inactive slots' table rows at it, so their (discarded) writes can never
+    land in a page owned by a live request or a shared read-only prefix page.
+    Every table entry must be a pool page id in `[0, num_pages)`: the reads
+    gather without an out-of-bounds fill (an id outside the pool is clamped
+    into it, not read as NaN).
+
+    QUANTIZED pool (`kv_cache_dtype` "int8" / "fp8_e4m3"): pages are stored
+    in the quantized dtype with per-page-per-head scales in parallel
+    `key_scale`/`value_scale` pool arrays ([num_pages, h] f32, same cache
+    collection — traced operands, never Python scalars), maintained by
+    `ops.quantization.quantized_pool_write` (offset-0 scale reset, scatter-max
+    growth, in-dispatch requant of touched pages)."""
     import jax
     import jax.numpy as jnp
 
@@ -283,6 +239,136 @@ def _write_slot_pool(
             pool_v.value, v_scale.value, v, pid, off, spec
         )
     return pool_k.value, pool_v.value, pos, table, (k_scale.value, v_scale.value)
+
+
+#: Bytes of K (or of V), in the compute dtype, that one turn of
+#: `_live_page_attention`'s loops gathers from the pool. Large enough that a
+#: turn's fixed cost is small beside its bytes, small enough that the padding
+#: of the last block (half a block on average) is small beside the live pages.
+_READ_BLOCK_BYTES = 16 << 20
+
+
+def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
+    """The paged slot cache's XLA READ: attention over the LIVE pages alone.
+
+    A slot's live pages are the table entries that hold a position some query
+    of its row may attend: `page * page_size <= max_j pos[b, j]`, a prefix of
+    its table row, so an idle slot (position 0, table row all scratch) is one
+    page and a full one all `pages_per_slot`. The live (slot, page) pairs of
+    all rows, slot-major, make one flat list of `n` entries, and two loops
+    walk it in blocks of `G` pages under a trip count `ceil(n / G)` computed on
+    the device from `pos` — so the bytes read follow the tokens that are
+    live, not slots x window, in ONE program whose shapes never change:
+
+      1. gather a block's K pages from the pool (`mode="clip"`: ids are pool
+         ids by contract, see `_write_slot_pool`; a quantized block is
+         dequantized here), `q[owner] . K` a page, scores kept in flat order;
+      2. the scores laid out as [B, P, ..., page_size] by one small gather,
+         masked `cols <= pos` (per query: a verify block is causal), softmax
+         in fp32 over the slot's window — the arithmetic of
+         `dot_product_attention`;
+      3. gather a block's V pages, `probs . V` a page in fp32, each page's
+         partial sum added into its owner's output row.
+
+    `G` is `_READ_BLOCK_BYTES` of compute-dtype pages, and never more than
+    `B * P`: where the whole window fits one block the loops run once and this
+    is the gather-everything read. What it still costs beside the page-walk
+    kernel's single read of every live page: each block is written by its
+    gather and read back once by its reduction, and the last block of each
+    loop is read whole however few of its entries are live.
+
+    q [B, s, Hq, D]; pools [N, page_size, Hkv, D] (Hq % Hkv == 0: a query
+    head's group is its kv head, as `jnp.repeat` pairs them); pos [B, s] and
+    table [B, P] as `_write_slot_pool` returns them. Returns [B, s, Hq, D]."""
+    import jax
+    import jax.numpy as jnp
+
+    from .quantization import dequantize_kv_pages
+
+    b, s, hq, d = q.shape
+    _, ps, hkv, _ = pool_k.shape
+    P = table.shape[-1]
+    if hq % hkv != 0:
+        raise ValueError(f"GQA requires query heads ({hq}) divisible by kv heads ({hkv})")
+    rep = hq // hkv
+    G = max(1, min(b * P, _READ_BLOCK_BYTES // (ps * hkv * d * q.dtype.itemsize)))
+    flat_len = -(-b * P // G) * G
+
+    # The flat list. Entry i belongs to the slot whose run of live pages
+    # [start, end) holds i; entries at and past n belong to nobody (owner B).
+    count = jnp.max(pos, axis=1) // ps + 1  # [B], 1..P live pages a slot
+    end = jnp.cumsum(count)
+    start = end - count
+    n = end[-1]
+    i = jnp.arange(flat_len, dtype=jnp.int32)
+    listed = i < n
+    owner = jnp.sum(i[:, None] >= end[None, :], axis=1, dtype=jnp.int32)  # [flat_len]
+    slot = jnp.minimum(owner, b - 1)
+    entry = slot * P + jnp.clip(i - start[slot], 0, P - 1)  # index into [B * P]
+    page_id = jnp.where(listed, jnp.take(table.reshape(-1), entry, mode="clip"), 0)
+    blocks = (n + G - 1) // G
+
+    def block_of(x, t):
+        return jax.lax.dynamic_slice_in_dim(x, t * G, G, axis=0)
+
+    def read_block(pool, scale_pool, t):
+        ids = block_of(page_id, t)
+        pages = jnp.take(pool, ids, axis=0, mode="clip")  # [G, ps, Hkv, D]
+        if scale_pool is None:
+            return pages
+        return dequantize_kv_pages(pages, jnp.take(scale_pool, ids, axis=0, mode="clip"), q.dtype)
+
+    k_scale, v_scale = scales if scales is not None else (None, None)
+    q_groups = q.reshape(b, s, hkv, rep, d)
+    scale = 1.0 / np.sqrt(d)  # a numpy scalar: bf16 scores are scaled in fp32, as there
+
+    def score_block(t, flat_scores):
+        k_block = read_block(pool_k, k_scale, t)
+        q_block = jnp.take(q_groups, block_of(slot, t), axis=0, mode="clip")
+        block = jnp.einsum("gskrd,gtkd->gskrt", q_block, k_block)
+        return jax.lax.dynamic_update_slice_in_dim(
+            flat_scores, block.reshape(G, s, hq * ps), t * G, axis=0
+        )
+
+    # Scores travel as [.., s, Hq * page_size]: a minor dimension of one page's
+    # tokens alone would be padded to the chip's 128 lanes, eight times over.
+    with jax.named_scope("kv_read"):
+        flat_scores = jax.lax.fori_loop(
+            0, blocks, score_block, jnp.zeros((flat_len, s, hq * ps), q.dtype)
+        )
+        # [B, P, s, Hkv, rep, ps]: a page that is not live takes some listed
+        # page's scores and loses them to the mask.
+        rows = start[:, None] + jnp.arange(P, dtype=jnp.int32)[None, :]
+        scores = jnp.take(flat_scores, rows, axis=0, mode="clip") * scale
+    scores = scores.reshape(b, P, s, hkv, rep, ps)
+    cols = jnp.arange(P)[:, None] * ps + jnp.arange(ps)[None, :]  # [P, ps]
+    attend = cols[None, :, None, :] <= pos[:, None, :, None]  # [B, P, s, ps]
+    scores = jnp.where(attend[:, :, :, None, None, :], scores, jnp.finfo(scores.dtype).min)
+    # Softmax in fp32 over the slot's window (axes P and ps), as
+    # dot_product_attention's.
+    scores = scores.astype(jnp.float32)
+    probs = jnp.exp(scores - jnp.max(scores, axis=(1, 5), keepdims=True))
+    probs = (probs / jnp.sum(probs, axis=(1, 5), keepdims=True)).astype(q.dtype)
+    flat_probs = jnp.take(probs.reshape(b * P, s, hq * ps), entry, axis=0, mode="clip")
+    flat_probs = jnp.where(listed[:, None, None], flat_probs, 0)
+
+    def value_block(t, out):
+        v_block = read_block(pool_v, v_scale, t)
+        page_out = jnp.einsum(
+            "gskrt,gtkd->gskrd", block_of(flat_probs, t).reshape(G, s, hkv, rep, ps), v_block,
+            preferred_element_type=jnp.float32,
+        )
+        # owner B (an entry past n) is an all-zero row: it adds nothing.
+        mine = jax.nn.one_hot(block_of(owner, t), b, dtype=jnp.float32)  # [G, B]
+        return out + jnp.einsum(
+            "gb,gskrd->bskrd", mine, page_out, precision=jax.lax.Precision.HIGHEST
+        )
+
+    with jax.named_scope("kv_read"):
+        out = jax.lax.fori_loop(
+            0, blocks, value_block, jnp.zeros((b, s, hkv, rep, d), jnp.float32)
+        )
+    return out.reshape(b, s, hq, d).astype(q.dtype)
 
 
 def _tp_paged_attention(fn, q, pool_k, pool_v, table, positions, k_scale, v_scale, mesh):
@@ -332,63 +418,84 @@ def slot_cache_attention(
     One function covers decode steps (s == 1) and speculative verify blocks
     (s == draft_tokens + 1); `attention_impl` picks the read-side engine:
 
-      - ``"xla"`` (default, and the only option for the contiguous layout):
-        `update_slot_cache`'s gather-then-mask read + `dot_product_attention`.
-        Paged mode touches every slot's whole logical window three times a
-        layer per dispatch, live or not: the gather writes it (reading as
-        many pool pages), q·K reads K's, probs·V reads V's — nothing else
-        does (`tests/test_tpu_compile.py` holds the compiled program to it).
-        The engine's default, and the PARITY ORACLE the kernels are pinned
-        against.
+      - ``"xla"`` (default, and the only option for the contiguous layout).
+        Contiguous: `update_slot_cache`'s masked read of every slot's whole
+        row + `dot_product_attention`. Paged: `_write_slot_pool`, then
+        `_live_page_attention` walks the LIVE pages of all slots in fixed
+        blocks under a trip count it computes from `positions`, so a
+        dispatch's bytes follow the live tokens: every live page is read
+        from the pool and written by a block's gather, then read back once
+        by that block's reduction, for K and again for V — three passes over
+        the live pages where the kernel below makes one, and none over the
+        rest of the window (`tests/test_tpu_compile.py` holds the compiled
+        program to it). An idle slot must sit at position 0 to count as one
+        page: the engine's `_finish` keeps it there. The engine's default,
+        and the PARITY ORACLE the kernels are pinned against.
       - ``"pallas_paged"`` (paged mode only): the pool write plus the
         `ops/paged_attention` kernels, which walk each slot's page table
         directly and never materialize the gathered cache. Greedy decode is
         token-identical to the oracle (`tests/test_paged_kernel.py`).
 
-    `kv_cache_dtype` "int8"/"fp8_e4m3" stores the pool quantized with
-    per-page-per-head scale pools (see `update_slot_cache`); the kernels
-    receive the scale pools as operands and fuse the dequant into the
-    page-streaming loop, so quantized decode moves int8/fp8 bytes.
+    PAGED mode (`page_size > 0`; pool layout, page table, scratch page and
+    quantized pools: `_write_slot_pool`) decodes the same tokens as the
+    contiguous layout: the same positions, the same `cols <= pos` mask.
+    `kv_cache_dtype` "int8"/"fp8_e4m3" (paged only) stores the pool quantized
+    with per-page-per-head scale pools; the XLA read dequantizes each block it
+    gathers, and the kernels receive the scale pools as operands and fuse the
+    dequant into the page-streaming loop, so quantized decode moves int8/fp8
+    bytes.
 
     `mesh` (a 1-axis ("model",) Mesh, threaded from the model config's
     `decode_tp_mesh` by a tensor-parallel `ContinuousBatcher(tp=N)`) makes
     the kernel path `shard_map` over the KV-head grid so each device walks
     only its own pool shard; the XLA paths ignore it — GSPMD partitions them
-    automatically from the sharded pool/param operands.
+    by heads from the sharded pool/param operands, and the page axis stays
+    whole.
 
-    Args and cache semantics match `update_slot_cache`; returns the attention
-    output [B, s, Hq, D]."""
+    Args:
+        positions: [B, s] int32 — each token's absolute write/attend position.
+        page_table: [B, pages_per_slot] int32 pool-page ids per slot (paged only).
+        page_size / num_pages: static pool geometry (paged only).
+        kv_cache_dtype: "bf16" (unquantized, the model compute dtype) |
+            "int8" | "fp8_e4m3" — pool storage dtype (paged only).
+
+    Returns the attention output [B, s, Hq, D]."""
     global LAST_DISPATCH
     if attention_impl not in SLOT_ATTENTION_IMPLS:
         raise ValueError(
             f"unknown attention_impl {attention_impl!r}; expected one of {SLOT_ATTENTION_IMPLS}"
         )
-    if attention_impl == "pallas_paged":
-        if not page_size:
+    if not page_size:
+        if attention_impl == "pallas_paged":
             raise ValueError(
                 "attention_impl='pallas_paged' requires the paged slot cache "
                 "(page_size > 0); the contiguous layout has no page table to walk"
             )
-        from .paged_attention import paged_decode_attention, paged_verify_attention
-
-        pool_k, pool_v, pos, table, scales = _write_slot_pool(
-            module, k, v, positions, page_table, page_size, num_pages,
-            kv_cache_dtype=kv_cache_dtype,
-        )
-        k_scale, v_scale = scales if scales is not None else (None, None)
-        LAST_DISPATCH = "pallas_paged"
-        fn = paged_decode_attention if q.shape[1] == 1 else paged_verify_attention
-        if mesh is not None and mesh.shape.get("model", 1) > 1:
-            return _tp_paged_attention(
-                fn, q, pool_k, pool_v, table, pos, k_scale, v_scale, mesh
+        if kv_cache_dtype != "bf16":
+            raise ValueError(
+                f"kv_cache_dtype={kv_cache_dtype!r} requires the paged slot cache "
+                "(page_size > 0); the contiguous layout has no page-scale pool"
             )
-        return fn(q, pool_k, pool_v, table, pos, k_scale=k_scale, v_scale=v_scale)
-    k_all, v_all, decode_mask = update_slot_cache(
-        module, k, v, cache_length, positions,
-        page_table=page_table, page_size=page_size, num_pages=num_pages,
+        k_all, v_all, decode_mask = update_slot_cache(module, k, v, cache_length, positions)
+        return dot_product_attention(q, k_all, v_all, mask=decode_mask, causal=False)
+    _check_slot_positions(positions, *k.shape[:2])
+    pool_k, pool_v, pos, table, scales = _write_slot_pool(
+        module, k, v, positions, page_table, page_size, num_pages,
         kv_cache_dtype=kv_cache_dtype,
     )
-    return dot_product_attention(q, k_all, v_all, mask=decode_mask, causal=False)
+    if attention_impl == "xla":
+        LAST_DISPATCH = "xla"
+        return _live_page_attention(q, pool_k, pool_v, pos, table, scales)
+    from .paged_attention import paged_decode_attention, paged_verify_attention
+
+    k_scale, v_scale = scales if scales is not None else (None, None)
+    LAST_DISPATCH = "pallas_paged"
+    fn = paged_decode_attention if q.shape[1] == 1 else paged_verify_attention
+    if mesh is not None and mesh.shape.get("model", 1) > 1:
+        return _tp_paged_attention(
+            fn, q, pool_k, pool_v, table, pos, k_scale, v_scale, mesh
+        )
+    return fn(q, pool_k, pool_v, table, pos, k_scale=k_scale, v_scale=v_scale)
 
 
 def _auto_sequence_parallel(batch: int, seq_len: int):

@@ -21,7 +21,7 @@ ARRAY operand, never a Python scalar — TPU117 lints the violation):
     paged slot cache (`ops/attention._write_slot_pool`) stores pages in the
     quantized dtype with per-page-per-head scales riding in a parallel
     ``[num_pages, heads]`` pool array inside the same flax "cache" collection.
-    The XLA gather path dequantizes on read (the parity oracle); the Pallas
+    The XLA read dequantizes each block it gathers (the parity oracle); the Pallas
     paged kernels (`ops/paged_attention.py`) fuse the dequant into the
     page-streaming online-softmax loop, so quantized decode moves int8/fp8
     bytes per page, not bf16.

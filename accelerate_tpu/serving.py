@@ -11,7 +11,7 @@ but makes the BATCH dynamic at the host level:
     A slot is a logical cache row; requests come and go, the compiled program
     never changes shape. By default (`paged=True`) the cache is a POOL of
     fixed-size KV pages plus per-slot page tables riding as traced int32
-    operands (`ops/attention.update_slot_cache` paged mode): admission reserves
+    operands (`ops/attention.slot_cache_attention` paged mode): admission reserves
     `ceil((prompt + max_new) / page_size)` pages — memory proportional to each
     request's ACTUAL footprint, not the engine-wide `max_length` worst case —
     and a page-granular prefix cache (`paging.PagePool`) maps shared prompt
@@ -26,7 +26,7 @@ but makes the BATCH dynamic at the host level:
     and sample the first token. TTFT = one insert dispatch.
   - **decode_chunk** (ONE executable per engine): a `lax.scan` stepping ALL
     slots `chunk_size` tokens per dispatch through the models' per-row slot
-    cache (`ops/attention.update_slot_cache`). Per-slot position counters,
+    cache (`ops/attention.slot_cache_attention`). Per-slot position counters,
     per-slot GenerationConfig scalars (temperature / repetition penalty / EOS id
     / token budget ride as traced operands, the no-recompile discipline of
     generation.py's fused loop), EOS + budget masking, and a packed
@@ -37,7 +37,7 @@ but makes the BATCH dynamic at the host level:
     (`speculative.propose_ngram_drafts`) proposes `draft_tokens` continuations
     from the slot's own observed context, ONE multi-token verify dispatch
     (`make_causal_programs(..., verify_block=True)` over
-    `update_slot_cache`'s multi-position path) scores all of them, and the
+    `slot_cache_attention`'s multi-position path) scores all of them, and the
     longest greedily-confirmed prefix plus one bonus token is emitted — 1 to
     draft_tokens+1 tokens per dispatch instead of exactly 1, with greedy
     output token-identical to the plain path by construction. The accept/
@@ -647,6 +647,12 @@ class ContinuousBatcher:
             self._m_pages_in_use = self.metrics.gauge(
                 "serving_pages_in_use", help="pool pages referenced by in-flight requests"
             )
+            self._m_kv_live_page_share = self.metrics.gauge(
+                "serving_kv_live_page_share",
+                help="live pages of the active slots over num_slots * pages_per_slot, "
+                "as the last decode chunk was dispatched: the share of the window "
+                "the paged XLA read visits",
+            )
             self._m_prefix_hits = self.metrics.counter(
                 "serving_prefix_cache_hits_total",
                 help="prompt pages served from the shared-prefix cache",
@@ -1032,7 +1038,7 @@ class ContinuousBatcher:
         on-device n-gram drafter, scores the pending token plus every draft in
         ONE (draft_tokens+1)-position verify dispatch
         (`make_causal_programs(..., verify_block=True)` through
-        `ops.attention.update_slot_cache`'s multi-token path), and emits the
+        `ops.attention.slot_cache_attention`'s multi-token path), and emits the
         longest greedily-confirmed draft prefix plus one bonus token — up to
         draft_tokens+1 tokens per slot for one dispatch's latency, 1..k+1
         always, so it can only match or beat the plain chunk. Accept/reject,
@@ -1259,6 +1265,7 @@ class ContinuousBatcher:
         if self.paged:
             view["pages_total"] = self.pool.pages_total
             view["pages_in_use"] = self.pool.pages_in_use
+            view["kv_live_page_share"] = float(self._m_kv_live_page_share.value)
             view["prefix_cache"] = {
                 "enabled": self.use_prefix_cache,
                 "hits": int(self._m_prefix_hits.value),
@@ -1427,6 +1434,10 @@ class ContinuousBatcher:
         if slot is not None:
             self._slot_request[slot] = None
             self._active[slot] = False
+            # An idle slot sits at position 0: the paged XLA read takes a
+            # row's live pages from its position, and a released slot left at
+            # its last one would pass for that many pages of scratch.
+            self._pos[slot] = 0
             if self.paged:
                 # Release the slot's page references (a shared prefix page
                 # drops to CACHED at refcount 0, private pages go free) and
@@ -1786,6 +1797,7 @@ class ContinuousBatcher:
                 chunk_size=self.chunk_size,
                 active_slots=int(self._active.sum()),
                 pages_in_use=self.pool.pages_in_use if self.paged else None,
+                **self._live_page_counts(),
             ) as chunk_span:
                 with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:
                     operands = self._chunk_operands()
@@ -1826,6 +1838,20 @@ class ContinuousBatcher:
         # admission, and a drained buffer may be a read-only view.
         mirrors = tuple(np.array(x) for x in host[:4])
         return (mirrors, host[4][: int(host[5])], pos_before), wait_span.duration_s
+
+    def _live_page_counts(self) -> Dict[str, int]:
+        """What the paged read is about to visit, from the host mirrors: the
+        active slots' live pages (`pos // page_size + 1` each) beside the
+        window's `num_slots * pages_per_slot`, for the chunk's span; their
+        share goes to the `kv_live_page_share` gauge. The device also visits
+        one scratch page an idle slot, and a slot's pages grow inside the
+        chunk: neither is counted."""
+        if not self.paged:
+            return {}
+        live = int((self._pos[self._active] // self.page_size + 1).sum())
+        window = self.num_slots * self.pages_per_slot
+        self._m_kv_live_page_share.set(live / window)
+        return {"live_pages": live, "window_pages": window}
 
     def _chunk_counts(self, host) -> Dict[str, int]:
         """What a chunk's readback counts, for its span: the tokens streamed

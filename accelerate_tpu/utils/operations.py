@@ -582,8 +582,8 @@ def tree_gather_pages(pool, dense_struct, page_ids, cache_index):
             )
             # Insert the page_size axis after the page axis and the head_dim
             # axis at the end, then broadcast-multiply in fp32.
-            # The barrier keeps the quantized -> f32 convert in this fusion (as in
-            # `update_slot_cache`): hoisted to the gather it writes the pages in f32.
+            # The barrier keeps the quantized -> f32 convert in this fusion: hoisted
+            # to the gather it writes the pages in f32 (PERF.md §6, PR 25).
             scale_b = jnp.expand_dims(pages_scale, axis + 1)[..., None]
             pages = jax.lax.optimization_barrier(pages).astype(jnp.float32) * scale_b
         merged = pages.reshape(

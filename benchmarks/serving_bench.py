@@ -358,12 +358,13 @@ def estimate_decode_hbm_bytes(
     `compute_dtype_bytes` for the buffers XLA materializes in the compute
     dtype. Per implementation:
 
-      - ``xla``: `update_slot_cache` reads the pool pages (POOL dtype — the
-        only quantized pass), dequantizes into a logical [S, L, hkv, d] K/V
-        buffer it writes, then the masked attention reads that buffer back —
-        the gather write + re-read move COMPUTE-dtype bytes even on a
-        quantized pool, which is exactly why the oracle is the parity path
-        and dequant must fuse into the kernel to bank the bandwidth.
+      - ``xla``: `_live_page_attention` reads the live pool pages a block at
+        a time (POOL dtype — the only quantized pass), writes each gathered
+        block, then a reduction reads the block back — three passes over what
+        is live, the whole window in this worst case; the estimate charges
+        the write + re-read at COMPUTE dtype (the v5e's compiler fuses the
+        dequantize into the reduction and keeps the block in fast memory:
+        PERF.md §6, PR 28).
       - ``pallas_paged``: the kernel streams each table page into VMEM once —
         1 pass at POOL dtype, no materialized buffer.
 
@@ -385,7 +386,7 @@ def estimate_decode_hbm_bytes(
 
 def run_attention_workload(model, args, cfg, max_length, workload, tracer=None):
     """The kernel-vs-XLA A/B: the SAME mixed workload served through two
-    otherwise-identical paged engines, attention_impl "xla" (gather oracle)
+    otherwise-identical paged engines, attention_impl "xla" (the live-page oracle)
     vs "pallas_paged" (fused page-walk kernels). Each engine's timed pass
     runs under an armed TraceGuard with the hard 0-recompile /
     0-host-transfer gate — the kernel path must hold the compiled-once

@@ -117,23 +117,51 @@ def test_scatter_pages_roundtrip_and_untouched_pages():
     np.testing.assert_array_equal(got[0], src[0])
 
 
+def _gathered_window(pool, table, scale_pool=None, dtype=None):
+    """Every slot's whole logical window, gathered through its page table
+    ([B, P * page_size, h, d]), live or not: the view the gather-everything
+    read attended."""
+    from accelerate_tpu.ops.quantization import dequantize_kv_pages
+
+    pages = jnp.take(pool, table, axis=0, mode="clip")  # [B, P, ps, h, d]
+    if scale_pool is not None:
+        pages = dequantize_kv_pages(pages, jnp.take(scale_pool, table, axis=0, mode="clip"), dtype)
+    b, pages_per_slot, ps, h, d = pages.shape
+    return pages.reshape(b, pages_per_slot * ps, h, d)
+
+
+def _gather_everything_attention(q, pool_k, pool_v, pos, table, scales):
+    """ORACLE, a drop-in for `ops.attention._live_page_attention`: the paged
+    XLA read as the program had it until PR 28 — gather every slot's whole
+    window, mask `cols <= pos`, `dot_product_attention`. 3 x slots x window
+    bytes a layer whatever is live, which is why it lives here now."""
+    from accelerate_tpu.ops.attention import dot_product_attention
+
+    k_scale, v_scale = scales if scales is not None else (None, None)
+    k_full = _gathered_window(pool_k, table, k_scale, q.dtype)
+    v_full = _gathered_window(pool_v, table, v_scale, q.dtype)
+    cols = jnp.arange(k_full.shape[1])[None, None, :]
+    mask = (cols <= pos[:, :, None])[:, None, :, :]  # [B, 1, s, L]
+    return dot_product_attention(q, k_full, v_full, mask=mask, causal=False)
+
+
 def test_paged_slot_write_crosses_page_boundaries():
-    """The paged update_slot_cache write lands at pool[table[pos//ps], pos%ps]
-    and the gathered read reproduces the dense logical order, for positions on
-    both sides of every page boundary."""
+    """The paged write lands at pool[table[pos//ps], pos%ps] and the window
+    gathered through the table reproduces the dense logical order, for
+    positions on both sides of every page boundary."""
     import flax.linen as nn
 
-    from accelerate_tpu.ops.attention import update_slot_cache
+    from accelerate_tpu.ops.attention import _write_slot_pool
 
     ps, num_pages, P = 4, 6, 3
 
     class Probe(nn.Module):
         @nn.compact
         def __call__(self, k, v, positions, page_table):
-            return update_slot_cache(
-                self, k, v, P * ps, positions, page_table=page_table,
-                page_size=ps, num_pages=num_pages,
+            pool_k, _pool_v, pos, table, _scales = _write_slot_pool(
+                self, k, v, positions, page_table, ps, num_pages
             )
+            return _gathered_window(pool_k, table), pos
 
     probe = Probe()
     table = jnp.asarray([[2, 5, 1], [4, 3, 0]], jnp.int32)  # two slots
@@ -145,7 +173,7 @@ def test_paged_slot_write_crosses_page_boundaries():
         v = jnp.asarray(rng.normal(size=(2, 1, 2, 3)), jnp.float32)
         positions = jnp.full((2, 1), pos, jnp.int32)
         variables = {"cache": cache} if cache is not None else {}
-        (k_full, v_full, mask), mutated = probe.apply(
+        (k_full, clipped), mutated = probe.apply(
             variables, k, v, positions, table, mutable=["cache"]
         )
         cache = mutated["cache"]
@@ -153,14 +181,218 @@ def test_paged_slot_write_crosses_page_boundaries():
         # the gathered logical view holds every row written so far, in order
         for p_seen, kk in written.items():
             np.testing.assert_array_equal(np.asarray(k_full)[:, p_seen], kk[:, 0])
-        # mask admits exactly the written prefix
-        np.testing.assert_array_equal(
-            np.asarray(mask)[0, 0, 0], np.arange(P * ps) <= pos
-        )
+        np.testing.assert_array_equal(np.asarray(clipped), np.full((2, 1), pos))
     # physical placement: slot 0 wrote pages 2,5,1; slot 1 wrote 4,3,0
     pool_k = np.asarray(cache["cached_key"])
     np.testing.assert_array_equal(pool_k[5, 3], written[7][0, 0])  # slot 0, pos 7
     np.testing.assert_array_equal(pool_k[3, 0], written[4][1, 0])  # slot 1, pos 4
+
+
+# --------------------------------------------------------- the live-page read
+
+
+def _read_layer(read, page_size, num_pages, kv_cache_dtype="bf16"):
+    """One attention layer over a slot cache: `read` "live" is the program's
+    paged read, "everything" the oracle above behind the same write, and
+    "contiguous" the other layout."""
+    import flax.linen as nn
+
+    from accelerate_tpu.ops import attention
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v, positions, table):
+            length = table.shape[-1] * page_size
+            if read == "contiguous":
+                return attention.slot_cache_attention(self, q, k, v, length, positions)
+            if read == "live":
+                return attention.slot_cache_attention(
+                    self, q, k, v, length, positions, page_table=table, page_size=page_size,
+                    num_pages=num_pages, kv_cache_dtype=kv_cache_dtype,
+                )
+            pools = attention._write_slot_pool(
+                self, k, v, positions, table, page_size, num_pages, kv_cache_dtype=kv_cache_dtype
+            )
+            pool_k, pool_v, pos, clipped_table, scales = pools
+            return _gather_everything_attention(q, pool_k, pool_v, pos, clipped_table, scales)
+
+    return Layer()
+
+
+_POOL_DTYPES = {"int8": jnp.int8, "fp8_e4m3": jnp.float8_e4m3fn}
+
+
+def _read_case(first_positions, s=1, hq=4, hkv=2, pool="bf16", dtype=jnp.float32, seed=0):
+    """Slots at `first_positions` (each row's queries sit at first..first+s-1)
+    over a shuffled pool with P=6 pages of 4 tokens a slot: returns the layer
+    operands, a pool cache holding random history, and the same history laid
+    out contiguously. A slot's table row holds pool pages for its live pages
+    and the scratch page past them, as the engine leaves it."""
+    ps, P, d = 4, 6, 8
+    rng = np.random.default_rng(seed)
+    first = np.asarray(first_positions)
+    b = first.size
+    num_pages = b * P + 1
+    table = np.zeros((b, P), np.int32)
+    free = rng.permutation(np.arange(1, num_pages))
+    for row in range(b):
+        live = (first[row] + s - 1) // ps + 1
+        table[row, :live] = free[row * P : row * P + live]
+    spread = 1.0 if pool == "bf16" else 20.0  # quantized pages use their range
+    pools = {
+        name: jnp.asarray(rng.normal(size=(num_pages, ps, hkv, d)) * spread, jnp.float32)
+        for name in ("cached_key", "cached_value")
+    }
+    contiguous = {name: _gathered_window(x, jnp.asarray(table)).astype(dtype) for name, x in pools.items()}
+    cache = {name: x.astype(_POOL_DTYPES.get(pool, dtype)) for name, x in pools.items()}
+    if pool != "bf16":
+        for name in ("key_scale", "value_scale"):
+            cache[name] = jnp.asarray(rng.uniform(0.01, 0.05, size=(num_pages, hkv)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, s, hq, d)), dtype)
+    k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
+    v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
+    positions = jnp.asarray(first[:, None] + np.arange(s)[None, :], jnp.int32)
+    operands = (q, k, v, positions, jnp.asarray(table))
+    return operands, cache, contiguous, (ps, num_pages)
+
+
+def _run_read(read, operands, cache, geometry, pool="bf16"):
+    layer = _read_layer(read, *geometry, kv_cache_dtype=pool)
+    out, _ = jax.jit(lambda c, *a: layer.apply({"cache": c}, *a, mutable=["cache"]))(cache, *operands)
+    return np.asarray(out, np.float32)
+
+
+@pytest.fixture
+def block_pages(monkeypatch):
+    """Set the live-page read's block to `pages` pages of the case's K, through
+    the one constant it derives its block from (tiny test windows otherwise fit
+    one block, and the loops run once)."""
+    from accelerate_tpu.ops import attention
+
+    def set_block(pages, operands):
+        _q, k, *_ = operands
+        page_bytes = 4 * k.shape[2] * k.shape[3] * k.dtype.itemsize  # page_size 4
+        monkeypatch.setattr(attention, "_READ_BLOCK_BYTES", pages * page_bytes)
+
+    return set_block
+
+
+# Positions over P=6 pages of 4: slot 0 at position 0, slot 1 at max_length - 1,
+# slot 2 on a page boundary, slot 3 one short of one, slot 4 on another.
+_RAGGED = (0, 23, 4, 3, 8)  # live pages 1 + 6 + 2 + 1 + 3 = 13
+
+
+@pytest.mark.parametrize(
+    "first,block,kwargs",
+    [
+        pytest.param(_RAGGED, 4, {}, id="ragged-gqa-partial-last-block"),
+        pytest.param(_RAGGED, 4, {"hq": 4, "hkv": 4}, id="ragged-mha"),
+        pytest.param(_RAGGED, 1, {}, id="one-page-blocks"),
+        pytest.param((0, 23, 4, 3, 4), 4, {}, id="n-is-3-blocks-exactly"),  # 1+6+2+1+2 = 12
+        pytest.param((0,), 4, {}, id="n-is-1"),
+        pytest.param(_RAGGED, None, {}, id="window-fits-one-block"),
+        pytest.param(_RAGGED, 4, {"dtype": jnp.bfloat16}, id="bf16"),
+        pytest.param((0, 19, 4, 3, 8), 4, {"s": 5}, id="verify5"),  # 19 + 4 = max_length - 1
+        pytest.param((0, 19, 4, 3, 8), 4, {"s": 5, "hq": 4, "hkv": 4}, id="verify5-mha"),
+        pytest.param(_RAGGED, 4, {"pool": "int8"}, id="int8"),
+        pytest.param(_RAGGED, 4, {"pool": "fp8_e4m3"}, id="fp8"),
+        pytest.param((0, 19, 4, 3, 8), 4, {"s": 5, "pool": "int8"}, id="int8-verify5"),
+    ],
+)
+def test_live_page_read_matches_gather_everything_and_contiguous(first, block, kwargs, block_pages):
+    """The paged XLA read (blocks of live pages under a trip count from the
+    positions) == the gather-everything oracle == the contiguous layout, on
+    the layer's output: ragged positions, block boundaries, verify blocks,
+    GQA and MHA, quantized pools (which the contiguous layout does not have)."""
+    operands, cache, contiguous, geometry = _read_case(first, **kwargs)
+    pool = kwargs.get("pool", "bf16")
+    if block is not None:
+        block_pages(block, operands)
+    live = _run_read("live", operands, cache, geometry, pool)
+    assert np.isfinite(live).all()
+    tol = 2e-2 if kwargs.get("dtype") is jnp.bfloat16 else 2e-5
+    everything = _run_read("everything", operands, cache, geometry, pool)
+    np.testing.assert_allclose(live, everything, atol=tol, rtol=tol)
+    if pool == "bf16":
+        dense = _run_read("contiguous", operands, contiguous, geometry)
+        np.testing.assert_allclose(live, dense, atol=tol, rtol=tol)
+
+
+def test_idle_slot_at_position_zero_contributes_nothing(block_pages):
+    """An idle slot as the engine leaves it — table row all scratch, position 0
+    — is one entry of the live list (the scratch page): the other slots' rows
+    read what they read without it, whatever the scratch page holds, and its
+    own row is finite."""
+    operands, cache, _, geometry = _read_case((9, 0, 14))
+    q, k, v, positions, table = operands
+    table = table.at[1].set(SCRATCH_PAGE)
+    cache = {name: x.at[SCRATCH_PAGE].set(1e4) for name, x in cache.items()}  # loud garbage
+    block_pages(2, operands)
+    with_idle = _run_read("live", (q, k, v, positions, table), cache, geometry)
+    busy = np.asarray([0, 2])
+    without = _run_read(
+        "live", tuple(x[busy] for x in (q, k, v, positions, table)), cache, geometry
+    )
+    assert np.isfinite(with_idle).all()
+    np.testing.assert_allclose(with_idle[busy], without, atol=2e-6, rtol=2e-6)
+
+
+def _decode_logits(engine):
+    """One more decode step's logits off an engine's live state (its own
+    un-jitted step program; nothing is donated or adopted)."""
+    args = [engine.params, engine._cache, jnp.asarray(engine._token), jnp.asarray(engine._pos)]
+    if engine.paged:
+        args.append(jnp.asarray(engine._page_table))
+    logits, _cache = jax.jit(engine._step_raw)(*args)
+    return np.asarray(logits, np.float32)
+
+
+@pytest.mark.parametrize("family", ["llama-gqa", "gpt_neox-mha"])
+def test_paged_read_logits_match_oracle_and_contiguous(family, monkeypatch):
+    """Model level, both slot-cache families: three engines two chunks into the
+    same ragged requests (one slot left idle) — the paged read, the
+    gather-everything oracle in its place, the contiguous layout — hold the
+    same state and score the next step alike."""
+    import dataclasses
+
+    from accelerate_tpu.ops import attention
+
+    if family == "llama-gqa":
+        model = _model()
+    else:
+        from accelerate_tpu.models.gpt_neox import create_gpt_neox_model, gpt_neox_tiny
+
+        model = create_gpt_neox_model(
+            dataclasses.replace(gpt_neox_tiny(), max_position_embeddings=64), seq_len=32
+        )
+    vocab = model.module.config.vocab_size
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(1, vocab, (n,)).astype(np.int32) for n in (3, 16, 9)]
+    # One K page of these models is 8 tokens x kv heads x head_dim: blocks of 2 pages.
+    cfg = model.module.config
+    kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+    monkeypatch.setattr(attention, "_READ_BLOCK_BYTES", 2 * 8 * kv_heads * cfg.head_dim * 4)
+
+    def two_chunks(**kwargs):
+        engine = ContinuousBatcher(model, num_slots=4, max_length=32, chunk_size=2, **kwargs)
+        for i, p in enumerate(prompts):
+            engine.submit(Request(i, p, max_new_tokens=12))
+        engine.step()
+        engine.step()
+        return engine
+
+    live = two_chunks(page_size=8)
+    dense = two_chunks(paged=False)
+    assert live._pos[3] == 0 and not live._active[3]  # the idle slot
+    busy = np.arange(3)
+    live_logits = _decode_logits(live)[busy]
+    np.testing.assert_allclose(live_logits, _decode_logits(dense)[busy], atol=2e-5)
+    monkeypatch.setattr(attention, "_live_page_attention", _gather_everything_attention)
+    everything = two_chunks(page_size=8)
+    np.testing.assert_allclose(live_logits, _decode_logits(everything)[busy], atol=2e-5)
+    for other in (dense, everything):
+        np.testing.assert_array_equal(live._token, other._token)
+        np.testing.assert_array_equal(live._pos, other._pos)
 
 
 # ------------------------------------------------------------------ parity

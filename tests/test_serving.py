@@ -98,6 +98,49 @@ def test_greedy_parity_with_static_generator_mixed_workload():
         np.testing.assert_array_equal(outputs[i], _static_reference(model, p, m))
 
 
+def test_released_slot_sits_at_position_zero_through_reuse():
+    """The paged XLA read counts a row's live pages from its position, so a
+    slot that `_finish` releases goes back to position 0 (one page: scratch)
+    — while greedy decode through slot reuse stays token-identical to the
+    contiguous engine — and the host's count of what each chunk visits rides
+    the chunk's span and the `kv_live_page_share` gauge."""
+    from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
+    from accelerate_tpu.telemetry.tracing import Tracer
+
+    model = _model()
+    rng = np.random.default_rng(4)
+    lengths = [12, 5, 20, 3, 9]
+    budgets = [3, 9, 4, 8, 6]
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in lengths]
+    requests = lambda: [  # noqa: E731 — fresh Request objects per engine
+        Request(i, p, max_new_tokens=m) for i, (p, m) in enumerate(zip(prompts, budgets))
+    ]
+    recorder = FlightRecorder()
+    tracer = Tracer(recorder=recorder, category="serve")
+    paged = ContinuousBatcher(
+        model, num_slots=3, max_length=32, chunk_size=4, page_size=8, tracer=tracer
+    )
+    for req in requests():
+        paged.submit(req)
+    shares = []
+    while paged.pending:
+        paged.step()
+        idle = [slot for slot, r in enumerate(paged._slot_request) if r is None]
+        assert not paged._pos[idle].any(), (paged._pos, idle)
+        assert not paged._page_table[idle].any()
+        shares.append(paged.stats["kv_live_page_share"])
+    contiguous = ContinuousBatcher(model, num_slots=3, max_length=32, chunk_size=4, paged=False)
+    expect = contiguous.run(requests())
+    for i in range(len(prompts)):
+        np.testing.assert_array_equal(np.asarray(paged.results[i].tokens), expect[i])
+    assert not contiguous._pos.any()  # one exit path: both layouts release alike
+    chunks = [r for r in recorder.records() if r["name"] == "serve.decode_chunk"]
+    assert chunks and all(c["attrs"]["window_pages"] == 3 * 4 for c in chunks)
+    # the first chunk: prompts of 12, 5 and 20 tokens, 8 a page -> 2 + 1 + 3 live pages
+    assert chunks[0]["attrs"]["live_pages"] == 6 and shares[0] == 0.5
+    assert all(0 < c["attrs"]["live_pages"] <= c["attrs"]["window_pages"] for c in chunks)
+
+
 def test_greedy_parity_gpt_neox_family():
     """The slot-cache decode path is model-layer plumbing (llama AND gpt_neox
     gained the per-row cache write): pin parity on the second family too."""
