@@ -694,7 +694,6 @@ class _ModuleChecker:
         "paged_decode_attention",
         "paged_verify_attention",
         "slot_cache_attention",
-        "update_slot_cache",
         "quantized_pool_write",
         "dequantize_kv",
         "quantize_kv",
@@ -936,7 +935,7 @@ class _ModuleChecker:
                         "per-request deadline",
                     )
 
-    # -- kernel-path fallback (TPU115) -------------------------------------------
+    # -- pinned KV read / forced interpreter (TPU115) ----------------------------
     #: Pallas attention kernel entry points whose `interpret=` knob is a
     #: CPU-test shim, never a production setting.
     _PALLAS_KERNEL_FUNCS = {
@@ -946,11 +945,6 @@ class _ModuleChecker:
     }
     #: Constructors/seams that accept an attention implementation flag.
     _ATTENTION_IMPL_KWARGS = {"attention_impl", "decode_attention_impl"}
-    #: Call targets where paging is the DEFAULT (absent page kwargs still mean
-    #: a paged engine). Everywhere else — the seam functions, config
-    #: constructors — page_size defaults to 0, so an "xla" pin without page
-    #: kwargs is the contiguous layout's only legal impl, not a fallback.
-    _PAGED_BY_DEFAULT_CTORS = {"ContinuousBatcher", "Router"}
 
     @staticmethod
     def _call_name(func: ast.AST) -> Optional[str]:
@@ -961,15 +955,14 @@ class _ModuleChecker:
         return None
 
     def _check_kernel_fallback(self):
-        """TPU115: the Pallas paged-decode/block-verify kernels are the serving
-        hot path; the XLA gather materializes the whole logical cache per
-        dispatch and exists as the parity oracle. Flags (a) a serving
-        decode/verify construction pinned to the oracle by a LITERAL
-        attention_impl="xla" where the paged kernel applies (the call doesn't
-        also opt out of paging), and (b) a kernel call forced into interpret
+        """TPU115: the slot cache has two reads, the XLA live-page read and
+        the Pallas paged-decode/block-verify kernels, and only the first has
+        been timed on the chip (ROADMAP D13 settles the choice). Flags (a) a
+        serving decode/verify construction whose read is pinned by a LITERAL
+        attention_impl="xla", and (b) a kernel call forced into interpret
         mode with a literal interpret=True — the CPU-test shim; production
-        call sites use interpret=None so the kernel compiles on TPU. Both are
-        one explicit keyword away from silently serving off the kernel path."""
+        call sites use interpret=None so the kernel compiles on TPU. Each is
+        one explicit keyword that fixes the read where the call site is."""
         if not self.index.imports_jax:
             return
         for node in ast.walk(self.index.tree):
@@ -985,27 +978,14 @@ class _ModuleChecker:
                 and isinstance(impl, ast.Constant)
                 and impl.value == "xla"
             ):
-                paged = kwargs.get("paged")
-                page_size = kwargs.get("page_size") or kwargs.get("decode_page_size")
-                opted_out = (
-                    isinstance(paged, ast.Constant) and paged.value is False
-                ) or (isinstance(page_size, ast.Constant) and page_size.value in (0, None))
-                if name in self._PAGED_BY_DEFAULT_CTORS:
-                    paged_applies = not opted_out
-                else:
-                    # Seam/config spellings default to page_size=0: paging only
-                    # applies when the call really threads page geometry (and
-                    # doesn't zero it out).
-                    paged_applies = page_size is not None and not opted_out
-                if paged_applies:
-                    self.emit(
-                        node,
-                        "TPU115",
-                        'attention_impl="xla" pins this decode/verify program to the '
-                        "XLA oracle (three passes over every live page per dispatch) "
-                        'where the Pallas paged kernel applies — pass "pallas_paged", '
-                        "or suppress where the oracle is deliberate",
-                    )
+                self.emit(
+                    node,
+                    "TPU115",
+                    'the literal attention_impl="xla" pins this decode/verify '
+                    "program's KV read to the XLA live-page read — thread the "
+                    "impl as a value the caller sets, or suppress where the pin "
+                    "is deliberate",
+                )
             if name in self._PALLAS_KERNEL_FUNCS:
                 interp = kwargs.get("interpret")
                 if isinstance(interp, ast.Constant) and interp.value is True:

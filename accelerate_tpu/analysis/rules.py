@@ -175,16 +175,15 @@ RULES = (
         id="TPU115",
         slug="kernel-fallback",
         severity="warn",
-        summary='serving decode/verify programs pinned to attention_impl="xla" '
-        "where the Pallas paged kernel applies, or a Pallas attention kernel "
-        "forced into interpret mode outside test code",
-        fixit='pass attention_impl="pallas_paged" for paged serving engines (the '
-        "XLA gather path materializes the whole logical cache per decode "
-        "dispatch and is the parity oracle; the kernels compile for the chip "
-        "and run on it, and which read is faster there is not measured yet — "
-        "ROADMAP S4) — or suppress where the oracle is deliberate; "
-        "interpret=True is the CPU-test shim, production call sites must let "
-        "the kernel compile (interpret=None auto-selects)",
+        summary='serving decode/verify programs whose KV read is pinned by a '
+        'literal attention_impl="xla", or a Pallas attention kernel forced '
+        "into interpret mode outside test code",
+        fixit="thread attention_impl as a value the caller sets (the XLA "
+        "live-page read is the only read timed on the chip; the Pallas "
+        "kernels compile for the chip and run on it, and which is faster "
+        "there is not measured yet — ROADMAP D13) — or suppress where the pin "
+        "is deliberate; interpret=True is the CPU-test shim, production call "
+        "sites must let the kernel compile (interpret=None auto-selects)",
     ),
     Rule(
         id="TPU116",

@@ -748,7 +748,6 @@ class ChaosRunner:
         max_queue: int = 4,
         max_new_tokens: int = 4,
         max_cycles: int = 200,
-        paged: bool = True,
         speculative: bool = False,
         attention_impl: str = "xla",
         kv_cache_dtype: str = "bf16",
@@ -781,15 +780,14 @@ class ChaosRunner:
             rope_theta=10000.0,
         )
         model = create_llama_model(cfg, seq_len=32)
-        # Paged (default): page_size=4 with a shared 8-token system prompt on
-        # half the traffic, so the dispatch-failure sweeps exercise page
-        # refcounts AND live prefix registrations — the page-ledger invariant
-        # below is non-vacuous. paged=False drives the same sweeps through the
-        # contiguous fallback layout (its blast-radius recovery stays covered).
+        # page_size=4 with a shared 8-token system prompt on half the traffic,
+        # so the dispatch-failure sweeps exercise page refcounts AND live
+        # prefix registrations — the page-ledger invariant below is
+        # non-vacuous.
         engine = ContinuousBatcher(
             model, num_slots=num_slots, max_length=64, chunk_size=chunk_size,
             max_queue=max_queue, registry=self.session.registry,
-            tracer=self.tracer, paged=paged, page_size=4,
+            tracer=self.tracer, page_size=4,
             speculative=speculative, draft_tokens=3,
             attention_impl=attention_impl, kv_cache_dtype=kv_cache_dtype,
             tp=tp,
@@ -966,7 +964,7 @@ class ChaosRunner:
             model, replicas=replicas, num_slots=num_slots, max_length=64,
             chunk_size=chunk_size, max_queue=max_queue, default_deadline_s=60.0,
             hedge_after_s=hedge_after_s, registry=self.session.registry,
-            tracer=self.tracer, paged=True, page_size=4,
+            tracer=self.tracer, page_size=4,
             rejoin_cooldown_s=0.05, probation_steps=2, stall_degrade_s=None,
         )
         RouterInjector(self.session).arm(router)
@@ -1162,7 +1160,7 @@ class ChaosRunner:
             model,
             engine_kwargs=dict(
                 num_slots=num_slots, max_length=64, chunk_size=chunk_size,
-                max_queue=max_queue, paged=True, page_size=4,
+                max_queue=max_queue, page_size=4,
             ),
             workdir=workdir, env=worker_env, step_timeout_s=step_timeout_s,
             transport=transport,
@@ -1301,10 +1299,19 @@ class ChaosRunner:
             faults_before = landed
             cycles += 1
         results = dict(router.drain())
-        # Recovery phase: cycle until every ejected replica rejoined (the
-        # respawn path), then until the autoscaler converged back to its floor.
+        # Recovery phase: cycle until every fault has run its course — a
+        # replica mid-reconnect either heals in place or exhausts its budget
+        # and escalates to a death, and every ejected replica rejoins (the
+        # respawn path) — then until the autoscaler converged back to its
+        # floor. Traffic that drains faster than `reconnect_deadline_s` must
+        # not end the run with a reconnect still undecided: the verdict would
+        # then depend on how long the workers took to compile.
         while (
-            any(s == "ejected" for s in router.replica_states.values())
+            any(
+                r.state in ("ejected", "reconnecting")
+                or getattr(r.engine, "reconnecting", False)
+                for r in router.replica_set.replicas
+            )
             and cycles < max_cycles
         ):
             self.session.clock.sleep(0.01)
@@ -1696,15 +1703,13 @@ class ChaosRunner:
 
     @staticmethod
     def _check_page_ledger(engine) -> InvariantCheck:
-        """Paged engines must end a drained run with ZERO pages in use — every
+        """An engine must end a drained run with ZERO pages in use — every
         refcount returned through finish/cancel/error/abort, none leaked by the
         blast-radius rebuild — and a structurally consistent pool: no page both
         free and cached, no prefix registration pointing at a freed page (the
         'resurrected prefix' failure a post-recovery stale hash map would
-        cause). Contiguous engines pass vacuously."""
-        pool = getattr(engine, "pool", None)
-        if pool is None:
-            return InvariantCheck("page_ledger", True, {"note": "contiguous engine (no pool)"})
+        cause)."""
+        pool = engine.pool
         problems = pool.check_consistency()
         return InvariantCheck(
             "page_ledger",

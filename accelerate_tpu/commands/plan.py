@@ -30,8 +30,7 @@ def register_subcommand(subparsers):
     parser.add_argument("--tp", type=int, default=2, help="Tensor-parallel degree to plan for")
     parser.add_argument("--num-slots", type=int, default=8, help="Serving slots (decode batch rows)")
     parser.add_argument("--max-length", type=int, default=None, help="Per-slot cache length (default: model max)")
-    parser.add_argument("--page-size", type=int, default=16, help="KV pool page size (paged cache)")
-    parser.add_argument("--no-paged", action="store_true", help="Price the contiguous per-slot KV layout")
+    parser.add_argument("--page-size", type=int, default=16, help="KV pool page size")
     parser.add_argument("--kv-cache-dtype", default="bf16", choices=["bf16", "int8", "fp8_e4m3"],
                         help="KV pool storage dtype the cost model prices")
     parser.add_argument("--weight-dtype", default="bf16", choices=["bf16", "int8"],
@@ -409,20 +408,11 @@ def plan_command(args):
         args.model, args.seq_len, materialize=refine >= 1
     )
     max_length = int(args.max_length or config.max_position_embeddings)
-    paged = not args.no_paged
-    if paged:
-        pages_per_slot = -(-max_length // args.page_size)
-        padded_length = pages_per_slot * args.page_size
-        num_pages = args.num_slots * pages_per_slot + 1
-    else:
-        padded_length = max_length
-        num_pages = 0
+    num_pages = args.num_slots * -(-max_length // args.page_size) + 1
 
     mesh = {"model": int(args.tp)}
     plan_kwargs = dict(
         num_slots=args.num_slots,
-        padded_length=padded_length,
-        paged=paged,
         page_size=args.page_size,
         num_pages=num_pages,
         kv_cache_dtype=args.kv_cache_dtype,
@@ -476,7 +466,7 @@ def plan_command(args):
         return payload
 
     print(f"[plan] {args.model} | tp={args.tp} | slots={args.num_slots} | "
-          f"{'paged' if paged else 'contiguous'} kv={args.kv_cache_dtype} "
+          f"kv={args.kv_cache_dtype} "
           f"weights={args.weight_dtype}")
     print()
     print(plan.describe())

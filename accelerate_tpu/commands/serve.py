@@ -116,7 +116,6 @@ def register_subcommand(subparsers):
         help='JSONL with one {"tokens": [...], "max_new_tokens": N?} object per line '
         "(replaces the synthetic workload)",
     )
-    parser.add_argument("--no-paged", action="store_true", help="Contiguous per-slot KV layout")
     parser.add_argument(
         "--weight-dtype", default="bf16", choices=["bf16", "int8"],
         help="weight storage dtype: int8 quantizes per-output-channel at load "
@@ -125,7 +124,7 @@ def register_subcommand(subparsers):
     )
     parser.add_argument(
         "--kv-cache-dtype", default="bf16", choices=["bf16", "int8", "fp8_e4m3"],
-        help="KV page-pool storage dtype (paged cache only): int8/fp8_e4m3 "
+        help="KV page-pool storage dtype: int8/fp8_e4m3 "
         "store pages quantized with per-page-per-head scales, cutting "
         "cache-read bytes 2x vs bf16 and multiplying pool capacity",
     )
@@ -182,13 +181,6 @@ def serve_command(args):
     from ..models import create_named_model, get_model_family
     from ..router import Router
 
-    if args.no_paged and args.kv_cache_dtype != "bf16":
-        print(
-            "accelerate-tpu serve: --kv-cache-dtype requires the paged KV cache "
-            "(drop --no-paged)",
-            file=sys.stderr,
-        )
-        raise SystemExit(2)
     if args.sharding == "auto" and args.tp <= 1:
         print(
             "accelerate-tpu serve: --sharding auto plans a tensor-parallel "
@@ -251,7 +243,6 @@ def serve_command(args):
         max_replicas=args.max_replicas,
         out_of_process=args.out_of_process,
         worker_kwargs=worker_kwargs or None,
-        paged=not args.no_paged,
         weight_dtype=args.weight_dtype,
         kv_cache_dtype=args.kv_cache_dtype,
         tp=args.tp,

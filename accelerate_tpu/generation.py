@@ -157,7 +157,6 @@ def _params_resolver(model):
 def make_causal_programs(
     module,
     resolve,
-    full_prefill_logits: bool = False,
     step_mask_operand: bool = False,
     verify_block: bool = False,
 ):
@@ -167,14 +166,12 @@ def make_causal_programs(
     programs.
 
     `prefill(params, input_ids, positions, attention_mask=None)` writes the whole
-    prompt into a fresh cache and returns `(last_logits, cache)` — or the full
-    `[B, S, V]` logits with `full_prefill_logits=True` (serving's bucketed insert
-    reads the logits at each prompt's REAL length, not the padded end);
+    prompt into a fresh cache and returns `(last_logits, cache)`;
     `step(params, cache, token, position)` advances one token. Both are un-jitted
     so callers can trace them inside larger fused programs.
 
     `step_mask_operand=True` gives `step` a fifth argument threaded through as
-    the module's `attention_mask`: the PAGED slot cache reads it as the
+    the module's `attention_mask`: the slot cache reads it as the
     [B, pages_per_slot] int32 page table (a traced operand — the one decode
     executable survives every admission), since slot decode never carries a
     boolean mask of its own. The module config's `decode_attention_impl`
@@ -193,11 +190,12 @@ def make_causal_programs(
     during tracing); "bf16" is a no-op context.
 
     `verify_block=True` appends the speculative-decode seam to the tuple:
-    `verify(params, cache, tokens, positions[, mask])` scores a [B, s] token
+    `verify(params, cache, tokens, positions, mask=None)` scores a [B, s] token
     BLOCK (the pending token plus s-1 draft proposals) in ONE dispatch,
     writing every block position's K/V and returning the full [B, s, V]
-    logits plus the mutated cache — the multi-token twin of `step`, with the
-    same mask-operand convention. Position j's logits are computed after
+    logits plus the mutated cache — the multi-token twin of `step`; the
+    serving engine passes its page table as `mask`, the static `Generator`
+    (a dense decode cache) leaves it out. Position j's logits are computed after
     exactly the block prefix <= j (the cache paths mask per-query), so
     `argmax(logits[:, j])` is precisely the token greedy decode would emit
     after accepting the first j block tokens — the property the accept loop
@@ -214,8 +212,6 @@ def make_causal_programs(
             logits, mutated = module.apply(
                 resolve(params), input_ids, attention_mask, positions, mutable=["cache"]
             )
-        if full_prefill_logits:
-            return logits, mutated["cache"]
         return logits[:, -1, :], mutated["cache"]
 
     def step(params, cache, token, position):
@@ -240,14 +236,7 @@ def make_causal_programs(
             )
         return logits[:, -1, :], mutated["cache"]
 
-    def verify(params, cache, tokens, positions):
-        with weight_autocast(weight_dtype):
-            logits, mutated = module.apply(
-                {**resolve(params), "cache": cache}, tokens, None, positions, mutable=["cache"]
-            )
-        return logits, mutated["cache"]
-
-    def verify_with_mask(params, cache, tokens, positions, mask):
+    def verify(params, cache, tokens, positions, mask=None):
         with weight_autocast(weight_dtype):
             logits, mutated = module.apply(
                 {**resolve(params), "cache": cache}, tokens, mask, positions, mutable=["cache"]
@@ -256,7 +245,7 @@ def make_causal_programs(
 
     step_fn = step_with_mask if step_mask_operand else step
     if verify_block:
-        return prefill, step_fn, (verify_with_mask if step_mask_operand else verify)
+        return prefill, step_fn, verify
     return prefill, step_fn
 
 

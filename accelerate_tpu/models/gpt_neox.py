@@ -54,7 +54,7 @@ class GPTNeoXConfig:
     decode_cache_length: int = 0
     # Per-row slot-cache decode for continuous batching (see LlamaConfig).
     decode_slot_cache: bool = False
-    # Paged slot cache: pool geometry + page tables via the mask seam (see
+    # The slot cache's pool: geometry + page tables via the mask seam (see
     # LlamaConfig for the full semantics).
     decode_page_size: int = 0
     decode_num_pages: int = 0
@@ -69,6 +69,13 @@ class GPTNeoXConfig:
     # 1-axis ("model",) Mesh the Pallas page-walk kernels shard_map over.
     decode_tp_mesh: Optional[Any] = None
     param_dtype: str = "float32"
+
+    def __post_init__(self):
+        if self.decode_slot_cache and self.decode_page_size < 1:
+            raise ValueError(
+                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
+                "cache is a page pool"
+            )
 
     @property
     def head_dim(self) -> int:
@@ -114,13 +121,13 @@ class GPTNeoXAttention(nn.Module):
             L = cfg.decode_cache_length
             if cfg.decode_slot_cache:
                 # Continuous-batching decode: per-row scatter writes at each
-                # slot's own position (serving.ContinuousBatcher). Paged mode
-                # reads `mask` as the [B, pages_per_slot] int32 page table;
+                # slot's own position (serving.ContinuousBatcher). `mask` is
+                # the [B, pages_per_slot] int32 page table;
                 # decode_attention_impl picks the XLA live-page read or the fused
                 # Pallas page-walk kernels.
                 out = slot_cache_attention(
                     self, q, k, v, L, positions,
-                    page_table=mask if cfg.decode_page_size else None,
+                    page_table=mask,
                     page_size=cfg.decode_page_size,
                     num_pages=cfg.decode_num_pages,
                     attention_impl=cfg.decode_attention_impl,

@@ -61,21 +61,21 @@ class LlamaConfig:
     # Slot-batched serving (serving.ContinuousBatcher): every batch row is an
     # independent request slot whose decode position comes from the `positions`
     # argument (per-row scatter writes) instead of the shared `cache_index`.
+    # Needs decode_page_size > 0: the slot cache is a page pool.
     decode_slot_cache: bool = False
-    # Paged slot cache: K/V live in one pool of decode_num_pages fixed-size
-    # pages ([num_pages, page_size, h, d]) instead of a dense row per slot, and
-    # the per-slot page tables ride in through the `attention_mask` argument as
-    # [B, pages_per_slot] int32 traced operands (slot decode never carries a
-    # boolean mask, so the seam is free). 0 = contiguous per-slot rows.
+    # The slot cache's pool: K/V live in decode_num_pages fixed-size pages
+    # ([num_pages, page_size, h, d]), and the per-slot page tables ride in
+    # through the `attention_mask` argument as [B, pages_per_slot] int32 traced
+    # operands (slot decode never carries a boolean mask, so the seam is free).
     decode_page_size: int = 0
     decode_num_pages: int = 0
-    # Serving-decode attention implementation (paged slot cache only):
+    # Serving-decode attention implementation:
     # "xla" = gather the slot's pages into a logical buffer then attend (the
     # parity oracle); "pallas_paged" = the ops/paged_attention kernels, which
     # walk the page table inside the kernel and never materialize the gather.
     # Threaded from serving.ContinuousBatcher(attention_impl=...).
     decode_attention_impl: str = "xla"
-    # KV page-pool storage dtype (paged slot cache only): "bf16" keeps the
+    # KV page-pool storage dtype: "bf16" keeps the
     # model compute dtype; "int8"/"fp8_e4m3" store pages quantized with
     # per-page-per-head scale pools riding in the cache collection
     # (ops/quantization.py). Threaded from ContinuousBatcher(kv_cache_dtype=).
@@ -91,6 +91,13 @@ class LlamaConfig:
     # the KV-head grid, since pallas_call has no GSPMD partitioning rule.
     # None = single-device serving, byte-for-byte the pre-TP behavior.
     decode_tp_mesh: Optional[Any] = None
+
+    def __post_init__(self):
+        if self.decode_slot_cache and self.decode_page_size < 1:
+            raise ValueError(
+                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
+                "cache is a page pool"
+            )
 
     @property
     def head_dim(self) -> int:
@@ -138,13 +145,13 @@ class LlamaAttention(nn.Module):
             if cfg.decode_slot_cache:
                 # Continuous-batching decode: each slot row writes at its OWN
                 # position (per-row scatter) and attends its written prefix
-                # only. Paged mode reads `mask` as the slot page table ([B,
-                # pages_per_slot] int32) mapping positions onto pool pages;
+                # only. `mask` is the slot page table ([B, pages_per_slot]
+                # int32) mapping positions onto pool pages;
                 # decode_attention_impl picks the XLA live-page read or the
                 # fused Pallas page-walk kernels.
                 out = slot_cache_attention(
                     self, q, k, v, cfg.decode_cache_length, positions,
-                    page_table=mask if cfg.decode_page_size else None,
+                    page_table=mask,
                     page_size=cfg.decode_page_size,
                     num_pages=cfg.decode_num_pages,
                     attention_impl=cfg.decode_attention_impl,

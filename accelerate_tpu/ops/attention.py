@@ -8,8 +8,9 @@
     activations are sequence-sharded.
 
 `slot_cache_attention` is the SERVING twin: the fused cache-write + attend seam
-for slot-batched decode, with its own `attention_impl` dispatch — the XLA
-oracle (paged: blocks of live pages gathered and reduced in two loops), or the
+for slot-batched decode over the KV page pool, with its own `attention_impl`
+dispatch — the XLA read (blocks of live pages gathered and reduced in two
+loops), or the
 Pallas paged-decode / block-verify kernels (ops/paged_attention.py) that walk
 the page table without materializing any gathered page.
 
@@ -102,72 +103,12 @@ def update_decode_cache(module, k, v, cache_length: int, pad_mask=None):
     return cached_k.value, cached_v.value, decode_mask
 
 
-def update_slot_cache(module, k, v, cache_length: int, positions):
-    """Per-ROW cache writes for slot-based continuous batching (serving.py),
-    CONTIGUOUS layout: every batch row is an independent request slot with its
-    OWN running position, so the new K/V of row i lands at `positions[i]`
-    instead of a shared scalar `cache_index`. The scatter (`.at[rows, pos].set`)
-    is the per-slot twin of `update_decode_cache`'s `dynamic_update_slice`; the
-    returned mask lets each query attend exactly to its written prefix
-    `cols <= its position` — stale K/V from a previous slot occupant above the
-    current position is never visible, which is what makes slot reuse sound
-    without ever clearing the cache.
-
-    Decode (s == 1) and speculative VERIFY BLOCKS (s == draft_tokens + 1,
-    positions[i] = pos_i + [0..s)): the s > 1 path writes every block token's
-    K/V at its own position and returns a per-query causal mask, so one
-    dispatch scores all s positions — query j of row i attends
-    `cols <= positions[i, j]`, i.e. the accepted prefix plus the block tokens
-    at or before it, every one of which this same dispatch just wrote. Rejected
-    draft positions need no rollback: the engine simply does not advance the
-    slot's position past the accepted prefix, the mask keeps the stale K/V
-    invisible, and the next dispatch overwrites it before anything attends it.
-    Positions past the cache capacity (a draft window overrunning a finishing
-    request) clip to the last cell, which is never attended — the final token
-    of a capacity-exact request is emitted without ever being dispatched.
-
-    Slot PREFILL goes through the ordinary `update_decode_cache` path on a
-    batch-1 cache that the serving engine scatters into the slot row
-    (utils/operations.tree_scatter_rows) — or, paged, into the slot's pool
-    pages (tree_scatter_pages) — so one attention code path covers both
-    programs.
-
-    The PAGED layout (`slot_cache_attention(page_size > 0)`) keeps these
-    semantics — the same positions, the same `cols <= pos` mask, the same
-    tokens — but has no gathered view to return: `_write_slot_pool` writes
-    through the page table and `_live_page_attention` reads the live pages
-    alone. The read that gathered every slot's whole window here is now the
-    oracle of tests/test_paging.py.
-
-    Args:
-        positions: [B, s] int32 — each token's absolute write/attend position.
-
-    Returns `(k_full, v_full, decode_mask)` like `update_decode_cache`.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    b, s, h, d = k.shape
-    _check_slot_positions(positions, b, s)
-    L = cache_length
-    cached_k = module.variable("cache", "cached_key", jnp.zeros, (b, L, h, d), k.dtype)
-    cached_v = module.variable("cache", "cached_value", jnp.zeros, (b, L, h, d), v.dtype)
-    pos = jnp.clip(positions, 0, L - 1).astype(jnp.int32)  # [B, s]
-    rows = jnp.arange(b)[:, None]
-    with jax.named_scope("kv_write"):
-        cached_k.value = cached_k.value.at[rows, pos].set(k)
-        cached_v.value = cached_v.value.at[rows, pos].set(v)
-    cols = jnp.arange(L)[None, None, :]
-    decode_mask = (cols <= pos[:, :, None])[:, None, :, :]  # [B, 1, s, L]
-    return cached_k.value, cached_v.value, decode_mask
-
-
 def _check_slot_positions(positions, b: int, s: int):
     if positions.shape != (b, s):
         raise ValueError(
             f"the slot cache needs per-token positions [B, S] = {(b, s)}, "
             f"got {positions.shape}; slot prefill goes through "
-            "update_decode_cache on a batch-1 cache (tree_scatter_rows)"
+            "update_decode_cache on a batch-1 cache (tree_scatter_pages)"
         )
 
 
@@ -175,7 +116,7 @@ def _write_slot_pool(
     module, k, v, positions, page_table, page_size: int, num_pages: int,
     kv_cache_dtype: str = "bf16",
 ):
-    """The paged slot cache's WRITE half: scatter this dispatch's [B, s] K/V
+    """The slot cache's WRITE half: scatter this dispatch's [B, s] K/V
     into the page pool through the slot page tables, and return the updated
     pools plus the clipped positions/table and (quantized pools only) the
     `(key_scale, value_scale)` parallel scale pools. Shared by both of
@@ -183,10 +124,28 @@ def _write_slot_pool(
     kernels) so the two can never disagree about where K/V lives — or what
     scale it was stored under.
 
+    Every batch row is an independent request slot with its OWN running
+    position, so the new K/V of row i lands at `positions[i]` instead of a
+    shared scalar `cache_index`, and each query attends exactly its written
+    prefix `cols <= its position`: stale K/V from a previous slot occupant
+    above the current position is never visible, which is what makes slot
+    reuse sound without ever clearing the cache. Decode (s == 1) and
+    speculative VERIFY BLOCKS (s == draft_tokens + 1, positions[i] = pos_i +
+    [0..s)) share it: query j of row i attends the accepted prefix plus the
+    block tokens at or before it, every one of which this same dispatch just
+    wrote. Rejected draft positions need no rollback: the engine does not
+    advance the slot past the accepted prefix, the mask keeps the stale K/V
+    invisible, and the next dispatch overwrites it before anything attends
+    it. Positions past the window (a draft window overrunning a finishing
+    request) clip to the last cell, which is never attended. Slot PREFILL
+    goes through the ordinary `update_decode_cache` path on a batch-1 cache
+    that the serving engine scatters into the slot's pool pages
+    (`utils/operations.tree_scatter_pages`).
+
     The cache collection holds one POOL of `num_pages` fixed-size pages
-    ([num_pages, page_size, h, d]) instead of one `cache_length` row per slot,
-    and `page_table` ([B, pages_per_slot] int32, a traced operand — admissions
-    never recompile) maps each slot's logical positions onto pool pages. Row
+    ([num_pages, page_size, h, d]), and `page_table` ([B, pages_per_slot]
+    int32, a traced operand — admissions never recompile) maps each slot's
+    logical positions onto pool pages. Row
     i's new K/V lands at `pool[page_table[i, pos_i // page_size], pos_i %
     page_size]`. Page 0 is the engine's reserved scratch page: the host points
     inactive slots' table rows at it, so their (discarded) writes can never
@@ -206,8 +165,11 @@ def _write_slot_pool(
 
     from .quantization import kv_quant_spec, quantized_pool_write
 
-    if page_table is None:
-        raise ValueError("paged slot cache needs a [B, pages_per_slot] page_table operand")
+    if page_table is None or page_size < 1:
+        raise ValueError(
+            "the slot cache is a page pool: it needs a [B, pages_per_slot] "
+            "page_table operand and page_size >= 1"
+        )
     b, s, h, d = k.shape
     pages_per_slot = page_table.shape[-1]
     L = pages_per_slot * page_size
@@ -418,9 +380,7 @@ def slot_cache_attention(
     One function covers decode steps (s == 1) and speculative verify blocks
     (s == draft_tokens + 1); `attention_impl` picks the read-side engine:
 
-      - ``"xla"`` (default, and the only option for the contiguous layout).
-        Contiguous: `update_slot_cache`'s masked read of every slot's whole
-        row + `dot_product_attention`. Paged: `_write_slot_pool`, then
+      - ``"xla"`` (default): `_write_slot_pool`, then
         `_live_page_attention` walks the LIVE pages of all slots in fixed
         blocks under a trip count it computes from `positions`, so a
         dispatch's bytes follow the live tokens: every live page is read
@@ -431,15 +391,18 @@ def slot_cache_attention(
         program to it). An idle slot must sit at position 0 to count as one
         page: the engine's `_finish` keeps it there. The engine's default,
         and the PARITY ORACLE the kernels are pinned against.
-      - ``"pallas_paged"`` (paged mode only): the pool write plus the
+      - ``"pallas_paged"``: the pool write plus the
         `ops/paged_attention` kernels, which walk each slot's page table
         directly and never materialize the gathered cache. Greedy decode is
         token-identical to the oracle (`tests/test_paged_kernel.py`).
 
-    PAGED mode (`page_size > 0`; pool layout, page table, scratch page and
-    quantized pools: `_write_slot_pool`) decodes the same tokens as the
-    contiguous layout: the same positions, the same `cols <= pos` mask.
-    `kv_cache_dtype` "int8"/"fp8_e4m3" (paged only) stores the pool quantized
+    The cache is a page pool (`page_size >= 1` and a `page_table` are
+    required; pool layout, scratch page and quantized pools:
+    `_write_slot_pool`). It decodes the same tokens as a dense `cache_length`
+    row a slot read under the same `cols <= pos` mask — the reference in
+    `tests/test_paging.py`. `cache_length` itself sizes nothing here: the
+    window is `page_table.shape[-1] * page_size`.
+    `kv_cache_dtype` "int8"/"fp8_e4m3" stores the pool quantized
     with per-page-per-head scale pools; the XLA read dequantizes each block it
     gathers, and the kernels receive the scale pools as operands and fuse the
     dequant into the page-streaming loop, so quantized decode moves int8/fp8
@@ -454,10 +417,10 @@ def slot_cache_attention(
 
     Args:
         positions: [B, s] int32 — each token's absolute write/attend position.
-        page_table: [B, pages_per_slot] int32 pool-page ids per slot (paged only).
-        page_size / num_pages: static pool geometry (paged only).
+        page_table: [B, pages_per_slot] int32 pool-page ids per slot.
+        page_size / num_pages: static pool geometry.
         kv_cache_dtype: "bf16" (unquantized, the model compute dtype) |
-            "int8" | "fp8_e4m3" — pool storage dtype (paged only).
+            "int8" | "fp8_e4m3" — pool storage dtype.
 
     Returns the attention output [B, s, Hq, D]."""
     global LAST_DISPATCH
@@ -465,19 +428,6 @@ def slot_cache_attention(
         raise ValueError(
             f"unknown attention_impl {attention_impl!r}; expected one of {SLOT_ATTENTION_IMPLS}"
         )
-    if not page_size:
-        if attention_impl == "pallas_paged":
-            raise ValueError(
-                "attention_impl='pallas_paged' requires the paged slot cache "
-                "(page_size > 0); the contiguous layout has no page table to walk"
-            )
-        if kv_cache_dtype != "bf16":
-            raise ValueError(
-                f"kv_cache_dtype={kv_cache_dtype!r} requires the paged slot cache "
-                "(page_size > 0); the contiguous layout has no page-scale pool"
-            )
-        k_all, v_all, decode_mask = update_slot_cache(module, k, v, cache_length, positions)
-        return dot_product_attention(q, k_all, v_all, mask=decode_mask, causal=False)
     _check_slot_positions(positions, *k.shape[:2])
     pool_k, pool_v, pos, table, scales = _write_slot_pool(
         module, k, v, positions, page_table, page_size, num_pages,

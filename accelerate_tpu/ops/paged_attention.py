@@ -2,19 +2,18 @@
 speculative block-verify variant, with the page-table gather FUSED into the
 attention walk.
 
-The XLA paged path (`ops/attention.update_slot_cache`) gathers every slot's
-pages back into a logical ``[B, L, h, d]`` K/V buffer before attending: per
-layer and dispatch it reads ``B * pages_per_slot`` pool pages, writes them as
-the buffer, and reads the buffer once in q·K and once in probs·V — three
-passes over every slot's WHOLE window, live or not, which is the HBM traffic
-that bounds decode throughput (measured on the v5e: PERF.md §5). These
-kernels never materialize that buffer: the grid walks each slot's
-``page_table`` directly (the table rides as a SCALAR-PREFETCH operand, so the
-BlockSpec index maps pick which pool page to stream into VMEM for each grid
-step) and folds every page into the shared online-softmax accumulator
-(`ops/flash_common.py`). HBM traffic per dispatch drops from "the whole
-logical window, read, written, then read again" to "each live page, read
-once".
+The XLA read (`ops/attention._live_page_attention`) walks the LIVE pages of
+all slots in fixed blocks: per layer and dispatch it gathers each block of
+pool pages, then reads the block once in q·K (and again, for V, in probs·V),
+with the scores of the whole window written between its two loops — three
+passes over every live page, of which the v5e's compiler keeps two in fast
+memory (measured: PERF.md §5–§6). These kernels gather nothing: the grid
+walks each slot's ``page_table`` directly (the table rides as a
+SCALAR-PREFETCH operand, so the BlockSpec index maps pick which pool page to
+stream into VMEM for each grid step) and folds every page into the shared
+online-softmax accumulator (`ops/flash_common.py`), each live page read once
+and no scores buffer. Which read is faster on the chip has not been measured
+(ROADMAP D13).
 
 Page-walk contract (mirrors the engine's host-side conventions, paging.py):
 

@@ -1,7 +1,7 @@
 """Host-side page-pool allocator and shared-prefix cache for paged KV serving.
 
 The device side of the paged cache is dumb on purpose: one pool of fixed-size
-KV pages per layer (`ops/attention.slot_cache_attention` paged mode) plus per-slot
+KV pages per layer (`ops/attention.slot_cache_attention`) plus per-slot
 page tables riding as traced int32 operands, so the single decode executable
 and the per-bucket insert executables never retrace. ALL policy lives here, on
 the host, between dispatches:

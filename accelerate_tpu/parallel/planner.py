@@ -1177,10 +1177,8 @@ def plan_serving_sharding(
     config,
     *,
     num_slots: int,
-    padded_length: int,
-    paged: bool,
-    page_size: int = 0,
-    num_pages: int = 0,
+    page_size: int,
+    num_pages: int,
     kv_cache_dtype: str = "bf16",
     weight_dtype: str = "bf16",
     chip: Optional[ChipSpec] = None,
@@ -1198,14 +1196,10 @@ def plan_serving_sharding(
     )
     layers = config.num_hidden_layers
     kv_bytes_per_elem = {"bf16": 2.0, "int8": 1.0, "fp8_e4m3": 1.0}.get(kv_cache_dtype, 2.0)
-    if paged:
-        kv_elems = 2.0 * layers * num_pages * page_size * kv_heads * head_dim
-        scale_bytes = (
-            2.0 * layers * num_pages * kv_heads * 4.0 if kv_cache_dtype != "bf16" else 0.0
-        )
-    else:
-        kv_elems = 2.0 * layers * num_slots * padded_length * kv_heads * head_dim
-        scale_bytes = 0.0
+    kv_elems = 2.0 * layers * num_pages * page_size * kv_heads * head_dim
+    scale_bytes = (
+        2.0 * layers * num_pages * kv_heads * 4.0 if kv_cache_dtype != "bf16" else 0.0
+    )
     workload = Workload(
         batch=num_slots,
         seq=1,

@@ -9,21 +9,22 @@ but makes the BATCH dynamic at the host level:
 
   - A fixed-capacity **slot batch**: `num_slots` rows over one static KV cache.
     A slot is a logical cache row; requests come and go, the compiled program
-    never changes shape. By default (`paged=True`) the cache is a POOL of
-    fixed-size KV pages plus per-slot page tables riding as traced int32
-    operands (`ops/attention.slot_cache_attention` paged mode): admission reserves
+    never changes shape. The cache is a POOL of fixed-size KV pages plus
+    per-slot page tables riding as traced int32 operands
+    (`ops/attention.slot_cache_attention`): admission reserves
     `ceil((prompt + max_new) / page_size)` pages — memory proportional to each
     request's ACTUAL footprint, not the engine-wide `max_length` worst case —
     and a page-granular prefix cache (`paging.PagePool`) maps shared prompt
     prefixes (system prompts) to shared read-only pages with refcounts, so a
     repeated prefix costs zero prefill FLOPs and zero duplicate HBM after its
-    first request. `paged=False` keeps the dense one-row-per-slot layout;
-    greedy decode is token-identical between the two.
-  - **insert** (one executable per power-of-two prompt bucket): prefill a new
-    request's prompt through the ordinary decode-cache path on a batch-1 cache,
-    then `tree_scatter_rows` it into the free slot's cache rows, read the logits
-    at the prompt's REAL length (a traced scalar — bucket pads never recompile),
-    and sample the first token. TTFT = one insert dispatch.
+    first request. (A dense one-row-per-slot read survives only as the
+    tests' reference, in `tests/test_paging.py`.)
+  - **insert** (one executable per power-of-two prompt bucket): gather the
+    slot's pages into a batch-1 dense cache, prefill the new request's prompt
+    (the part no shared prefix covers) through the ordinary decode-cache path,
+    `tree_scatter_pages` the result back into the pool, read the logits at the
+    prompt's REAL length (a traced scalar — bucket pads never recompile), and
+    sample the first token. TTFT = one insert dispatch.
   - **decode_chunk** (ONE executable per engine): a `lax.scan` stepping ALL
     slots `chunk_size` tokens per dispatch through the models' per-row slot
     cache (`ops/attention.slot_cache_attention`). Per-slot position counters,
@@ -44,7 +45,7 @@ but makes the BATCH dynamic at the host level:
     reject loop, EOS-in-block truncation, and history maintenance are all
     traced ops inside the one decode executable; the host only pushes its
     [S, max_length] context mirror as one more per-dispatch operand. Greedy
-    engines only (sampling/repetition-penalty engines raise); paged admission
+    engines only (sampling/repetition-penalty engines raise); admission
     reserves the draft window's pages alongside the request footprint.
 
 Between chunks the host frees finished slots and admits queued requests — a
@@ -109,7 +110,6 @@ from .telemetry.tracing import default_tracer
 from .utils.operations import (
     tree_gather_pages,
     tree_scatter_pages,
-    tree_scatter_rows,
     tree_zero_cache_tail,
 )
 
@@ -207,7 +207,7 @@ class ContinuousBatcher:
         trace_guard=None,
         registry: Optional[MetricsRegistry] = None,
         tracer=None,
-        paged: bool = True,
+        paged: bool = True,  # accepts only True; kept for the benchmark's pins (ROADMAP B0)
         page_size: int = 16,
         num_pages: Optional[int] = None,
         prefix_cache: bool = True,
@@ -226,22 +226,26 @@ class ContinuousBatcher:
         if getattr(model, "module", None) is None or not hasattr(model.module, "config"):
             raise ValueError("ContinuousBatcher needs a Model bundle built from an in-tree flax module")
         base = model.module.config
-        if not hasattr(base, "decode_slot_cache"):
-            raise ValueError(
-                f"{type(model.module).__name__}'s config has no `decode_slot_cache` "
-                "field — this model family doesn't support slot-batched serving yet"
-            )
-        if paged and not hasattr(base, "decode_page_size"):
+        if not hasattr(base, "decode_page_size"):
             raise ValueError(
                 f"{type(model.module).__name__}'s config has no `decode_page_size` "
-                "field — this model family doesn't support the paged KV cache; "
-                "pass paged=False for the contiguous per-slot layout"
+                "field — this model family doesn't support slot-batched serving "
+                "yet (the slot cache is a page pool)"
             )
+        # `paged` and `self.paged` stay only because the benchmark's workload
+        # files pass `paged=True` by value and its driver reads `engine.paged`;
+        # ROADMAP B0 removes both.
+        if paged is not True:
+            raise ValueError(
+                f"paged={paged!r}: the contiguous per-slot KV layout is gone — the "
+                "page pool is the engine's only KV store; drop the argument"
+            )
+        self.paged = True
         self.base_config = base
         # Quantized serving (ops/quantization.py): `weight_dtype="int8"`
         # quantizes the params ONCE at load/swap time (the `params` setter
         # below) and routes every Dense through the int8-epilogue matmul;
-        # `kv_cache_dtype` picks the paged pool's storage dtype, with
+        # `kv_cache_dtype` picks the page pool's storage dtype, with
         # per-page-per-head scales riding the cache collection as traced
         # operands. Both are static config — dtypes never retrace.
         from .ops.quantization import KV_CACHE_DTYPES, WEIGHT_DTYPES
@@ -255,11 +259,6 @@ class ContinuousBatcher:
         if self.kv_cache_dtype not in KV_CACHE_DTYPES:
             raise ValueError(
                 f"unknown kv_cache_dtype {kv_cache_dtype!r}; expected one of {KV_CACHE_DTYPES}"
-            )
-        if self.kv_cache_dtype != "bf16" and not paged:
-            raise ValueError(
-                "a quantized KV cache requires the paged layout (paged=True): "
-                "the per-page-per-head scale pools have no contiguous twin"
             )
         # Tensor-parallel decode: one engine spanning a `tp`-device submesh
         # whose single "model" axis carries the model family's Megatron
@@ -280,7 +279,7 @@ class ContinuousBatcher:
         # planner (parallel/planner.py) searches the layout from shapes +
         # mesh topology and emits an equivalent table; an explicit list is a
         # caller override. The planner call itself happens below, once the
-        # paged-pool geometry it prices is known.
+        # pool geometry it prices is known.
         self.sharding_mode = "rules" if sharding_rules is None else sharding_rules
         if isinstance(self.sharding_mode, (list, tuple)):
             self._tp_rules = list(self.sharding_mode)
@@ -349,9 +348,9 @@ class ContinuousBatcher:
                 )
         # Decode/verify attention implementation: "xla" keeps the gather-then-
         # attend oracle; "pallas_paged" fuses the page-table walk into the
-        # ops/paged_attention kernels (paged engines only). Either way the ONE
-        # decode executable and the traced-operand page tables are unchanged —
-        # the impl only swaps the attention read inside the compiled program.
+        # ops/paged_attention kernels. Either way the ONE decode executable
+        # and the traced-operand page tables are unchanged — the impl only
+        # swaps the attention read inside the compiled program.
         from .ops.attention import SLOT_ATTENTION_IMPLS
 
         self.attention_impl = str(attention_impl)
@@ -360,38 +359,27 @@ class ContinuousBatcher:
                 f"unknown attention_impl {attention_impl!r}; expected one of "
                 f"{SLOT_ATTENTION_IMPLS}"
             )
-        if self.attention_impl == "pallas_paged" and not paged:
-            raise ValueError(
-                "attention_impl='pallas_paged' requires the paged KV cache "
-                "(paged=True); the contiguous layout has no page table to walk"
-            )
-        self.paged = bool(paged)
         self.page_size = int(page_size)
-        if self.paged:
-            if self.page_size < 1:
-                raise ValueError("page_size must be >= 1")
-            self.pages_per_slot = -(-self.max_length // self.page_size)
-            # Per-slot logical capacity rounded up to whole pages; columns past
-            # max_length stay masked (exact zeros under the f32 softmax), so
-            # decode is token-identical to the contiguous layout.
-            self._padded_length = self.pages_per_slot * self.page_size
-            # Default pool: the contiguous layout's worst case (every slot at
-            # max_length) plus the scratch page — same capacity, so admission
-            # only ever gets LOOSER. Size it DOWN for real HBM savings: any
-            # request mix whose actual token footprint fits still completes.
-            self.num_pages = (
-                int(num_pages) if num_pages is not None
-                else self.num_slots * self.pages_per_slot + 1
-            )
-        else:
-            self.pages_per_slot = 0
-            self._padded_length = self.max_length
-            self.num_pages = 0
+        if self.page_size < 1:
+            raise ValueError("page_size must be >= 1")
+        self.pages_per_slot = -(-self.max_length // self.page_size)
+        # Per-slot logical capacity rounded up to whole pages; columns past
+        # max_length stay masked (exact zeros under the f32 softmax), so
+        # decode is token-identical to a dense max_length row.
+        self._padded_length = self.pages_per_slot * self.page_size
+        # Default pool: the worst case (every slot at max_length) plus the
+        # scratch page, so admission never waits on pages. Size it DOWN for
+        # real HBM savings: any request mix whose actual token footprint fits
+        # still completes.
+        self.num_pages = (
+            int(num_pages) if num_pages is not None
+            else self.num_slots * self.pages_per_slot + 1
+        )
         # Prefix sharing needs the suffix-only insert to seed presence from the
         # WHOLE prompt, which the suffix program never sees — repetition-penalty
-        # engines therefore run the paged cache without prefix reuse.
-        self.use_prefix_cache = bool(prefix_cache) and self.paged and not use_repetition_penalty
-        if prefix_cache and self.paged and use_repetition_penalty:
+        # engines therefore run without prefix reuse.
+        self.use_prefix_cache = bool(prefix_cache) and not use_repetition_penalty
+        if prefix_cache and use_repetition_penalty:
             logger.info(
                 "prefix cache disabled: use_repetition_penalty needs whole-prompt "
                 "presence seeding, which shared-prefix inserts cannot provide"
@@ -418,8 +406,6 @@ class ContinuousBatcher:
                 self.mesh,
                 base,
                 num_slots=self.num_slots,
-                padded_length=self._padded_length,
-                paged=self.paged,
                 page_size=self.page_size,
                 num_pages=self.num_pages,
                 kv_cache_dtype=self.kv_cache_dtype,
@@ -444,8 +430,8 @@ class ContinuousBatcher:
         resolve = _params_resolver(model)
         # Prefill rides the ORDINARY decode-cache path on a batch-1 cache (shared
         # scalar cache_index); decode steps ride the per-row slot cache. Same
-        # logical cache capacity so the prefilled rows line up for the scatter —
-        # into slot rows (contiguous) or pool pages (paged).
+        # logical cache capacity so the prefilled rows line up for the scatter
+        # into pool pages.
         cache_len = self._padded_length
         quant_cfg = {}
         if self.weight_dtype != "bf16":
@@ -469,47 +455,40 @@ class ContinuousBatcher:
                     "support tensor-parallel serving yet"
                 )
             quant_cfg["decode_tp_mesh"] = self.mesh
-        if self.paged:
-            if self.kv_cache_dtype != "bf16":
-                if not hasattr(base, "decode_kv_cache_dtype"):
-                    raise ValueError(
-                        f"{type(model.module).__name__}'s config has no "
-                        "`decode_kv_cache_dtype` field — this model family doesn't "
-                        "support the quantized KV page pool yet"
-                    )
-                quant_cfg["decode_kv_cache_dtype"] = self.kv_cache_dtype
-            step_cfg = dataclasses.replace(
-                base, decode_cache_length=cache_len, decode_slot_cache=True,
-                decode_page_size=self.page_size, decode_num_pages=self.num_pages,
-                decode_attention_impl=self.attention_impl, **quant_cfg,
-            )
-        else:
-            step_cfg = dataclasses.replace(
-                base, decode_cache_length=cache_len, decode_slot_cache=True, **quant_cfg
-            )
+        if self.kv_cache_dtype != "bf16":
+            if not hasattr(base, "decode_kv_cache_dtype"):
+                raise ValueError(
+                    f"{type(model.module).__name__}'s config has no "
+                    "`decode_kv_cache_dtype` field — this model family doesn't "
+                    "support the quantized KV page pool yet"
+                )
+            quant_cfg["decode_kv_cache_dtype"] = self.kv_cache_dtype
+        step_cfg = dataclasses.replace(
+            base, decode_cache_length=cache_len, decode_slot_cache=True,
+            decode_page_size=self.page_size, decode_num_pages=self.num_pages,
+            decode_attention_impl=self.attention_impl, **quant_cfg,
+        )
         prefill_module = type(model.module)(prefill_cfg)
         step_module = type(model.module)(step_cfg)
-        self._prefill_raw, _ = make_causal_programs(prefill_module, resolve, full_prefill_logits=True)
         _, self._step_raw, self._verify_raw = make_causal_programs(
-            step_module, resolve, step_mask_operand=self.paged, verify_block=True
+            step_module, resolve, step_mask_operand=True, verify_block=True
         )
         self._step_module = step_module
         self._resolve = resolve
-        if self.paged:
-            self._cached_prefill_raw = make_cached_prefill_program(prefill_module, resolve)
-            # The dense batch-1 cache STRUCTURE the paged insert materializes by
-            # gathering pool pages (zero compute/compile: eval_shape only). The
-            # weight_autocast wrap matters even for eval_shape: int8 engines
-            # hold quantized kernel entries the raw Dense can't consume.
-            from .ops.quantization import weight_autocast
+        self._cached_prefill_raw = make_cached_prefill_program(prefill_module, resolve)
+        # The dense batch-1 cache STRUCTURE the insert materializes by
+        # gathering pool pages (zero compute/compile: eval_shape only). The
+        # weight_autocast wrap matters even for eval_shape: int8 engines
+        # hold quantized kernel entries the raw Dense can't consume.
+        from .ops.quantization import weight_autocast
 
-            dummy = jnp.zeros((1, 1), jnp.int32)
-            dpos = jnp.zeros((1, 1), jnp.int32)
-            with weight_autocast(self.weight_dtype):
-                self._dense_cache_struct = jax.eval_shape(
-                    lambda p: prefill_module.apply(resolve(p), dummy, None, dpos, mutable=["cache"])[1]["cache"],
-                    self.params,
-                )
+        dummy = jnp.zeros((1, 1), jnp.int32)
+        dpos = jnp.zeros((1, 1), jnp.int32)
+        with weight_autocast(self.weight_dtype):
+            self._dense_cache_struct = jax.eval_shape(
+                lambda p: prefill_module.apply(resolve(p), dummy, None, dpos, mutable=["cache"])[1]["cache"],
+                self.params,
+            )
 
         self._sample_config = GenerationConfig(do_sample=do_sample, top_k=top_k, top_p=top_p)
         # Python-side effects run at TRACE time: these count compiles, and the
@@ -546,11 +525,10 @@ class ContinuousBatcher:
         self._eos = np.full(S, -1, np.int32)
         self._temp = np.ones(S, np.float32)
         self._pen = np.ones(S, np.float32)
-        # Per-slot page tables (paged): all-zeros rows point at the scratch
-        # page, so a freed/inactive slot's discarded decode writes can never
-        # land in a live request's pages. Contiguous engines keep a [S, 1]
-        # dummy so the chunk signature stays uniform (the operand is unused).
-        self._page_table = np.zeros((S, self.pages_per_slot if self.paged else 1), np.int32)
+        # Per-slot page tables: all-zeros rows point at the scratch page, so a
+        # freed/inactive slot's discarded decode writes can never land in a
+        # live request's pages.
+        self._page_table = np.zeros((S, self.pages_per_slot), np.int32)
         self._slot_pages: List[List[int]] = [[] for _ in range(S)]
         # Speculative engines: host mirror of each slot's observed context
         # (prompt + generated, packed from index 0), pushed as a traced operand
@@ -638,43 +616,41 @@ class ContinuousBatcher:
         self._first_tokens: List[RequestResult] = []
 
         # Page-pool + prefix-cache telemetry and the host allocator itself
-        # (paged engines only; all updates are host-scalar arithmetic).
-        self.pool: Optional[PagePool] = None
-        if self.paged:
-            self._m_pages_total = self.metrics.gauge(
-                "serving_pages_total", help="usable KV pool pages (excludes the scratch page)"
-            )
-            self._m_pages_in_use = self.metrics.gauge(
-                "serving_pages_in_use", help="pool pages referenced by in-flight requests"
-            )
-            self._m_kv_live_page_share = self.metrics.gauge(
-                "serving_kv_live_page_share",
-                help="live pages of the active slots over num_slots * pages_per_slot, "
-                "as the last decode chunk was dispatched: the share of the window "
-                "the paged XLA read visits",
-            )
-            self._m_prefix_hits = self.metrics.counter(
-                "serving_prefix_cache_hits_total",
-                help="prompt pages served from the shared-prefix cache",
-            )
-            self._m_prefix_misses = self.metrics.counter(
-                "serving_prefix_cache_misses_total",
-                help="full prompt pages that had to be prefilled (no cached prefix)",
-            )
-            self._m_prefix_evictions = self.metrics.counter(
-                "serving_prefix_cache_evictions_total",
-                help="unreferenced cached prefix pages reclaimed by the allocator",
-            )
-            self._m_prefill_saved = self.metrics.counter(
-                "prefill_tokens_saved_total",
-                help="prompt tokens whose prefill FLOPs the prefix cache skipped",
-            )
-            self.pool = PagePool(
-                self.num_pages, self.page_size,
-                on_evict=self._m_prefix_evictions.inc,
-                kv_cache_dtype=self.kv_cache_dtype,
-            )
-            self._m_pages_total.set(self.pool.pages_total)
+        # (all updates are host-scalar arithmetic).
+        self._m_pages_total = self.metrics.gauge(
+            "serving_pages_total", help="usable KV pool pages (excludes the scratch page)"
+        )
+        self._m_pages_in_use = self.metrics.gauge(
+            "serving_pages_in_use", help="pool pages referenced by in-flight requests"
+        )
+        self._m_kv_live_page_share = self.metrics.gauge(
+            "serving_kv_live_page_share",
+            help="live pages of the active slots over num_slots * pages_per_slot, "
+            "as the last decode chunk was dispatched: the share of the window "
+            "the paged XLA read visits",
+        )
+        self._m_prefix_hits = self.metrics.counter(
+            "serving_prefix_cache_hits_total",
+            help="prompt pages served from the shared-prefix cache",
+        )
+        self._m_prefix_misses = self.metrics.counter(
+            "serving_prefix_cache_misses_total",
+            help="full prompt pages that had to be prefilled (no cached prefix)",
+        )
+        self._m_prefix_evictions = self.metrics.counter(
+            "serving_prefix_cache_evictions_total",
+            help="unreferenced cached prefix pages reclaimed by the allocator",
+        )
+        self._m_prefill_saved = self.metrics.counter(
+            "prefill_tokens_saved_total",
+            help="prompt tokens whose prefill FLOPs the prefix cache skipped",
+        )
+        self.pool = PagePool(
+            self.num_pages, self.page_size,
+            on_evict=self._m_prefix_evictions.inc,
+            kv_cache_dtype=self.kv_cache_dtype,
+        )
+        self._m_pages_total.set(self.pool.pages_total)
 
         # Speculative-decode telemetry (host-scalar arithmetic over the chunk
         # readback; docs/observability.md documents the instruments). The
@@ -739,12 +715,11 @@ class ContinuousBatcher:
         self._params = value
 
     def _init_cache(self):
-        """Create the slot cache — dense [num_slots, max_length] rows, or the
-        [num_pages, page_size] pool when paged (quantized dtypes add the
-        per-page-per-head scale pools): `eval_shape` the slot-mode
-        module's cache variables (zero compute, zero compile — no throwaway
-        executable at engine construction) and materialize them as zeros.
-        Correct because every slot's rows/pages are overwritten by insert
+        """Create the slot cache — the [num_pages, page_size] pool (quantized
+        dtypes add the per-page-per-head scale pools): `eval_shape` the
+        slot-mode module's cache variables (zero compute, zero compile — no
+        throwaway executable at engine construction) and materialize them as
+        zeros. Correct because every slot's pages are overwritten by insert
         before they're ever attended."""
         from .ops.quantization import weight_autocast
 
@@ -752,7 +727,7 @@ class ContinuousBatcher:
         module, resolve = self._step_module, self._resolve
         dummy = jnp.zeros((S, 1), jnp.int32)
         pos = jnp.zeros((S, 1), jnp.int32)
-        mask = jnp.zeros((S, self.pages_per_slot), jnp.int32) if self.paged else None
+        mask = jnp.zeros((S, self.pages_per_slot), jnp.int32)
         with weight_autocast(self.weight_dtype):
             shapes = jax.eval_shape(
                 lambda p: module.apply(resolve(p), dummy, mask, pos, mutable=["cache"])[1]["cache"],
@@ -803,9 +778,8 @@ class ContinuousBatcher:
     def insert_bucket_ladder(self) -> List[int]:
         """Every insert bucket any admission of this engine can mint: the pow2
         ladder below the cache window plus the capped top value. Closed by
-        `plan_admission_bucket` (paged) / the `min(bucket, max_length)` cap
-        (contiguous)."""
-        limit = self._padded_length if self.paged else self.max_length
+        `plan_admission_bucket`."""
+        limit = self._padded_length
         ladder = []
         b = 1
         while b < limit:
@@ -841,73 +815,23 @@ class ContinuousBatcher:
                 else None
             )
             ids = jnp.zeros((1, bucket), jnp.int32)
-            if self.paged:
-                fn(
-                    self.params, dummy_cache, dummy_presence, ids,
-                    _operand(1, np.int32), _operand(0, np.int32), _operand(0, np.int32),
-                    jnp.asarray(np.zeros((self.pages_per_slot,), np.int32)),
-                    _operand(0, np.int32), _operand(1.0, np.float32),
-                    _operand(1.0, np.float32), self._rng,
-                )
-            else:
-                fn(
-                    self.params, dummy_cache, dummy_presence, ids,
-                    _operand(1, np.int32), _operand(0, np.int32),
-                    _operand(1.0, np.float32), _operand(1.0, np.float32), self._rng,
-                )
+            fn(
+                self.params, dummy_cache, dummy_presence, ids,
+                _operand(1, np.int32), _operand(0, np.int32), _operand(0, np.int32),
+                jnp.asarray(np.zeros((self.pages_per_slot,), np.int32)),
+                _operand(0, np.int32), _operand(1.0, np.float32),
+                _operand(1.0, np.float32), self._rng,
+            )
             warmed.append(bucket)
         return warmed
 
     def _insert_fn(self, bucket: int):
-        """One compiled insert per power-of-two prompt bucket (paged: per
-        SUFFIX bucket — the unmatched tail after prefix-cache hits). The real
-        length, the slot index, page table row, matched prefix length,
-        temperature/penalty and the rng all ride as traced operands —
-        re-admission never recompiles anything."""
-        if self.paged:
-            return self._paged_insert_fn(bucket)
-        return self._contiguous_insert_fn(bucket)
+        """One compiled insert per power-of-two SUFFIX bucket (the unmatched
+        tail after prefix-cache hits). The real length, the slot index, page
+        table row, matched prefix length, temperature/penalty and the rng all
+        ride as traced operands — re-admission never recompiles anything.
 
-    def _contiguous_insert_fn(self, bucket: int):
-        fn = self._insert_fns.get(bucket)
-        if fn is not None:
-            return fn
-        prefill = self._prefill_raw
-        use_pen = self.use_repetition_penalty
-        config = self._sample_config
-        V = self.base_config.vocab_size
-        mesh = self.mesh
-
-        def insert(params, cache, presence, input_ids, real_len, slot, temperature, penalty, rng):
-            self.trace_counts["insert"] += 1
-            positions = jnp.broadcast_to(jnp.arange(bucket)[None, :], (1, bucket))
-            logits, small = prefill(params, input_ids, positions)
-            with jax.named_scope("kv_write"):
-                cache = constrain_tp_cache(tree_scatter_rows(cache, small, slot), mesh)
-            # Logits at the REAL last prompt token (right-bucket pads sit above
-            # it and, being causal, never influenced it).
-            last = jax.lax.dynamic_slice_in_dim(logits, real_len - 1, 1, axis=1)[:, 0, :]
-            row = None
-            with jax.named_scope("sample"):
-                if use_pen:
-                    valid = jnp.arange(bucket) < real_len
-                    row = jnp.zeros((V,), bool).at[input_ids[0]].max(valid)
-                    last = _apply_repetition_penalty(last, row[None, :], penalty)
-                token, rng = _sample(last, config, rng, temperature)
-                if use_pen:
-                    row = row.at[token[0]].set(True)
-                    presence = jax.lax.dynamic_update_slice(
-                        presence, row[None, :], (jnp.asarray(slot, jnp.int32), jnp.int32(0))
-                    )
-            return token[0], cache, presence, rng
-
-        donate = (1, 2) if use_pen else (1,)
-        fn = jax.jit(insert, donate_argnums=donate)
-        self._insert_fns[bucket] = fn
-        return fn
-
-    def _paged_insert_fn(self, bucket: int):
-        """Paged admission: gather the slot's (possibly shared-prefix) pages
+        Gather the slot's (possibly shared-prefix) pages
         into a batch-1 dense cache positioned at `matched_len`, prefill ONLY the
         unmatched suffix through it, scatter the result back into pool pages —
         with every already-matched table entry redirected to the scratch page,
@@ -955,7 +879,7 @@ class ContinuousBatcher:
                 if use_pen:
                     # Penalty engines run with the prefix cache OFF (matched_len is
                     # always 0), so the "suffix" here is the whole prompt and the
-                    # presence row seeds exactly as on the contiguous path.
+                    # presence row is seeded from all of it.
                     valid = jnp.arange(bucket) < real_len
                     row = jnp.zeros((V,), bool).at[suffix_ids[0]].max(valid)
                     last = _apply_repetition_penalty(last, row[None, :], penalty)
@@ -978,7 +902,6 @@ class ContinuousBatcher:
         S, L, chunk = self.num_slots, self.max_length, self.chunk_size
         step_inner = self._step_raw
         use_pen = self.use_repetition_penalty
-        paged = self.paged
         config = self._sample_config
         mesh = self.mesh
 
@@ -990,10 +913,7 @@ class ContinuousBatcher:
                 # The page table is loop-invariant: admission reserves a
                 # request's whole worst-case footprint up front, so no page
                 # boundary crossed mid-chunk ever needs a fresh page.
-                if paged:
-                    logits, cache = step_inner(params, cache, token, pos, page_table)
-                else:
-                    logits, cache = step_inner(params, cache, token, pos)
+                logits, cache = step_inner(params, cache, token, pos, page_table)
                 with jax.named_scope("sample"):
                     if use_pen:
                         logits = _apply_repetition_penalty(logits, presence, penalty[:, None])
@@ -1046,13 +966,13 @@ class ContinuousBatcher:
         run as traced ops: steady state stays this one executable, zero
         recompiles, zero host reads.
 
-        Rejected draft K/V needs no rollback in either cache mode: the slot's
-        position simply doesn't advance past the accepted prefix, the
-        per-query `cols <= pos` mask keeps stale rows invisible, and the next
-        verify block overwrites them before anything can attend them. (Paged:
-        rejected writes land through the slot's OWN page table — the draft
-        window is part of the admission reservation — or fall through to the
-        scratch page past the table's last real entry.)
+        Rejected draft K/V needs no rollback: the slot's position simply
+        doesn't advance past the accepted prefix, the per-query `cols <= pos`
+        mask keeps stale rows invisible, and the next verify block overwrites
+        them before anything can attend them. (Rejected writes land through
+        the slot's OWN page table — the draft window is part of the admission
+        reservation — or fall through to the scratch page past the table's
+        last real entry.)
 
         An EOS inside the verified block terminates the request THERE: the
         block's tail is discarded (not emitted, not counted against the
@@ -1065,7 +985,6 @@ class ContinuousBatcher:
         S, chunk = self.num_slots, self.chunk_size
         H = self.max_length
         verify_inner = self._verify_raw
-        paged = self.paged
         k_draft, m_gram = self.draft_tokens, self.draft_ngram
         mesh = self.mesh
 
@@ -1080,10 +999,7 @@ class ContinuousBatcher:
                 drafts, valid_len = propose_ngram_drafts(history, hist_len, k_draft, m_gram)
                 block = jnp.concatenate([token[:, None], drafts], axis=1)  # [S, k+1]
                 positions = pos[:, None] + js[None, :]
-                if paged:
-                    logits, cache = verify_inner(params, cache, block, positions, page_table)
-                else:
-                    logits, cache = verify_inner(params, cache, block, positions)
+                logits, cache = verify_inner(params, cache, block, positions, page_table)
                 greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k+1]
                 accept = greedy_accept_length(drafts, greedy[:, :k_draft], valid_len)
                 # Budget cap: emit at most `rem` tokens (accept + 1 bonus).
@@ -1262,19 +1178,18 @@ class ContinuousBatcher:
                 # speculation never helped; k+1 is the ceiling.
                 "accepted_tokens_per_step": round((steps + accepted) / steps, 4) if steps else None,
             }
-        if self.paged:
-            view["pages_total"] = self.pool.pages_total
-            view["pages_in_use"] = self.pool.pages_in_use
-            view["kv_live_page_share"] = float(self._m_kv_live_page_share.value)
-            view["prefix_cache"] = {
-                "enabled": self.use_prefix_cache,
-                "hits": int(self._m_prefix_hits.value),
-                "misses": int(self._m_prefix_misses.value),
-                "evictions": int(self._m_prefix_evictions.value),
-                "prefill_tokens_saved": int(self._m_prefill_saved.value),
-                "entries": self.pool.prefix_entries,
-                "cached_pages": self.pool.pages_cached,
-            }
+        view["pages_total"] = self.pool.pages_total
+        view["pages_in_use"] = self.pool.pages_in_use
+        view["kv_live_page_share"] = float(self._m_kv_live_page_share.value)
+        view["prefix_cache"] = {
+            "enabled": self.use_prefix_cache,
+            "hits": int(self._m_prefix_hits.value),
+            "misses": int(self._m_prefix_misses.value),
+            "evictions": int(self._m_prefix_evictions.value),
+            "prefill_tokens_saved": int(self._m_prefill_saved.value),
+            "entries": self.pool.prefix_entries,
+            "cached_pages": self.pool.pages_cached,
+        }
         return view
 
     def _update_occupancy_gauges(self):
@@ -1286,8 +1201,7 @@ class ContinuousBatcher:
         in_use = sum(r is not None for r in self._slot_request)
         self._m_slots_in_use.set(in_use)
         self._m_slot_utilization.set(in_use / self.num_slots)
-        if self.paged:
-            self._m_pages_in_use.set(self.pool.pages_in_use)
+        self._m_pages_in_use.set(self.pool.pages_in_use)
 
     def submit(self, request: Request) -> int:
         """Validate + enqueue. Raises `ValueError` for malformed requests (the
@@ -1308,16 +1222,15 @@ class ContinuousBatcher:
                 f"prompt ({ids.size}) + max_new_tokens ({request.max_new_tokens}) "
                 f"exceeds the {self.max_length}-token slot capacity"
             )
-        if self.paged:
-            need = self._pages_needed(int(ids.size), request.max_new_tokens)
-            if need > self.pool.pages_total:
-                raise ValueError(
-                    f"request needs {need} KV pages ({ids.size} prompt + "
-                    f"{request.max_new_tokens} new tokens"
-                    + (f" + {self.draft_tokens} draft-window" if self.speculative else "")
-                    + f" at page_size {self.page_size}) but the pool holds "
-                    f"{self.pool.pages_total}"
-                )
+        need = self._pages_needed(int(ids.size), request.max_new_tokens)
+        if need > self.pool.pages_total:
+            raise ValueError(
+                f"request needs {need} KV pages ({ids.size} prompt + "
+                f"{request.max_new_tokens} new tokens"
+                + (f" + {self.draft_tokens} draft-window" if self.speculative else "")
+                + f" at page_size {self.page_size}) but the pool holds "
+                f"{self.pool.pages_total}"
+            )
         if request.request_id in self.results:
             raise ValueError(f"duplicate request_id {request.request_id}")
         if self.max_queue is not None and len(self._queue) >= self.max_queue:
@@ -1393,15 +1306,14 @@ class ContinuousBatcher:
             # context belonged to a request that just errored. Admissions
             # reseed their own rows.
             self._history[:] = 0
-        if self.paged:
-            # The pool CONTENT died with the donated buffers: every refcount,
-            # page-table row and — critically — prefix registration goes with
-            # it (a stale hash->page mapping would serve zeroed KV as a
-            # "cached" prefix to the next shared-prompt request).
-            self.pool.reset()
-            self._page_table[:] = SCRATCH_PAGE
-            self._slot_pages = [[] for _ in range(self.num_slots)]
-            self._m_pages_in_use.set(0)
+        # The pool CONTENT died with the donated buffers: every refcount,
+        # page-table row and — critically — prefix registration goes with
+        # it (a stale hash->page mapping would serve zeroed KV as a
+        # "cached" prefix to the next shared-prompt request).
+        self.pool.reset()
+        self._page_table[:] = SCRATCH_PAGE
+        self._slot_pages = [[] for _ in range(self.num_slots)]
+        self._m_pages_in_use.set(0)
 
     def _slot_of(self, request_id: int) -> Optional[int]:
         for slot, result in enumerate(self._slot_request):
@@ -1434,19 +1346,18 @@ class ContinuousBatcher:
         if slot is not None:
             self._slot_request[slot] = None
             self._active[slot] = False
-            # An idle slot sits at position 0: the paged XLA read takes a
-            # row's live pages from its position, and a released slot left at
-            # its last one would pass for that many pages of scratch.
+            # An idle slot sits at position 0: the XLA read takes a row's
+            # live pages from its position, and a released slot left at its
+            # last one would pass for that many pages of scratch.
             self._pos[slot] = 0
-            if self.paged:
-                # Release the slot's page references (a shared prefix page
-                # drops to CACHED at refcount 0, private pages go free) and
-                # point the table row at the scratch page so any residual
-                # write for this row is discarded.
-                if self._slot_pages[slot]:
-                    self.pool.release(self._slot_pages[slot])
-                    self._slot_pages[slot] = []
-                self._page_table[slot] = SCRATCH_PAGE
+            # Release the slot's page references (a shared prefix page
+            # drops to CACHED at refcount 0, private pages go free) and
+            # point the table row at the scratch page so any residual
+            # write for this row is discarded.
+            if self._slot_pages[slot]:
+                self.pool.release(self._slot_pages[slot])
+                self._slot_pages[slot] = []
+            self._page_table[slot] = SCRATCH_PAGE
         self._update_occupancy_gauges()
 
     def _drop_queued(self, request_id: int) -> bool:
@@ -1484,7 +1395,7 @@ class ContinuousBatcher:
         """Fill free slots from the queue (FIFO). Each admission is one insert
         dispatch; the first token streams out immediately (TTFT).
 
-        Paged admission is PAGE-based, not slot-based: the request reserves
+        Admission is PAGE-based, not slot-based: the request reserves
         `ceil((prompt + max_new) / page_size)` pool pages minus whatever its
         prompt prefix already shares from the prefix cache — so a mix of small
         requests can occupy every slot even when the pool is far smaller than
@@ -1509,57 +1420,47 @@ class ContinuousBatcher:
             ids = req.input_ids
             p = int(ids.size)
             result = self.results[req.request_id]
-            pages: List[int] = []
             hashes: List[str] = []
-            matched_pages = 0
-            matched_len = 0
-            if self.paged:
-                total_pages = self._pages_needed(p, req.max_new_tokens)
-                if self.use_prefix_cache:
-                    hashes = chain_hashes(ids, self.page_size)
-                    # Cap below the whole prompt: the last real token always
-                    # reruns so the insert has first-token logits to sample.
-                    shared = self.pool.match_prefix(hashes, min(len(hashes), (p - 1) // self.page_size))
-                else:
-                    shared = []
-                matched_pages = len(shared)
-                # Closed-bucket planning: when the pow2 suffix bucket would
-                # overflow the cache window (`matched_len + bucket >
-                # _padded_length`), DROP trailing matched pages instead of
-                # minting a matched_len-dependent capped bucket — an open set
-                # of bucket sizes no warmup can enumerate, and the source of
-                # the first-hit insert recompiles the bench's 0-recompile
-                # assert used to trip at non-default --max-new-max sizes.
-                _bucket, keep_pages = self.plan_admission_bucket(
-                    p, matched_pages, self.page_size, self._padded_length
-                )
-                while matched_pages > keep_pages:
-                    self.pool.release([shared.pop()])
-                    matched_pages -= 1
-                matched_len = matched_pages * self.page_size
-                private = self.pool.reserve(total_pages - matched_pages)
-                if private is None:
-                    if shared:
-                        self.pool.release(shared)
-                    self._queue.appendleft(req)
-                    break
-                pages = shared + private
-                if self.use_prefix_cache:
-                    full_pages = p // self.page_size
-                    self._m_prefix_hits.inc(matched_pages)
-                    self._m_prefix_misses.inc(max(full_pages - matched_pages, 0))
-                    if matched_len:
-                        self._m_prefill_saved.inc(matched_len)
-                suffix = p - matched_len
-                bucket = _bucket
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :suffix] = ids[matched_len:]
-                page_row = np.zeros((self.pages_per_slot,), np.int32)
-                page_row[: len(pages)] = pages
+            total_pages = self._pages_needed(p, req.max_new_tokens)
+            if self.use_prefix_cache:
+                hashes = chain_hashes(ids, self.page_size)
+                # Cap below the whole prompt: the last real token always
+                # reruns so the insert has first-token logits to sample.
+                shared = self.pool.match_prefix(hashes, min(len(hashes), (p - 1) // self.page_size))
             else:
-                bucket = min(_bucket_for(p), self.max_length)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :p] = ids
+                shared = []
+            matched_pages = len(shared)
+            # Closed-bucket planning: when the pow2 suffix bucket would
+            # overflow the cache window (`matched_len + bucket >
+            # _padded_length`), DROP trailing matched pages instead of
+            # minting a matched_len-dependent capped bucket — an open set
+            # of bucket sizes no warmup can enumerate, and the source of
+            # the first-hit insert recompiles the bench's 0-recompile
+            # assert used to trip at non-default --max-new-max sizes.
+            bucket, keep_pages = self.plan_admission_bucket(
+                p, matched_pages, self.page_size, self._padded_length
+            )
+            while matched_pages > keep_pages:
+                self.pool.release([shared.pop()])
+                matched_pages -= 1
+            matched_len = matched_pages * self.page_size
+            private = self.pool.reserve(total_pages - matched_pages)
+            if private is None:
+                if shared:
+                    self.pool.release(shared)
+                self._queue.appendleft(req)
+                break
+            pages = shared + private
+            if self.use_prefix_cache:
+                full_pages = p // self.page_size
+                self._m_prefix_hits.inc(matched_pages)
+                self._m_prefix_misses.inc(max(full_pages - matched_pages, 0))
+                if matched_len:
+                    self._m_prefill_saved.inc(matched_len)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, : p - matched_len] = ids[matched_len:]
+            page_row = np.zeros((self.pages_per_slot,), np.int32)
+            page_row[: len(pages)] = pages
             rspan = self._request_spans.get(req.request_id)
             if rspan is not None:
                 rspan.event(
@@ -1576,33 +1477,20 @@ class ContinuousBatcher:
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
                 ) as ispan:
                     fn = self._insert_fn(bucket)
-                    if self.paged:
-                        on_device, self._cache, self._presence, self._rng = fn(
-                            self.params,
-                            self._cache,
-                            self._presence,
-                            jnp.asarray(padded),
-                            _operand(p - matched_len, np.int32),
-                            _operand(matched_len, np.int32),
-                            _operand(matched_pages, np.int32),
-                            jnp.asarray(page_row),
-                            _operand(slot, np.int32),
-                            _operand(req.temperature, np.float32),
-                            _operand(req.repetition_penalty, np.float32),
-                            self._rng,
-                        )
-                    else:
-                        on_device, self._cache, self._presence, self._rng = fn(
-                            self.params,
-                            self._cache,
-                            self._presence,
-                            jnp.asarray(padded),
-                            _operand(p, np.int32),
-                            _operand(slot, np.int32),
-                            _operand(req.temperature, np.float32),
-                            _operand(req.repetition_penalty, np.float32),
-                            self._rng,
-                        )
+                    on_device, self._cache, self._presence, self._rng = fn(
+                        self.params,
+                        self._cache,
+                        self._presence,
+                        jnp.asarray(padded),
+                        _operand(p - matched_len, np.int32),
+                        _operand(matched_len, np.int32),
+                        _operand(matched_pages, np.int32),
+                        jnp.asarray(page_row),
+                        _operand(slot, np.int32),
+                        _operand(req.temperature, np.float32),
+                        _operand(req.repetition_penalty, np.float32),
+                        self._rng,
+                    )
                     # jax.device_get, not int(token): on a TPU only device_get is an
                     # EXPLICIT device-to-host read an armed transfer guard admits.
                     with self.tracer.span("serve.insert.wait", category="serve", record=False) as wait:
@@ -1611,8 +1499,7 @@ class ContinuousBatcher:
                     device_wait_s += wait.duration_s
                 token = int(on_host)
             except Exception as exc:  # noqa: BLE001 — isolate, report, keep serving
-                if pages:
-                    self.pool.release(pages)
+                self.pool.release(pages)
                 if self.trace_guard is not None:
                     self.trace_guard.observe(exc)
                 logger.warning(
@@ -1632,7 +1519,7 @@ class ContinuousBatcher:
                     )
                     self._abort_in_flight(exc)
                 continue
-            if self.paged and self.use_prefix_cache:
+            if self.use_prefix_cache:
                 # The insert just wrote this prompt's full pages: register them
                 # so the NEXT request with the same prefix shares instead of
                 # prefilling. Decode writes land at pos >= prompt_len, past
@@ -1667,14 +1554,12 @@ class ContinuousBatcher:
                     self._history[slot, :p] = ids
                     self._history[slot, p] = token
                     self._history[slot, p + 1:] = 0
-                if self.paged:
-                    self._slot_pages[slot] = pages
-                    self._page_table[slot] = page_row
+                self._slot_pages[slot] = pages
+                self._page_table[slot] = page_row
             else:
-                if pages:
-                    # One-token request: its pages release immediately — but a
-                    # prefix it just registered stays CACHED for the next hit.
-                    self.pool.release(pages)
+                # One-token request: its pages release immediately — but a
+                # prefix it just registered stays CACHED for the next hit.
+                self.pool.release(pages)
                 self._finish(result, "eos" if token == eos else "length", now=now)
         self._update_occupancy_gauges()
         return events, device_wait_s
@@ -1796,7 +1681,7 @@ class ContinuousBatcher:
                 "serve.decode_chunk", category="serve",
                 chunk_size=self.chunk_size,
                 active_slots=int(self._active.sum()),
-                pages_in_use=self.pool.pages_in_use if self.paged else None,
+                pages_in_use=self.pool.pages_in_use,
                 **self._live_page_counts(),
             ) as chunk_span:
                 with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:
@@ -1840,14 +1725,12 @@ class ContinuousBatcher:
         return (mirrors, host[4][: int(host[5])], pos_before), wait_span.duration_s
 
     def _live_page_counts(self) -> Dict[str, int]:
-        """What the paged read is about to visit, from the host mirrors: the
+        """What the KV read is about to visit, from the host mirrors: the
         active slots' live pages (`pos // page_size + 1` each) beside the
         window's `num_slots * pages_per_slot`, for the chunk's span; their
         share goes to the `kv_live_page_share` gauge. The device also visits
         one scratch page an idle slot, and a slot's pages grow inside the
         chunk: neither is counted."""
-        if not self.paged:
-            return {}
         live = int((self._pos[self._active] // self.page_size + 1).sum())
         window = self.num_slots * self.pages_per_slot
         self._m_kv_live_page_share.set(live / window)

@@ -443,79 +443,10 @@ def pad_input_tensors(tensor, batch_size: int, num_processes: int, dim: int = 0)
 
 
 # --------------------------------------------------------------------------------------
-# Slot-row gather/scatter over cache pytrees (serving.py's continuous batcher)
+# Page-pool gather/scatter over cache pytrees (serving.py's KV page pool)
 # --------------------------------------------------------------------------------------
 
-# The flax "cache" collection leaves and the axis their BATCH (slot) dimension
-# lives at, counted from the BACK so the same rule covers plain stacks
-# ([B, L, h, d]) and nn.scan-stacked layers ([layers, B, L, h, d]).
-_SLOT_AXIS_FROM_BACK = {"cached_key": 4, "cached_value": 4, "pad_mask": 2}
-
-
-def _key_name(entry) -> str:
-    """DictKey/GetAttrKey/SequenceKey path entry -> plain string."""
-    return str(getattr(entry, "key", getattr(entry, "name", entry)))
-
-
-def _leaf_name(path) -> str:
-    return _key_name(path[-1])
-
-
-def tree_scatter_rows(dst, src, index):
-    """Write `src`'s single slot row into `dst` at row `index` for every cache
-    leaf: `dst.cached_*[..., index:index+1, :, :, :] = src.cached_*`. This is how
-    a freshly-prefilled batch-1 KV cache is INSERTED into a slot of the shared
-    `num_slots`-row serving cache without the model ever seeing a slot index —
-    jit-traceable (`index` may be a traced scalar), so the whole insert program
-    compiles once per prompt bucket.
-
-    Leaves not in the slot-axis table (e.g. the scalar `cache_index`, meaningless
-    per-slot) keep `dst`'s value; leaves present only in `src` are dropped.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    src_leaves = {
-        tuple(_key_name(p) for p in path): leaf
-        for path, leaf in jax.tree_util.tree_flatten_with_path(src)[0]
-    }
-
-    def _scatter(path, d):
-        names = tuple(_key_name(p) for p in path)
-        axis_back = _SLOT_AXIS_FROM_BACK.get(names[-1])
-        s = src_leaves.get(names)
-        if axis_back is None or s is None:
-            return d
-        axis = d.ndim - axis_back
-        start = [jnp.int32(0)] * d.ndim
-        start[axis] = jnp.asarray(index, jnp.int32)
-        return jax.lax.dynamic_update_slice(d, s.astype(d.dtype), tuple(start))
-
-    return jax.tree_util.tree_map_with_path(_scatter, dst)
-
-
-def tree_gather_rows(tree, index):
-    """Slice slot row `index` out of every cache leaf (the inverse of
-    `tree_scatter_rows`): returns a batch-1 cache view for debugging/tests.
-    Non-slot leaves (scalars like `cache_index`) pass through unchanged."""
-    import jax
-    import jax.numpy as jnp
-
-    def _gather(path, t):
-        axis_back = _SLOT_AXIS_FROM_BACK.get(_leaf_name(path))
-        if axis_back is None:
-            return t
-        axis = t.ndim - axis_back
-        return jax.lax.dynamic_slice_in_dim(t, jnp.asarray(index, jnp.int32), 1, axis=axis)
-
-    return jax.tree_util.tree_map_with_path(_gather, tree)
-
-
-# --------------------------------------------------------------------------------------
-# Page-pool gather/scatter over cache pytrees (serving.py's paged KV cache)
-# --------------------------------------------------------------------------------------
-
-# K/V leaves of a PAGED slot cache are pool-shaped: [..., num_pages, page_size,
+# K/V leaves of the slot cache are pool-shaped: [..., num_pages, page_size,
 # heads, head_dim] — the page axis sits where the dense cache's batch axis sits
 # (4 from the back), so the same rule covers plain stacks and nn.scan-stacked
 # layers ([layers, num_pages, page_size, h, d]).
@@ -529,6 +460,15 @@ _PAGE_AXIS_FROM_BACK = {"cached_key": 4, "cached_value": 4}
 _SCALE_AXIS_FROM_BACK = {"key_scale": 2, "value_scale": 2}
 _SCALE_OF = {"cached_key": "key_scale", "cached_value": "value_scale"}
 _KV_OF = {v: k for k, v in _SCALE_OF.items()}
+
+
+def _key_name(entry) -> str:
+    """DictKey/GetAttrKey/SequenceKey path entry -> plain string."""
+    return str(getattr(entry, "key", getattr(entry, "name", entry)))
+
+
+def _leaf_name(path) -> str:
+    return _key_name(path[-1])
 
 
 def _path_names(path):

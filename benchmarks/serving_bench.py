@@ -154,7 +154,7 @@ def run_router_workload(model, args, cfg, max_length, rng, tracer=None):
         model, replicas=args.replicas, num_slots=args.num_slots,
         max_length=max_length, chunk_size=args.chunk_size,
         max_queue=args.requests + 16, default_deadline_s=600.0,
-        paged=not args.no_paged, page_size=args.page_size, tracer=tracer,
+        page_size=args.page_size, tracer=tracer,
         rejoin_cooldown_s=0.2, probation_steps=1, stall_degrade_s=None,
         attention_impl=args.attention_impl,
         weight_dtype=args.weight_dtype, kv_cache_dtype=args.kv_cache_dtype,
@@ -290,7 +290,7 @@ def run_spec_workload(model, args, cfg, max_length, rng, tracer=None):
     for label, spec_on in (("plain", False), ("speculative", True)):
         engine = ContinuousBatcher(
             model, num_slots=args.num_slots, max_length=max_length,
-            chunk_size=args.chunk_size, paged=not args.no_paged,
+            chunk_size=args.chunk_size,
             page_size=args.page_size, tracer=tracer, speculative=spec_on,
             draft_tokens=args.draft_tokens, draft_ngram=args.draft_ngram,
             max_queue=args.requests,
@@ -416,7 +416,7 @@ def run_attention_workload(model, args, cfg, max_length, workload, tracer=None):
     for impl in ("xla", "pallas_paged"):
         engine = ContinuousBatcher(
             model, num_slots=args.num_slots, max_length=max_length,
-            chunk_size=args.chunk_size, paged=True, page_size=args.page_size,
+            chunk_size=args.chunk_size, page_size=args.page_size,
             tracer=tracer, max_queue=args.requests, attention_impl=impl,
             weight_dtype=args.weight_dtype, kv_cache_dtype=args.kv_cache_dtype,
         )
@@ -536,7 +536,7 @@ def run_quant_workload(model, args, cfg, max_length, workload, tracer=None):
     for label, weight_dtype, kv_dtype in rows:
         engine = ContinuousBatcher(
             model, num_slots=args.num_slots, max_length=max_length,
-            chunk_size=args.chunk_size, paged=True, page_size=args.page_size,
+            chunk_size=args.chunk_size, page_size=args.page_size,
             tracer=tracer, max_queue=args.requests,
             attention_impl=args.attention_impl,
             weight_dtype=weight_dtype, kv_cache_dtype=kv_dtype,
@@ -645,7 +645,7 @@ def _run_guarded_engine_pass(model, args, cfg, max_length, workload, tracer, lab
     prompts, budgets, arrivals = workload
     engine = ContinuousBatcher(
         model, num_slots=args.num_slots, max_length=max_length,
-        chunk_size=args.chunk_size, paged=not args.no_paged,
+        chunk_size=args.chunk_size,
         page_size=args.page_size, tracer=tracer, max_queue=args.requests,
         attention_impl=args.attention_impl,
         weight_dtype=args.weight_dtype, kv_cache_dtype=args.kv_cache_dtype,
@@ -885,7 +885,7 @@ def run_prefix_workload(model, args, cfg, max_length, rng, tracer=None):
     for label, use_prefix in (("uncached", False), ("cached", True)):
         engine = ContinuousBatcher(
             model, num_slots=args.num_slots, max_length=max_length,
-            chunk_size=args.chunk_size, paged=True, page_size=args.page_size,
+            chunk_size=args.chunk_size, page_size=args.page_size,
             prefix_cache=use_prefix, tracer=tracer, max_queue=args.requests,
         )
         log(f"prefix workload ({label}): warmup...")
@@ -966,7 +966,6 @@ def run_ramp_workload(model, args, cfg, max_length, rng, tracer=None):
         # rejected arrivals — the queue bound is sized above one full level.
         max_queue=max(4 * n, 64),
         default_deadline_s=600.0,
-        paged=not args.no_paged,
         page_size=args.page_size,
         tracer=tracer,
         out_of_process=args.out_of_process,
@@ -1124,7 +1123,7 @@ def run_transport_workload(model, args, cfg, max_length, rng, tracer=None):
         router = Router(
             model, replicas=1, num_slots=args.num_slots, max_length=max_length,
             chunk_size=args.chunk_size, max_queue=args.requests + 16,
-            default_deadline_s=600.0, paged=not args.no_paged,
+            default_deadline_s=600.0,
             page_size=args.page_size, tracer=tracer, stall_degrade_s=None,
             weight_dtype=args.weight_dtype, kv_cache_dtype=args.kv_cache_dtype,
             out_of_process=True,
@@ -1277,7 +1276,6 @@ def main(argv=None):
     parser.add_argument("--mean-interarrival", type=float, default=0.02, help="Poisson arrival mean gap (virtual seconds)")
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--page-size", type=int, default=16, help="KV pool page size in tokens (paged cache)")
-    parser.add_argument("--no-paged", action="store_true", help="use the contiguous per-slot KV layout (disables the prefix workload)")
     parser.add_argument("--prefix-tokens", type=int, default=None,
                         help="shared system-prompt length for the prefix-heavy workload; default 64 on accelerators, 24 on CPU; 0 disables")
     parser.add_argument("--no-speculative", action="store_true",
@@ -1411,13 +1409,9 @@ def main(argv=None):
         print(json.dumps(result))
         return 0
 
-    if args.attention_impl == "pallas_paged" and args.no_paged:
-        parser.error("--attention-impl pallas_paged requires the paged cache (drop --no-paged)")
-    if args.kv_cache_dtype != "bf16" and args.no_paged:
-        parser.error("--kv-cache-dtype requires the paged cache (drop --no-paged)")
     engine = ContinuousBatcher(
         model, num_slots=args.num_slots, max_length=max_length, chunk_size=args.chunk_size,
-        paged=not args.no_paged, page_size=args.page_size, tracer=tracer,
+        page_size=args.page_size, tracer=tracer,
         max_queue=args.requests, attention_impl=args.attention_impl,
         weight_dtype=args.weight_dtype, kv_cache_dtype=args.kv_cache_dtype,
     )
@@ -1473,10 +1467,9 @@ def main(argv=None):
     )
 
     # Prefix-heavy workload: same model, shared system prompt across requests,
-    # prefix cache ON vs OFF (paged engines only — the contiguous layout has no
-    # pages to share).
+    # prefix cache ON vs OFF.
     prefix_block = None
-    if not args.no_paged and args.prefix_tokens > 0:
+    if args.prefix_tokens > 0:
         max_prefix = max_length - args.max_new_max - args.prompt_min
         if args.prefix_tokens > max_prefix:
             log(f"capping prefix_tokens to {max_prefix} for the {max_length}-token cache")
@@ -1506,7 +1499,7 @@ def main(argv=None):
     # records both impls' decode tokens/sec plus the pool-geometry HBM
     # estimate — the bandwidth claim as an artifact.
     attention_ab = None
-    if not args.no_paged and not args.no_attention_ab:
+    if not args.no_attention_ab:
         attention_ab = run_attention_workload(
             model, args, cfg, max_length, (prompts, budgets, arrivals), tracer=tracer
         )
@@ -1515,7 +1508,7 @@ def main(argv=None):
     # the same workload — tokens/sec, per-dispatch attention seconds, actual
     # pool/weight bytes, token agreement and the >= 2x cache-byte drop gate.
     quant_block = None
-    if not args.no_paged and not args.no_quant_ab:
+    if not args.no_quant_ab:
         quant_block = run_quant_workload(
             model, args, cfg, max_length, (prompts, budgets, arrivals), tracer=tracer
         )
@@ -1606,14 +1599,13 @@ def main(argv=None):
             "span_counts": span_counts,
         },
     }
-    paging_block = {"enabled": not args.no_paged}
-    if not args.no_paged:
-        paging_block.update(
-            page_size=args.page_size,
-            pages_total=engine.stats["pages_total"],
-            kv_cache_dtype=engine.stats["kv_cache_dtype"],
-            prefix_cache=engine.stats["prefix_cache"],
-        )
+    paging_block = dict(
+        enabled=True,
+        page_size=args.page_size,
+        pages_total=engine.stats["pages_total"],
+        kv_cache_dtype=engine.stats["kv_cache_dtype"],
+        prefix_cache=engine.stats["prefix_cache"],
+    )
     result = {
         "metric": f"{prefix}continuous-batching serving tokens/sec "
         f"({model_name}, slots {args.num_slots}, chunk {args.chunk_size}, "

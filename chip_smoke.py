@@ -313,7 +313,7 @@ def make_router(model, sizes: Sizes, max_length: int, **engine_kwargs):
 
     return Router(
         model, replicas=1, num_slots=sizes.num_slots, max_length=max_length,
-        chunk_size=sizes.chunk_size, paged=True, **engine_kwargs,
+        chunk_size=sizes.chunk_size, **engine_kwargs,
     )
 
 
@@ -772,7 +772,7 @@ def phase_multichip(ledger, sizes: Sizes, seed: int, chips: int) -> None:
         requests = make_requests(sizes, cfg.vocab_size, seed + 2)
         router = Router(
             model, replicas=chips, tp=1, num_slots=sizes.num_slots, max_length=max_length,
-            chunk_size=sizes.chunk_size, paged=True,
+            chunk_size=sizes.chunk_size,
         )
         serve_requests(router, requests)
         placement = {}

@@ -185,9 +185,9 @@ def test_tpu115_interpret_variant():
 
 
 def test_tpu115_impl_pin_variants():
-    """attention_impl="xla" flags only where the paged kernel applies: an
-    explicit paged=False or page_size=0 opt-out is clean (no page table to
-    walk), as is threading the impl as a variable (A/B harnesses)."""
+    """A literal attention_impl="xla" flags wherever it is spelled — the
+    engine, a config field, the seam — since every slot cache is a page pool;
+    threading the impl as a variable (A/B harnesses) is clean."""
     hazard = (
         "import jax\n"
         "from accelerate_tpu.serving import ContinuousBatcher\n"
@@ -195,9 +195,7 @@ def test_tpu115_impl_pin_variants():
         '    return ContinuousBatcher(model, max_queue=8, attention_impl="xla")\n'
     )
     assert [f.rule_id for f in analyze_source(hazard)] == ["TPU115"]
-    assert not analyze_source(
-        hazard.replace('attention_impl="xla"', 'paged=False, attention_impl="xla"')
-    )
+    assert "fallback" not in analyze_source(hazard)[0].message
     assert not analyze_source(
         hazard.replace('attention_impl="xla"', "attention_impl=impl")
     )
@@ -209,23 +207,15 @@ def test_tpu115_impl_pin_variants():
         '    return dataclasses.replace(base, decode_page_size=4, decode_attention_impl="xla")\n'
     )
     assert [f.rule_id for f in analyze_source(cfg)] == ["TPU115"]
-    assert not analyze_source(
-        cfg.replace("decode_page_size=4", "decode_page_size=0")
-    )
-    # A seam call relying on its own page_size=0 default (the contiguous
-    # layout, where "xla" is the ONLY legal impl) must not flag — only calls
-    # that really thread page geometry, or the paged-by-default constructors.
     seam = (
         "import jax\n"
         "from accelerate_tpu.ops.attention import slot_cache_attention\n"
-        "def attend(module, q, k, v, pos):\n"
-        '    return slot_cache_attention(module, q, k, v, 32, pos, attention_impl="xla")\n'
+        "def attend(module, q, k, v, pos, tbl, ps):\n"
+        "    return slot_cache_attention(module, q, k, v, 32, pos, page_table=tbl,\n"
+        '                                page_size=ps, attention_impl="xla")\n'
     )
-    assert not analyze_source(seam)
-    paged_seam = seam.replace(
-        'attention_impl="xla"', 'page_size=ps, attention_impl="xla"'
-    )
-    assert [f.rule_id for f in analyze_source(paged_seam)] == ["TPU115"]
+    assert [f.rule_id for f in analyze_source(seam)] == ["TPU115"]
+    assert not analyze_source(seam.replace('attention_impl="xla"', "attention_impl=impl"))
 
 
 def test_tpu116_worker_loop_variants():

@@ -520,23 +520,6 @@ def test_consumed_donation_recovers_with_speculation_enabled():
     assert steps["value"] > 0
 
 
-def test_consumed_donation_recovers_on_the_contiguous_layout_too():
-    """paged=False remains a supported fallback (and the only option for model
-    families without pool-cache support): its blast-radius recovery must stay
-    chaos-covered, not just the paged default's."""
-    plan = FaultPlan(
-        name="chunk-consumes-donation-contiguous",
-        events=[FaultEvent(kind="serve.dispatch_error", at_call=2,
-                           args={"consume_donated": True})],
-    )
-    report = ChaosRunner(plan).run_serve(num_requests=4, max_queue=4, paged=False)
-    assert report.ok, report.render_text()
-    recovered = next(c for c in report.checks if c.name == "engine_recovered")
-    assert recovered.details["requests_after_error"] >= 2
-    ledger = next(c for c in report.checks if c.name == "page_ledger")
-    assert ledger.details.get("note") == "contiguous engine (no pool)"
-
-
 # ------------------------------------------------------- kernel-path serving chaos
 @pytest.mark.kernels
 def test_smoke_serve_sweep_on_the_kernel_path():
@@ -556,15 +539,17 @@ def test_smoke_serve_sweep_on_the_kernel_path():
     assert by_name["engine_recovered"].details.get("requests_after_error", 0) >= 2
 
 
-def test_smoke_serve_sweep_on_the_quantized_pool():
-    """The smoke-serve acceptance sweep with `kv_cache_dtype="int8"`: fault
+@pytest.mark.parametrize("kv_cache_dtype", ["int8", "fp8_e4m3"])
+def test_smoke_serve_sweep_on_the_quantized_pool(kv_cache_dtype):
+    """The smoke-serve acceptance sweep on a quantized pool (int8, fp8): fault
     paths must exercise the QUANTIZED page pool — dispatch stalls, queue
     bursts, and the blast-radius dispatch failure all land on an engine whose
-    pool pages are int8 with per-page-per-head scale pools, and recovery must
-    rebuild pools AND scales from zeros with the page ledger still closed."""
+    pool pages are quantized with per-page-per-head scale pools, and recovery
+    must rebuild pools AND scales from zeros with the page ledger still
+    closed."""
     plan = builtin_plans()["smoke-serve"]
     report = ChaosRunner(plan).run_serve(
-        num_requests=6, max_queue=3, kv_cache_dtype="int8"
+        num_requests=6, max_queue=3, kv_cache_dtype=kv_cache_dtype
     )
     assert report.ok, report.render_text()
     by_name = {c.name: c for c in report.checks}

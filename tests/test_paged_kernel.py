@@ -375,7 +375,7 @@ def test_engine_parity_gpt_neox():
 # ------------------------------------------------------------------ guardrails
 def test_pallas_paged_requires_paged_cache():
     model = create_llama_model(_tiny_config(), seq_len=16)
-    with pytest.raises(ValueError, match="paged"):
+    with pytest.raises(ValueError, match="contiguous per-slot KV layout is gone"):
         ContinuousBatcher(
             model, num_slots=2, max_length=32, paged=False,
             attention_impl="pallas_paged", max_queue=4,

@@ -1,12 +1,14 @@
-"""Paged KV cache + shared-prefix reuse tests (paging.PagePool, the paged
-`ops/attention.update_slot_cache` mode, `utils/operations.tree_gather_pages`/
-`tree_scatter_pages`, and the `ContinuousBatcher(paged=True)` engine).
+"""KV page pool + shared-prefix reuse tests (paging.PagePool,
+`ops/attention.slot_cache_attention`, `utils/operations.tree_gather_pages`/
+`tree_scatter_pages`, and the `ContinuousBatcher` engine).
 
 The load-bearing contracts:
   1. the paged scatter/gather ops round-trip against a dense reference,
      including page-boundary writes and arbitrary pool permutations;
-  2. greedy decode is TOKEN-IDENTICAL between the paged and contiguous cache
-     paths, across slot reuse and shared-prefix scenarios;
+  2. greedy decode is TOKEN-IDENTICAL to the static Generator across slot
+     reuse and shared-prefix scenarios, and the read matches the two
+     references kept here: the gather-everything read and the dense
+     one-row-per-slot read the program had until PR 29;
   3. slot/page reuse never exposes a prior occupant's tokens;
   4. admission is PAGE-based: request mixes whose worst-case rows exceed the
      old slot capacity are admitted and complete when their actual token
@@ -75,7 +77,7 @@ def _fake_caches(rng, layers=2, pages=7, ps=4, h=2, d=3):
 
 def test_gather_pages_matches_dense_reference():
     """Gathering pages [ids] must equal concatenating those pool pages in table
-    order — the dense layout the contiguous path would have held."""
+    order — the dense row a slot's window is."""
     from accelerate_tpu.utils.operations import tree_gather_pages
 
     rng = np.random.default_rng(0)
@@ -145,6 +147,27 @@ def _gather_everything_attention(q, pool_k, pool_v, pos, table, scales):
     return dot_product_attention(q, k_full, v_full, mask=mask, causal=False)
 
 
+def _dense_slot_attention(module, q, k, v, cache_length, positions):
+    """REFERENCE, the dense one-row-per-slot read the program had until PR 29
+    (`ops.attention.update_slot_cache` + `dot_product_attention`): the cache
+    is [B, cache_length, h, d], row i's new K/V lands at `positions[i]`, and
+    each query attends `cols <= its position` of its own row — every slot's
+    whole row is read, whatever is live."""
+    from accelerate_tpu.ops.attention import dot_product_attention
+
+    b, s, h, d = k.shape
+    L = cache_length
+    cached_k = module.variable("cache", "cached_key", jnp.zeros, (b, L, h, d), k.dtype)
+    cached_v = module.variable("cache", "cached_value", jnp.zeros, (b, L, h, d), v.dtype)
+    pos = jnp.clip(positions, 0, L - 1).astype(jnp.int32)  # [B, s]
+    rows = jnp.arange(b)[:, None]
+    cached_k.value = cached_k.value.at[rows, pos].set(k)
+    cached_v.value = cached_v.value.at[rows, pos].set(v)
+    cols = jnp.arange(L)[None, None, :]
+    mask = (cols <= pos[:, :, None])[:, None, :, :]  # [B, 1, s, L]
+    return dot_product_attention(q, cached_k.value, cached_v.value, mask=mask, causal=False)
+
+
 def test_paged_slot_write_crosses_page_boundaries():
     """The paged write lands at pool[table[pos//ps], pos%ps] and the window
     gathered through the table reproduces the dense logical order, for
@@ -193,8 +216,8 @@ def test_paged_slot_write_crosses_page_boundaries():
 
 def _read_layer(read, page_size, num_pages, kv_cache_dtype="bf16"):
     """One attention layer over a slot cache: `read` "live" is the program's
-    paged read, "everything" the oracle above behind the same write, and
-    "contiguous" the other layout."""
+    read, "everything" the oracle above behind the same write, and
+    "contiguous" the dense reference above over one row a slot."""
     import flax.linen as nn
 
     from accelerate_tpu.ops import attention
@@ -204,7 +227,7 @@ def _read_layer(read, page_size, num_pages, kv_cache_dtype="bf16"):
         def __call__(self, q, k, v, positions, table):
             length = table.shape[-1] * page_size
             if read == "contiguous":
-                return attention.slot_cache_attention(self, q, k, v, length, positions)
+                return _dense_slot_attention(self, q, k, v, length, positions)
             if read == "live":
                 return attention.slot_cache_attention(
                     self, q, k, v, length, positions, page_table=table, page_size=page_size,
@@ -301,9 +324,9 @@ _RAGGED = (0, 23, 4, 3, 8)  # live pages 1 + 6 + 2 + 1 + 3 = 13
 )
 def test_live_page_read_matches_gather_everything_and_contiguous(first, block, kwargs, block_pages):
     """The paged XLA read (blocks of live pages under a trip count from the
-    positions) == the gather-everything oracle == the contiguous layout, on
+    positions) == the gather-everything oracle == the dense reference, on
     the layer's output: ragged positions, block boundaries, verify blocks,
-    GQA and MHA, quantized pools (which the contiguous layout does not have)."""
+    GQA and MHA, quantized pools (which the dense reference does not have)."""
     operands, cache, contiguous, geometry = _read_case(first, **kwargs)
     pool = kwargs.get("pool", "bf16")
     if block is not None:
@@ -340,19 +363,21 @@ def test_idle_slot_at_position_zero_contributes_nothing(block_pages):
 def _decode_logits(engine):
     """One more decode step's logits off an engine's live state (its own
     un-jitted step program; nothing is donated or adopted)."""
-    args = [engine.params, engine._cache, jnp.asarray(engine._token), jnp.asarray(engine._pos)]
-    if engine.paged:
-        args.append(jnp.asarray(engine._page_table))
+    args = [
+        engine.params, engine._cache, jnp.asarray(engine._token), jnp.asarray(engine._pos),
+        jnp.asarray(engine._page_table),
+    ]
     logits, _cache = jax.jit(engine._step_raw)(*args)
     return np.asarray(logits, np.float32)
 
 
 @pytest.mark.parametrize("family", ["llama-gqa", "gpt_neox-mha"])
 def test_paged_read_logits_match_oracle_and_contiguous(family, monkeypatch):
-    """Model level, both slot-cache families: three engines two chunks into the
-    same ragged requests (one slot left idle) — the paged read, the
-    gather-everything oracle in its place, the contiguous layout — hold the
-    same state and score the next step alike."""
+    """Model level, both slot-cache families: two engines two chunks into the
+    same ragged requests (one slot left idle) — the program's read, and the
+    gather-everything oracle in its place — hold the same state and score the
+    next step alike, and what they streamed so far is the static Generator's
+    (its dense decode cache is the other layout)."""
     import dataclasses
 
     from accelerate_tpu.ops import attention
@@ -373,26 +398,27 @@ def test_paged_read_logits_match_oracle_and_contiguous(family, monkeypatch):
     kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
     monkeypatch.setattr(attention, "_READ_BLOCK_BYTES", 2 * 8 * kv_heads * cfg.head_dim * 4)
 
-    def two_chunks(**kwargs):
-        engine = ContinuousBatcher(model, num_slots=4, max_length=32, chunk_size=2, **kwargs)
+    def two_chunks():
+        engine = ContinuousBatcher(model, num_slots=4, max_length=32, chunk_size=2, page_size=8)
         for i, p in enumerate(prompts):
             engine.submit(Request(i, p, max_new_tokens=12))
         engine.step()
         engine.step()
         return engine
 
-    live = two_chunks(page_size=8)
-    dense = two_chunks(paged=False)
+    live = two_chunks()
     assert live._pos[3] == 0 and not live._active[3]  # the idle slot
     busy = np.arange(3)
     live_logits = _decode_logits(live)[busy]
-    np.testing.assert_allclose(live_logits, _decode_logits(dense)[busy], atol=2e-5)
+    for i, p in enumerate(prompts):
+        streamed = live.results[i].tokens
+        assert len(streamed) == 5  # the insert's token and two chunks of 2
+        np.testing.assert_array_equal(streamed, _static_reference(model, p, 12)[:5])
     monkeypatch.setattr(attention, "_live_page_attention", _gather_everything_attention)
-    everything = two_chunks(page_size=8)
+    everything = two_chunks()
     np.testing.assert_allclose(live_logits, _decode_logits(everything)[busy], atol=2e-5)
-    for other in (dense, everything):
-        np.testing.assert_array_equal(live._token, other._token)
-        np.testing.assert_array_equal(live._pos, other._pos)
+    np.testing.assert_array_equal(live._token, everything._token)
+    np.testing.assert_array_equal(live._pos, everything._pos)
 
 
 # ------------------------------------------------------------------ parity
@@ -422,9 +448,9 @@ def _shared_prefix_churn_workload(rng):
     ids=["slot_reuse", "shared_prefix_churn"],
 )
 def test_paged_contiguous_and_static_parity_with_slot_reuse(workload):
-    """Acceptance pin: greedy decode is token-identical between the paged "xla"
-    read and the contiguous layout across a slot-reuse workload and a
-    shared-prefix one, and both match the static Generator."""
+    """Acceptance pin: greedy decode through the "xla" read is token-identical
+    to the static Generator (a dense decode cache, one request at a time)
+    across a slot-reuse workload and a shared-prefix one."""
     model = _model()
     prompts, budgets = workload(np.random.default_rng(3))
     requests = lambda: [  # noqa: E731 — fresh Request objects per engine
@@ -433,11 +459,8 @@ def test_paged_contiguous_and_static_parity_with_slot_reuse(workload):
     paged = ContinuousBatcher(
         model, num_slots=2, max_length=32, chunk_size=4, page_size=8, attention_impl="xla"
     )
-    contiguous = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=4, paged=False)
     out_p = paged.run(requests())
-    out_c = contiguous.run(requests())
     for i, (p, m) in enumerate(zip(prompts, budgets)):
-        np.testing.assert_array_equal(out_p[i], out_c[i])
         np.testing.assert_array_equal(out_p[i], _static_reference(model, p, m))
     assert paged.trace_counts["decode_chunk"] == 1
     assert paged.pool.pages_in_use == 0

@@ -244,7 +244,7 @@ def test_auto_plan_matches_or_beats_hand_rules_on_modeled_cost(family, tp):
     mesh = {"model": tp}
     plan = plan_serving_sharding(
         model.params, mesh, cfg,
-        num_slots=2, padded_length=64, paged=True, page_size=4, num_pages=33,
+        num_slots=2, page_size=4, num_pages=33,
     )
     hand = score_rules(model.params, mesh, hand_rules, workload=plan.workload)
     assert plan.cost.total <= hand.cost.total * (1 + 1e-9), (
@@ -425,7 +425,7 @@ def test_refine_measures_real_forwards_on_cpu_mesh():
     mesh = serving_tp_mesh(2)
     plans = plan_serving_sharding(
         model.params, mesh, cfg,
-        num_slots=2, padded_length=64, paged=True, page_size=4, num_pages=33,
+        num_slots=2, page_size=4, num_pages=33,
         top_k=3,
     )
     assert len(plans) >= 2

@@ -434,8 +434,8 @@ def test_quantized_decode_compiled_once_and_guarded():
 
 
 def test_quantized_engine_validation():
-    """Config validation: off-set dtypes and the quantized-contiguous combo
-    fail loudly at construction, and weight quantization is idempotent across
+    """Config validation: off-set dtypes and the contiguous layout that a
+    quantized pool never had fail loudly at construction, and weight quantization is idempotent across
     the params setter (the swap_weights seam re-assigns raw params)."""
     from accelerate_tpu.models.llama import create_llama_model, llama_tiny
     from accelerate_tpu.serving import ContinuousBatcher
@@ -445,7 +445,7 @@ def test_quantized_engine_validation():
         ContinuousBatcher(model, max_queue=4, kv_cache_dtype="int4")
     with pytest.raises(ValueError, match="weight_dtype"):
         ContinuousBatcher(model, max_queue=4, weight_dtype="fp4")
-    with pytest.raises(ValueError, match="paged"):
+    with pytest.raises(ValueError, match="contiguous per-slot KV layout is gone"):
         ContinuousBatcher(model, max_queue=4, paged=False, kv_cache_dtype="int8")
     assert "int8" in KV_CACHE_DTYPES and "int8" in WEIGHT_DTYPES
     eng = ContinuousBatcher(

@@ -1,7 +1,6 @@
-"""TPU115 clean fixture: the sanctioned spellings — the kernel path on paged
-engines, the oracle only where paging is explicitly off (no page table to
-walk), impl flags threaded as variables, and kernels left to auto-select
-interpret mode."""
+"""TPU115 clean fixture: the sanctioned spellings — the kernel path named,
+impl flags threaded as variables, and kernels left to auto-select interpret
+mode."""
 
 import jax.numpy as jnp
 
@@ -12,12 +11,6 @@ from accelerate_tpu.serving import ContinuousBatcher
 def build_engine(model):
     # The kernel path: the page-table gather fused into the attention walk.
     return ContinuousBatcher(model, max_queue=8, attention_impl="pallas_paged")
-
-
-def build_contiguous_engine(model):
-    # "xla" is the ONLY implementation for the contiguous layout — no page
-    # table exists to walk, so pinning the oracle here is not a fallback.
-    return ContinuousBatcher(model, max_queue=8, paged=False, attention_impl="xla")
 
 
 def build_ab_engine(model, impl):

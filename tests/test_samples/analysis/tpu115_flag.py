@@ -1,6 +1,5 @@
-"""TPU115 flag fixture: a paged serving engine pinned to the XLA gather oracle
-by a literal attention_impl="xla" — one keyword away from silently serving off
-the kernel path. (The interpret=True kernel-call variant is unit-tested in
+"""TPU115 flag fixture: a serving engine whose KV read is pinned to the XLA
+live-page read by a literal attention_impl="xla". (The interpret=True kernel-call variant is unit-tested in
 test_analysis_rules.test_tpu115_interpret_variant; the tree-walk contract
 allows exactly one finding per flag fixture.)"""
 
@@ -10,6 +9,5 @@ from accelerate_tpu.serving import ContinuousBatcher
 
 
 def build_engine(model):
-    # FLAG: paged engine (paged defaults True) explicitly pinned to the
-    # gather oracle — the Pallas paged kernel applies to this configuration.
+    # FLAG: the literal pins the read where the engine is built.
     return ContinuousBatcher(model, max_queue=8, attention_impl="xla")

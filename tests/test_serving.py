@@ -102,7 +102,7 @@ def test_released_slot_sits_at_position_zero_through_reuse():
     """The paged XLA read counts a row's live pages from its position, so a
     slot that `_finish` releases goes back to position 0 (one page: scratch)
     — while greedy decode through slot reuse stays token-identical to the
-    contiguous engine — and the host's count of what each chunk visits rides
+    static Generator — and the host's count of what each chunk visits rides
     the chunk's span and the `kv_live_page_share` gauge."""
     from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
     from accelerate_tpu.telemetry.tracing import Tracer
@@ -129,11 +129,11 @@ def test_released_slot_sits_at_position_zero_through_reuse():
         assert not paged._pos[idle].any(), (paged._pos, idle)
         assert not paged._page_table[idle].any()
         shares.append(paged.stats["kv_live_page_share"])
-    contiguous = ContinuousBatcher(model, num_slots=3, max_length=32, chunk_size=4, paged=False)
-    expect = contiguous.run(requests())
-    for i in range(len(prompts)):
-        np.testing.assert_array_equal(np.asarray(paged.results[i].tokens), expect[i])
-    assert not contiguous._pos.any()  # one exit path: both layouts release alike
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        np.testing.assert_array_equal(
+            np.asarray(paged.results[i].tokens), _static_reference(model, p, m)
+        )
+    assert not paged._pos.any()  # drained: every slot was released to position 0
     chunks = [r for r in recorder.records() if r["name"] == "serve.decode_chunk"]
     assert chunks and all(c["attrs"]["window_pages"] == 3 * 4 for c in chunks)
     # the first chunk: prompts of 12, 5 and 20 tokens, 8 a page -> 2 + 1 + 3 live pages
@@ -266,27 +266,30 @@ def test_admission_rejects_oversized_and_duplicate_requests():
     np.testing.assert_array_equal(outputs[1], np.asarray(first.tokens, np.int32))
 
 
-def test_tree_scatter_gather_roundtrip():
-    """tree_gather_rows inverts tree_scatter_rows on the live CONTIGUOUS engine
-    cache, and non-slot leaves (scalars like cache_index) pass through
-    untouched — the debugging contract both helpers document. (The paged
-    layout's pool gather/scatter twins are pinned in tests/test_paging.py.)"""
-    import jax.numpy as jnp
+def test_the_contiguous_layout_is_refused_by_the_engine_and_both_clis(capsys):
+    """The page pool is the engine's only KV store: `paged=False` (or any
+    value but True) raises, `paged=True` is accepted for the benchmark's
+    pinned workload files and `engine.paged` reads True, a slot-cache module
+    config without a page size cannot be built, and `accelerate-tpu serve` /
+    `plan` no longer know `--no-paged` (argparse's own error)."""
+    import dataclasses
 
-    from accelerate_tpu.utils.operations import tree_gather_rows, tree_scatter_rows
+    from accelerate_tpu.commands.accelerate_cli import get_command_parser
 
     model = _model()
-    engine = ContinuousBatcher(model, num_slots=3, max_length=32, chunk_size=2, paged=False)
-    engine.run([Request(0, np.arange(1, 6, dtype=np.int32), max_new_tokens=3)])
-    row = tree_gather_rows(engine._cache, 1)
-    for leaf in jax.tree_util.tree_leaves(row):
-        if leaf.ndim >= 4:  # cached_key/value [1, L, h, d]
-            assert leaf.shape[0] == 1
-    scattered = tree_scatter_rows(engine._cache, row, jnp.int32(1))
-    for a, b in zip(
-        jax.tree_util.tree_leaves(scattered), jax.tree_util.tree_leaves(engine._cache)
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for value in (False, None, 0):
+        with pytest.raises(ValueError, match="contiguous per-slot KV layout is gone"):
+            ContinuousBatcher(model, num_slots=2, max_length=32, paged=value)
+    assert ContinuousBatcher(model, num_slots=2, max_length=32, paged=True).paged is True
+    with pytest.raises(ValueError, match="decode_page_size"):
+        dataclasses.replace(model.module.config, decode_cache_length=32, decode_slot_cache=True)
+    parser = get_command_parser()
+    for argv in (["serve", "--no-paged"], ["plan", "--no-paged"]):
+        with pytest.raises(SystemExit) as exit_info:
+            parser.parse_args(argv)
+        assert exit_info.value.code == 2
+        assert "--no-paged" in capsys.readouterr().err
+    assert parser.parse_args(["serve"]).command == "serve"
 
 
 # ------------------------------------------------------------- fault isolation

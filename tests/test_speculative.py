@@ -6,7 +6,8 @@ Pins the three load-bearing contracts:
      context (never out-of-vocab, never past the observed length), and
      degrades to valid_len == 0 — plain decode — on degenerate input;
   2. greedy output is TOKEN-IDENTICAL with speculation on vs off, across
-     {llama, gpt_neox} x {paged, contiguous} serving engines, slot reuse,
+     {llama, gpt_neox} x the page pool's shapes (a page size that does not
+     divide the window, a pool too small for the slots, no prefix cache), slot reuse,
      EOS inside a verified block, and the static Generator loop — the
      verification invariant that makes the speedup safe to ship;
   3. the no-recompile discipline survives: one decode executable for the
@@ -137,12 +138,38 @@ def test_greedy_accept_length_masks_and_prefixes():
 
 
 # ----------------------------------------------------- serving parity sweep
+def _count_pool_refusals(engine):
+    """How often admission found the pool short (`reserve` -> None) and put a
+    request back at the head of the queue: the returned list's one entry."""
+    refusals = [0]
+    reserve = engine.pool.reserve
+
+    def counting(n):
+        pages = reserve(n)
+        refusals[0] += pages is None
+        return pages
+
+    engine.pool.reserve = counting
+    return refusals
+
+
 @pytest.mark.parametrize("family", ["llama", "gpt_neox"])
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "contiguous"])
-def test_serving_greedy_parity_spec_vs_nonspec(family, paged):
+@pytest.mark.parametrize(
+    "pool",
+    [
+        pytest.param({}, id="paged"),
+        # 32 tokens in pages of 5: the window is padded to 7 pages, 35 tokens
+        pytest.param({"page_size": 5}, id="ragged-window"),
+        # 8 usable pages of 4 for two slots whose requests take 4 or 5 each
+        # (draft window included): admission waits for pages, not for slots
+        pytest.param({"page_size": 4, "num_pages": 9}, id="tight-pool"),
+        pytest.param({"page_size": 4, "prefix_cache": False}, id="no-prefix-cache"),
+    ],
+)
+def test_serving_greedy_parity_spec_vs_nonspec(family, pool):
     """THE verification invariant: greedy tokens are identical with
     speculation on vs off, per request, across mixed prompt lengths/budgets
-    and slot reuse — for both model families and both cache layouts."""
+    and slot reuse — for both model families and every shape of the pool."""
     model = _model() if family == "llama" else _neox_model()
     vocab = model.module.config.vocab_size
     rng = np.random.default_rng(11)
@@ -152,20 +179,23 @@ def test_serving_greedy_parity_spec_vs_nonspec(family, paged):
     requests = lambda: [  # noqa: E731 — rebuilt per engine (ids reused)
         Request(i, p, max_new_tokens=m) for i, (p, m) in enumerate(zip(prompts, budgets))
     ]
-    plain = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=4, paged=paged)
+    plain = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=4, **pool)
     spec = ContinuousBatcher(
-        model, num_slots=2, max_length=32, chunk_size=4, paged=paged,
-        speculative=True, draft_tokens=3,
+        model, num_slots=2, max_length=32, chunk_size=4, speculative=True, draft_tokens=3, **pool
     )
+    refusals = _count_pool_refusals(spec)
     ref = plain.run(requests())
     got = spec.run(requests())
     for i in range(len(prompts)):
         np.testing.assert_array_equal(got[i], ref[i])
         assert spec.results[i].finish_reason == plain.results[i].finish_reason
+    assert spec._padded_length == (35 if pool.get("page_size") == 5 else 32)
+    assert bool(refusals[0]) == ("num_pages" in pool)  # only the tight pool makes a request wait
+    assert spec.pool.pages_in_use == 0 and spec.pool.check_consistency() == []
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "contiguous"])
-def test_eos_inside_verified_block_matches_one_token_path(paged):
+@pytest.mark.parametrize("page_size", [16, 5], ids=["paged", "ragged-window"])
+def test_eos_inside_verified_block_matches_one_token_path(page_size):
     """Satellite bugfix pin: an accepted EOS inside a verified block must end
     the request THERE — tail discarded, result ending with the EOS token, the
     same `_trim_at_eos` semantics as the one-token path. draft_tokens=4 with
@@ -177,7 +207,7 @@ def test_eos_inside_verified_block_matches_one_token_path(paged):
     eos = int(free_run[len(free_run) // 2])
     ref = _static_reference(model, prompt, 16, eos_token_id=eos)
     engine = ContinuousBatcher(
-        model, num_slots=2, max_length=32, chunk_size=3, paged=paged,
+        model, num_slots=2, max_length=32, chunk_size=3, page_size=page_size,
         speculative=True, draft_tokens=4,
     )
     outputs = engine.run([Request(0, prompt, max_new_tokens=16, eos_token_id=eos)])

@@ -6,8 +6,8 @@ multi-device CPU mesh (`tests/conftest.py` exports
 The acceptance pins:
 
   - **token parity** — greedy decode is token-IDENTICAL tp==N vs tp==1
-    across {llama, gpt_neox} x {paged, contiguous} x {speculative on/off} x
-    {bf16, int8 KV}: GSPMD partitioning is a layout change, never a numerics
+    across {llama, gpt_neox} x {speculative on/off} x
+    {bf16, int8, fp8 KV}: GSPMD partitioning is a layout change, never a numerics
     change (and the Pallas page-walk kernels, shard_mapped over the KV-head
     grid, hold the same identity);
   - **compiled-once discipline** — the ONE decode executable survives mixed
@@ -98,18 +98,17 @@ def assert_parity(a, b, tag=""):
     "variant",
     [
         {"page_size": 4},
-        {"paged": False},
         {"page_size": 4, "speculative": True, "draft_tokens": 3},
-        {"paged": False, "speculative": True, "draft_tokens": 3},
         {"page_size": 4, "kv_cache_dtype": "int8"},
         {"page_size": 4, "kv_cache_dtype": "int8", "speculative": True, "draft_tokens": 3},
+        {"page_size": 4, "kv_cache_dtype": "fp8_e4m3"},
+        {"page_size": 4, "kv_cache_dtype": "fp8_e4m3", "speculative": True, "draft_tokens": 3},
     ],
-    ids=["paged", "contiguous", "paged-spec", "contiguous-spec", "int8kv", "int8kv-spec"],
+    ids=["paged", "paged-spec", "int8kv", "int8kv-spec", "fp8kv", "fp8kv-spec"],
 )
 def test_tp_token_parity(family, variant):
     """Greedy decode tp==2 vs tp==1: token-identical across the whole
-    {family} x {layout} x {speculative} x {kv dtype} matrix (int8 KV is
-    paged-only by engine contract, so the contiguous axis carries bf16)."""
+    {family} x {speculative} x {kv dtype} matrix."""
     model = get_model(family)
     _, base = run_engine(model, tp=1, **variant)
     _, spanned = run_engine(model, tp=2, **variant)
