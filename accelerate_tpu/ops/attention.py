@@ -9,9 +9,9 @@
 
 `slot_cache_attention` is the SERVING twin: the fused cache-write + attend seam
 for slot-batched decode over the KV page pool, with its own `attention_impl`
-dispatch — the XLA read (blocks of live pages gathered and reduced in two
-loops), or the
-Pallas paged-decode / block-verify kernels (ops/paged_attention.py) that walk
+dispatch — the XLA read (one loop over blocks of live pages, gathered and
+folded into a running softmax), or the Pallas paged-decode / block-verify
+kernels (ops/paged_attention.py) that walk
 the page table without materializing any gathered page.
 
 Shapes follow the [batch, seq, heads, head_dim] convention (BSHD) throughout.
@@ -203,41 +203,61 @@ def _write_slot_pool(
     return pool_k.value, pool_v.value, pos, table, (k_scale.value, v_scale.value)
 
 
-#: Bytes of K (or of V), in the compute dtype, that one turn of
-#: `_live_page_attention`'s loops gathers from the pool. Large enough that a
-#: turn's fixed cost is small beside its bytes, small enough that the padding
-#: of the last block (half a block on average) is small beside the live pages.
-_READ_BLOCK_BYTES = 16 << 20
+#: Bytes of K, and as many of V, in the compute dtype, that one turn of
+#: `_live_page_attention`'s loop gathers from the pool: both blocks are live in
+#: the same turn. Large enough that a turn's fixed cost (~10 us on a v5e) is
+#: small beside its bytes, small enough that the padding of the last block
+#: (half a block on average) is small beside the live pages. 8 MiB against 16
+#: on one layer of pythia-1.4b, 32 slots x 88 pages (PERF.md §6, PR 30): 385 /
+#: 402 us with 870 pages live, 94 / 127 with every slot idle, 1,094 / 1,039
+#: with every page live.
+_READ_BLOCK_BYTES = 8 << 20
+
+
+def read_block_pages(window_pages: int, page_size: int, kv_heads: int, head_dim: int,
+                     itemsize: int) -> int:
+    """`G`, the (slot, page) entries one turn of `_live_page_attention`'s loop
+    visits: `_READ_BLOCK_BYTES` of compute-dtype K pages, and never more than
+    the `slots * pages_per_slot` entries the window has. The read calls it with
+    its operands' shapes and the engine with the same numbers, to say how many
+    turns a dispatch makes (`serve.decode_chunk`'s `read_blocks`)."""
+    return max(1, min(window_pages, _READ_BLOCK_BYTES // (page_size * kv_heads * head_dim * itemsize)))
 
 
 def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
-    """The paged slot cache's XLA READ: attention over the LIVE pages alone.
+    """The paged slot cache's XLA READ: attention over the LIVE pages alone,
+    in one pass.
 
     A slot's live pages are the table entries that hold a position some query
     of its row may attend: `page * page_size <= max_j pos[b, j]`, a prefix of
     its table row, so an idle slot (position 0, table row all scratch) is one
     page and a full one all `pages_per_slot`. The live (slot, page) pairs of
-    all rows, slot-major, make one flat list of `n` entries, and two loops
-    walk it in blocks of `G` pages under a trip count `ceil(n / G)` computed on
-    the device from `pos` — so the bytes read follow the tokens that are
-    live, not slots x window, in ONE program whose shapes never change:
+    all rows, slot-major, make one flat list of `n` entries, and ONE loop
+    walks it in blocks of `G` pages under a trip count `ceil(n / G)` computed
+    on the device from `pos` — so the bytes read follow the tokens that are
+    live, not slots x window, in ONE program whose shapes never change. A
+    turn, for its block's `G` entries:
 
-      1. gather a block's K pages from the pool (`mode="clip"`: ids are pool
-         ids by contract, see `_write_slot_pool`; a quantized block is
-         dequantized here), `q[owner] . K` a page, scores kept in flat order;
-      2. the scores laid out as [B, P, ..., page_size] by one small gather,
-         masked `cols <= pos` (per query: a verify block is causal), softmax
-         in fp32 over the slot's window — the arithmetic of
-         `dot_product_attention`;
-      3. gather a block's V pages, `probs . V` a page in fp32, each page's
-         partial sum added into its owner's output row.
+      1. gathers the block's K pages and its V pages from the pool
+         (`mode="clip"`: ids are pool ids by contract, see `_write_slot_pool`;
+         a quantized block is dequantized here);
+      2. `q[owner] . K` a page, scaled in fp32 and masked where the entries
+         lie: entry `i` of slot `b` is that slot's page `i - start[b]`, whose
+         tokens attend `page * page_size + token <= pos` (per query: a verify
+         block is causal), and an entry past `n` attends nothing;
+      3. folds the block into a running softmax kept BY OWNER in fp32 — the
+         maximum `m`, the sum `l` and the unnormalized output `acc` of every
+         slot's row: an entry's own maximum, row sum and `probs . V` a page
+         are reduced into its owner's row through the block's [G, B] one-hot,
+         and what the row held is rescaled by `exp(m - m_new)`.
 
-    `G` is `_READ_BLOCK_BYTES` of compute-dtype pages, and never more than
-    `B * P`: where the whole window fits one block the loops run once and this
-    is the gather-everything read. What it still costs beside the page-walk
-    kernel's single read of every live page: each block is written by its
-    gather and read back once by its reduction, and the last block of each
-    loop is read whole however few of its entries are live.
+    After the loop `acc / l` is the softmax of `dot_product_attention` in
+    another summation order: no scores leave the loop. `G` is
+    `read_block_pages`, and never more than `B * P`: where the whole window
+    fits one block the loop runs once. What it still costs beside the
+    page-walk kernel's single read of every live page: each block is written
+    by its gather and read back once by its reduction, and the last block is
+    read whole however few of its entries are live.
 
     q [B, s, Hq, D]; pools [N, page_size, Hkv, D] (Hq % Hkv == 0: a query
     head's group is its kv head, as `jnp.repeat` pairs them); pos [B, s] and
@@ -253,7 +273,7 @@ def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
     if hq % hkv != 0:
         raise ValueError(f"GQA requires query heads ({hq}) divisible by kv heads ({hkv})")
     rep = hq // hkv
-    G = max(1, min(b * P, _READ_BLOCK_BYTES // (ps * hkv * d * q.dtype.itemsize)))
+    G = read_block_pages(b * P, ps, hkv, d, q.dtype.itemsize)
     flat_len = -(-b * P // G) * G
 
     # The flat list. Entry i belongs to the slot whose run of live pages
@@ -266,15 +286,17 @@ def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
     listed = i < n
     owner = jnp.sum(i[:, None] >= end[None, :], axis=1, dtype=jnp.int32)  # [flat_len]
     slot = jnp.minimum(owner, b - 1)
-    entry = slot * P + jnp.clip(i - start[slot], 0, P - 1)  # index into [B * P]
-    page_id = jnp.where(listed, jnp.take(table.reshape(-1), entry, mode="clip"), 0)
+    page = jnp.clip(i - start[slot], 0, P - 1)  # the entry's page of its slot's window
+    page_id = jnp.where(listed, jnp.take(table.reshape(-1), slot * P + page, mode="clip"), 0)
+    # The last token of its page an entry's query j attends, [flat_len, s]:
+    # under 0 where the page lies past the query's position, or is nobody's.
+    last = jnp.where(listed[:, None], pos[slot] - (page * ps)[:, None], -1)
     blocks = (n + G - 1) // G
 
     def block_of(x, t):
         return jax.lax.dynamic_slice_in_dim(x, t * G, G, axis=0)
 
-    def read_block(pool, scale_pool, t):
-        ids = block_of(page_id, t)
+    def read_block(pool, scale_pool, ids):
         pages = jnp.take(pool, ids, axis=0, mode="clip")  # [G, ps, Hkv, D]
         if scale_pool is None:
             return pages
@@ -283,54 +305,43 @@ def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
     k_scale, v_scale = scales if scales is not None else (None, None)
     q_groups = q.reshape(b, s, hkv, rep, d)
     scale = 1.0 / np.sqrt(d)  # a numpy scalar: bf16 scores are scaled in fp32, as there
+    lowest = jnp.finfo(jnp.float32).min  # finite: a row with nothing to attend yet makes no NaN
 
-    def score_block(t, flat_scores):
-        k_block = read_block(pool_k, k_scale, t)
-        q_block = jnp.take(q_groups, block_of(slot, t), axis=0, mode="clip")
-        block = jnp.einsum("gskrd,gtkd->gskrt", q_block, k_block)
-        return jax.lax.dynamic_update_slice_in_dim(
-            flat_scores, block.reshape(G, s, hq * ps), t * G, axis=0
+    def fold_block(t, carry):
+        m, l, acc = carry  # [B, s, Hkv, rep], the same, [B, s, Hkv, rep, D]: fp32
+        ids, slots = block_of(page_id, t), block_of(slot, t)
+        k_block = read_block(pool_k, k_scale, ids)
+        v_block = read_block(pool_v, v_scale, ids)
+        q_block = jnp.take(q_groups, slots, axis=0, mode="clip")
+        scores = jnp.einsum("gskrd,gtkd->gskrt", q_block, k_block) * scale
+        attend = jnp.arange(ps) <= block_of(last, t)[:, :, None, None, None]  # [G, s, 1, 1, ps]
+        scores = jnp.where(attend, scores.astype(jnp.float32), lowest)
+        # owner B (an entry past n) is an all-false row: it reaches nobody's.
+        mine = block_of(owner, t)[:, None] == jnp.arange(b)[None, :]  # [G, B]
+        block_max = jnp.max(
+            jnp.where(mine[:, :, None, None, None], jnp.max(scores, axis=-1)[:, None], lowest),
+            axis=0,
         )
-
-    # Scores travel as [.., s, Hq * page_size]: a minor dimension of one page's
-    # tokens alone would be padded to the chip's 128 lanes, eight times over.
-    with jax.named_scope("kv_read"):
-        flat_scores = jax.lax.fori_loop(
-            0, blocks, score_block, jnp.zeros((flat_len, s, hq * ps), q.dtype)
-        )
-        # [B, P, s, Hkv, rep, ps]: a page that is not live takes some listed
-        # page's scores and loses them to the mask.
-        rows = start[:, None] + jnp.arange(P, dtype=jnp.int32)[None, :]
-        scores = jnp.take(flat_scores, rows, axis=0, mode="clip") * scale
-    scores = scores.reshape(b, P, s, hkv, rep, ps)
-    cols = jnp.arange(P)[:, None] * ps + jnp.arange(ps)[None, :]  # [P, ps]
-    attend = cols[None, :, None, :] <= pos[:, None, :, None]  # [B, P, s, ps]
-    scores = jnp.where(attend[:, :, :, None, None, :], scores, jnp.finfo(scores.dtype).min)
-    # Softmax in fp32 over the slot's window (axes P and ps), as
-    # dot_product_attention's.
-    scores = scores.astype(jnp.float32)
-    probs = jnp.exp(scores - jnp.max(scores, axis=(1, 5), keepdims=True))
-    probs = (probs / jnp.sum(probs, axis=(1, 5), keepdims=True)).astype(q.dtype)
-    flat_probs = jnp.take(probs.reshape(b * P, s, hq * ps), entry, axis=0, mode="clip")
-    flat_probs = jnp.where(listed[:, None, None], flat_probs, 0)
-
-    def value_block(t, out):
-        v_block = read_block(pool_v, v_scale, t)
+        m_new = jnp.maximum(m, block_max)
+        alpha = jnp.exp(m - m_new)
+        probs = jnp.exp(scores - jnp.take(m_new, slots, axis=0, mode="clip")[..., None])
+        probs = jnp.where(attend, probs, 0.0)
         page_out = jnp.einsum(
-            "gskrt,gtkd->gskrd", block_of(flat_probs, t).reshape(G, s, hkv, rep, ps), v_block,
+            "gskrt,gtkd->gskrd", probs.astype(q.dtype), v_block,
             preferred_element_type=jnp.float32,
         )
-        # owner B (an entry past n) is an all-zero row: it adds nothing.
-        mine = jax.nn.one_hot(block_of(owner, t), b, dtype=jnp.float32)  # [G, B]
-        return out + jnp.einsum(
-            "gb,gskrd->bskrd", mine, page_out, precision=jax.lax.Precision.HIGHEST
-        )
+        one_hot, highest = mine.astype(jnp.float32), jax.lax.Precision.HIGHEST
+        l = alpha * l + jnp.einsum("gb,gskr->bskr", one_hot, jnp.sum(probs, axis=-1), precision=highest)
+        acc = alpha[..., None] * acc + jnp.einsum("gb,gskrd->bskrd", one_hot, page_out, precision=highest)
+        return m_new, l, acc
 
+    rows = (b, s, hkv, rep)
     with jax.named_scope("kv_read"):
-        out = jax.lax.fori_loop(
-            0, blocks, value_block, jnp.zeros((b, s, hkv, rep, d), jnp.float32)
+        _, l, acc = jax.lax.fori_loop(
+            0, blocks, fold_block,
+            (jnp.full(rows, lowest), jnp.zeros(rows, jnp.float32), jnp.zeros(rows + (d,), jnp.float32)),
         )
-    return out.reshape(b, s, hq, d).astype(q.dtype)
+    return (acc / l[..., None]).reshape(b, s, hq, d).astype(q.dtype)
 
 
 def _tp_paged_attention(fn, q, pool_k, pool_v, table, positions, k_scale, v_scale, mesh):
@@ -382,15 +393,18 @@ def slot_cache_attention(
 
       - ``"xla"`` (default): `_write_slot_pool`, then
         `_live_page_attention` walks the LIVE pages of all slots in fixed
-        blocks under a trip count it computes from `positions`, so a
-        dispatch's bytes follow the live tokens: every live page is read
-        from the pool and written by a block's gather, then read back once
-        by that block's reduction, for K and again for V — three passes over
-        the live pages where the kernel below makes one, and none over the
-        rest of the window (`tests/test_tpu_compile.py` holds the compiled
-        program to it). An idle slot must sit at position 0 to count as one
-        page: the engine's `_finish` keeps it there. The engine's default,
-        and the PARITY ORACLE the kernels are pinned against.
+        blocks, in ONE loop under a trip count it computes from
+        `positions`, so a dispatch's bytes follow the live tokens: a turn
+        gathers a block of K pages and a block of V pages and folds them
+        into a running softmax kept by slot, so no scores leave the loop.
+        Every live page is read from the pool and written by its block's
+        gather, then read back once by the block's reduction — three
+        passes over the live pages where the kernel below makes one, and
+        none over the rest of the window (`tests/test_tpu_compile.py`
+        holds the compiled program to it). An idle slot must sit at
+        position 0 to count as one page: the engine's `_finish` keeps it
+        there. The engine's default, and the PARITY ORACLE the kernels are
+        pinned against.
       - ``"pallas_paged"``: the pool write plus the
         `ops/paged_attention` kernels, which walk each slot's page table
         directly and never materialize the gathered cache. Greedy decode is

@@ -3,16 +3,16 @@ speculative block-verify variant, with the page-table gather FUSED into the
 attention walk.
 
 The XLA read (`ops/attention._live_page_attention`) walks the LIVE pages of
-all slots in fixed blocks: per layer and dispatch it gathers each block of
-pool pages, then reads the block once in q·K (and again, for V, in probs·V),
-with the scores of the whole window written between its two loops — three
+all slots in fixed blocks, in one loop a layer: a turn gathers a block of K
+pages and a block of V pages from the pool, reads each back once (q·K,
+probs·V) and folds the block into a running softmax kept by slot — three
 passes over every live page, of which the v5e's compiler keeps two in fast
 memory (measured: PERF.md §5–§6). These kernels gather nothing: the grid
 walks each slot's ``page_table`` directly (the table rides as a
 SCALAR-PREFETCH operand, so the BlockSpec index maps pick which pool page to
 stream into VMEM for each grid step) and folds every page into the shared
-online-softmax accumulator (`ops/flash_common.py`), each live page read once
-and no scores buffer. Which read is faster on the chip has not been measured
+online-softmax accumulator (`ops/flash_common.py`), each live page read
+once. Which read is faster on the chip has not been measured
 (ROADMAP D13).
 
 Page-walk contract (mirrors the engine's host-side conventions, paging.py):

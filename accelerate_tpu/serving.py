@@ -108,12 +108,22 @@ from .speculative import (
 from .telemetry import MetricsRegistry
 from .telemetry.tracing import default_tracer
 from .utils.operations import (
+    _leaf_name,
     tree_gather_pages,
     tree_scatter_pages,
     tree_zero_cache_tail,
 )
 
 logger = get_logger(__name__)
+
+
+def _cached_key_leaf(cache):
+    """One layer's `cached_key` leaf of a cache tree (every layer's has the
+    same shape and dtype)."""
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        if _leaf_name(path) == "cached_key":
+            return leaf
+    raise ValueError("the cache holds no `cached_key`")
 
 
 class QueueFull(RuntimeError):
@@ -489,6 +499,17 @@ class ContinuousBatcher:
                 lambda p: prefill_module.apply(resolve(p), dummy, None, dpos, mutable=["cache"])[1]["cache"],
                 self.params,
             )
+        # What one turn of the XLA read's loop visits (`_live_page_counts`):
+        # the prefill cache is K as the model computes it — [1, length, KV
+        # heads, head_dim] in the compute dtype, the very operands the read
+        # sizes its block from.
+        from .ops.attention import read_block_pages
+
+        key = _cached_key_leaf(self._dense_cache_struct)
+        self._read_block_pages = read_block_pages(
+            self.num_slots * self.pages_per_slot, self.page_size, key.shape[2], key.shape[3],
+            np.dtype(key.dtype).itemsize,
+        )
 
         self._sample_config = GenerationConfig(do_sample=do_sample, top_k=top_k, top_p=top_p)
         # Python-side effects run at TRACE time: these count compiles, and the
@@ -1092,11 +1113,7 @@ class ContinuousBatcher:
         """Stored bytes per cached K/V VALUE in the live cache (pool leaf
         itemsize) — the honest dtype figure for HBM-traffic estimates, which
         used to be (wrongly, under quantization) read off the params dtype."""
-        for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]:
-            name = str(getattr(path[-1], "key", getattr(path[-1], "name", path[-1])))
-            if name == "cached_key":
-                return int(np.dtype(leaf.dtype).itemsize)
-        return int(np.dtype(np.float32).itemsize)
+        return int(np.dtype(_cached_key_leaf(self._cache).dtype).itemsize)
 
     @property
     def kv_cache_nbytes(self) -> int:
@@ -1728,13 +1745,17 @@ class ContinuousBatcher:
         """What the KV read is about to visit, from the host mirrors: the
         active slots' live pages (`pos // page_size + 1` each) beside the
         window's `num_slots * pages_per_slot`, for the chunk's span; their
-        share goes to the `kv_live_page_share` gauge. The device also visits
-        one scratch page an idle slot, and a slot's pages grow inside the
-        chunk: neither is counted."""
+        share goes to the `kv_live_page_share` gauge. `read_blocks` is the
+        trip count of the XLA read's loop in the chunk's first step: those
+        pages and the one scratch page the device visits an idle slot, in
+        blocks of `read_block_pages`. A slot's pages grow inside the chunk:
+        that is not counted."""
         live = int((self._pos[self._active] // self.page_size + 1).sum())
         window = self.num_slots * self.pages_per_slot
         self._m_kv_live_page_share.set(live / window)
-        return {"live_pages": live, "window_pages": window}
+        listed = live + self.num_slots - int(self._active.sum())
+        return {"live_pages": live, "window_pages": window,
+                "read_blocks": -(-listed // self._read_block_pages)}
 
     def _chunk_counts(self, host) -> Dict[str, int]:
         """What a chunk's readback counts, for its span: the tokens streamed

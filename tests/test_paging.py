@@ -245,12 +245,18 @@ def _read_layer(read, page_size, num_pages, kv_cache_dtype="bf16"):
 _POOL_DTYPES = {"int8": jnp.int8, "fp8_e4m3": jnp.float8_e4m3fn}
 
 
-def _read_case(first_positions, s=1, hq=4, hkv=2, pool="bf16", dtype=jnp.float32, seed=0):
+def _read_case(first_positions, s=1, hq=4, hkv=2, pool="bf16", dtype=jnp.float32, seed=0, peak=None,
+               peak_score=30.0):
     """Slots at `first_positions` (each row's queries sit at first..first+s-1)
     over a shuffled pool with P=6 pages of 4 tokens a slot: returns the layer
     operands, a pool cache holding random history, and the same history laid
     out contiguously. A slot's table row holds pool pages for its live pages
-    and the scratch page past them, as the engine leaves it."""
+    and the scratch page past them, as the engine leaves it.
+
+    `peak=(slot, page)`: keys eight times as large, so scores range over +-30,
+    and that page of that slot holds, for every query head of the slot's first
+    query, a key along it that scores `peak_score` — the row's maximum lies
+    where the case puts it (`_peak_page` checks)."""
     ps, P, d = 4, 6, 8
     rng = np.random.default_rng(seed)
     first = np.asarray(first_positions)
@@ -263,20 +269,45 @@ def _read_case(first_positions, s=1, hq=4, hkv=2, pool="bf16", dtype=jnp.float32
         table[row, :live] = free[row * P : row * P + live]
     spread = 1.0 if pool == "bf16" else 20.0  # quantized pages use their range
     pools = {
-        name: jnp.asarray(rng.normal(size=(num_pages, ps, hkv, d)) * spread, jnp.float32)
+        name: rng.normal(size=(num_pages, ps, hkv, d)) * spread
         for name in ("cached_key", "cached_value")
     }
-    contiguous = {name: _gathered_window(x, jnp.asarray(table)).astype(dtype) for name, x in pools.items()}
-    cache = {name: x.astype(_POOL_DTYPES.get(pool, dtype)) for name, x in pools.items()}
+    scale_pools = {}
     if pool != "bf16":
         for name in ("key_scale", "value_scale"):
-            cache[name] = jnp.asarray(rng.uniform(0.01, 0.05, size=(num_pages, hkv)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(b, s, hq, d)), dtype)
-    k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
-    v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
+            scale_pools[name] = jnp.asarray(rng.uniform(0.01, 0.05, size=(num_pages, hkv)), jnp.float32)
+    q, k, v = (rng.normal(size=(b, s, heads, d)) for heads in (hq, hkv, hkv))
+    if peak is not None:
+        row, page = peak
+        rep = hq // hkv
+        pools["cached_key"] *= 8.0
+        k *= 8.0
+        for head in range(hq):  # token `head % rep` of the page, the head's kv head
+            along = q[row, 0, head]
+            pools["cached_key"][table[row, page], head % rep, head // rep] = (
+                along * peak_score * np.sqrt(d) / (along @ along)
+            )
+    pools = {name: jnp.asarray(x, jnp.float32) for name, x in pools.items()}
+    contiguous = {name: _gathered_window(x, jnp.asarray(table)).astype(dtype) for name, x in pools.items()}
+    cache = {name: x.astype(_POOL_DTYPES.get(pool, dtype)) for name, x in pools.items()}
+    cache.update(scale_pools)
+    q, k, v = (jnp.asarray(x, dtype) for x in (q, k, v))
     positions = jnp.asarray(first[:, None] + np.arange(s)[None, :], jnp.int32)
     operands = (q, k, v, positions, jnp.asarray(table))
     return operands, cache, contiguous, (ps, num_pages)
+
+
+def _peak_page(operands, contiguous, row):
+    """The page of slot `row`'s window in which each query head of its first
+    query scores highest over the history below its position ([Hq] pages),
+    by plain numpy on the contiguous layout."""
+    q, _k, _v, positions, _table = operands
+    keys = np.asarray(contiguous["cached_key"][row], np.float32)  # [L, Hkv, d]
+    query = np.asarray(q[row, 0], np.float32)  # [Hq, d]
+    rep = query.shape[0] // keys.shape[1]
+    scores = np.einsum("hd,lhd->hl", query, np.repeat(keys, rep, axis=1))
+    scores[:, int(positions[row, 0]):] = -np.inf  # the position itself is this dispatch's k
+    return scores.argmax(axis=1) // 4
 
 
 def _run_read(read, operands, cache, geometry, pool="bf16"):
@@ -289,7 +320,7 @@ def _run_read(read, operands, cache, geometry, pool="bf16"):
 def block_pages(monkeypatch):
     """Set the live-page read's block to `pages` pages of the case's K, through
     the one constant it derives its block from (tiny test windows otherwise fit
-    one block, and the loops run once)."""
+    one block, and the loop runs once)."""
     from accelerate_tpu.ops import attention
 
     def set_block(pages, operands):
@@ -320,15 +351,45 @@ _RAGGED = (0, 23, 4, 3, 8)  # live pages 1 + 6 + 2 + 1 + 3 = 13
         pytest.param(_RAGGED, 4, {"pool": "int8"}, id="int8"),
         pytest.param(_RAGGED, 4, {"pool": "fp8_e4m3"}, id="fp8"),
         pytest.param((0, 19, 4, 3, 8), 4, {"s": 5, "pool": "int8"}, id="int8-verify5"),
+        # What only a running softmax can get wrong. One slot of 6 live pages in
+        # blocks of 2: its row's maximum (scores over +-30) is met in the first
+        # block, so every later block rescales nothing and must add little; in
+        # the middle; or in the last, so all that was summed is rescaled.
+        *(
+            pytest.param((23,), 2, {"peak": (0, page), **extra}, id=f"max-in-{where}-block{tag}")
+            for extra, tag in (({}, ""), ({"dtype": jnp.bfloat16}, "-bf16"))
+            for page, where in ((0, "first"), (3, "middle"), (5, "last"))
+        ),
+        # The same beside other owners in its blocks: [idle, long | long, long |
+        # long, long | long, one-page] — a block's maximum by owner, not by block.
+        *(
+            pytest.param((0, 23, 3), 2, {"peak": (1, page)}, id=f"max-in-{where}-block-shared")
+            for page, where in ((0, "first"), (2, "middle"), (5, "last"))
+        ),
+        pytest.param((0, 23, 3), 2, {"peak": (1, 5), "dtype": jnp.bfloat16},
+                     id="max-in-last-block-shared-bf16"),
+        # A neighbour's maximum must not be this row's: 120 above the one-page slot's
+        # scores, it would underflow every one of them and leave 0 / 0.
+        pytest.param((0, 23, 3), 2, {"peak": (1, 5), "peak_score": 120.0},
+                     id="max-by-owner-not-by-block"),
+        pytest.param((0, 19, 4, 3, 8), 1, {"s": 5}, id="one-page-blocks-verify5"),
+        # 1 + 4 = 5 entries in blocks of 4: the last block is one entry and a tail of nobody's.
+        pytest.param((0, 15), 4, {"peak": (1, 3)}, id="last-block-one-entry-then-unlisted"),
+        pytest.param((0, 14), 4, {"s": 2}, id="last-block-one-entry-then-unlisted-verify2"),
     ],
 )
 def test_live_page_read_matches_gather_everything_and_contiguous(first, block, kwargs, block_pages):
-    """The paged XLA read (blocks of live pages under a trip count from the
-    positions) == the gather-everything oracle == the dense reference, on
-    the layer's output: ragged positions, block boundaries, verify blocks,
-    GQA and MHA, quantized pools (which the dense reference does not have)."""
+    """The paged XLA read (one pass over blocks of live pages under a trip
+    count from the positions, a running softmax by owner) == the
+    gather-everything oracle == the dense reference, on the layer's output:
+    ragged positions, block boundaries, verify blocks, GQA and MHA, quantized
+    pools (which the dense reference does not have), and a row's maximum met
+    early, midway or late in its blocks."""
     operands, cache, contiguous, geometry = _read_case(first, **kwargs)
     pool = kwargs.get("pool", "bf16")
+    if "peak" in kwargs:
+        row, page = kwargs["peak"]
+        np.testing.assert_array_equal(_peak_page(operands, contiguous, row), page)
     if block is not None:
         block_pages(block, operands)
     live = _run_read("live", operands, cache, geometry, pool)

@@ -141,6 +141,57 @@ def test_released_slot_sits_at_position_zero_through_reuse():
     assert all(0 < c["attrs"]["live_pages"] <= c["attrs"]["window_pages"] for c in chunks)
 
 
+def test_chunk_span_read_blocks_is_the_reads_trip_count(monkeypatch):
+    """`serve.decode_chunk.read_blocks` is the trip count the XLA read's loop
+    computes on the device in the chunk's first step — `ceil(n / G)`, `n` the
+    live pages of ALL slots (an idle one is one page) from the positions the
+    chunk is dispatched with — because the engine asks the read's own helper
+    for `G`, with the operands' numbers the read hands it at trace time."""
+    from accelerate_tpu.ops import attention
+    from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
+    from accelerate_tpu.telemetry.tracing import Tracer
+
+    model = _model()
+    cfg = model.module.config
+    kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+    # blocks of 2 pages of 8 tokens (float32 K): several turns a read
+    monkeypatch.setattr(attention, "_READ_BLOCK_BYTES", 2 * 8 * kv_heads * cfg.head_dim * 4)
+    asked = []
+    helper = attention.read_block_pages
+
+    def recording(*args):
+        asked.append((args, helper(*args)))
+        return asked[-1][1]
+
+    monkeypatch.setattr(attention, "read_block_pages", recording)
+    recorder = FlightRecorder()
+    engine = ContinuousBatcher(
+        model, num_slots=4, max_length=32, chunk_size=2, page_size=8,
+        tracer=Tracer(recorder=recorder, category="serve"),
+    )
+    rng = np.random.default_rng(5)
+    for i, n in enumerate((20, 7, 15)):  # 3 + 1 + 2 live pages, and one idle slot's one
+        engine.submit(Request(i, rng.integers(1, 128, (n,)).astype(np.int32), max_new_tokens=12))
+    pushed = []
+    push = engine._chunk_operands
+    monkeypatch.setattr(
+        engine, "_chunk_operands", lambda: (pushed.append(engine._pos.copy()), push())[1]
+    )
+    engine.step()
+    engine.step()
+    # the engine's call (at construction) and the read's (as the chunk is traced, a layer each)
+    assert len(asked) > 1 and len(set(asked)) == 1, asked
+    (_, block_pages), = set(asked)
+    assert block_pages == 2
+    chunks = [r for r in recorder.records() if r["name"] == "serve.decode_chunk"]
+    assert len(chunks) == len(pushed) == 2
+    for chunk, pos in zip(chunks, pushed):
+        n = int((pos // engine.page_size + 1).sum())  # as the read counts: every slot
+        assert chunk["attrs"]["read_blocks"] == -(-n // block_pages)
+    # 7 pages; two tokens on, two slots have crossed into a new page: 3 + 2 + 3 + 1
+    assert [c["attrs"]["read_blocks"] for c in chunks] == [4, 5]
+
+
 def test_greedy_parity_gpt_neox_family():
     """The slot-cache decode path is model-layer plumbing (llama AND gpt_neox
     gained the per-row cache write): pin parity on the second family too."""

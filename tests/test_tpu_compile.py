@@ -116,12 +116,15 @@ _HLO_RESULT = re.compile(r"^\s*(?:ROOT )?%\S+ = ([a-z]+[0-9]+\w*)\[([0-9,]+)\]\S
 def test_xla_paged_read_walks_blocks_not_the_window(v5e, s_block, pool):
     """`slot_cache_attention(attention_impl="xla")` — `_write_slot_pool`'s
     scatter, then `_live_page_attention` — as the v5e's compiler leaves it:
-    two `while`s whose bodies gather one BLOCK of pool pages
-    ([256, 16, 16, 128], 16.8 MB in bf16) and reduce it. Nothing has the size
-    of the window ([32, 88, 16, 16, 128], 185 MB a tensor in bf16, which the
-    read gathered whole, K and V, until PR 28), the pool is copied nowhere on
-    its way into the loops, and the program's temporaries are not even one
-    block (PERF.md §6, PR 28). A compile, not a timing."""
+    ONE `while` whose body gathers a BLOCK of K pages and a block of V pages
+    ([128, 16, 16, 128], 8.4 MB each in bf16) and folds them into a running
+    softmax. Nothing has the size of the window ([32, 88, 16, 16, 128], 185 MB
+    a tensor in bf16, which the read gathered whole, K and V, until PR 28),
+    the pool is copied nowhere on its way into the loop, no list of scores
+    exists outside it (until PR 30 a first loop wrote one of `flat_len` pages'
+    scores for a window-shaped softmax and a second loop read it back), and
+    the program's temporaries are not even one block (PERF.md §6, PR 28 and
+    PR 30). A compile, not a timing."""
     import flax.linen as nn
 
     from accelerate_tpu.ops import attention
@@ -152,41 +155,69 @@ def test_xla_paged_read_walks_blocks_not_the_window(v5e, s_block, pool):
         return out, mutated["cache"]
 
     compiled = jax.jit(step, donate_argnums=0).lower(cache, *operands).compile()
-    block_pages = attention._READ_BLOCK_BYTES // (CELL_PAGE * 2)  # 256 pages: 16 MiB of bf16
+    text = compiled.as_text()
+    window_pages = CELL_SLOTS * CELL_PAGES_PER_SLOT
+    block_pages = attention.read_block_pages(  # 128 pages: 8 MiB of bf16
+        window_pages, CELL_PAGE_SIZE, CELL_HEADS, CELL_D, 2
+    )
     pool_shape = f"[{num_pages},{CELL_PAGE_SIZE},{CELL_HEADS},{CELL_D}]"
+
+    def results(lines):
+        for line in lines:
+            m = _HLO_RESULT.match(line)
+            if m:
+                yield (line.strip(), math.prod(int(n) for n in m.group(2).split(",")),
+                       f"[{m.group(2)}]" == pool_shape, m.group(3))
+
     window_sized, pool_copies, block_gathers = [], [], 0
-    for line in compiled.as_text().splitlines():
-        m = _HLO_RESULT.match(line)
-        if not m:
-            continue
-        elements = math.prod(int(n) for n in m.group(2).split(","))
-        is_pool = f"[{m.group(2)}]" == pool_shape
-        if is_pool and m.group(3).startswith("copy"):
-            pool_copies.append(line.strip()[:160])
+    for line, elements, is_pool, op in results(text.splitlines()):
+        if is_pool and op.startswith("copy"):
+            pool_copies.append(line[:160])
         # The pool itself is an operand of its in-place scatter, of each block's
-        # gather and of the loops' tuples; anything else of a quarter of the
+        # gather and of the loop's tuple; anything else of a quarter of the
         # window or more is the window coming back.
         if not is_pool and elements >= CELL_WINDOW // 4:
-            window_sized.append(line.strip()[:160])
+            window_sized.append(line[:160])
         block_gathers += elements == block_pages * CELL_PAGE and "/while/body/" in line \
             and "gather" in line
     assert not window_sized, window_sized
     assert not pool_copies, pool_copies
-    assert compiled.as_text().count(" while(") == 2
-    assert block_gathers >= 2  # one of K in the first loop, one of V in the second
+    assert text.count(" while(") == 1
+    assert block_gathers >= 2  # a block of K and a block of V, in the one body
+    # What the program materializes outside the loop (the entry computation's
+    # own instructions; fused computations and the loop's body are others).
+    # The list of scores was flat_len x s x Hq x page_size elements, and the
+    # window-shaped scores and probabilities as many: nothing of that size is
+    # left but the pool, and the pages a quantized write gathers to requantize
+    # (`kv_write`: slots x s pages, more elements than a decode's scores).
+    entry = text[text.index("\nENTRY "):]
+    flat_len = -(-window_pages // block_pages) * block_pages
+    scores = flat_len * s_block * CELL_HEADS * CELL_PAGE_SIZE
+    scores_sized = [
+        line[:160] for line, elements, is_pool, op in results(entry[: entry.index("\n}")].splitlines())
+        if elements >= scores and not is_pool and op != "bitcast" and "/kv_write/" not in line
+    ]
+    assert not scores_sized, scores_sized
     # Loop-body temporaries: under two blocks of bf16 pages (PR 25's read kept
-    # 184.6 MB, one window). Found: 1.2-2.7 MB — the compiler keeps the blocks
-    # and the scores in the chip's fast memory (`S(1)` in the layouts).
+    # 184.6 MB, one window). Found: 1.5-2.2 MB — the compiler keeps both
+    # gathered blocks of a turn, in the pool's dtype, in the chip's fast memory
+    # (`S(1)` in their layouts), which is why a page is read from HBM once.
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * block_pages * CELL_PAGE * 2
-    # `bytes accessed` counts each loop body ONCE, so it is bytes a loop turn,
-    # not a dispatch. bf16 decode: 0.238 GB with blocks of 256 pages and 0.180 GB
-    # with blocks of 128, so a turn of both loops is 0.116 GB (three passes over
-    # a block of K and one of V) and the rest 0.122 GB (the analysis counts the
-    # in-place scatters as passes over their pools): at the 870 live pages of
-    # 2,816 the saturated cell holds, 4 turns, 0.586 GB a layer against the
-    # 1.156 GB of PR 25's read. int8 decode 0.236 GB (1.417), bf16 verify5 0.745
-    # (1.208), int8 verify5 0.974 (2.442): all the dequantize reads is a block.
-    bound = {("bf16", 1): 0.26e9, ("int8", 1): 0.26e9, ("bf16", 5): 0.82e9, ("int8", 5): 1.07e9}
+    stored = "s8" if pool == "int8" else "bf16"
+    in_fast_memory = re.findall(
+        rf"= {stored}\[{block_pages},{CELL_PAGE_SIZE},{CELL_HEADS},{CELL_D}\]\{{[^}}]*S\(1\)\}} fusion\(", text
+    )
+    assert len(in_fast_memory) >= 2, in_fast_memory
+    # `bytes accessed` counts the loop body ONCE, so it is bytes a loop turn,
+    # not a dispatch. bf16 decode: 0.0753 GB with blocks of 128 pages and
+    # 0.1340 GB with blocks of 256, so a turn is 0.0587 GB (the pool's pages
+    # read, the gathered block written and read back, for K and for V: six
+    # passes over 8.4 MB and the turn's small operands) and the rest 0.0166 GB:
+    # at the 870 live pages of 2,816 the saturated cell holds, 7 turns, 0.428
+    # GB a layer against the 0.586 GB of PR 28's two loops and the 1.156 GB of
+    # PR 25's read. int8 decode 0.0995 GB (0.236 at PR 28), bf16 verify5 0.163
+    # (0.745), int8 verify5 0.372 (0.974): the scores lists were most of those.
+    bound = {("bf16", 1): 0.082e9, ("int8", 1): 0.108e9, ("bf16", 5): 0.176e9, ("int8", 5): 0.40e9}
     assert compiled.cost_analysis()["bytes accessed"] <= bound[(pool, s_block)]
 
 
