@@ -28,6 +28,13 @@ from .gpt_neox import (
     gpt_neox_20b,
     gpt_neox_tiny,
 )
+from .latent_moe import (
+    LatentMoEConfig,
+    LatentMoEForCausalLM,
+    create_latent_moe_model,
+    kimi_vl_a3b_text,
+    latent_moe_tiny,
+)
 from .opt import OPTConfig, OPTForCausalLM, create_opt_model, opt_30b, opt_tiny
 from .t5 import (
     T5Config,
@@ -54,6 +61,8 @@ MODEL_REGISTRY = {
     "gptj-tiny": ("gptj", gptj_tiny),
     "gpt-neox-20b": ("gpt_neox", gpt_neox_20b),
     "gpt-neox-tiny": ("gpt_neox", gpt_neox_tiny),
+    "kimi-vl-a3b-text": ("latent_moe", kimi_vl_a3b_text),
+    "latent-moe-tiny": ("latent_moe", latent_moe_tiny),
     "opt-30b": ("opt", opt_30b),
     "opt-tiny": ("opt", opt_tiny),
     "t0pp-11b": ("t5", t0pp_11b),
@@ -71,6 +80,7 @@ CREATE_BY_FAMILY = {
     "gpt_neox": create_gpt_neox_model,
     "opt": create_opt_model,
     "t5": create_t5_model,
+    "latent_moe": create_latent_moe_model,
 }
 
 # family -> (flax module class name, LayeredApply class) for models shipping a
@@ -223,6 +233,28 @@ def _mixtral_cfg(c: MixtralConfig) -> dict:
     }
 
 
+def _latent_moe_cfg(c: LatentMoEConfig) -> dict:
+    return {
+        "model_type": "latent_moe",
+        "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size,
+        "num_hidden_layers": c.num_hidden_layers,
+        "num_attention_heads": c.num_attention_heads,
+        "intermediate_size": c.intermediate_size,
+        "moe_intermediate_size": c.moe_intermediate_size,
+        "n_routed_experts": c.n_routed_experts,
+        "n_shared_experts": c.n_shared_experts,
+        "num_experts_per_tok": c.num_experts_per_tok,
+        "first_k_dense_replace": c.first_k_dense_replace,
+        "kv_lora_rank": c.kv_lora_rank,
+        "qk_nope_head_dim": c.qk_nope_head_dim,
+        "qk_rope_head_dim": c.qk_rope_head_dim,
+        "v_head_dim": c.v_head_dim,
+        "hidden_act": "silu",
+        "tie_word_embeddings": False,
+    }
+
+
 def _bert_cfg(c: BertConfig) -> dict:
     return {
         "model_type": "bert",
@@ -258,6 +290,7 @@ _CFG_BUILDERS = {
     "gpt_neox": _gpt_neox_cfg,
     "opt": _opt_cfg,
     "t5": _t5_cfg,
+    "latent_moe": _latent_moe_cfg,
 }
 
 

@@ -1,0 +1,325 @@
+"""Latent-attention, sparse-expert decoder: the DeepSeek-V3 layer as Kimi-VL-A3B's
+language model publishes it (`text_config` of
+https://huggingface.co/moonshotai/Kimi-VL-A3B-Instruct/blob/main/config.json).
+Text only: the vision tower and its projector are not here.
+
+Attention is multi-head LATENT attention (MLA, `q_lora_rank` null): queries are
+full heads of `qk_nope_head_dim + qk_rope_head_dim`; keys and values are
+decompressed from ONE row a token, `[RMSNorm(c) | RoPE(k_pe)]` of
+`kv_lora_rank + qk_rope_head_dim` values, and that row (zero-padded to whole
+128-lane tiles, `LatentMoEConfig.decode_kv_row_values`) is all the cache
+holds. Two forms of the same mathematics:
+
+  - decompressed (training, prefill, the dense decode cache): `[k_nope | v] =
+    c . W_kvb` for every cached row, `k = [k_nope | k_pe]` with `k_pe` shared
+    by all heads, ordinary attention at scale `1 / sqrt(qk_head_dim)`;
+  - absorbed (slot decode and verify blocks against the page pool): `W_kvb`'s
+    key half moves onto the query, `q_abs = q_nope . W_kvb^K[h]^T`, so the
+    scores are `[q_abs | q_pe] . row` and the output `probs . c` is lifted by
+    `W_kvb`'s value half afterwards — the pool is read as it lies, one gather a
+    block (`ops.attention.slot_cache_attention` with `v=None`).
+
+Layer 0 (`first_k_dense_replace`) is a dense SwiGLU; every later layer is
+`parallel.expert.dropless_expert_ffn` over `n_routed_experts` sigmoid-routed
+experts beside a shared expert of `n_shared_experts` times their width.
+
+Departures from the published code: RoPE pairs dimension i with i + d/2
+(half-split) where the published code pairs 2i with 2i + 1 — the same function
+up to a fixed permutation of the rope columns of `wq` and `wkv_a`.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..modeling import Model
+from ..ops.attention import dot_product_attention, slot_cache_attention, update_decode_cache
+from ..ops.quantization import dequantize_weight_int8, is_quantized_kernel
+from ..ops.remat import maybe_remat
+from ..parallel.expert import EXPERT_SHARDING_RULES, dropless_expert_ffn, sigmoid_top_k_routing
+from ..parallel.sharding import constrain_activation
+from .llama import RMSNorm, causal_lm_loss, rotary_embedding
+
+LATENT_MOE_SHARDING_RULES = [
+    (r"(wq|wkv_b)/kernel", (None, "model")),
+    (r"wo/kernel", ("model", None)),
+    (r"(mlp|shared)/(w_gate|w_up)/kernel", (None, "model")),
+    (r"(mlp|shared)/w_down/kernel", ("model", None)),
+    (r"embed_tokens/embedding", ("model", None)),
+    (r"lm_head/kernel", (None, "model")),
+    (r"(wkv_a|router)/kernel", ()),  # the latent row and the router are whole on every device
+] + EXPERT_SHARDING_RULES
+
+
+@dataclass
+class LatentMoEConfig:
+    """Keys as the published config names them; defaults are Kimi-VL-A3B's."""
+
+    vocab_size: int = 163840
+    hidden_size: int = 2048
+    intermediate_size: int = 11264  # the dense layers' SwiGLU width
+    moe_intermediate_size: int = 1408  # one routed expert's width
+    num_hidden_layers: int = 27
+    num_attention_heads: int = 16
+    n_shared_experts: int = 2
+    n_routed_experts: int = 64
+    num_experts_per_tok: int = 6
+    routed_scaling_factor: float = 2.446
+    norm_topk_prob: bool = True
+    first_k_dense_replace: int = 1
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    max_position_embeddings: int = 131072
+    rope_theta: float = 800000.0
+    rms_norm_eps: float = 1e-5
+    # Serving (see LlamaConfig for the semantics of each): the dense decode
+    # cache, the slot cache's page pool, its read, int8 weights. There is no
+    # `decode_kv_cache_dtype` and no `decode_tp_mesh`: a quantized pool and a
+    # tensor-parallel split of latent rows are not built, and the engine's
+    # admission says so.
+    decode_cache_length: int = 0
+    decode_slot_cache: bool = False
+    decode_page_size: int = 0
+    decode_num_pages: int = 0
+    decode_attention_impl: str = "xla"
+    weight_dtype: str = "bf16"
+    param_dtype: str = "float32"
+
+    def __post_init__(self):
+        if self.decode_slot_cache and self.decode_page_size < 1:
+            raise ValueError(
+                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
+                "cache is a page pool"
+            )
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def decode_kv_row_values(self) -> int:
+        """Values the cache holds a token a layer: the latent row `[c | k_pe]`,
+        zero-padded to whole 128-lane tiles (576 -> 640). A TPU array's minor
+        axis is laid out in tiles of 128: at 576 the compiler either pads it to
+        640 itself or, to save that, lays the pool out page-minor and copies it
+        whole (2.7 GB) in every program that gathers pages. The padding is made
+        here, where it is counted. Its presence is how the engine knows the
+        cache is latent."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def full_head_kv_values(self) -> int:
+        """What a cache of decompressed keys and values would hold instead."""
+        return self.num_attention_heads * (self.qk_head_dim + self.v_head_dim)
+
+    @property
+    def num_moe_layers(self) -> int:
+        return max(self.num_hidden_layers - self.first_k_dense_replace, 0)
+
+    @property
+    def _pdtype(self):
+        return jnp.dtype(self.param_dtype)
+
+
+class Kernel(nn.Module):
+    """A weight the family multiplies by hand (an absorbed projection, a stack
+    of expert matrices), stored as `<name>/kernel` like a `Dense`'s so that the
+    engine's int8 weights (`quantize_params_int8`) find it; a quantized entry
+    is dequantized where it is used."""
+
+    shape: Tuple[int, ...]
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, dtype):
+        if self.has_variable("params", "kernel"):
+            stored = self.get_variable("params", "kernel")
+            if is_quantized_kernel(stored):
+                return dequantize_weight_int8(stored, dtype)
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1)
+        return self.param("kernel", init, self.shape, self.param_dtype).astype(dtype)
+
+
+def _dense(features: int, cfg: LatentMoEConfig, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, param_dtype=cfg._pdtype, name=name)
+
+
+class LatentAttention(nn.Module):
+    config: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, hidden, positions, mask):
+        cfg = self.config
+        b, s, _ = hidden.shape
+        heads, nope, rope = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+        scale = 1.0 / np.sqrt(cfg.qk_head_dim)
+
+        q = _dense(heads * cfg.qk_head_dim, cfg, "wq")(hidden).reshape(b, s, heads, cfg.qk_head_dim)
+        q_nope, q_pe = q[..., :nope], rotary_embedding(q[..., nope:], positions, cfg.rope_theta)
+        row = _dense(rank + rope, cfg, "wkv_a")(hidden)
+        c = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(row[..., :rank])
+        k_pe = rotary_embedding(row[..., None, rank:], positions, cfg.rope_theta)[:, :, 0]  # one for all heads
+        pad = cfg.decode_kv_row_values - rank - rope  # zeros that make the row whole 128-lane tiles
+        row = jnp.concatenate([c, k_pe, jnp.zeros((b, s, pad), c.dtype)], axis=-1)  # what the cache holds
+        w_kvb = Kernel((rank, heads * (nope + vd)), cfg._pdtype, name="wkv_b")(hidden.dtype)
+        w_kvb = w_kvb.reshape(rank, heads, nope + vd)
+
+        if cfg.decode_cache_length and cfg.decode_slot_cache:
+            with jax.named_scope("mla_absorb"):
+                q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :nope])
+                q_row = jnp.concatenate([q_abs, q_pe, jnp.zeros((b, s, heads, pad), q.dtype)], axis=-1)
+            with jax.named_scope("latent_read"):
+                out_latent = slot_cache_attention(
+                    self, q_row, row, None, cfg.decode_cache_length, positions,
+                    page_table=mask, page_size=cfg.decode_page_size,
+                    num_pages=cfg.decode_num_pages, attention_impl=cfg.decode_attention_impl,
+                    scale=scale, value_dim=rank,
+                )
+            with jax.named_scope("mla_absorb"):
+                out = jnp.einsum("bshr,rhv->bshv", out_latent, w_kvb[..., nope:])
+        else:
+            decode_mask = mask
+            if cfg.decode_cache_length:
+                row, _, decode_mask = update_decode_cache(self, row, None, cfg.decode_cache_length, pad_mask=mask)
+            kv = jnp.einsum("btr,rhn->bthn", row[..., :rank], w_kvb)
+            t = row.shape[1]
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(row[:, :, None, rank:rank + rope], (b, t, heads, rope))], axis=-1)
+            q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            # "xla": the flash kernel takes one head size for keys and values
+            out = dot_product_attention(q, k, kv[..., nope:], mask=decode_mask, scale=scale,
+                                        causal=not cfg.decode_cache_length, implementation="xla")
+        return _dense(cfg.hidden_size, cfg, "wo")(out.reshape(b, s, heads * vd))
+
+
+class SwiGLU(nn.Module):
+    config: LatentMoEConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, hidden):
+        cfg = self.config
+        gate = _dense(self.width, cfg, "w_gate")(hidden)
+        up = _dense(self.width, cfg, "w_up")(hidden)
+        return _dense(cfg.hidden_size, cfg, "w_down")(nn.silu(gate) * up)
+
+
+class RoutedExperts(nn.Module):
+    """The routed experts' three stacks of matrices, `[E, in, out]` each."""
+
+    config: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, x, ids, weights):
+        cfg = self.config
+        E, h, F = cfg.n_routed_experts, cfg.hidden_size, cfg.moe_intermediate_size
+        stack = lambda name, shape: Kernel(shape, cfg._pdtype, name=name)(x.dtype)  # noqa: E731
+        return dropless_expert_ffn(
+            x, ids, weights, stack("w_gate", (E, h, F)), stack("w_up", (E, h, F)), stack("w_down", (E, F, h)))
+
+
+class DroplessMoE(nn.Module):
+    """Router, routed experts and the shared expert of one layer:
+    `y = sum_e w_e E_e(h) + S(h)`. Slot-decode modules keep a count in the
+    cache collection, `expert_tokens` ([2, E] int32: the tokens each expert
+    was given, and the dispatches in which it was given any), which the engine
+    zeroes before a chunk and reads back with it."""
+
+    config: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, hidden):
+        cfg = self.config
+        b, s, h = hidden.shape
+        E, F = cfg.n_routed_experts, cfg.moe_intermediate_size
+        x = hidden.reshape(b * s, h)
+        with jax.named_scope("moe_route"):
+            w_router = Kernel((h, E), cfg._pdtype, name="router")(jnp.float32)
+            logits = jnp.dot(x.astype(jnp.float32), w_router, precision=jax.lax.Precision.HIGHEST)
+            bias = self.param("router_bias", nn.initializers.zeros, (E,), jnp.float32)
+            ids, weights = sigmoid_top_k_routing(
+                logits, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor, cfg.norm_topk_prob)
+        routed, counts = RoutedExperts(cfg, name="experts")(x, ids, weights)
+        if cfg.decode_slot_cache:
+            seen = self.variable("cache", "expert_tokens", jnp.zeros, (2, E), jnp.int32)
+            seen.value = seen.value + jnp.stack([counts, (counts > 0).astype(jnp.int32)])
+        with jax.named_scope("moe_shared"):
+            shared = SwiGLU(cfg, cfg.n_shared_experts * F, name="shared")(hidden)
+        return routed.reshape(b, s, h) + shared
+
+
+class LatentMoELayer(nn.Module):
+    config: LatentMoEConfig
+    dense: bool
+
+    @nn.compact
+    def __call__(self, hidden, positions, mask):
+        cfg = self.config
+        attn = LatentAttention(cfg, name="attention")(
+            RMSNorm(cfg.rms_norm_eps, name="input_norm")(hidden), positions, mask)
+        hidden = constrain_activation(hidden + attn)
+        normed = RMSNorm(cfg.rms_norm_eps, name="post_attn_norm")(hidden)
+        if self.dense:
+            ffn = SwiGLU(cfg, cfg.intermediate_size, name="mlp")(normed)
+        else:
+            ffn = DroplessMoE(cfg, name="moe")(normed)
+        return constrain_activation(hidden + ffn)
+
+
+class LatentMoEForCausalLM(nn.Module):
+    config: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, input_ids, attention_mask=None, positions=None):
+        cfg = self.config
+        b, s = input_ids.shape
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        hidden = constrain_activation(
+            nn.Embed(cfg.vocab_size, cfg.hidden_size, param_dtype=cfg._pdtype, name="embed_tokens")(input_ids)
+        )
+        Layer = maybe_remat(LatentMoELayer)
+        for i in range(cfg.num_hidden_layers):
+            hidden = Layer(cfg, i < cfg.first_k_dense_replace, name=f"layer_{i}")(
+                hidden, positions, attention_mask)
+        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(hidden)
+        return _dense(cfg.vocab_size, cfg, "lm_head")(hidden)
+
+
+def create_latent_moe_model(
+    config: Optional[LatentMoEConfig] = None, rng=None, seq_len: int = 2048, param_dtype=None
+) -> Model:
+    config = config or latent_moe_tiny()
+    if param_dtype is not None:
+        config = dataclasses.replace(config, param_dtype=str(jnp.dtype(param_dtype)))
+    if rng is None:
+        rng = jax.random.key(0)
+    module = LatentMoEForCausalLM(config)
+    sample = jnp.zeros((1, min(seq_len, config.max_position_embeddings, 128)), dtype=jnp.int32)
+    params = jax.jit(module.init)(rng, sample)
+    return Model.from_flax(module, params, loss_fn=causal_lm_loss, sharding_rules=LATENT_MOE_SHARDING_RULES)
+
+
+def kimi_vl_a3b_text() -> LatentMoEConfig:
+    """Kimi-VL-A3B-Instruct's language model as published: 27 layers, 15.96 B
+    parameters (31.9 GB in bfloat16 — more than one v5e chip holds)."""
+    return LatentMoEConfig()
+
+
+def latent_moe_tiny() -> LatentMoEConfig:
+    return LatentMoEConfig(
+        vocab_size=512, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=3, num_attention_heads=4, n_shared_experts=2, n_routed_experts=8,
+        num_experts_per_tok=3, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, max_position_embeddings=256, rope_theta=10000.0,
+    )

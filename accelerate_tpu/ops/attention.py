@@ -59,6 +59,11 @@ def update_decode_cache(module, k, v, cache_length: int, pad_mask=None):
     every LATER decode step keeps masking them without re-threading the mask —
     ragged prompts batch-generate like HF's left-pad convention.
 
+    `v=None` is a cache of ONE row a token (a latent family: `k` [B, s, row]
+    is the row its keys and its values are both computed from, with no head
+    axis): it is kept as `cached_latent` [B, L, row], no `cached_value`
+    exists, and `v_full` comes back None.
+
     Call from inside the attention module's `__call__` (needs `module.variable`).
     Returns `(k_full, v_full, decode_mask)` — feed to
     `dot_product_attention(..., mask=decode_mask, causal=False)`.
@@ -66,14 +71,18 @@ def update_decode_cache(module, k, v, cache_length: int, pad_mask=None):
     import jax
     import jax.numpy as jnp
 
-    b, s, h, d = k.shape
+    b, s = k.shape[:2]
     L = cache_length
-    cached_k = module.variable("cache", "cached_key", jnp.zeros, (b, L, h, d), k.dtype)
-    cached_v = module.variable("cache", "cached_value", jnp.zeros, (b, L, h, d), v.dtype)
+    cached_k = module.variable(
+        "cache", "cached_key" if v is not None else "cached_latent", jnp.zeros, (b, L) + k.shape[2:], k.dtype)
     cache_index = module.variable("cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
     cur = cache_index.value
-    cached_k.value = jax.lax.dynamic_update_slice(cached_k.value, k, (0, cur, 0, 0))
-    cached_v.value = jax.lax.dynamic_update_slice(cached_v.value, v, (0, cur, 0, 0))
+    cached_k.value = jax.lax.dynamic_update_slice(cached_k.value, k, (0, cur) + (0,) * (k.ndim - 2))
+    v_full = None
+    if v is not None:
+        cached_v = module.variable("cache", "cached_value", jnp.zeros, (b, L) + v.shape[2:], v.dtype)
+        cached_v.value = jax.lax.dynamic_update_slice(cached_v.value, v, (0, cur, 0, 0))
+        v_full = cached_v.value
     cache_index.value = cur + s
     # causal over absolute positions: query row i (absolute cur+i) sees cache
     # slots j <= cur+i and only written slots (j < cur+s).
@@ -100,7 +109,7 @@ def update_decode_cache(module, k, v, cache_length: int, pad_mask=None):
         valid = module.get_variable("cache", "pad_mask")
     if valid is not None:
         decode_mask = decode_mask & valid[:, None, None, :]
-    return cached_k.value, cached_v.value, decode_mask
+    return cached_k.value, v_full, decode_mask
 
 
 def _check_slot_positions(positions, b: int, s: int):
@@ -159,7 +168,15 @@ def _write_slot_pool(
     `key_scale`/`value_scale` pool arrays ([num_pages, h] f32, same cache
     collection — traced operands, never Python scalars), maintained by
     `ops.quantization.quantized_pool_write` (offset-0 scale reset, scatter-max
-    growth, in-dispatch requant of touched pages)."""
+    growth, in-dispatch requant of touched pages).
+
+    LATENT pool (`v=None`): the payload is ONE row a token, `k` [B, s, row]
+    (MLA's `[c | k_pe]`), which keys and values are both read out of, so the
+    collection holds one pool, `cached_latent` [num_pages, page_size, row] —
+    no head axis: a size-1 axis next to the row makes the TPU lay the pool out
+    page-minor and copy it whole in every program that touches it — and the
+    value pool comes back None. Its rows have no heads to scale by: a
+    quantized latent pool is refused."""
     import jax
     import jax.numpy as jnp
 
@@ -170,22 +187,34 @@ def _write_slot_pool(
             "the slot cache is a page pool: it needs a [B, pages_per_slot] "
             "page_table operand and page_size >= 1"
         )
-    b, s, h, d = k.shape
+    b, s = k.shape[:2]
     pages_per_slot = page_table.shape[-1]
     L = pages_per_slot * page_size
     spec = kv_quant_spec(kv_cache_dtype)
+    if v is None and spec is not None:
+        raise ValueError(
+            f"kv_cache_dtype={kv_cache_dtype!r} on a latent cache: the quantized pool "
+            "scales a page by KV head, and a latent row has none — a quantized pool "
+            "for latent rows is not built"
+        )
     pool_dtype = k.dtype if spec is None else spec[0]
     pool_k = module.variable(
-        "cache", "cached_key", jnp.zeros, (num_pages, page_size, h, d), pool_dtype
-    )
-    pool_v = module.variable(
-        "cache", "cached_value", jnp.zeros, (num_pages, page_size, h, d), pool_dtype
+        "cache", "cached_key" if v is not None else "cached_latent", jnp.zeros,
+        (num_pages, page_size) + k.shape[2:], pool_dtype,
     )
     pos = jnp.clip(positions, 0, L - 1).astype(jnp.int32)  # [B, s]
     table = jnp.asarray(page_table, jnp.int32)
     page_slot = jnp.clip(pos // page_size, 0, pages_per_slot - 1)
     pid = jnp.take_along_axis(table, page_slot, axis=1)  # [B, s]
     off = pos % page_size
+    if v is None:
+        with jax.named_scope("kv_write"):
+            pool_k.value = pool_k.value.at[pid, off].set(k)
+        return pool_k.value, None, pos, table, None
+    h = k.shape[2]
+    pool_v = module.variable(
+        "cache", "cached_value", jnp.zeros, (num_pages, page_size) + k.shape[2:], pool_dtype
+    )
     if spec is None:
         with jax.named_scope("kv_write"):
             pool_k.value = pool_k.value.at[pid, off].set(k)
@@ -224,7 +253,54 @@ def read_block_pages(window_pages: int, page_size: int, kv_heads: int, head_dim:
     return max(1, min(window_pages, _READ_BLOCK_BYTES // (page_size * kv_heads * head_dim * itemsize)))
 
 
-def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
+#: Tokens of ONE slot that an entry of `_live_page_attention`'s list covers where
+#: many query heads share a KV head (`read_run_pages`): a matrix unit's width.
+_READ_RUN_TOKENS = 128
+#: Query heads a KV head from which an entry is a run: the measured rule. One
+#: layer, 32 slots x 88 pages of 16 tokens, heads of 128, device us with 870
+#: pages live / every page live, an entry a page -> a run of 8 (PERF.md §6, PR
+#: 31): 1 query head a KV head (16 KV heads) 385 -> 454 / 1,094 -> 1,159; 2
+#: (32 over 16) 495 -> 354 / 1,433 -> 884; 4 (32 over 8) 326 -> 204 / 800 ->
+#: 468; 8 (32 over 4) 288 -> 216 / 745 -> 528; 16 (32 over 2) 319 -> 217 / 840
+#: -> 534; a latent row read by 16 heads, 6,000 live pages of 16,384, 2,040 ->
+#: 801. Multi-head attention keeps one page an entry; every grouped-query
+#: shape and the latent row take runs.
+_READ_RUN_MIN_GROUP = 2
+
+
+def read_run_pages(page_size: int, group: int) -> int:
+    """`R`, the consecutive pages of one slot that make ONE entry of
+    `_live_page_attention`'s list, from the query heads a KV head (`group`).
+    With one query head a KV head an entry's products are a row times a page
+    and an entry is a page: `R = 1`. Where several heads read the same keys
+    (grouped-query attention; a latent row serves all 16) they are matrix
+    products, [group, D] x [D, tokens], and a page of 16 tokens fills an
+    eighth of a 128-wide matrix unit's columns while every page pays for the
+    whole pass: an entry is then a run of `_READ_RUN_TOKENS` tokens of one
+    slot, so that the products have that width. The price is the slot's last
+    run, gathered whole however few of its pages are live (the rest point at
+    the scratch page) — which is what one head a KV head does not earn back
+    (`_READ_RUN_MIN_GROUP`)."""
+    if group < _READ_RUN_MIN_GROUP:
+        return 1
+    return max(1, _READ_RUN_TOKENS // page_size)
+
+
+def read_blocks(top_positions, pages_per_slot: int, page_size: int, kv_heads: int, head_dim: int,
+                itemsize: int, group: int) -> int:
+    """The trip count of `_live_page_attention`'s loop, on the host, for slots
+    whose queries attend up to `top_positions` (one a slot of the dispatch; an
+    idle slot sits at 0 and is one entry): the entries the read lists — a
+    page, or a run of `read_run_pages` — in blocks of `read_block_pages`,
+    from the numbers the read itself takes off its operands. The engine says
+    it on `serve.decode_chunk` as `read_blocks` without knowing either rule."""
+    top = np.asarray(top_positions)
+    run = read_run_pages(page_size, group)
+    block = max(1, read_block_pages(top.size * pages_per_slot, page_size, kv_heads, head_dim, itemsize) // run)
+    return -(-int((top // (run * page_size) + 1).sum()) // block)
+
+
+def _live_page_attention(q, pool_k, pool_v, pos, table, scales, scale=None, value_dim=None):
     """The paged slot cache's XLA READ: attention over the LIVE pages alone,
     in one pass.
 
@@ -254,31 +330,48 @@ def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
     After the loop `acc / l` is the softmax of `dot_product_attention` in
     another summation order: no scores leave the loop. `G` is
     `read_block_pages`, and never more than `B * P`: where the whole window
-    fits one block the loop runs once. What it still costs beside the
-    page-walk kernel's single read of every live page: each block is written
+    fits one block the loop runs once. Where `read_run_pages` says so (many
+    query heads a KV head) an entry is not a page but a run of `R` consecutive
+    pages of its slot — the list holds `ceil(live pages / R)` entries a slot, a
+    block `G / R` of them, and "page" above reads "run". What it still costs
+    beside the page-walk kernel's single read of every live page: each block is written
     by its gather and read back once by its reduction, and the last block is
     read whole however few of its entries are live.
 
+    The payload is what the pools are. Two pools of full heads: a turn
+    gathers a block of each. ONE pool of latent rows (`pool_v=None`, [N,
+    page_size, D] with no head axis; MLA's absorbed form: every query head
+    reads the one row, `D` the row's width): a turn gathers one block,
+    the keys are its rows and the values the SAME rows — `probs . row` over
+    the whole row, of which the caller's `value_dim` leading columns are the
+    values (the rest, the rope part, is dropped after the loop: slicing the
+    block inside it would copy it). `scale` is the softmax scale where it is
+    not `1 / sqrt(D)` (a latent row is wider than the head it stands for).
+
     q [B, s, Hq, D]; pools [N, page_size, Hkv, D] (Hq % Hkv == 0: a query
     head's group is its kv head, as `jnp.repeat` pairs them); pos [B, s] and
-    table [B, P] as `_write_slot_pool` returns them. Returns [B, s, Hq, D]."""
+    table [B, P] as `_write_slot_pool` returns them. Returns [B, s, Hq, D],
+    or [B, s, Hq, value_dim]."""
     import jax
     import jax.numpy as jnp
 
     from .quantization import dequantize_kv_pages
 
     b, s, hq, d = q.shape
-    _, ps, hkv, _ = pool_k.shape
+    ps, hkv = pool_k.shape[1], (pool_k.shape[2] if pool_k.ndim == 4 else 1)
     P = table.shape[-1]
     if hq % hkv != 0:
         raise ValueError(f"GQA requires query heads ({hq}) divisible by kv heads ({hkv})")
     rep = hq // hkv
-    G = read_block_pages(b * P, ps, hkv, d, q.dtype.itemsize)
-    flat_len = -(-b * P // G) * G
+    R = read_run_pages(ps, rep)  # pages an entry covers: one, or a run of one slot's
+    span, runs = R * ps, -(-P // R)  # tokens an entry covers; entries a full slot makes
+    G = max(1, read_block_pages(b * P, ps, hkv, d, q.dtype.itemsize) // R)
+    flat_len = -(-b * runs // G) * G
 
-    # The flat list. Entry i belongs to the slot whose run of live pages
+    # The flat list. Entry i belongs to the slot whose run of live entries
     # [start, end) holds i; entries at and past n belong to nobody (owner B).
-    count = jnp.max(pos, axis=1) // ps + 1  # [B], 1..P live pages a slot
+    top = jnp.max(pos, axis=1)  # [B], the last position a slot's queries attend
+    count = top // span + 1  # [B], 1..runs live entries a slot
     end = jnp.cumsum(count)
     start = end - count
     n = end[-1]
@@ -286,35 +379,46 @@ def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
     listed = i < n
     owner = jnp.sum(i[:, None] >= end[None, :], axis=1, dtype=jnp.int32)  # [flat_len]
     slot = jnp.minimum(owner, b - 1)
-    page = jnp.clip(i - start[slot], 0, P - 1)  # the entry's page of its slot's window
-    page_id = jnp.where(listed, jnp.take(table.reshape(-1), slot * P + page, mode="clip"), 0)
+    page = jnp.clip(i - start[slot], 0, runs - 1)  # the entry's page (run) of its slot's window
+    if R == 1:
+        page_id = jnp.where(listed, jnp.take(table.reshape(-1), slot * P + page, mode="clip"), 0)
+    else:
+        # [flat_len, R]: a run's pages; those past the slot's last live page read the scratch page
+        pages = page[:, None] * R + jnp.arange(R, dtype=jnp.int32)[None, :]
+        live = listed[:, None] & (pages * ps <= top[slot][:, None])
+        page_id = jnp.where(
+            live, jnp.take(table.reshape(-1), slot[:, None] * P + jnp.minimum(pages, P - 1), mode="clip"), 0)
     # The last token of its page an entry's query j attends, [flat_len, s]:
     # under 0 where the page lies past the query's position, or is nobody's.
-    last = jnp.where(listed[:, None], pos[slot] - (page * ps)[:, None], -1)
+    last = jnp.where(listed[:, None], pos[slot] - (page * span)[:, None], -1)
     blocks = (n + G - 1) // G
 
     def block_of(x, t):
         return jax.lax.dynamic_slice_in_dim(x, t * G, G, axis=0)
 
     def read_block(pool, scale_pool, ids):
-        pages = jnp.take(pool, ids, axis=0, mode="clip")  # [G, ps, Hkv, D]
-        if scale_pool is None:
-            return pages
-        return dequantize_kv_pages(pages, jnp.take(scale_pool, ids, axis=0, mode="clip"), q.dtype)
+        ids = ids.reshape(-1)
+        pages = jnp.take(pool, ids, axis=0, mode="clip")  # [G * R, ps, Hkv, D]
+        if pages.ndim == 3:  # latent rows: the one "head" every query head reads
+            pages = pages[:, :, None, :]
+        if scale_pool is not None:
+            pages = dequantize_kv_pages(pages, jnp.take(scale_pool, ids, axis=0, mode="clip"), q.dtype)
+        return pages if R == 1 else pages.reshape(G, span, hkv, d)  # a run's pages end to end
 
     k_scale, v_scale = scales if scales is not None else (None, None)
     q_groups = q.reshape(b, s, hkv, rep, d)
-    scale = 1.0 / np.sqrt(d)  # a numpy scalar: bf16 scores are scaled in fp32, as there
+    if scale is None:
+        scale = 1.0 / np.sqrt(d)  # a numpy scalar: bf16 scores are scaled in fp32, as there
     lowest = jnp.finfo(jnp.float32).min  # finite: a row with nothing to attend yet makes no NaN
 
     def fold_block(t, carry):
         m, l, acc = carry  # [B, s, Hkv, rep], the same, [B, s, Hkv, rep, D]: fp32
         ids, slots = block_of(page_id, t), block_of(slot, t)
         k_block = read_block(pool_k, k_scale, ids)
-        v_block = read_block(pool_v, v_scale, ids)
+        v_block = k_block if pool_v is None else read_block(pool_v, v_scale, ids)
         q_block = jnp.take(q_groups, slots, axis=0, mode="clip")
         scores = jnp.einsum("gskrd,gtkd->gskrt", q_block, k_block) * scale
-        attend = jnp.arange(ps) <= block_of(last, t)[:, :, None, None, None]  # [G, s, 1, 1, ps]
+        attend = jnp.arange(span) <= block_of(last, t)[:, :, None, None, None]  # [G, s, 1, 1, span]
         scores = jnp.where(attend, scores.astype(jnp.float32), lowest)
         # owner B (an entry past n) is an all-false row: it reaches nobody's.
         mine = block_of(owner, t)[:, None] == jnp.arange(b)[None, :]  # [G, B]
@@ -341,7 +445,10 @@ def _live_page_attention(q, pool_k, pool_v, pos, table, scales):
             0, blocks, fold_block,
             (jnp.full(rows, lowest), jnp.zeros(rows, jnp.float32), jnp.zeros(rows + (d,), jnp.float32)),
         )
-    return (acc / l[..., None]).reshape(b, s, hq, d).astype(q.dtype)
+    out = (acc / l[..., None]).reshape(b, s, hq, d)
+    if value_dim is not None:
+        out = out[..., :value_dim]
+    return out.astype(q.dtype)
 
 
 def _tp_paged_attention(fn, q, pool_k, pool_v, table, positions, k_scale, v_scale, mesh):
@@ -384,7 +491,8 @@ def _tp_paged_attention(fn, q, pool_k, pool_v, table, positions, k_scale, v_scal
 def slot_cache_attention(
     module, q, k, v, cache_length: int, positions, page_table=None,
     page_size: int = 0, num_pages: int = 0, attention_impl: str = "xla",
-    kv_cache_dtype: str = "bf16", mesh=None,
+    kv_cache_dtype: str = "bf16", mesh=None, scale: Optional[float] = None,
+    value_dim: Optional[int] = None,
 ):
     """Write this dispatch's K/V into the slot cache AND attend — the fused
     serving-decode seam every slot-cache model family calls (llama, gpt_neox).
@@ -429,6 +537,14 @@ def slot_cache_attention(
     by heads from the sharded pool/param operands, and the page axis stays
     whole.
 
+    A LATENT cache (`v=None`, `k` [B, s, row], `q` [B, s, Hq, row] already
+    in the row's space — MLA's absorbed form) keeps one pool of rows and is
+    read by the `"xla"` loop alone: a turn gathers ONE block, which is keys
+    and values both (`_live_page_attention`); `scale` and `value_dim` are the
+    family's softmax scale and the row's leading columns that are values.
+    `"pallas_paged"` and a quantized pool refuse it: the kernels and the
+    scale pools are written for a K pool and a V pool of full heads.
+
     Args:
         positions: [B, s] int32 — each token's absolute write/attend position.
         page_table: [B, pages_per_slot] int32 pool-page ids per slot.
@@ -443,13 +559,20 @@ def slot_cache_attention(
             f"unknown attention_impl {attention_impl!r}; expected one of {SLOT_ATTENTION_IMPLS}"
         )
     _check_slot_positions(positions, *k.shape[:2])
+    if v is None and attention_impl != "xla":
+        raise ValueError(
+            f"attention_impl={attention_impl!r} on a latent cache: the page-walk kernels "
+            "read a K pool and a V pool of full heads — a page-walk kernel for latent "
+            "rows is not built; use attention_impl=\"xla\""
+        )
     pool_k, pool_v, pos, table, scales = _write_slot_pool(
         module, k, v, positions, page_table, page_size, num_pages,
         kv_cache_dtype=kv_cache_dtype,
     )
     if attention_impl == "xla":
         LAST_DISPATCH = "xla"
-        return _live_page_attention(q, pool_k, pool_v, pos, table, scales)
+        latent = {} if v is not None else {"scale": scale, "value_dim": value_dim}
+        return _live_page_attention(q, pool_k, pool_v, pos, table, scales, **latent)
     from .paged_attention import paged_decode_attention, paged_verify_attention
 
     k_scale, v_scale = scales if scales is not None else (None, None)

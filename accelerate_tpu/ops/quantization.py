@@ -214,7 +214,7 @@ def quantize_params_int8(params):
         if (
             name == "kernel"
             and getattr(leaf, "ndim", 0) >= 2
-            and jnp.issubdtype(jnp.asarray(leaf).dtype, jnp.floating)
+            and jnp.issubdtype(jnp.result_type(leaf), jnp.floating)  # no transfer of a host leaf
         ):
             return quantize_weight_int8(leaf)
         return leaf

@@ -16,6 +16,13 @@ Shapes (per jit program, global):  tokens T = B*S, experts E, capacity C, hidden
   expert_in  = einsum('tec,th->ech', dispatch, x)     (all-to-all under GSPMD)
   expert_out = vmapped_ffn(expert_in)                 (fully expert-parallel)
   y          = einsum('tec,ech->th', combine, expert_out)  (all-to-all back)
+
+`DroplessMoE` is the other formulation, for families whose routing may drop
+nothing (sigmoid scores, a choice bias, a shared expert): the (token, expert)
+pairs are sorted by expert and every expert multiplies exactly its own rows
+(`grouped_matmul`: a Pallas kernel on a TPU), so the work follows the pairs,
+not experts x capacity, at every token count. One device holds all its experts; an "expert" mesh axis for
+it is not built. `MoEBlock` stays what `mixtral` trains through.
 """
 
 from __future__ import annotations
@@ -147,3 +154,83 @@ class MoEBlock(nn.Module):
         )(expert_in)
         y = jnp.einsum("tec,ech->th", combine, expert_out.astype(jnp.float32))
         return y.reshape(B, S, H).astype(hidden.dtype), aux
+
+
+# ------------------------------------------------------------- dropless routing
+def sigmoid_top_k_routing(logits, choice_bias, top_k: int, scaling: float, normalize: bool = True):
+    """DeepSeek-V3-style routing (`noaux_tc`, one group): scores are
+    `sigmoid(logits)` in float32; the `top_k` experts are the largest of
+    `scores + choice_bias` — the bias steers the CHOICE only — and the weights
+    are the chosen experts' own scores, normalised to sum to one (`normalize`)
+    and scaled. logits [T, E], choice_bias [E] -> (expert ids [T, k] int32,
+    weights [T, k] float32). No capacity: every pair is kept."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, ids = jax.lax.top_k(scores + choice_bias.astype(jnp.float32)[None, :], top_k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if normalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), weights * scaling
+
+
+#: Tile of the Pallas grouped matmul: rows of a visit, columns of a weight block
+#: (all of `K` rides in one block). One v5e, 64 experts of [2048, 1408] and
+#: [1408, 2048], 768 / 1,536 / 6,144 rows (PERF.md §6, PR 31): 543 / 583 / 814 us
+#: a call against a floor of 451 (every expert's matrix once at 819 GB/s) where
+#: XLA's own `ragged_dot` kernel takes 1,922 / 2,883 / 3,332; 256 rows or
+#: narrower blocks are slower, wider ones no faster.
+_GMM_TILE_ROWS, _GMM_TILE_COLS = 128, 512
+
+
+def _gmm(rows, kernels, group_sizes, interpret: bool = False):
+    """The megablox Pallas kernel (`jax.experimental.pallas.ops.tpu.megablox`):
+    a grid over (column block, visit, K block) where a visit is one expert's
+    rows inside one tile of `_GMM_TILE_ROWS` rows, the expert's weight block
+    streamed once a visit. Rows are padded to whole tiles; what lies past the
+    last group is never visited and is cut off."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = rows.shape
+    n = kernels.shape[2]
+    pad = -m % _GMM_TILE_ROWS
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    out = gmm(rows, kernels, group_sizes, rows.dtype, (_GMM_TILE_ROWS, k, min(_GMM_TILE_COLS, n)),
+              interpret=interpret)
+    return out[:m]
+
+
+def grouped_matmul(rows, kernels, group_sizes):
+    """`rows[start_e:end_e] @ kernels[e]` for every expert e, rows sorted by
+    expert: rows [M, K], kernels [E, K, N], group_sizes [E] (sum M) -> [M, N].
+    On a TPU the Pallas grouped matmul (`_gmm`), which reads each expert's
+    matrix once at four fifths of the chip's bandwidth; elsewhere
+    `jax.lax.ragged_dot`, the same sum as XLA's plain loop."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    if jax.default_backend() == "tpu":
+        return _gmm(rows, kernels, group_sizes)
+    return jax.lax.ragged_dot(rows, kernels, group_sizes)
+
+
+def dropless_expert_ffn(x, expert_ids, weights, w_gate, w_up, w_down):
+    """`y[t] = sum_j weights[t, j] * E_{ids[t, j]}(x[t])`, `E_e(z) = (silu(z W1_e)
+    * z W3_e) W2_e`, with no token dropped at any count: the T*k (token,
+    expert) pairs are sorted by expert (stable, so a token's order inside an
+    expert is its own), each expert multiplies its own run of rows, and the
+    results return to token order by the inverse permutation — a gather, no
+    scatter-add. x [T, H]; kernels [E, H, F], [E, H, F], [E, F, H]. Returns
+    (y [T, H] in x's dtype, tokens an expert [E] int32)."""
+    T, k = expert_ids.shape
+    E = w_gate.shape[0]
+    flat = expert_ids.reshape(T * k)
+    with jax.named_scope("moe_route"):
+        order = jnp.argsort(flat, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        rows = jnp.take(x, order // k, axis=0)  # pair i is token i // k
+    with jax.named_scope("moe_experts"):
+        hidden = nn.silu(grouped_matmul(rows, w_gate, group_sizes)) * grouped_matmul(rows, w_up, group_sizes)
+        out = grouped_matmul(hidden.astype(x.dtype), w_down, group_sizes)
+    with jax.named_scope("moe_route"):
+        out = jnp.take(out, inverse, axis=0).reshape(T, k, -1)
+        y = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)
+    return y.astype(x.dtype), group_sizes

@@ -119,11 +119,30 @@ logger = get_logger(__name__)
 
 def _cached_key_leaf(cache):
     """One layer's `cached_key` leaf of a cache tree (every layer's has the
-    same shape and dtype)."""
+    same shape and dtype), [..., KV heads, head_dim] — or a latent family's
+    `cached_latent`, [..., row], which keys and values are both read from."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        if _leaf_name(path) == "cached_key":
+        if _leaf_name(path) in ("cached_key", "cached_latent"):
             return leaf
-    raise ValueError("the cache holds no `cached_key`")
+    raise ValueError("the cache holds no `cached_key` and no `cached_latent`")
+
+
+def _expert_token_counts(cache):
+    """`[expert layers, 2, experts]` int32 — the `expert_tokens` leaves a family
+    with routed experts keeps in its slot cache (`models/latent_moe.py`: the
+    tokens an expert was given, and the dispatches that gave it any) — or None
+    where the cache holds none."""
+    leaves = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+              if _leaf_name(path) == "expert_tokens"]
+    return jnp.stack(leaves) if leaves else None
+
+
+def _zero_expert_token_counts(cache):
+    """The cache with its `expert_tokens` leaves at zero: a chunk counts its own."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jnp.zeros_like(leaf) if _leaf_name(path) == "expert_tokens" else leaf,
+        cache,
+    )
 
 
 class QueueFull(RuntimeError):
@@ -270,6 +289,32 @@ class ContinuousBatcher:
             raise ValueError(
                 f"unknown kv_cache_dtype {kv_cache_dtype!r}; expected one of {KV_CACHE_DTYPES}"
             )
+        # A latent cache (MLA: one `[c | k_pe]` row a token a layer, which the
+        # config says by `decode_kv_row_values`) is read by the XLA loop on one
+        # device, unquantized. The three other combinations name what is missing.
+        latent_row = getattr(base, "decode_kv_row_values", None)
+        if latent_row is not None:
+            family = type(model.module).__name__
+            if str(attention_impl) == "pallas_paged":
+                raise ValueError(
+                    f"attention_impl={attention_impl!r} with {family}: its cache is one "
+                    f"latent row of {latent_row} values a token, and the page-walk "
+                    "kernels read a K pool and a V pool of full heads — a page-walk kernel "
+                    "for latent rows is not built; use attention_impl=\"xla\""
+                )
+            if self.kv_cache_dtype != "bf16":
+                raise ValueError(
+                    f"kv_cache_dtype={self.kv_cache_dtype!r} with {family}: the quantized pool "
+                    "keeps one scale a page a KV head, and a latent row has no heads — a "
+                    "quantized pool for latent rows is not built; use kv_cache_dtype=\"bf16\""
+                )
+            if int(tp) > 1:
+                raise ValueError(
+                    f"tp={tp} with {family}: every head reads the same latent row, so a "
+                    "tensor-parallel engine needs the row replicated and the absorbed "
+                    "projections split by head (and the experts an \"expert\" axis) — that "
+                    "layout is not built; use tp=1"
+                )
         # Tensor-parallel decode: one engine spanning a `tp`-device submesh
         # whose single "model" axis carries the model family's Megatron
         # column/row-parallel rules (parallel/sharding.py). Weights, the KV
@@ -499,17 +544,14 @@ class ContinuousBatcher:
                 lambda p: prefill_module.apply(resolve(p), dummy, None, dpos, mutable=["cache"])[1]["cache"],
                 self.params,
             )
-        # What one turn of the XLA read's loop visits (`_live_page_counts`):
-        # the prefill cache is K as the model computes it — [1, length, KV
-        # heads, head_dim] in the compute dtype, the very operands the read
-        # sizes its block from.
-        from .ops.attention import read_block_pages
-
+        # What the XLA read's loop is sized from (`_live_page_counts`): the
+        # prefill cache is K as the model computes it — [1, length, KV heads,
+        # head_dim] in the compute dtype ([1, length, row] for a latent
+        # family), the very operands the read takes its block and its run from.
         key = _cached_key_leaf(self._dense_cache_struct)
-        self._read_block_pages = read_block_pages(
-            self.num_slots * self.pages_per_slot, self.page_size, key.shape[2], key.shape[3],
-            np.dtype(key.dtype).itemsize,
-        )
+        kv_heads = key.shape[2] if key.ndim == 4 else 1  # latent rows [1, length, row]: no head axis
+        self._read_shape = (self.pages_per_slot, self.page_size, kv_heads, key.shape[-1],
+                            np.dtype(key.dtype).itemsize, base.num_attention_heads // kv_heads)
 
         self._sample_config = GenerationConfig(do_sample=do_sample, top_k=top_k, top_p=top_p)
         # Python-side effects run at TRACE time: these count compiles, and the
@@ -520,6 +562,24 @@ class ContinuousBatcher:
         self._insert_fns: Dict[int, Any] = {}
         self._chunk_fn = self._build_spec_chunk() if self.speculative else self._build_chunk()
         self._cache = self._init_cache()
+        # Values and stored bytes one token holds in the pool over all layers:
+        # keys and values of full heads ([..., pages, page_size, heads,
+        # head_dim]) or a latent family's one row a layer ([..., pages,
+        # page_size, row]); nn.scan puts its layers in front.
+        held, layers = {"cached_key": 2, "cached_value": 2, "cached_latent": 1}, 0
+        self._kv_bytes_per_token = self.kv_row_values = 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]:
+            trailing = held.get(_leaf_name(path))
+            if trailing is None:
+                continue
+            stacked = int(np.prod(leaf.shape[: leaf.ndim - trailing - 2]))
+            values = stacked * int(np.prod(leaf.shape[leaf.ndim - trailing:]))
+            self.kv_row_values += values
+            self._kv_bytes_per_token += values * np.dtype(leaf.dtype).itemsize
+            layers += stacked * (_leaf_name(path) != "cached_value")
+        self.kv_row_values //= layers  # a layer's: 2 x KV heads x head_dim, or the latent row
+        counts = _expert_token_counts(self._cache)
+        self._expert_layers = 0 if counts is None else int(counts.shape[0])
         self._presence = (
             jnp.zeros((self.num_slots, base.vocab_size), bool) if use_repetition_penalty else None
         )
@@ -650,6 +710,18 @@ class ContinuousBatcher:
             "as the last decode chunk was dispatched: the share of the window "
             "the paged XLA read visits",
         )
+        self._m_kv_bytes_per_token = self.metrics.gauge(
+            "serving_kv_bytes_per_token",
+            help="stored bytes one token holds in the page pool, all layers: keys and "
+            "values of full heads, or a latent family's one row a layer",
+        )
+        self._m_kv_bytes_per_token.set(self._kv_bytes_per_token)
+        self._m_expert_load = self.metrics.gauge(
+            "serving_expert_load_max_over_mean",
+            help="the last decode chunk's tokens of the busiest routed expert over the "
+            "mean expert's, layers averaged: what dropless routing pays under imbalance "
+            "(0 for a family without routed experts)",
+        )
         self._m_prefix_hits = self.metrics.counter(
             "serving_prefix_cache_hits_total",
             help="prompt pages served from the shared-prefix cache",
@@ -721,7 +793,13 @@ class ContinuousBatcher:
         Megatron rules (`derive_tp_param_shardings` — quantized {"q",
         "scale"} entries ride their kernel's rule), so a rolling
         `swap_weights` lands already-sharded weights with zero recompiles
-        and an already-placed tree passes through as the same buffers."""
+        and an already-placed tree passes through as the same buffers.
+
+        HOST leaves (a checkpoint's numpy arrays) are placed here, once: left
+        as they come, every dispatch would transfer them again. An int8
+        engine quantizes them a leaf at a time on the way, so it never holds
+        the floating tree on the device — what lets int8 weights serve a model
+        whose bf16 weights would not fit beside them."""
         if self.weight_dtype == "int8":
             from .ops.quantization import quantize_params_int8
 
@@ -732,8 +810,8 @@ class ContinuousBatcher:
             self._param_shardings = derive_tp_param_shardings(
                 value, self.mesh, self._tp_rules
             )
-            value = jax.device_put(value, self._param_shardings)
-        self._params = value
+        # One chip: no shardings, the default device. Placed leaves pass through as the same buffers.
+        self._params = jax.device_put(value, self._param_shardings)
 
     def _init_cache(self):
         """Create the slot cache — the [num_pages, page_size] pool (quantized
@@ -928,6 +1006,7 @@ class ContinuousBatcher:
 
         def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng):
             self.trace_counts["decode_chunk"] += 1
+            cache = _zero_expert_token_counts(cache)
 
             def body(carry, _):
                 cache, presence, token, pos, active, rem, rng = carry
@@ -968,7 +1047,11 @@ class ContinuousBatcher:
                     ],
                     axis=-1,
                 ).astype(jnp.int32)
-            return cache, presence, token, pos, active, rem, rng, packed, flat_valid.sum()
+            out = (cache, presence, token, pos, active, rem, rng, packed, flat_valid.sum())
+            # A family with routed experts: the chunk's tokens an expert a
+            # layer ride the same readback, last.
+            counts = _expert_token_counts(cache)
+            return out if counts is None else out + (counts,)
 
         donate = (1, 2) if use_pen else (1,)
         return jax.jit(decode_chunk, donate_argnums=donate)
@@ -1011,6 +1094,7 @@ class ContinuousBatcher:
 
         def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, history):
             self.trace_counts["decode_chunk"] += 1
+            cache = _zero_expert_token_counts(cache)
             js = jnp.arange(k_draft + 1, dtype=jnp.int32)
             rows = jnp.arange(S)
 
@@ -1069,10 +1153,12 @@ class ContinuousBatcher:
                     ],
                     axis=-1,
                 ).astype(jnp.int32)
-            return (
+            out = (
                 cache, presence, token, pos, active, rem, rng, packed, flat_valid.sum(),
                 emitted_mat, proposed_mat,
             )
+            counts = _expert_token_counts(cache)
+            return out if counts is None else out + (counts,)
 
         return jax.jit(decode_chunk, donate_argnums=(1,))
 
@@ -1198,6 +1284,9 @@ class ContinuousBatcher:
         view["pages_total"] = self.pool.pages_total
         view["pages_in_use"] = self.pool.pages_in_use
         view["kv_live_page_share"] = float(self._m_kv_live_page_share.value)
+        view["kv_bytes_per_token"] = self._kv_bytes_per_token
+        if self._expert_layers:
+            view["expert_load_max_over_mean"] = float(self._m_expert_load.value)
         view["prefix_cache"] = {
             "enabled": self.use_prefix_cache,
             "hits": int(self._m_prefix_hits.value),
@@ -1492,6 +1581,7 @@ class ContinuousBatcher:
                     "serve.insert", category="serve",
                     request_id=int(req.request_id), slot=slot, bucket=int(bucket),
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
+                    **self._routed_pairs(bucket),
                 ) as ispan:
                     fn = self._insert_fn(bucket)
                     on_device, self._cache, self._presence, self._rng = fn(
@@ -1580,6 +1670,14 @@ class ContinuousBatcher:
                 self._finish(result, "eos" if token == eos else "length", now=now)
         self._update_occupancy_gauges()
         return events, device_wait_s
+
+    def _routed_pairs(self, bucket: int) -> Dict[str, int]:
+        """`routed_pairs` of an insert, for its span: the (token, expert) pairs
+        its bucket sends through the routed experts, pads included — dropless
+        routing computes every one. Nothing for a family without experts."""
+        if not self._expert_layers:
+            return {}
+        return {"routed_pairs": int(bucket) * int(self.base_config.num_experts_per_tok) * self._expert_layers}
 
     def _hand_back(self):
         """Runs as step() returns, which is when a client gets the first token
@@ -1746,23 +1844,38 @@ class ContinuousBatcher:
         active slots' live pages (`pos // page_size + 1` each) beside the
         window's `num_slots * pages_per_slot`, for the chunk's span; their
         share goes to the `kv_live_page_share` gauge. `read_blocks` is the
-        trip count of the XLA read's loop in the chunk's first step: those
-        pages and the one scratch page the device visits an idle slot, in
-        blocks of `read_block_pages`. A slot's pages grow inside the chunk:
-        that is not counted."""
+        trip count of the XLA read's loop in the chunk's first step, as the
+        read's own module counts it (`ops.attention.read_blocks`: an idle
+        slot is the one scratch page the device visits). A slot's pages grow
+        inside the chunk: that is not counted."""
+        from .ops.attention import read_blocks
+
         live = int((self._pos[self._active] // self.page_size + 1).sum())
         window = self.num_slots * self.pages_per_slot
         self._m_kv_live_page_share.set(live / window)
-        listed = live + self.num_slots - int(self._active.sum())
         return {"live_pages": live, "window_pages": window,
-                "read_blocks": -(-listed // self._read_block_pages)}
+                "read_blocks": read_blocks(np.where(self._active, self._pos, 0), *self._read_shape),
+                "kv_row_values": self.kv_row_values}
 
     def _chunk_counts(self, host) -> Dict[str, int]:
-        """What a chunk's readback counts, for its span: the tokens streamed
-        and — speculative engines — the fold of the per-(iteration, slot)
+        """What a chunk's readback counts, for its span: the tokens streamed,
+        — a family with routed experts — `expert_tokens_max` / `_mean` and
+        `experts_touched`, and
+        — speculative engines — the fold of the per-(iteration, slot)
         emit/propose matrices into the spec ledger. Every count is a host
         scalar off the readback."""
         counts = {"tokens_streamed": int(host[5])}
+        if self._expert_layers:
+            # Over the chunk's dispatches, every row the program ran, idle
+            # slots' too — they are rows the experts multiply.
+            tokens, dispatches = np.asarray(host[-1])[:, 0], np.asarray(host[-1])[:, 1]
+            busiest, mean = float(tokens.max(axis=1).mean()), float(tokens.mean())
+            self._m_expert_load.set(busiest / mean if mean else 0.0)
+            counts.update(
+                expert_tokens_max=busiest, expert_tokens_mean=mean,
+                # routed experts a layer a dispatch that were given any row
+                experts_touched=float(dispatches.sum()) / (self._expert_layers * self.chunk_size),
+            )
         if self.speculative:
             spec_emitted, spec_proposed = host[6:8]
             steps = int((spec_emitted > 0).sum())

@@ -449,8 +449,9 @@ def pad_input_tensors(tensor, batch_size: int, num_processes: int, dim: int = 0)
 # K/V leaves of the slot cache are pool-shaped: [..., num_pages, page_size,
 # heads, head_dim] — the page axis sits where the dense cache's batch axis sits
 # (4 from the back), so the same rule covers plain stacks and nn.scan-stacked
-# layers ([layers, num_pages, page_size, h, d]).
-_PAGE_AXIS_FROM_BACK = {"cached_key": 4, "cached_value": 4}
+# layers ([layers, num_pages, page_size, h, d]). A latent family's one pool of
+# rows has no head axis: [..., num_pages, page_size, row].
+_PAGE_AXIS_FROM_BACK = {"cached_key": 4, "cached_value": 4, "cached_latent": 3}
 
 # Per-page-per-head scale pools of a QUANTIZED paged cache
 # (ops/quantization.py): [..., num_pages, heads] f32, page axis 2 from the
@@ -560,9 +561,9 @@ def tree_zero_cache_tail(dense, valid_len):
 
     def _zero(path, leaf):
         name = _leaf_name(path)
-        if name not in _PAGE_AXIS_FROM_BACK:  # cached_key / cached_value only
+        if name not in _PAGE_AXIS_FROM_BACK:  # the page-pool leaves only
             return leaf
-        seq_axis = leaf.ndim - 3  # [..., batch, L, heads, head_dim]
+        seq_axis = leaf.ndim - _PAGE_AXIS_FROM_BACK[name] + 1  # [..., batch, L, heads, head_dim]
         cols = jnp.arange(leaf.shape[seq_axis])
         keep = (cols < jnp.asarray(valid_len, jnp.int32)).reshape(
             (leaf.shape[seq_axis],) + (1,) * (leaf.ndim - seq_axis - 1)
@@ -641,7 +642,7 @@ def tree_scatter_pages(pool, dense, page_ids):
         blocks_front = _kv_blocks_front(names, leaf)
         spec = (
             kv_spec_for_dtype(leaf.dtype)
-            if names[:-1] + (_SCALE_OF[name],) in pool_leaves
+            if names[:-1] + (_SCALE_OF.get(name, ""),) in pool_leaves
             else None
         )
         if spec is not None:

@@ -314,6 +314,7 @@ def _raise_from_reply(reply: Dict[str, Any]):
 _FAMILY_BY_MODULE = {
     "LlamaForCausalLM": "llama",
     "GPTNeoXForCausalLM": "gpt_neox",
+    "LatentMoEForCausalLM": "latent_moe",
 }
 
 

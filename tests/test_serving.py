@@ -141,12 +141,15 @@ def test_released_slot_sits_at_position_zero_through_reuse():
     assert all(0 < c["attrs"]["live_pages"] <= c["attrs"]["window_pages"] for c in chunks)
 
 
-def test_chunk_span_read_blocks_is_the_reads_trip_count(monkeypatch):
+@pytest.mark.parametrize("run_pages", [1, 2])
+def test_chunk_span_read_blocks_is_the_reads_trip_count(monkeypatch, run_pages):
     """`serve.decode_chunk.read_blocks` is the trip count the XLA read's loop
     computes on the device in the chunk's first step — `ceil(n / G)`, `n` the
-    live pages of ALL slots (an idle one is one page) from the positions the
-    chunk is dispatched with — because the engine asks the read's own helper
-    for `G`, with the operands' numbers the read hands it at trace time."""
+    live entries of ALL slots (an idle one is one) from the positions the
+    chunk is dispatched with — because the engine asks the read's own helper,
+    with the operands' numbers the read hands it at trace time. This model's
+    two query heads a KV head make an entry a RUN of pages (`read_run_pages`):
+    of one page, as multi-head attention lists them, and of two."""
     from accelerate_tpu.ops import attention
     from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
     from accelerate_tpu.telemetry.tracing import Tracer
@@ -156,6 +159,8 @@ def test_chunk_span_read_blocks_is_the_reads_trip_count(monkeypatch):
     kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
     # blocks of 2 pages of 8 tokens (float32 K): several turns a read
     monkeypatch.setattr(attention, "_READ_BLOCK_BYTES", 2 * 8 * kv_heads * cfg.head_dim * 4)
+    monkeypatch.setattr(attention, "_READ_RUN_TOKENS", 8 * run_pages)
+    assert attention.read_run_pages(8, cfg.num_attention_heads // kv_heads) == run_pages
     asked = []
     helper = attention.read_block_pages
 
@@ -179,17 +184,18 @@ def test_chunk_span_read_blocks_is_the_reads_trip_count(monkeypatch):
     )
     engine.step()
     engine.step()
-    # the engine's call (at construction) and the read's (as the chunk is traced, a layer each)
+    # the engine's calls (`ops.attention.read_blocks`, a chunk each) and the read's (as the chunk is traced, a layer each)
     assert len(asked) > 1 and len(set(asked)) == 1, asked
     (_, block_pages), = set(asked)
     assert block_pages == 2
     chunks = [r for r in recorder.records() if r["name"] == "serve.decode_chunk"]
     assert len(chunks) == len(pushed) == 2
     for chunk, pos in zip(chunks, pushed):
-        n = int((pos // engine.page_size + 1).sum())  # as the read counts: every slot
-        assert chunk["attrs"]["read_blocks"] == -(-n // block_pages)
-    # 7 pages; two tokens on, two slots have crossed into a new page: 3 + 2 + 3 + 1
-    assert [c["attrs"]["read_blocks"] for c in chunks] == [4, 5]
+        n = int((pos // (engine.page_size * run_pages) + 1).sum())  # as the read counts: every slot
+        assert chunk["attrs"]["read_blocks"] == -(-n // (block_pages // run_pages))
+    # 7 pages; two tokens on, two slots have crossed into a new page: 3 + 2 + 3 + 1 (9 pages, blocks of 2).
+    # In runs of two pages 2 + 1 + 1 + 1 entries, then 2 + 1 + 2 + 1, a block each.
+    assert [c["attrs"]["read_blocks"] for c in chunks] == {1: [4, 5], 2: [5, 6]}[run_pages]
 
 
 def test_greedy_parity_gpt_neox_family():

@@ -221,6 +221,108 @@ def test_xla_paged_read_walks_blocks_not_the_window(v5e, s_block, pool):
     assert compiled.cost_analysis()["bytes accessed"] <= bound[(pool, s_block)]
 
 
+# One layer of `chipbench/workloads/kimi-vl-a3b.decode-heavy-saturated.json`: 128 slots x
+# 128 pages of 16 latent rows of 640 values ([c 512 | k_pe 64] in whole 128-lane tiles),
+# 16 query heads in the row's space, the first 512 columns of a row its values.
+LATENT_SLOTS, LATENT_PAGES_PER_SLOT, LATENT_ROW, LATENT_VALUES = 128, 128, 640, 512
+
+
+@pytest.mark.parametrize("s_block", [1, 5], ids=["decode", "verify5"])
+def test_latent_read_gathers_one_block_a_turn(v5e, s_block):
+    """The same `_live_page_attention` over a pool of latent rows (`v=None`):
+    ONE `while` whose body gathers ONE block of pages ([408, 16, 640], 8.4 MB:
+    keys and values both) where two pools of full heads cost two — 51 runs of
+    8 pages of one slot each (`read_run_pages`: 16 heads read the one row, so
+    an entry is 128 tokens wide for the matrix unit), with `q` gathered by run
+    ([51, 16, 640]; by page it would be as large as the block). The pool is
+    [pages, page_size, row] with no head axis and a row of whole tiles: with a
+    size-1 axis, or 576 columns, the compiler lays it out page-minor and copies
+    all of it in every program that gathers pages (seen: one `copy` of the pool
+    a layer, 2.7 GB a decode step). Nothing of a quarter of the window leaves
+    the loop. A compile, not a timing."""
+    import flax.linen as nn
+
+    from accelerate_tpu.ops import attention
+
+    num_pages = LATENT_SLOTS * LATENT_PAGES_PER_SLOT + 1
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, q, row, positions, table):
+            return attention.slot_cache_attention(
+                self, q, row, None, LATENT_PAGES_PER_SLOT * CELL_PAGE_SIZE, positions, page_table=table,
+                page_size=CELL_PAGE_SIZE, num_pages=num_pages, attention_impl="xla",
+                scale=1.0 / math.sqrt(192), value_dim=LATENT_VALUES,
+            )
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    layer = Layer()
+    operands = (spec((LATENT_SLOTS, s_block, CELL_HEADS, LATENT_ROW), jnp.bfloat16),
+                spec((LATENT_SLOTS, s_block, LATENT_ROW), jnp.bfloat16),
+                spec((LATENT_SLOTS, s_block), jnp.int32),
+                spec((LATENT_SLOTS, LATENT_PAGES_PER_SLOT), jnp.int32))
+    cache = jax.eval_shape(lambda *a: layer.init(jax.random.key(0), *a), *operands)["cache"]
+    assert {name: leaf.shape for name, leaf in cache.items()} == {
+        "cached_latent": (num_pages, CELL_PAGE_SIZE, LATENT_ROW)}  # one pool: no `cached_value`
+    cache = jax.tree_util.tree_map(lambda leaf: spec(leaf.shape, leaf.dtype), cache)
+
+    def step(cache, *args):
+        out, mutated = layer.apply({"cache": cache}, *args, mutable=["cache"])
+        return out, mutated["cache"]
+
+    compiled = jax.jit(step, donate_argnums=0).lower(cache, *operands).compile()
+    text = compiled.as_text()
+    window_pages = LATENT_SLOTS * LATENT_PAGES_PER_SLOT
+    block_pages = attention.read_block_pages(window_pages, CELL_PAGE_SIZE, 1, LATENT_ROW, 2)
+    run_pages = attention.read_run_pages(CELL_PAGE_SIZE, CELL_HEADS)
+    assert (block_pages, run_pages) == (409, 8)  # 8 MiB of bf16 rows; runs of 128 tokens
+    block_pages = block_pages // run_pages * run_pages  # 51 whole runs a turn
+    pool_shape = f"[{num_pages},{CELL_PAGE_SIZE},{LATENT_ROW}]"
+    window = window_pages * CELL_PAGE_SIZE * LATENT_ROW
+    window_sized, pool_copies = [], []
+    for line in text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m:
+            continue
+        is_pool = f"[{m.group(2)}]" == pool_shape
+        if is_pool and m.group(3).startswith("copy"):
+            pool_copies.append(line.strip()[:160])
+        if not is_pool and math.prod(int(n) for n in m.group(2).split(",")) >= window // 4:
+            window_sized.append(line.strip()[:160])
+    assert not pool_copies, pool_copies
+    assert not window_sized, window_sized
+    assert text.count(" while(") == 1
+    # the pool's parameter is row-major: [pages, page_size, row] as it is indexed
+    assert re.search(rf"= bf16\[{num_pages},{CELL_PAGE_SIZE},{LATENT_ROW}\]\{{2,1,0:[^}}]*\}} parameter\(", text)
+    # In fast memory (`S(1)`), a turn: the ONE block of pages.
+    blocks = re.findall(rf"= bf16\[{block_pages},{CELL_PAGE_SIZE},{LATENT_ROW}\]\{{[^}}]*S\(1\)\}} fusion\(", text)
+    assert len(blocks) == 1, blocks
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * block_pages * CELL_PAGE_SIZE * LATENT_ROW * 2
+
+
+@pytest.mark.parametrize("rows", [768, 192, 6144], ids=["decode128x6", "insert32x6", "insert1024x6"])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)], ids=["gate_up", "down"])
+def test_grouped_expert_matmul_compiles_for_v5e(v5e, rows, k, n):
+    """`parallel.expert._gmm`, the Pallas grouped matmul the routed experts run
+    on a TPU, at the `kimi-vl-a3b` cell's shapes: 64 experts, a decode step's
+    768 (token, expert) pairs and the smallest and largest insert buckets'
+    (192 is padded to whole tiles of 128 rows). One kernel, no copy of the
+    stacked matrices on its way in."""
+    from accelerate_tpu.parallel.expert import _gmm
+
+    compiled = jax.jit(_gmm).lower(
+        jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=v5e),
+        jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=v5e),
+        jax.ShapeDtypeStruct((64,), jnp.int32, sharding=v5e),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = [^\n]*custom-call\(", text)) == 1
+    assert not re.search(rf"= bf16\[64,{k},{n}\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
 @pytest.mark.parametrize("seq", [1024, 2048])
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_flash_attention_compiles_for_v5e(v5e, direction, seq):
