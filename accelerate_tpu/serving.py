@@ -145,6 +145,16 @@ def _zero_expert_token_counts(cache):
     )
 
 
+def _start_fresh_slots(token, active, eos_ids, first_token, fresh):
+    """The decode chunk's `(token, active)` with the slots admitted in its own
+    step started from the token their insert left on the device: the host
+    pushed `fresh` and every other operand of such a slot, but has not seen
+    the token, so the test it would make (a first token that is the request's
+    EOS ends it) is made here."""
+    token = jnp.where(fresh, first_token, token)
+    return token, active & ~(fresh & (eos_ids >= 0) & (token == eos_ids))
+
+
 class QueueFull(RuntimeError):
     """Bounded-queue backpressure: the engine's wait queue is at `max_queue`.
     Callers shed load (HTTP 429 / retry-after) instead of growing host memory."""
@@ -191,9 +201,10 @@ class RequestResult:
     request_id: int
     tokens: List[int] = field(default_factory=list)
     arrival_time: float = 0.0
-    # Host perf_counter when the first token was ON THE HOST (its insert's
-    # readback returned). The client gets it when that step() returns, a decode
-    # chunk later: the request span's `handed_back` event carries the difference.
+    # Host perf_counter when the first token was ON THE HOST (the one readback
+    # of the step() that admitted it returned). The client gets it when that
+    # step() returns, once the drain is done: the request span's `handed_back`
+    # event carries the difference.
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     finished: bool = False
@@ -580,21 +591,14 @@ class ContinuousBatcher:
         self.kv_row_values //= layers  # a layer's: 2 x KV heads x head_dim, or the latent row
         counts = _expert_token_counts(self._cache)
         self._expert_layers = 0 if counts is None else int(counts.shape[0])
-        self._presence = (
-            jnp.zeros((self.num_slots, base.vocab_size), bool) if use_repetition_penalty else None
-        )
-        if self.mesh is not None:
-            # Commit the carried device state (rng; presence when penalized)
-            # REPLICATED on the submesh up front: these thread through every
-            # dispatch, and an uncommitted first-call signature followed by a
-            # committed second-call one would recompile the one decode
-            # executable the engine promises never to.
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            replicated = NamedSharding(self.mesh, PartitionSpec())
-            self._rng = jax.device_put(self._rng, replicated)
-            if self._presence is not None:
-                self._presence = jax.device_put(self._presence, replicated)
+        self._rng = self._carried(self._rng)
+        self._presence = self._new_presence()
+        # Where an insert leaves its sampled token, by slot: the decode chunk
+        # of the same step starts from it, and the step's one readback brings
+        # it to the host (_drain). Donated through every insert.
+        self._first_token = self._new_first_token()
+        # `fresh` of a chunk whose step admitted nothing: pushed once.
+        self._no_fresh = jnp.zeros((self.num_slots,), bool)
 
         S = self.num_slots
         # Host mirror of the per-slot device operands (small [S] vectors, pushed
@@ -683,6 +687,14 @@ class ContinuousBatcher:
             "serving_chunk_seconds",
             help="decode-chunk wall clock: operand push, dispatch and readback (the `serve.decode_chunk` span)",
         )
+        self._m_device_waits = self.metrics.counter(
+            "serving_device_waits_total",
+            help="blocking device reads: one a step() that dispatched anything",
+        )
+        self._m_dispatching_steps = self.metrics.counter(
+            "serving_dispatching_steps_total",
+            help="step() calls that dispatched an insert or a decode chunk",
+        )
         self._slot_last_event = np.zeros(S, np.float64)  # last drain time per slot
 
         # Tracing (telemetry.tracing): one `serve.request` span per accepted
@@ -692,6 +704,10 @@ class ContinuousBatcher:
         # the metrics (and TPU112 lints the annotations).
         self.tracer = tracer if tracer is not None else default_tracer()
         self._request_spans: Dict[int, Any] = {}
+        # Slots admitted in the step() now running, in admission order: their
+        # first tokens are still on the device (`_first_token`) until the
+        # step's one readback; _drain() hands them out and clears the list.
+        self._fresh: List[int] = []
         # Requests whose first token reached the host in the step() now
         # running: handed back, and timed, when it returns (_hand_back).
         self._first_tokens: List[RequestResult] = []
@@ -813,6 +829,28 @@ class ContinuousBatcher:
         # One chip: no shardings, the default device. Placed leaves pass through as the same buffers.
         self._params = jax.device_put(value, self._param_shardings)
 
+    def _carried(self, value):
+        """Device state that threads through every dispatch (rng, presence,
+        the first-token buffer), committed REPLICATED on a tensor-parallel
+        engine's submesh up front: an uncommitted first-call signature
+        followed by a committed second-call one would recompile the one
+        decode executable the engine promises never to."""
+        if self.mesh is None:
+            return value
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(value, NamedSharding(self.mesh, PartitionSpec()))
+
+    def _new_presence(self):
+        """Zeroed `bool[num_slots, vocab]` seen-token rows of a penalty engine."""
+        if not self.use_repetition_penalty:
+            return None
+        return self._carried(jnp.zeros((self.num_slots, self.base_config.vocab_size), bool))
+
+    def _new_first_token(self):
+        """The zeroed `int32[num_slots]` first-token buffer."""
+        return self._carried(jnp.zeros((self.num_slots,), jnp.int32))
+
     def _init_cache(self):
         """Create the slot cache — the [num_pages, page_size] pool (quantized
         dtypes add the per-page-per-head scale pools): `eval_shape` the
@@ -919,7 +957,7 @@ class ContinuousBatcher:
                 _operand(1, np.int32), _operand(0, np.int32), _operand(0, np.int32),
                 jnp.asarray(np.zeros((self.pages_per_slot,), np.int32)),
                 _operand(0, np.int32), _operand(1.0, np.float32),
-                _operand(1.0, np.float32), self._rng,
+                _operand(1.0, np.float32), self._rng, self._new_first_token(),
             )
             warmed.append(bucket)
         return warmed
@@ -937,7 +975,9 @@ class ContinuousBatcher:
         so a shared read-only prefix page is never rewritten — and sample the
         first token from the suffix's real last logits. A full prefix hit still
         recomputes the prompt's final token (matching is capped below the whole
-        prompt), so first-token logits always exist."""
+        prompt), so first-token logits always exist. The token is written into
+        the donated `first_token` buffer at `slot` and stays on the device:
+        nothing here is read back (step())."""
         fn = self._insert_fns.get(bucket)
         if fn is not None:
             return fn
@@ -951,7 +991,7 @@ class ContinuousBatcher:
 
         def insert(
             params, pool_cache, presence, suffix_ids, real_len, matched_len,
-            matched_pages, page_row, slot, temperature, penalty, rng,
+            matched_pages, page_row, slot, temperature, penalty, rng, first_token,
         ):
             self.trace_counts["insert"] += 1
             with jax.named_scope("kv_read"):
@@ -988,9 +1028,10 @@ class ContinuousBatcher:
                     presence = jax.lax.dynamic_update_slice(
                         presence, row[None, :], (jnp.asarray(slot, jnp.int32), jnp.int32(0))
                     )
-            return token[0], pool_cache, presence, rng
+            first_token = first_token.at[slot].set(token[0])
+            return first_token, pool_cache, presence, rng
 
-        donate = (1, 2) if use_pen else (1,)
+        donate = (1, 2, 12) if use_pen else (1, 12)
         fn = jax.jit(insert, donate_argnums=donate)
         self._insert_fns[bucket] = fn
         return fn
@@ -1004,9 +1045,10 @@ class ContinuousBatcher:
         config = self._sample_config
         mesh = self.mesh
 
-        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng):
+        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, first_token, fresh):
             self.trace_counts["decode_chunk"] += 1
             cache = _zero_expert_token_counts(cache)
+            token, active = _start_fresh_slots(token, active, eos_ids, first_token, fresh)
 
             def body(carry, _):
                 cache, presence, token, pos, active, rem, rng = carry
@@ -1092,11 +1134,16 @@ class ContinuousBatcher:
         k_draft, m_gram = self.draft_tokens, self.draft_ngram
         mesh = self.mesh
 
-        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, history):
+        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, first_token, fresh, history):
             self.trace_counts["decode_chunk"] += 1
             cache = _zero_expert_token_counts(cache)
+            token, active = _start_fresh_slots(token, active, eos_ids, first_token, fresh)
             js = jnp.arange(k_draft + 1, dtype=jnp.int32)
             rows = jnp.arange(S)
+            # A fresh slot's pending token belongs at history[pos]: the host
+            # seeded the prompt and could not know the token.
+            at_pos = jnp.clip(pos, 0, H - 1)
+            history = history.at[rows, at_pos].set(jnp.where(fresh, token, history[rows, at_pos]))
 
             def body(carry, _):
                 cache, token, pos, active, rem, history = carry
@@ -1263,6 +1310,11 @@ class ContinuousBatcher:
             "chunks": int(self._m_chunks.value),
             "decode_steps": int(self._m_decode_steps.value),
             "queue_peak": int(self._m_queue_peak.value),
+            # Blocking device reads a step() that dispatched anything: 1.0.
+            "waits_per_step": (
+                self._m_device_waits.value / self._m_dispatching_steps.value
+                if self._m_dispatching_steps.value else None
+            ),
             "finish_reasons": {
                 reason: int(counter.value) for reason, counter in self._m_finish.items()
             },
@@ -1381,7 +1433,8 @@ class ContinuousBatcher:
 
     def _abort_in_flight(self, exc: Exception, now: Optional[float] = None):
         """The shared-state blast radius: a dispatch failure that took the slot
-        cache with it (the decode chunk always; an insert only when its donated
+        cache with it (the decode chunk and anything that surfaces at the
+        step's one wait always; an insert's call only when its donated
         operands were consumed). Every in-flight request errors (partial tokens
         kept) and the cache is rebuilt from zeros — the donated buffers may
         already be invalidated, and keeping the references would poison every
@@ -1398,15 +1451,10 @@ class ContinuousBatcher:
             if result is not None:
                 self._finish(result, "error", now=now, slot=slot, error=repr(exc))
         self._active[:] = False
+        self._fresh.clear()  # they held slots: errored above, with no tokens
         self._cache = self._init_cache()
-        if self._presence is not None:
-            self._presence = jnp.zeros((self.num_slots, self.base_config.vocab_size), bool)
-            if self.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                self._presence = jax.device_put(
-                    self._presence, NamedSharding(self.mesh, PartitionSpec())
-                )
+        self._first_token = self._new_first_token()
+        self._presence = self._new_presence()
         if self.speculative:
             # The speculative state dies with the cache: every slot's drafting
             # context belonged to a request that just errored. Admissions
@@ -1497,9 +1545,16 @@ class ContinuousBatcher:
         self._finish(result, "cancelled", slot=self._slot_of(request_id))
         return True
 
-    def _admit(self) -> List[Tuple[int, List[int]]]:
+    def _admit(self):
         """Fill free slots from the queue (FIFO). Each admission is one insert
-        dispatch; the first token streams out immediately (TTFT).
+        DISPATCH and nothing more: the first token stays on the device
+        (`_first_token[slot]`), the slot joins `_fresh`, the step's decode
+        chunk starts from the token where it is, and the step's one readback
+        hands it to _drain(). So the host prepares the next admission, the
+        operand push and the chunk's launch while the inserts run. Every
+        admission holds its slot until that drain — a one-token request too,
+        so that no second admission of the step is given its entry of the
+        buffer.
 
         Admission is PAGE-based, not slot-based: the request reserves
         `ceil((prompt + max_new) / page_size)` pool pages minus whatever its
@@ -1511,15 +1566,14 @@ class ContinuousBatcher:
         release pages — FIFO order and guaranteed progress, since reserve-on-
         admit means every admitted request runs to completion.
 
-        Error isolation: an exception from ONE request's insert (transient device
-        error, a prompt the compiled program rejects) finishes only that request
-        with `finish_reason="error"` — the queue keeps draining and every other
-        slot keeps serving.
-
-        Returns the events and the seconds spent waiting for the device (the
-        inserts' first-token readbacks)."""
-        events: List[Tuple[int, List[int]]] = []
-        device_wait_s = 0.0
+        Error isolation: an exception raised at ONE request's insert CALL (a
+        bucket's executable dies, a prompt the compiled program rejects)
+        finishes only that request with `finish_reason="error"` — the queue
+        keeps draining and every other slot keeps serving. A failure of an
+        insert ON THE DEVICE surfaces at the step's one wait, where it cannot
+        be told from the chunk's: it takes the blast-radius path
+        (`_abort_in_flight`) as a chunk failure does, and the requests admitted
+        in that step error with no tokens."""
         while self._queue and self.free_slots:
             req = self._queue.popleft()
             slot = self._slot_request.index(None)
@@ -1581,10 +1635,13 @@ class ContinuousBatcher:
                     "serve.insert", category="serve",
                     request_id=int(req.request_id), slot=slot, bucket=int(bucket),
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
+                    # No wait in here since the token stays on the device; kept
+                    # so that a step's `device_wait_s` still bounds its inserts'.
+                    device_wait_s=0.0,
                     **self._routed_pairs(bucket),
-                ) as ispan:
+                ):
                     fn = self._insert_fn(bucket)
-                    on_device, self._cache, self._presence, self._rng = fn(
+                    self._first_token, self._cache, self._presence, self._rng = fn(
                         self.params,
                         self._cache,
                         self._presence,
@@ -1597,14 +1654,8 @@ class ContinuousBatcher:
                         _operand(req.temperature, np.float32),
                         _operand(req.repetition_penalty, np.float32),
                         self._rng,
+                        self._first_token,
                     )
-                    # jax.device_get, not int(token): on a TPU only device_get is an
-                    # EXPLICIT device-to-host read an armed transfer guard admits.
-                    with self.tracer.span("serve.insert.wait", category="serve", record=False) as wait:
-                        on_host = jax.device_get(on_device)
-                    ispan.annotate(device_wait_s=round(wait.duration_s, 6))
-                    device_wait_s += wait.duration_s
-                token = int(on_host)
             except Exception as exc:  # noqa: BLE001 — isolate, report, keep serving
                 self.pool.release(pages)
                 if self.trace_guard is not None:
@@ -1632,44 +1683,30 @@ class ContinuousBatcher:
                 # prefilling. Decode writes land at pos >= prompt_len, past
                 # every full prompt page, so registered content stays frozen.
                 self.pool.register_prefix(hashes[: p // self.page_size], pages, start=matched_pages)
-            now = time.perf_counter()
             self._m_inserts.inc()
-            if rspan is not None:
-                rspan.event("first_token")
-            self._slot_last_event[slot] = now
-            result.tokens.append(token)
-            result.first_token_time = now
-            self._first_tokens.append(result)
-            events.append((req.request_id, [token]))
-
-            eos = -1 if req.eos_token_id is None else int(req.eos_token_id)
-            rem = req.max_new_tokens - 1
-            active = rem > 0 and token != eos
-            if active:
-                self._slot_request[slot] = result
-                self._token[slot] = token
+            self._fresh.append(slot)
+            self._slot_request[slot] = result
+            self._slot_pages[slot] = pages
+            self._slot_last_event[slot] = 0.0  # no token of it has reached the host
+            self._rem[slot] = req.max_new_tokens - 1
+            self._eos[slot] = -1 if req.eos_token_id is None else int(req.eos_token_id)
+            if self._rem[slot] > 0:
                 self._pos[slot] = p  # the first generated token's write position
                 self._active[slot] = True
-                self._rem[slot] = rem
-                self._eos[slot] = eos
                 self._temp[slot] = req.temperature
                 self._pen[slot] = req.repetition_penalty
                 if self.speculative:
                     # Seed the drafter's context: full prompt (prefix-cache
                     # hits included — the host has the whole prompt even when
-                    # the insert only saw the suffix) plus the first token.
+                    # the insert only saw the suffix). The chunk puts the
+                    # first token at [slot, p] on the device, _drain() here.
                     self._history[slot, :p] = ids
-                    self._history[slot, p] = token
-                    self._history[slot, p + 1:] = 0
-                self._slot_pages[slot] = pages
+                    self._history[slot, p:] = 0
                 self._page_table[slot] = page_row
-            else:
-                # One-token request: its pages release immediately — but a
-                # prefix it just registered stays CACHED for the next hit.
-                self.pool.release(pages)
-                self._finish(result, "eos" if token == eos else "length", now=now)
+            # else a one-token request: the chunk sees an idle slot (position
+            # 0, the scratch row); _drain() finishes it and releases its pages
+            # — a prefix it just registered stays CACHED for the next hit.
         self._update_occupancy_gauges()
-        return events, device_wait_s
 
     def _routed_pairs(self, bucket: int) -> Dict[str, int]:
         """`routed_pairs` of an insert, for its span: the (token, expert) pairs
@@ -1712,8 +1749,14 @@ class ContinuousBatcher:
 
     def _chunk_operands(self) -> List[Any]:
         """The decode-chunk dispatch's operand list: device-resident state
-        (params, donated cache/presence, rng) plus this cycle's push of the
-        small per-slot host mirrors."""
+        (params, donated cache/presence, rng, the inserts' first tokens) plus
+        this cycle's push of the small per-slot host mirrors and of `fresh`,
+        the slots whose token is the one their insert left on the device."""
+        fresh = self._no_fresh
+        if self._fresh:
+            fresh = np.zeros(self.num_slots, bool)
+            fresh[self._fresh] = True
+            fresh = jnp.asarray(fresh)
         args = [
             self.params,
             self._cache,
@@ -1727,6 +1770,8 @@ class ContinuousBatcher:
             jnp.asarray(self._pen),
             jnp.asarray(self._page_table),
             self._rng,
+            self._first_token,
+            fresh,
         ]
         if self.speculative:
             args.append(jnp.asarray(self._history))
@@ -1741,49 +1786,104 @@ class ContinuousBatcher:
 
     def step(self) -> List[Tuple[int, List[int]]]:
         """One serving cycle: expire deadlines → admit → one decode-chunk
-        dispatch → drain the packed stream. Returns `(request_id, new_tokens)`
-        events in stream order (admissions' first tokens included).
+        dispatch → ONE wait → drain the first tokens and the packed stream.
+        Returns `(request_id, new_tokens)` events in stream order (admissions'
+        first tokens included, each ahead of its request's chunk tokens).
+
+        A step enqueues every device program it has — each admission's insert,
+        then the decode chunk — before it blocks on anything, and blocks once,
+        on the chunk's readback (which brings the inserts' first tokens too;
+        on the first-token buffer alone when nothing is left to decode).
+        Nothing is in flight when it returns.
 
         One span tree a step, each also a profiler annotation of its name:
 
-            serve.step ⊃ serve.admit ⊃ serve.insert (one an admission) ⊃ serve.insert.wait
+            serve.step ⊃ serve.admit ⊃ serve.insert (one an admission: dispatch only)
                        ⊃ serve.decode_chunk ⊃ serve.chunk.push, .dispatch, .wait
                        ⊃ serve.drain
 
         `serve.step`, `serve.insert` and `serve.decode_chunk` are recorded (the
         flight recorder's ring stays proportional to dispatches); the others
         are annotations whose seconds ride `serve.step` as `admit_s`, `push_s`,
-        `dispatch_s`, `drain_s` and `device_wait_s` (the inserts' and the
-        chunk's readbacks together). `host_s` is the rest of the step: its own
-        time, with the device's taken out."""
+        `dispatch_s`, `drain_s` and `device_wait_s` (the step's one wait).
+        `host_s` is the rest of the step: its own time, with the device's
+        taken out. `waits` counts the step's blocking device reads (1, or 0
+        for an idle step) and `dispatched_ahead` the programs enqueued before
+        the first of them (inserts + chunk)."""
         if self._closed:
             return []
         tracer = self.tracer
         with tracer.span("serve.step", category="serve") as step_span:
+            waits_before = self._m_device_waits.value
             self._expire_deadlines()
             with tracer.span("serve.admit", category="serve", record=False) as admit_span:
-                events, device_wait_s = self._admit()
-            step_span.annotate(inserts=len(self._first_tokens),
+                self._admit()
+            inserts = len(self._fresh)
+            step_span.annotate(inserts=inserts,
                                admit_s=round(admit_span.duration_s, 6), push_s=0.0, dispatch_s=0.0)
-            drained = None
-            if self._active.any():
-                drained, chunk_wait_s = self._decode_chunk(step_span)
-                device_wait_s += chunk_wait_s
+            events: List[Tuple[int, List[int]]] = []
+            drained, device_wait_s = None, 0.0
+            decoding = bool(self._active.any())
+            if decoding:
+                drained, device_wait_s = self._decode_chunk(step_span)
+            elif inserts:
+                drained, device_wait_s = self._await_first_tokens()
             with tracer.span("serve.drain", category="serve", record=False) as drain_span:
                 if drained is not None:
                     self._drain(events, *drained)
                 self._hand_back()
+            dispatched = inserts + decoding
+            if dispatched:
+                self._m_dispatching_steps.inc()
             step_span.annotate(
                 drain_s=round(drain_span.duration_s, 6),
                 device_wait_s=round(device_wait_s, 6),
                 host_s=round(step_span.duration_s - device_wait_s, 6),
+                waits=int(self._m_device_waits.value - waits_before),
+                dispatched_ahead=dispatched,
             )
         return events
 
+    def _read_back(self, span_name: str, values):
+        """The step's ONE blocking device read, under the annotation
+        `span_name`: `values` on the host, and the seconds it waited.
+        jax.device_get — np.asarray / int() on a device value are IMPLICIT
+        reads, which an armed transfer guard rejects on a TPU."""
+        self._m_device_waits.inc()
+        with self.tracer.span(span_name, category="serve", record=False) as wait_span:
+            host = jax.device_get(values)
+        return host, wait_span.duration_s
+
+    def _wait_failed(self, exc: Exception, what: str):
+        """A failure at the step's dispatches or its wait: dispatch is async
+        on accelerators, so an insert's or the chunk's device-side failure
+        surfaces at the readback, and the programs share the donated cache —
+        the in-flight state is unrecoverable, so every in-flight request
+        errors (partial tokens kept; this step's admissions with none). The
+        engine itself stays up: slots free, the queue keeps draining, new
+        admissions rebuild their own cache rows from scratch."""
+        if self.trace_guard is not None:
+            self.trace_guard.observe(exc)
+        in_flight = sum(r is not None for r in self._slot_request)
+        logger.warning("%s failed; erroring %d in-flight request(s): %r", what, in_flight, exc)
+        self._abort_in_flight(exc)
+        return None, 0.0
+
+    def _await_first_tokens(self):
+        """The wait of a step that admitted and has nothing to decode (only
+        one-token requests): the first-token buffer alone. Returns what
+        _drain() takes, or None when the read failed."""
+        try:
+            first, wait_s = self._read_back("serve.first_tokens.wait", self._first_token)
+        except Exception as exc:  # noqa: BLE001
+            return self._wait_failed(exc, "first-token readback")
+        return (first, None), wait_s
+
     def _decode_chunk(self, step_span):
         """Push the slot mirrors, dispatch the decode chunk and read its
-        outputs back, under `serve.decode_chunk`; `push_s` and `dispatch_s` go
-        on `step_span`. Returns what _drain() takes — None when the dispatch
+        outputs and the inserts' first tokens back, under
+        `serve.decode_chunk`; `push_s` and `dispatch_s` go on `step_span`.
+        Returns what _drain() takes — None when the dispatch or the wait
         failed (every in-flight request then errored) — and the seconds the
         readback waited for the device."""
         tracer = self.tracer
@@ -1803,30 +1903,17 @@ class ContinuousBatcher:
                     operands = self._chunk_operands()
                 with tracer.span("serve.chunk.dispatch", category="serve", record=False) as dispatch_span:
                     out = self._chunk_fn(*operands)
-                # ONE explicit drain of everything the host needs (jax.device_get:
-                # np.asarray / int() on a device value are IMPLICIT reads, which an
-                # armed transfer guard rejects on a TPU). The readback sits INSIDE
-                # the try: on accelerators the dispatch is async, so a device-side
-                # failure surfaces here rather than at the enqueue above — it is
-                # the same blast radius.
-                with tracer.span("serve.chunk.wait", category="serve", record=False) as wait_span:
-                    host = jax.device_get(out[2:6] + out[7:])
+                # ONE explicit drain of everything the host needs. It sits
+                # INSIDE the try: see _wait_failed().
+                (first, host), wait_s = self._read_back(
+                    "serve.chunk.wait",
+                    (self._first_token if self._fresh else None, out[2:6] + out[7:]),
+                )
                 step_span.annotate(push_s=round(push_span.duration_s, 6),
                                    dispatch_s=round(dispatch_span.duration_s, 6))
                 chunk_span.annotate(**self._chunk_counts(host))
         except Exception as exc:  # noqa: BLE001
-            if self.trace_guard is not None:
-                self.trace_guard.observe(exc)
-            # The ONE shared executable covers every slot: if the dispatch itself
-            # dies the in-flight cache state is unrecoverable, so every in-flight
-            # request errors (partial tokens kept) — but the engine itself stays
-            # up: slots free, the queue keeps draining, new admissions rebuild
-            # their own cache rows from scratch.
-            in_flight = sum(r is not None for r in self._slot_request)
-            logger.warning("decode chunk dispatch failed; erroring %d in-flight request(s): %r",
-                           in_flight, exc)
-            self._abort_in_flight(exc)
-            return None, 0.0
+            return self._wait_failed(exc, "decode chunk dispatch")
         self._cache, self._presence = out[0], out[1]
         self._rng = out[6]
         self._m_chunks.inc()
@@ -1837,7 +1924,7 @@ class ContinuousBatcher:
         # np.array (copy): these mirrors are written in-place at the next
         # admission, and a drained buffer may be a read-only view.
         mirrors = tuple(np.array(x) for x in host[:4])
-        return (mirrors, host[4][: int(host[5])], pos_before), wait_span.duration_s
+        return (first, (mirrors, host[4][: int(host[5])], pos_before)), wait_s
 
     def _live_page_counts(self) -> Dict[str, int]:
         """What the KV read is about to visit, from the host mirrors: the
@@ -1896,14 +1983,37 @@ class ContinuousBatcher:
             )
         return counts
 
-    def _drain(self, events: List[Tuple[int, List[int]]], mirrors, packed, pos_before):
-        """Hand the chunk's packed `(slot, token)` stream to its requests,
-        adopt the device's slot state, and finish what ended."""
+    def _drain(self, events: List[Tuple[int, List[int]]], first_token, chunk):
+        """Hand this step's admissions their first tokens (`first_token`: the
+        buffer the inserts wrote, on the host now) and then the chunk's packed
+        `(slot, token)` stream to its requests, adopt the device's slot state,
+        and finish what ended. `chunk` is None in a step that decoded nothing."""
+        now = time.perf_counter()
+        self.tracer.recorder.poll()  # serve the `trace dump` touch file
+        for slot in self._fresh:
+            result = self._slot_request[slot]
+            token = int(first_token[slot])
+            result.tokens.append(token)
+            result.first_token_time = now
+            self._first_tokens.append(result)
+            events.append((result.request_id, [token]))
+            span = self._request_spans.get(result.request_id)
+            if span is not None:
+                span.event("first_token")
+            if self._rem[slot] == 0:  # a one-token request: no chunk ever saw it
+                self._finish(result, "eos" if token == self._eos[slot] else "length",
+                             now=now, slot=slot)
+            elif self.speculative:
+                self._history[slot, self._pos[slot]] = token  # as the chunk did on the device
+            # A first token that is the request's EOS: the chunk cleared the
+            # slot's `active`, and the sweep below finishes it as "eos".
+        self._fresh.clear()
+        if chunk is None:
+            return
+        mirrors, packed, pos_before = chunk
         per_slot: Dict[int, List[int]] = {}
         for slot, tok in packed:
             per_slot.setdefault(int(slot), []).append(int(tok))
-        now = time.perf_counter()
-        self.tracer.recorder.poll()  # serve the `trace dump` touch file
         for slot, toks in per_slot.items():
             result = self._slot_request[slot]
             if result is None:  # defensive: stream for a freed slot

@@ -92,12 +92,13 @@ class Pool:
         padded[0, : len(suffix)] = suffix
         row = np.zeros((engine.pages_per_slot,), np.int32)
         row[: len(pages)] = pages
-        token, self.cache, _, _ = engine._insert_fn(bucket)(
+        first, self.cache, _, _ = engine._insert_fn(bucket)(
             engine.params, self.cache, None, jnp.asarray(padded), _operand(len(suffix), np.int32),
             _operand(matched_len, np.int32), _operand(matched_pages, np.int32), jnp.asarray(row),
-            _operand(slot, np.int32), _operand(1.0, np.float32), _operand(1.0, np.float32), engine._rng)
+            _operand(slot, np.int32), _operand(1.0, np.float32), _operand(1.0, np.float32), engine._rng,
+            engine._new_first_token())
         self.table[slot] = row
-        return int(token)
+        return int(first[slot])
 
     def _only(self, slot):
         """The page tables with every other slot idle: at position 0 of the

@@ -349,6 +349,170 @@ def test_the_contiguous_layout_is_refused_by_the_engine_and_both_clis(capsys):
     assert parser.parse_args(["serve"]).command == "serve"
 
 
+# ------------------------------------------------- one wait a step (ISSUE 33)
+# A step enqueues every insert and the decode chunk before it blocks on
+# anything, and blocks once: an admission's first token stays on the device
+# until the chunk's own readback.
+
+_ENGINE_KINDS = {
+    "plain": {},
+    "penalty": {"use_repetition_penalty": True},
+    "speculative": {"speculative": True, "draft_tokens": 2},
+}
+
+
+def _count_device_gets(monkeypatch):
+    """Every `jax.device_get` from here on appends to the returned list."""
+    calls = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get", lambda tree: (calls.append(tree), real(tree))[1])
+    return calls
+
+
+@pytest.mark.parametrize("admissions", [0, 1, 3])
+@pytest.mark.parametrize("kind", list(_ENGINE_KINDS))
+def test_step_dispatches_everything_and_waits_once(monkeypatch, kind, admissions):
+    """Whatever a step admits, it reads the device back once, after every
+    insert and the chunk are enqueued (`waits` / `dispatched_ahead` of
+    `serve.step` say the same); a request's first token comes ahead of its
+    chunk tokens in the step's events; nothing is traced twice over the run;
+    and the tokens are the static Generator's."""
+    from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
+    from accelerate_tpu.telemetry.tracing import Tracer
+
+    model = _model()
+    rng = np.random.default_rng(33)
+    recorder = FlightRecorder()
+    engine = ContinuousBatcher(
+        model, num_slots=4, max_length=64, chunk_size=3,
+        tracer=Tracer(recorder=recorder, category="serve"), **_ENGINE_KINDS[kind],
+    )
+    penalty = {"repetition_penalty": 1.5} if kind == "penalty" else {}
+    resident = rng.integers(1, 128, (5,)).astype(np.int32)
+    engine.submit(Request(100, resident, max_new_tokens=30, **penalty))
+    engine.step()  # the resident request decodes through the step under test
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 3, 11)[:admissions]]
+    for i, p in enumerate(prompts):
+        engine.submit(Request(i, p, max_new_tokens=6, **penalty))
+
+    calls = _count_device_gets(monkeypatch)
+    steps_before = len([r for r in recorder.records() if r["name"] == "serve.step"])
+    events = engine.step()
+    assert len(calls) == 1
+    monkeypatch.undo()
+    step = [r for r in recorder.records() if r["name"] == "serve.step"][steps_before]["attrs"]
+    assert (step["inserts"], step["waits"], step["dispatched_ahead"]) == (admissions, 1, admissions + 1)
+    assert not engine._fresh  # nothing is in flight when step() returns
+
+    by_request = {}
+    for rid, toks in events:
+        by_request.setdefault(rid, []).append(toks)
+    assert set(by_request) == {100, *range(admissions)}
+    for i in range(admissions):
+        first, *rest = by_request[i]
+        assert len(first) == 1 and rest, by_request[i]  # the first token, then the chunk's
+        assert first + [t for toks in rest for t in toks] == engine.results[i].tokens
+        if kind == "speculative":  # the drafter's context, first token included
+            slot, n = engine._slot_of(i), len(engine.results[i].tokens)
+            np.testing.assert_array_equal(
+                engine._history[slot, : prompts[i].size + n],
+                np.concatenate([prompts[i], engine.results[i].tokens]),
+            )
+    # stream order: the admissions' first tokens, in admission order, lead the step's events
+    assert [rid for rid, _ in events[:admissions]] == list(range(admissions))
+
+    outputs = engine.run()
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(outputs[i], _static_reference(model, p, 6, **penalty))
+    np.testing.assert_array_equal(outputs[100], _static_reference(model, resident, 30, **penalty))
+    assert engine.trace_counts["decode_chunk"] == 1
+    assert engine.trace_counts["insert"] == len(engine._insert_fns) == len({8, *(8, 4, 16)[:admissions]})
+    assert engine.stats["waits_per_step"] == 1.0
+    idle = engine.step()  # nothing queued, nothing active: no dispatch, no wait
+    assert idle == [] and engine.stats["waits_per_step"] == 1.0
+    assert [r for r in recorder.records() if r["name"] == "serve.step"][-1]["attrs"]["waits"] == 0
+
+
+def _first_greedy_token(model, prompt):
+    return int(_static_reference(model, prompt, 1)[0])
+
+
+@pytest.mark.parametrize("kind", list(_ENGINE_KINDS))
+def test_first_token_eos_ends_the_request_on_the_device(kind):
+    """A first token that is the request's EOS: the host has not seen it when
+    it pushes the chunk's operands, so the chunk clears the slot itself — the
+    request ends with that one token, as "eos", beside a neighbour that
+    decodes on undisturbed."""
+    model = _model()
+    rng = np.random.default_rng(34)
+    prompt, other = (rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 9))
+    eos = _first_greedy_token(model, prompt)
+    engine = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=3, **_ENGINE_KINDS[kind])
+    engine.submit(Request(0, prompt, max_new_tokens=8, eos_token_id=eos))
+    engine.submit(Request(1, other, max_new_tokens=7))
+    events = engine.step()
+    assert events[0] == (0, [eos]) and [rid for rid, _ in events].count(0) == 1
+    assert engine.results[0].finished and engine.results[0].finish_reason == "eos"
+    assert engine.results[0].tokens == [eos]
+    assert engine.free_slots == 1 and engine.pool.pages_in_use == len(engine._slot_pages[1])
+    outputs = engine.run()
+    np.testing.assert_array_equal(outputs[1], _static_reference(model, other, 7))
+
+
+@pytest.mark.parametrize("slots", [1, 2, 3])
+def test_one_token_requests_in_one_step_each_get_their_own_token(monkeypatch, slots):
+    """A `max_new_tokens == 1` admission holds its slot until the step's drain,
+    so that two of them admitted together never share an entry of the
+    first-token buffer: with one slot they take a step each, with more they
+    share a step — whose only work is their inserts: no chunk, and the one
+    wait is the read of the buffer alone. Pages all come back."""
+    model = _model()
+    rng = np.random.default_rng(35)
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (5, 12, 7)]
+    expected = [_first_greedy_token(model, p) for p in prompts]
+    assert len(set(expected)) > 1, "the prompts must tell the slots apart"
+    engine = ContinuousBatcher(model, num_slots=slots, max_length=32, chunk_size=3)
+    for i, p in enumerate(prompts):
+        engine.submit(Request(i, p, max_new_tokens=1))
+    calls = _count_device_gets(monkeypatch)
+    steps = []
+    while engine.pending:
+        steps.append(engine.step())
+    assert [len(events) for events in steps] == {1: [1, 1, 1], 2: [2, 1], 3: [3]}[slots]
+    assert len(calls) == len(steps)  # one wait a step
+    assert [e for events in steps for e in events] == [(i, [t]) for i, t in enumerate(expected)]
+    assert all(r.finish_reason == "length" and r.first_token_time is not None
+               for r in engine.results.values())
+    assert engine.stats["chunks"] == 0 and engine.stats["waits_per_step"] == 1.0
+    assert engine.free_slots == slots and engine.pool.pages_in_use == 0
+
+
+def test_one_token_request_beside_a_decoding_slot_and_a_prefix_hit():
+    """One step admits a one-token request (which the chunk must see as an
+    idle slot) and a longer one; a later request shares the longer one's
+    prompt prefix from the prefix cache, whose registration needed no token.
+    All match the static path."""
+    model = _model()
+    rng = np.random.default_rng(36)
+    shared = rng.integers(1, 128, (16,)).astype(np.int32)  # two full pages of 8
+    long_a = np.concatenate([shared, rng.integers(1, 128, (3,)).astype(np.int32)])
+    long_b = np.concatenate([shared, rng.integers(1, 128, (5,)).astype(np.int32)])
+    single = rng.integers(1, 128, (9,)).astype(np.int32)
+    engine = ContinuousBatcher(model, num_slots=3, max_length=64, chunk_size=3, page_size=8)
+    engine.submit(Request(0, single, max_new_tokens=1))
+    engine.submit(Request(1, long_a, max_new_tokens=6))
+    events = engine.step()
+    assert [rid for rid, _ in events[:2]] == [0, 1] and engine.results[0].finished
+    assert engine.free_slots == 2
+    engine.submit(Request(2, long_b, max_new_tokens=5))
+    engine.submit(Request(3, long_b, max_new_tokens=1))  # a one-token request off the cache
+    outputs = engine.run()
+    assert engine.stats["prefix_cache"]["hits"] >= 4  # two pages, twice
+    for rid, (p, m) in enumerate([(single, 1), (long_a, 6), (long_b, 5), (long_b, 1)]):
+        np.testing.assert_array_equal(outputs[rid], _static_reference(model, p, m))
+    assert engine.pool.pages_in_use == 0
+
+
 # ------------------------------------------------------------- fault isolation
 # The serving-hardening contract: the engine degrades PER-REQUEST (deadline,
 # cancel, backpressure, admission/step errors), never per-process.
@@ -497,6 +661,44 @@ def test_chunk_dispatch_failure_errors_inflight_but_engine_survives():
     engine.submit(Request(2, prompts[0], max_new_tokens=4))
     outputs = engine.run()
     np.testing.assert_array_equal(outputs[2], _static_reference(model, prompts[0], 4))
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("decoding", [True, False])
+def test_device_failure_at_the_steps_wait_errors_admissions_and_inflight(monkeypatch, decoding):
+    """An insert's device-side failure surfaces at the step's one wait, where
+    it cannot be told from the chunk's: the requests admitted in that step
+    error with no tokens, the in-flight ones keep their partial tokens, and
+    the engine serves the next request correctly — whether the wait was the
+    chunk's readback or (a step of one-token admissions) the buffer's alone."""
+    model = _model()
+    rng = np.random.default_rng(19)
+    engine = ContinuousBatcher(model, num_slots=3, max_length=64, chunk_size=2)
+    prompts = [rng.integers(1, 128, (4,)).astype(np.int32) for _ in range(3)]
+    if decoding:
+        engine.submit(Request(0, prompts[0], max_new_tokens=8))
+        engine.step()
+    engine.submit(Request(1, prompts[1], max_new_tokens=6 if decoding else 1))
+    engine.submit(Request(2, prompts[2], max_new_tokens=1))
+
+    def dying_read(tree):
+        raise RuntimeError("device halted in an insert")
+
+    monkeypatch.setattr(jax, "device_get", dying_read)
+    assert engine.step() == []
+    monkeypatch.undo()
+
+    for rid in (1, 2):
+        assert engine.results[rid].finish_reason == "error"
+        assert "device halted" in engine.results[rid].error and engine.results[rid].tokens == []
+    if decoding:
+        assert engine.results[0].finish_reason == "error" and engine.results[0].tokens
+    assert engine.free_slots == 3 and not engine.pending and not engine._fresh
+    assert engine.pool.pages_in_use == 0
+
+    engine.submit(Request(3, prompts[0], max_new_tokens=4))
+    outputs = engine.run()
+    np.testing.assert_array_equal(outputs[3], _static_reference(model, prompts[0], 4))
 
 
 @pytest.mark.faults

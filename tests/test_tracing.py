@@ -286,13 +286,18 @@ def test_serving_request_lifecycle_spans():
 
 def test_serving_step_span_tree_and_hand_back():
     """One `serve.step` a step() over its inserts and its decode chunk; the
-    step's host and device-wait seconds add up to it; every request is handed
-    back once, and TTFT is observed there (a one-token request and one that
+    step's host and device-wait seconds add up to it, and it waits once
+    (`waits`), after everything it dispatches (`dispatched_ahead`): an insert
+    is dispatch only, with no `serve.insert.wait` under it; every request is
+    handed back once, its first token on the host only since the step's drain
+    (`held_s`), and TTFT is observed there (a one-token request and one that
     ends in its first chunk included: their spans wait for the event)."""
     from accelerate_tpu.serving import ContinuousBatcher, Request
 
     recorder = FlightRecorder()
     tracer = Tracer(recorder=recorder, category="serve")
+    opened, open_span = [], tracer.span
+    tracer.span = lambda name, **kwargs: (opened.append(name), open_span(name, **kwargs))[1]
     engine = ContinuousBatcher(_tiny_llama(), num_slots=2, max_length=64, chunk_size=4,
                                tracer=tracer)
     rng = np.random.default_rng(1)
@@ -326,12 +331,21 @@ def test_serving_step_span_tree_and_hand_back():
                  + (chunks[0]["duration_s"] - attrs["push_s"] - attrs["dispatch_s"] if chunks else 0.0))
         assert parts <= step["duration_s"] + 2e-4
         assert attrs["device_wait_s"] >= sum(c["attrs"]["device_wait_s"] for c in inserts) - 1e-5
+        assert all(c["attrs"]["device_wait_s"] == 0.0 for c in inserts)  # dispatch only
+        assert attrs["dispatched_ahead"] == len(inserts) + len(chunks)
+        assert attrs["waits"] == (1 if children else 0)
         for child in children:
             assert step["start_unix"] <= child["start_unix"] and child["end_unix"] <= step["end_unix"]
     assert sum(s["attrs"]["inserts"] for s in steps) == len(lengths)
     assert not [r for r in records if r["name"] in (
         "serve.admit", "serve.insert.wait", "serve.chunk.push", "serve.chunk.dispatch",
-        "serve.chunk.wait", "serve.drain")]  # annotations only
+        "serve.chunk.wait", "serve.first_tokens.wait", "serve.drain")]  # annotations only
+    # the tree of a step: no wait under an insert, one wait under the chunk
+    assert set(opened) == {"serve.step", "serve.admit", "serve.insert", "serve.decode_chunk",
+                           "serve.chunk.push", "serve.chunk.dispatch", "serve.chunk.wait",
+                           "serve.drain"}
+    assert opened.count("serve.chunk.wait") == len([s for s in steps if s["attrs"]["waits"]])
+    assert engine.stats["waits_per_step"] == 1.0
 
     requests = {r["attrs"]["request_id"]: r for r in records if r["name"] == "serve.request"}
     ttft_sum = 0.0
@@ -340,6 +354,8 @@ def test_serving_step_span_tree_and_hand_back():
         assert [e["name"] for e in requests[rid]["events"]].count("handed_back") == 1
         handed = events["handed_back"]["attrs"]
         assert handed["held_s"] >= 0 and handed["ttft_s"] >= handed["held_s"]
+        # the token reaches the host as its step drains: held for part of that drain, no chunk
+        assert handed["held_s"] <= max(s["attrs"]["drain_s"] for s in steps) + 1e-4
         assert events["admitted"]["attrs"]["queue_wait_s"] <= handed["ttft_s"]
         assert requests[rid]["attrs"]["tokens"] == n
         ttft_sum += handed["ttft_s"]
@@ -476,7 +492,7 @@ def test_decode_chunk_and_insert_programs_carry_named_scopes():
     lowered = insert.lower(
         engine.params, engine._cache, engine._presence, np.zeros((1, bucket), np.int32),
         np.int32(6), np.int32(0), np.int32(0), np.zeros((engine.pages_per_slot,), np.int32),
-        np.int32(0), np.float32(1.0), np.float32(1.0), engine._rng)
+        np.int32(0), np.float32(1.0), np.float32(1.0), engine._rng, engine._first_token)
     text = lowered.as_text(debug_info=True)
     for scope in ("kv_read", "kv_write", "sample"):
         assert _has_scope(text, scope), scope
