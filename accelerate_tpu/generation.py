@@ -250,25 +250,27 @@ def make_causal_programs(
 
 
 def make_cached_prefill_program(module, resolve):
-    """`prefill_with_cache(params, cache, input_ids, positions)` — prefill a
+    """`prefill_with_cache(params, cache, input_ids, positions, attention_mask=None)` — prefill a
     token block INTO AN EXISTING dense decode cache, continuing at the cache's
     own `cache_index` instead of position 0, and return the full `[B, S, V]`
     logits plus the mutated cache. The paged serving engine's shared-prefix
     insert drives this: the prefix pages are gathered into a batch-1 dense cache
     (`cache_index` = matched length), only the unmatched SUFFIX runs through the
     model here — the prefill FLOPs a shared system prompt would have cost are
-    simply never issued — and the result is scattered back into pool pages."""
+    simply never issued — and the result is scattered back into pool pages.
+    `attention_mask` ([B, S], 1 = real) is for a family whose layers run a
+    recurrence over the block: a bucket's padding must leave its state alone."""
 
     from .ops.quantization import weight_autocast
 
     weight_dtype = getattr(getattr(module, "config", None), "weight_dtype", "bf16")
 
-    def prefill_with_cache(params, cache, input_ids, positions):
+    def prefill_with_cache(params, cache, input_ids, positions, attention_mask=None):
         with weight_autocast(weight_dtype):
             logits, mutated = module.apply(
                 {**resolve(params), "cache": cache},
                 input_ids,
-                None,
+                attention_mask,
                 positions,
                 mutable=["cache"],
             )

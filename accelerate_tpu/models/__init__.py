@@ -35,6 +35,13 @@ from .latent_moe import (
     kimi_vl_a3b_text,
     latent_moe_tiny,
 )
+from .olmo_hybrid import (
+    OlmoHybridConfig,
+    OlmoHybridForCausalLM,
+    create_olmo_hybrid_model,
+    olmo_hybrid_7b,
+    olmo_hybrid_tiny,
+)
 from .opt import OPTConfig, OPTForCausalLM, create_opt_model, opt_30b, opt_tiny
 from .t5 import (
     T5Config,
@@ -63,6 +70,8 @@ MODEL_REGISTRY = {
     "gpt-neox-tiny": ("gpt_neox", gpt_neox_tiny),
     "kimi-vl-a3b-text": ("latent_moe", kimi_vl_a3b_text),
     "latent-moe-tiny": ("latent_moe", latent_moe_tiny),
+    "olmo-hybrid-7b": ("olmo_hybrid", olmo_hybrid_7b),
+    "olmo-hybrid-tiny": ("olmo_hybrid", olmo_hybrid_tiny),
     "opt-30b": ("opt", opt_30b),
     "opt-tiny": ("opt", opt_tiny),
     "t0pp-11b": ("t5", t0pp_11b),
@@ -81,6 +90,7 @@ CREATE_BY_FAMILY = {
     "opt": create_opt_model,
     "t5": create_t5_model,
     "latent_moe": create_latent_moe_model,
+    "olmo_hybrid": create_olmo_hybrid_model,
 }
 
 # family -> (flax module class name, LayeredApply class) for models shipping a
@@ -255,6 +265,26 @@ def _latent_moe_cfg(c: LatentMoEConfig) -> dict:
     }
 
 
+def _olmo_hybrid_cfg(c: OlmoHybridConfig) -> dict:
+    return {
+        "model_type": "olmo_hybrid",
+        "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size,
+        "num_hidden_layers": c.num_hidden_layers,
+        "num_attention_heads": c.num_attention_heads,
+        "num_key_value_heads": c.num_key_value_heads,
+        "intermediate_size": c.intermediate_size,
+        "layer_types": list(c.layer_types),
+        "linear_num_key_heads": c.linear_num_key_heads,
+        "linear_num_value_heads": c.linear_num_value_heads,
+        "linear_key_head_dim": c.linear_key_head_dim,
+        "linear_value_head_dim": c.linear_value_head_dim,
+        "linear_conv_kernel_dim": c.linear_conv_kernel_dim,
+        "hidden_act": "silu",
+        "tie_word_embeddings": False,
+    }
+
+
 def _bert_cfg(c: BertConfig) -> dict:
     return {
         "model_type": "bert",
@@ -291,6 +321,7 @@ _CFG_BUILDERS = {
     "opt": _opt_cfg,
     "t5": _t5_cfg,
     "latent_moe": _latent_moe_cfg,
+    "olmo_hybrid": _olmo_hybrid_cfg,
 }
 
 

@@ -111,6 +111,7 @@ from .utils.operations import (
     _leaf_name,
     tree_gather_pages,
     tree_scatter_pages,
+    tree_slot_state_nbytes,
     tree_zero_cache_tail,
 )
 
@@ -444,11 +445,14 @@ class ContinuousBatcher:
         # Prefix sharing needs the suffix-only insert to seed presence from the
         # WHOLE prompt, which the suffix program never sees — repetition-penalty
         # engines therefore run without prefix reuse.
-        self.use_prefix_cache = bool(prefix_cache) and not use_repetition_penalty
-        if prefix_cache and use_repetition_penalty:
-            logger.info(
-                "prefix cache disabled: use_repetition_penalty needs whole-prompt "
-                "presence seeding, which shared-prefix inserts cannot provide"
+        self.use_prefix_cache = bool(prefix_cache)
+        self.prefix_cache_disabled_reason: Optional[str] = None
+        if not prefix_cache:
+            self.prefix_cache_disabled_reason = "prefix_cache=False"
+        elif use_repetition_penalty:
+            self._disable_prefix_cache(
+                "use_repetition_penalty needs whole-prompt presence seeding, which "
+                "shared-prefix inserts cannot provide"
             )
 
         params_tree = model.params if "params" in model.params else {"params": model.params}
@@ -509,6 +513,51 @@ class ContinuousBatcher:
                 )
             quant_cfg["weight_dtype"] = self.weight_dtype
         prefill_cfg = dataclasses.replace(base, decode_cache_length=cache_len, **quant_cfg)
+        prefill_module = type(model.module)(prefill_cfg)
+        self._resolve = resolve
+        self._cached_prefill_raw = make_cached_prefill_program(prefill_module, resolve)
+        # The dense batch-1 cache STRUCTURE the insert materializes by
+        # gathering pool pages (zero compute/compile: eval_shape only). The
+        # weight_autocast wrap matters even for eval_shape: int8 engines
+        # hold quantized kernel entries the raw Dense can't consume.
+        from .ops.quantization import weight_autocast
+
+        dummy = jnp.zeros((1, 1), jnp.int32)
+        dpos = jnp.zeros((1, 1), jnp.int32)
+        with weight_autocast(self.weight_dtype):
+            self._dense_cache_struct = jax.eval_shape(
+                lambda p: prefill_module.apply(resolve(p), dummy, None, dpos, mutable=["cache"])[1]["cache"],
+                self.params,
+            )
+        # What a family keeps a request is read off its cache tree: page
+        # leaves (`cached_key` / `cached_value` / `cached_latent`) and, for a
+        # layer with a recurrence, BY-SLOT leaves (`recurrent_state`,
+        # `conv_state`: utils/operations) — a fixed state a slot that the
+        # insert writes whole and the decode chunk carries and updates. What
+        # is not built for such a state is refused here, by name.
+        self._state_bytes_per_slot = tree_slot_state_nbytes(self._dense_cache_struct)
+        if self._state_bytes_per_slot:
+            family = type(model.module).__name__
+            if self.speculative:
+                raise ValueError(
+                    f"speculative=True with {family}: its cache holds recurrent state by slot, "
+                    "which a verify block advances past drafts it may reject — speculative "
+                    "verify with a state roll-back (speculative.py) is not built; use "
+                    "speculative=False"
+                )
+            if self.tp > 1:
+                raise ValueError(
+                    f"tp={self.tp} with {family}: its cache holds recurrent state by slot, and "
+                    "the cache shardings (parallel/sharding.derive_tp_cache_shardings) place "
+                    "page pools by KV head only — a tensor-parallel layout for by-slot state is "
+                    "not built; use tp=1"
+                )
+            if self.use_prefix_cache:
+                self._disable_prefix_cache(
+                    f"{family} keeps recurrent state by slot, and a shared prefix hands back "
+                    "pages of tokens but not the state at that boundary (state snapshots are "
+                    "not built)"
+                )
         if self.mesh is not None:
             # The slot-decode modules carry the submesh so the Pallas page-walk
             # kernels can shard_map over the KV-head grid; prefill stays
@@ -534,27 +583,11 @@ class ContinuousBatcher:
             decode_page_size=self.page_size, decode_num_pages=self.num_pages,
             decode_attention_impl=self.attention_impl, **quant_cfg,
         )
-        prefill_module = type(model.module)(prefill_cfg)
         step_module = type(model.module)(step_cfg)
         _, self._step_raw, self._verify_raw = make_causal_programs(
             step_module, resolve, step_mask_operand=True, verify_block=True
         )
         self._step_module = step_module
-        self._resolve = resolve
-        self._cached_prefill_raw = make_cached_prefill_program(prefill_module, resolve)
-        # The dense batch-1 cache STRUCTURE the insert materializes by
-        # gathering pool pages (zero compute/compile: eval_shape only). The
-        # weight_autocast wrap matters even for eval_shape: int8 engines
-        # hold quantized kernel entries the raw Dense can't consume.
-        from .ops.quantization import weight_autocast
-
-        dummy = jnp.zeros((1, 1), jnp.int32)
-        dpos = jnp.zeros((1, 1), jnp.int32)
-        with weight_autocast(self.weight_dtype):
-            self._dense_cache_struct = jax.eval_shape(
-                lambda p: prefill_module.apply(resolve(p), dummy, None, dpos, mutable=["cache"])[1]["cache"],
-                self.params,
-            )
         # What the XLA read's loop is sized from (`_live_page_counts`): the
         # prefill cache is K as the model computes it — [1, length, KV heads,
         # head_dim] in the compute dtype ([1, length, row] for a latent
@@ -732,6 +765,18 @@ class ContinuousBatcher:
             "values of full heads, or a latent family's one row a layer",
         )
         self._m_kv_bytes_per_token.set(self._kv_bytes_per_token)
+        self._m_state_bytes_per_slot = self.metrics.gauge(
+            "serving_state_bytes_per_slot",
+            help="stored bytes a slot holds in by-slot leaves (recurrent and convolution "
+            "state of layers with a recurrence), all layers, whatever the request's length "
+            "(0 for a family that keeps pages alone)",
+        )
+        self._m_state_bytes_per_slot.set(self._state_bytes_per_slot)
+        self._m_state_share = self.metrics.gauge(
+            "serving_state_share_of_cache",
+            help="by-slot state of the active slots over that state plus their live pages' "
+            "bytes, as the last decode chunk was dispatched",
+        )
         self._m_expert_load = self.metrics.gauge(
             "serving_expert_load_max_over_mean",
             help="the last decode chunk's tokens of the busiest routed expert over the "
@@ -828,6 +873,13 @@ class ContinuousBatcher:
             )
         # One chip: no shardings, the default device. Placed leaves pass through as the same buffers.
         self._params = jax.device_put(value, self._param_shardings)
+
+    def _disable_prefix_cache(self, reason: str):
+        """Serve without prefix reuse, and say why once (`stats["prefix_cache"]`
+        keeps the reason)."""
+        self.use_prefix_cache = False
+        self.prefix_cache_disabled_reason = reason
+        logger.info("prefix cache disabled: %s", reason)
 
     def _carried(self, value):
         """Device state that threads through every dispatch (rng, presence,
@@ -988,6 +1040,7 @@ class ContinuousBatcher:
         V = self.base_config.vocab_size
         P = self.pages_per_slot
         mesh = self.mesh
+        recurrent = bool(self._state_bytes_per_slot)
 
         def insert(
             params, pool_cache, presence, suffix_ids, real_len, matched_len,
@@ -997,7 +1050,11 @@ class ContinuousBatcher:
             with jax.named_scope("kv_read"):
                 dense = tree_gather_pages(pool_cache, dense_struct, page_row, matched_len)
             positions = matched_len + jnp.broadcast_to(jnp.arange(bucket)[None, :], (1, bucket))
-            logits, dense = cached_prefill(params, dense, suffix_ids, positions)
+            # A recurrence runs over the whole bucket: it is told which
+            # positions are real, so that the padding leaves the slot's state
+            # as the last real token left it. Attention needs no such mark.
+            real = (jnp.arange(bucket) < real_len)[None, :] if recurrent else None
+            logits, dense = cached_prefill(params, dense, suffix_ids, positions, real)
             # Zero rows past the prompt before the write-back: the gather
             # resurrects a recycled page's stale content (never attended, but
             # a QUANTIZED scatter folds it into the boundary page's amax
@@ -1008,7 +1065,7 @@ class ContinuousBatcher:
                     jnp.arange(P) < matched_pages, jnp.int32(SCRATCH_PAGE), page_row
                 )
                 pool_cache = constrain_tp_cache(
-                    tree_scatter_pages(pool_cache, dense, write_row), mesh
+                    tree_scatter_pages(pool_cache, dense, write_row, slot), mesh
                 )
             # Logits at the REAL last suffix token (bucket pads sit above it
             # and, being causal, never influenced it).
@@ -1337,10 +1394,14 @@ class ContinuousBatcher:
         view["pages_in_use"] = self.pool.pages_in_use
         view["kv_live_page_share"] = float(self._m_kv_live_page_share.value)
         view["kv_bytes_per_token"] = self._kv_bytes_per_token
+        if self._state_bytes_per_slot:
+            view["state_bytes_per_slot"] = self._state_bytes_per_slot
+            view["state_share_of_cache"] = float(self._m_state_share.value)
         if self._expert_layers:
             view["expert_load_max_over_mean"] = float(self._m_expert_load.value)
         view["prefix_cache"] = {
             "enabled": self.use_prefix_cache,
+            "disabled_reason": self.prefix_cache_disabled_reason,
             "hits": int(self._m_prefix_hits.value),
             "misses": int(self._m_prefix_misses.value),
             "evictions": int(self._m_prefix_evictions.value),
@@ -1638,7 +1699,7 @@ class ContinuousBatcher:
                     # No wait in here since the token stays on the device; kept
                     # so that a step's `device_wait_s` still bounds its inserts'.
                     device_wait_s=0.0,
-                    **self._routed_pairs(bucket),
+                    **self._routed_pairs(bucket), **self._scan_chunks(bucket),
                 ):
                     fn = self._insert_fn(bucket)
                     self._first_token, self._cache, self._presence, self._rng = fn(
@@ -1715,6 +1776,16 @@ class ContinuousBatcher:
         if not self._expert_layers:
             return {}
         return {"routed_pairs": int(bucket) * int(self.base_config.num_experts_per_tok) * self._expert_layers}
+
+    def _scan_chunks(self, bucket: int) -> Dict[str, int]:
+        """`scan_chunks` of an insert, for its span: the chunks its bucket is
+        for a layer's chunked recurrence, pads included. Nothing for a family
+        without recurrent state."""
+        if not self._state_bytes_per_slot:
+            return {}
+        from .ops.delta_rule import CHUNK
+
+        return {"scan_chunks": -(-int(bucket) // CHUNK)}
 
     def _hand_back(self):
         """Runs as step() returns, which is when a client gets the first token
@@ -1940,9 +2011,19 @@ class ContinuousBatcher:
         live = int((self._pos[self._active] // self.page_size + 1).sum())
         window = self.num_slots * self.pages_per_slot
         self._m_kv_live_page_share.set(live / window)
-        return {"live_pages": live, "window_pages": window,
-                "read_blocks": read_blocks(np.where(self._active, self._pos, 0), *self._read_shape),
-                "kv_row_values": self.kv_row_values}
+        counts = {"live_pages": live, "window_pages": window,
+                  "read_blocks": read_blocks(np.where(self._active, self._pos, 0), *self._read_shape),
+                  "kv_row_values": self.kv_row_values}
+        if self._state_bytes_per_slot:
+            # A family with recurrent state: what the chunk's first step reads
+            # and writes whatever the contexts, beside the pages it visits.
+            slots = int(self._active.sum())
+            state = slots * self._state_bytes_per_slot
+            pages = live * self.page_size * self._kv_bytes_per_token
+            self._m_state_share.set(state / (state + pages) if state else 0.0)
+            counts.update(state_bytes_per_slot=self._state_bytes_per_slot, state_slots=slots,
+                          kv_page_bytes=self.page_size * self._kv_bytes_per_token)
+        return counts
 
     def _chunk_counts(self, host) -> Dict[str, int]:
         """What a chunk's readback counts, for its span: the tokens streamed,

@@ -453,6 +453,28 @@ def pad_input_tensors(tensor, batch_size: int, num_processes: int, dim: int = 0)
 # rows has no head axis: [..., num_pages, page_size, row].
 _PAGE_AXIS_FROM_BACK = {"cached_key": 4, "cached_value": 4, "cached_latent": 3}
 
+# BY-SLOT leaves: what a layer with a recurrence keeps a request whatever its
+# length (models/olmo_hybrid.py) — `recurrent_state` [..., slots, key_dim,
+# heads * value_dim] and `conv_state` [..., slots, taps - 1, channels], the
+# slot axis 3 from the back. They live in the same cache tree as the page
+# pools and are found by name as those are; a batch-1 dense cache holds them
+# with one row.
+_SLOT_AXIS_FROM_BACK = {"recurrent_state": 3, "conv_state": 3}
+
+
+def tree_slot_state_nbytes(cache) -> int:
+    """Stored bytes ONE slot holds in the by-slot leaves of a slot cache, all
+    layers; 0 for a cache of page pools alone."""
+    import jax
+
+    total = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        axis_back = _SLOT_AXIS_FROM_BACK.get(_leaf_name(path))
+        if axis_back is not None:
+            slots = leaf.shape[leaf.ndim - axis_back]
+            total += int(np.prod(leaf.shape)) // slots * np.dtype(leaf.dtype).itemsize
+    return total
+
 # Per-page-per-head scale pools of a QUANTIZED paged cache
 # (ops/quantization.py): [..., num_pages, heads] f32, page axis 2 from the
 # back. `_SCALE_OF` maps a K/V pool leaf to its sibling scale leaf; the
@@ -483,7 +505,11 @@ def tree_gather_pages(pool, dense_struct, page_ids, cache_index):
     [1, P*page_size, h, d] row; fill `cache_index` leaves with the traced
     `cache_index` scalar (the number of tokens already valid in the gathered
     prefix). `dense_struct` is the eval_shape pytree of the dense prefill
-    module's cache — it fixes the output tree layout and shapes.
+    module's cache — it fixes the output tree layout and shapes. A BY-SLOT leaf
+    (`recurrent_state`, `conv_state`) comes out as zeros: pages of tokens do
+    not say what state a prefix left behind, so an admission starts its
+    recurrence from nothing (the engine serves such a family with the prefix
+    cache off, `cache_index` 0).
 
     jit-traceable (`page_ids` [P] int32 and `cache_index` may be traced
     operands); the serving engine's paged insert uses this to give a suffix
@@ -573,12 +599,14 @@ def tree_zero_cache_tail(dense, valid_len):
     return jax.tree_util.tree_map_with_path(_zero, dense)
 
 
-def tree_scatter_pages(pool, dense, page_ids):
+def tree_scatter_pages(pool, dense, page_ids, slot=None):
     """Write a batch-1 dense cache back into pool pages (the inverse of
     `tree_gather_pages`): every `cached_key`/`cached_value` leaf is split into
     [P, page_size] blocks and scattered to `pool_leaf[page_ids[j]]`. Leaves the
     pool has no entry for in `dense` (the dense path's `cache_index` scalar,
-    meaningless pool-side) keep the pool's value.
+    meaningless pool-side) keep the pool's value. A BY-SLOT leaf
+    (`recurrent_state`, `conv_state`) is written whole at row `slot` (a traced
+    scalar): the slot's last tenant leaves nothing behind.
 
     Callers that must not rewrite shared read-only prefix pages redirect those
     entries of `page_ids` to the reserved scratch page before calling (the
@@ -635,6 +663,12 @@ def tree_scatter_pages(pool, dense, page_ids):
             axis = leaf.ndim - _SCALE_AXIS_FROM_BACK[name]
             front = jnp.moveaxis(leaf, axis, 0)
             return jnp.moveaxis(front.at[ids].set(scales.astype(leaf.dtype)), 0, axis)
+        if name in _SLOT_AXIS_FROM_BACK and names in dense_leaves:
+            if slot is None:
+                raise ValueError(f"by-slot leaf {'/'.join(names)} needs the slot it is written at")
+            start = [jnp.int32(0)] * leaf.ndim
+            start[leaf.ndim - _SLOT_AXIS_FROM_BACK[name]] = jnp.asarray(slot, jnp.int32)
+            return jax.lax.dynamic_update_slice(leaf, dense_leaves[names].astype(leaf.dtype), start)
         axis_back = _PAGE_AXIS_FROM_BACK.get(name)
         if axis_back is None or names not in dense_leaves:
             return leaf

@@ -315,6 +315,7 @@ _FAMILY_BY_MODULE = {
     "LlamaForCausalLM": "llama",
     "GPTNeoXForCausalLM": "gpt_neox",
     "LatentMoEForCausalLM": "latent_moe",
+    "OlmoHybridForCausalLM": "olmo_hybrid",
 }
 
 

@@ -340,3 +340,95 @@ def test_flash_attention_compiles_for_v5e(v5e, direction, seq):
     else:
         _compile(jax.grad(lambda q, k, v: forward(q, k, v).astype(jnp.float32).sum(), (0, 1, 2)),
                  x, x, x)
+
+
+# The hybrid linear-attention cell's shapes (`olmo-hybrid-7b.chat-saturated`):
+# 48 slots, 30 heads of a 96 x 192 float32 state, 11,520 convolution channels.
+HYBRID_SLOTS, HYBRID_HEADS, HYBRID_DK, HYBRID_DV = 48, 30, 96, 192
+HYBRID_STATE_BYTES = HYBRID_SLOTS * HYBRID_HEADS * HYBRID_DK * HYBRID_DV * 4
+
+
+@pytest.mark.parametrize("column_block", [None, 1920], ids=["whole_row", "blocks_of_10_heads"])
+def test_delta_step_kernel_keeps_the_state_at_its_bytes(v5e, column_block):
+    """`ops.delta_rule`'s one-token update at the cell's shapes: ONE Mosaic
+    kernel, the state `[48, 96, 5760]` float32 held at its values' bytes (a
+    `[.., 96, 192]` matrix a head would be laid out as `[.., 96, 256]`, a third
+    more), updated in place, nothing of its size made beside it."""
+    from accelerate_tpu.ops.delta_rule import _delta_step_pallas
+
+    def step(state, q, k, v, alpha, beta):
+        return _delta_step_pallas(state, q, k, v, alpha, beta, interpret=False, column_block=column_block)
+
+    def operand(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    heads = (HYBRID_SLOTS, HYBRID_HEADS)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        operand((HYBRID_SLOTS, HYBRID_DK, HYBRID_HEADS * HYBRID_DV), jnp.float32),
+        operand(heads + (HYBRID_DK,), jnp.bfloat16), operand(heads + (HYBRID_DK,), jnp.bfloat16),
+        operand(heads + (HYBRID_DV,), jnp.bfloat16), operand(heads, jnp.float32), operand(heads, jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%delta_step[.\d]* = [^\n]*custom-call\(", text)) == 1
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < HYBRID_STATE_BYTES * 1.02  # no padded layout: 106.2 MB, not 141.6
+    assert memory.alias_size_in_bytes >= HYBRID_STATE_BYTES  # in place
+    assert memory.temp_size_in_bytes < 4 << 20
+
+
+def test_hybrid_linear_layer_decodes_without_a_copy_of_its_state(v5e, monkeypatch):
+    """One linear-attention layer's decode step as the engine's chunk holds it
+    (`models.olmo_hybrid.GatedDeltaNet` against by-slot leaves for 48 slots):
+    the kernel is in the program, and no operation makes, copies or re-lays-out
+    an array of the state's size."""
+    import dataclasses
+
+    from accelerate_tpu.models.olmo_hybrid import GatedDeltaNet, OlmoHybridConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the layer picks its kernel by the backend
+    cfg = dataclasses.replace(
+        OlmoHybridConfig(num_hidden_layers=4, param_dtype="bfloat16"), decode_cache_length=1280,
+        decode_slot_cache=True, decode_page_size=16, decode_num_pages=3841)
+    layer = GatedDeltaNet(cfg)
+    hidden = jax.ShapeDtypeStruct((HYBRID_SLOTS, 1, cfg.hidden_size), jnp.bfloat16, sharding=v5e)
+    variables = jax.eval_shape(lambda h: layer.init(jax.random.key(0), h, None), hidden)
+    described = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e), variables)
+    assert described["cache"]["recurrent_state"].shape == (HYBRID_SLOTS, HYBRID_DK, HYBRID_HEADS * HYBRID_DV)
+    assert described["cache"]["conv_state"].shape == (HYBRID_SLOTS, 3, cfg.linear_conv_channels)
+
+    def decode(params, cache, h):
+        return layer.apply({"params": params, "cache": cache}, h, None, mutable=["cache"])
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        described["params"], described["cache"], hidden).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%delta_step[.\d]* = [^\n]*custom-call\(", text)) == 1
+    state_shaped = re.findall(r"= f32\[48,(?:96,5760|30,96,192|96,30,192)\]\S* (\w+)\(", text)
+    assert set(state_shaped) <= {"custom-call", "parameter", "get-tuple-element", "bitcast"}, state_shaped
+    assert compiled.memory_analysis().temp_size_in_bytes < HYBRID_STATE_BYTES // 4
+
+
+@pytest.mark.parametrize("tokens", [128, 512])
+def test_hybrid_linear_layer_prefill_compiles_for_v5e(v5e, tokens):
+    """The chunked scan over an insert bucket (batch 1, chunks of 64, a
+    triangular solve a chunk at float32) compiles for the chip and stays small:
+    the program around it holds 13.5 GB of weights, pool and state."""
+    import dataclasses
+
+    from accelerate_tpu.models.olmo_hybrid import GatedDeltaNet, OlmoHybridConfig
+
+    cfg = dataclasses.replace(OlmoHybridConfig(num_hidden_layers=4, param_dtype="bfloat16"), decode_cache_length=1280)
+    layer = GatedDeltaNet(cfg)
+    hidden = jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.bfloat16, sharding=v5e)
+    real = jax.ShapeDtypeStruct((1, tokens), jnp.bool_, sharding=v5e)
+    variables = jax.eval_shape(lambda h, m: layer.init(jax.random.key(0), h, m), hidden, real)
+    described = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e), variables)
+
+    def prefill(params, cache, h, m):
+        return layer.apply({"params": params, "cache": cache}, h, m, mutable=["cache"])
+
+    compiled = jax.jit(prefill).lower(described["params"], described["cache"], hidden, real).compile()
+    assert "tpu_custom_call" not in compiled.as_text()  # the scan is XLA's; the kernel is the decode step's
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
