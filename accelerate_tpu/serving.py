@@ -53,6 +53,29 @@ late-arriving request starts decoding while earlier long requests are still
 mid-flight. Stale K/V from a slot's previous occupant is never visible: each row
 attends only `cols <= its own position`, and insert overwrites the prompt rows.
 
+**What may be in flight when `step()` returns.** Slot state (`token`, `pos`,
+`active`, `rem`) is carried ON THE DEVICE from chunk to chunk — a chunk is
+dispatched on its predecessor's outputs, and the host pushes only the rows it
+changed (admitted, vacated, cancelled, timed out), merged in the program under
+a mask — so a chunk can be enqueued before the one ahead of it has been read.
+`step()` does that exactly when requests are LEFT IN THE QUEUE after admission
+(a backlog: no arrival could have been admitted sooner anyway): it enqueues
+its inserts and its decode chunk behind the chunk that is still running and
+only then reads that older chunk back, so the device always holds its next
+program. Such a step returns with ONE chunk in flight: `pending` stays True,
+and the tokens of that chunk — and the first tokens of the requests admitted
+with it — are handed out by the next `step()`. Whoever drives the engine
+flushes it by stepping while `pending` (`run()`, `drain()`), or by `close()`,
+which reads the chunk back before it cancels. With an EMPTY queue a step reads
+back the chunk it dispatched itself and nothing is in flight when it returns,
+so an arrival's insert never waits behind a chunk queued ahead of it. The host
+then works from a PREDICTED mirror of `pos` / `rem` (one token a decode step
+until the budget ends): a request that ends by length is known a chunk early,
+its slot is vacated at that dispatch and re-admitted before its last tokens
+are drained; one that stops on its EOS is known at its drain, one chunk late
+(`stats["slot_chunks_lost_to_eos"]`). Speculative engines, whose blocks the
+host cannot predict, always read their own chunk (`stats["run_ahead"]`).
+
 Greedy outputs are token-identical to the static `Generator` path (pads
 contribute exact zeros under the f32 softmax; rows are independent in every
 layer), which is what `tests/test_serving.py` pins.
@@ -62,11 +85,13 @@ degrades PER-REQUEST, never per-process. Admission failures (a transient device
 error during an insert, a malformed prompt that slipped validation) mark only
 that request `finish_reason="error"`; per-request wall-clock deadlines are
 enforced at step boundaries (`finish_reason="timeout"`); `cancel()` frees an
-in-flight slot immediately; a bounded queue raises `QueueFull` so callers get
+in-flight slot immediately (a chunk in flight may still stream up to one chunk
+of its tokens, which no step hands out); a bounded queue raises `QueueFull` so callers get
 explicit backpressure instead of unbounded host memory growth; and
 `drain()`/`close()` give the server a clean shutdown lifecycle. The one shared
 decode executable is the blast-radius exception: if a chunk dispatch itself
-dies, every in-flight request errors (the cache state is gone) but the engine
+dies, every in-flight request errors (the cache state is gone; a successor
+chunk and the inserts already enqueued behind it are condemned with it) but the engine
 stays up and keeps admitting — the slot cache is rebuilt from zeros, since the
 failed dispatch may already have consumed the donated buffers. An insert
 failure that consumed ITS donated operands (accelerators only) widens to the
@@ -146,14 +171,40 @@ def _zero_expert_token_counts(cache):
     )
 
 
-def _start_fresh_slots(token, active, eos_ids, first_token, fresh):
-    """The decode chunk's `(token, active)` with the slots admitted in its own
-    step started from the token their insert left on the device: the host
-    pushed `fresh` and every other operand of such a slot, but has not seen
-    the token, so the test it would make (a first token that is the request's
-    EOS ends it) is made here."""
+def _merge_slot_updates(token, pos, active, rem, eos_ids, first_token, update):
+    """The decode chunk's `(token, pos, active, rem)`: its predecessor's
+    outputs, which the host may not have seen yet, with the rows the host
+    changed since the last dispatch laid over them. `update` is
+    `int32[5, num_slots]` — `changed`, `fresh`, then the host's `pos`,
+    `active`, `rem` — and only the `changed` rows are taken from it: slots
+    admitted (also `fresh`), vacated, cancelled or timed out. A `fresh` slot
+    starts from the token its insert left on the device (`first_token`); the
+    host has not seen that token, so the test it would make (a first token
+    that is the request's EOS ends it) is made here. Returns `fresh` too."""
+    changed, fresh = update[0] != 0, update[1] != 0
+    pos = jnp.where(changed, update[2], pos)
+    active = jnp.where(changed, update[3] != 0, active)
+    rem = jnp.where(changed, update[4], rem)
     token = jnp.where(fresh, first_token, token)
-    return token, active & ~(fresh & (eos_ids >= 0) & (token == eos_ids))
+    active = active & ~(fresh & (eos_ids >= 0) & (token == eos_ids))
+    return token, pos, active, rem, fresh
+
+
+@dataclass
+class _Flight:
+    """One dispatched decode chunk whose outputs the host has not read: what
+    its drain needs of the host's state AS IT WAS WHEN THE CHUNK WAS
+    DISPATCHED, since a step that runs ahead admits into slots and predicts
+    past this chunk before it reads it back."""
+
+    read: Dict[str, Any]  # the chunk's outputs the drain takes, on the device
+    span: Any  # its open `serve.decode_chunk` span
+    tenants: List[Optional["RequestResult"]]  # slot -> request
+    fresh: List[int]  # slots whose first token rides it, in admission order
+    was_active: np.ndarray  # bool[num_slots]: decoding in this chunk
+    ends: np.ndarray  # bool[num_slots]: vacated at its dispatch; the drain finishes the result
+    eos: np.ndarray  # int32[num_slots]: the tenants' EOS ids
+    pos_before: np.ndarray  # speculative: where each slot's drained tokens append
 
 
 class QueueFull(RuntimeError):
@@ -226,11 +277,14 @@ class ContinuousBatcher:
             for request_id, new_tokens in engine.step():
                 stream(request_id, new_tokens)   # incremental drain
 
-    `step()` = admit-into-free-slots, dispatch ONE decode chunk, drain the packed
-    stream buffer. The decode executable is compiled exactly once per
-    (num_slots, chunk_size, sampler shape); admission compiles one insert
-    executable per power-of-two prompt bucket and never touches the decode
-    program (`trace_counts` proves it).
+    `step()` = admit-into-free-slots, dispatch ONE decode chunk, read ONE chunk
+    back and drain its packed stream buffer: its own with an empty queue, the
+    one the step before left running while requests wait in the queue — so
+    keep stepping while `pending`: a step may return with a chunk in flight
+    whose tokens only the next step (or `close()`) hands out. The decode
+    executable is compiled exactly once per (num_slots, chunk_size, sampler
+    shape); admission compiles one insert executable per power-of-two prompt
+    bucket and never touches the decode program (`trace_counts` proves it).
     """
 
     def __init__(
@@ -398,7 +452,15 @@ class ContinuousBatcher:
         self.speculative = bool(speculative)
         self.draft_tokens = int(draft_tokens)
         self.draft_ngram = int(draft_ngram)
+        # Why this engine never runs a chunk ahead (step()), or None where it
+        # does under a backlog; `stats["run_ahead"]` says it.
+        self.run_ahead_disabled_reason: Optional[str] = None
         if self.speculative:
+            self.run_ahead_disabled_reason = (
+                "speculative=True: the host cannot predict a verified block's length, and "
+                "pushes the drafter's context (`_history`), which it rebuilds from the "
+                "drained stream, with every chunk"
+            )
             if self.draft_tokens < 1 or self.draft_ngram < 1:
                 raise ValueError("speculative decode needs draft_tokens >= 1 and draft_ngram >= 1")
             if do_sample:
@@ -627,22 +689,37 @@ class ContinuousBatcher:
         self._rng = self._carried(self._rng)
         self._presence = self._new_presence()
         # Where an insert leaves its sampled token, by slot: the decode chunk
-        # of the same step starts from it, and the step's one readback brings
-        # it to the host (_drain). Donated through every insert.
+        # dispatched next starts from it and hands it back among its own
+        # outputs (`_Flight.read["first"]`), which _drain() gives the request.
+        # Donated through every insert.
         self._first_token = self._new_first_token()
-        # `fresh` of a chunk whose step admitted nothing: pushed once.
-        self._no_fresh = jnp.zeros((self.num_slots,), bool)
 
         S = self.num_slots
-        # Host mirror of the per-slot device operands (small [S] vectors, pushed
-        # each dispatch; the CACHE and presence stay device-resident/donated).
-        self._token = np.zeros(S, np.int32)
+        # Slot state lives ON THE DEVICE from chunk to chunk: `_carry` is
+        # `(token, pos, active, rem)` as the last dispatched chunk returned
+        # them — maybe not computed yet: the next chunk is dispatched on them.
+        self._carry = self._new_carry()
+        # The host keeps a PREDICTED mirror of `pos`, `active`, `rem`: what
+        # they will be once every dispatched chunk has run (_predict), put
+        # right at the drain where a request stopped on an EOS the host had
+        # not seen. Only the rows the host itself changed since the last
+        # dispatch — `_changed`; admissions also `_from_buffer` — are pushed,
+        # as the chunk's `update` operand; a step that changed none pushes
+        # `_no_update`, made once.
         self._pos = np.zeros(S, np.int32)
         self._active = np.zeros(S, bool)
         self._rem = np.zeros(S, np.int32)
+        self._changed = np.zeros(S, bool)
+        self._from_buffer = np.zeros(S, bool)
+        self._no_update = jnp.zeros((5, S), jnp.int32)
+        # What only the host writes (`eos`, `temperature`, `penalty`, the page
+        # table): a host mirror each, its copy on the device in `_pushed`, and
+        # its name in `_stale` when the mirror has changed since that copy.
         self._eos = np.full(S, -1, np.int32)
         self._temp = np.ones(S, np.float32)
         self._pen = np.ones(S, np.float32)
+        self._pushed: Dict[str, Any] = {}
+        self._stale = {"eos", "temp", "pen", "table"}
         # Per-slot page tables: all-zeros rows point at the scratch page, so a
         # freed/inactive slot's discarded decode writes can never land in a
         # live request's pages.
@@ -718,17 +795,32 @@ class ContinuousBatcher:
         )
         self._m_chunk_latency = self.metrics.histogram(
             "serving_chunk_seconds",
-            help="decode-chunk wall clock: operand push, dispatch and readback (the `serve.decode_chunk` span)",
+            help="decode-chunk wall clock, operand push to readback (the `serve.decode_chunk` span): "
+            "a chunk dispatched ahead is read back in the next step(), after its predecessor",
         )
         self._m_device_waits = self.metrics.counter(
             "serving_device_waits_total",
-            help="blocking device reads: one a step() that dispatched anything",
+            help="blocking device reads: one a step() that had a dispatched program to read back",
         )
         self._m_dispatching_steps = self.metrics.counter(
             "serving_dispatching_steps_total",
-            help="step() calls that dispatched an insert or a decode chunk",
+            help="step() calls that had a program to read back: their own inserts and chunk, or "
+            "the chunk the step before left in flight (not the step that only starts running ahead)",
         )
-        self._slot_last_event = np.zeros(S, np.float64)  # last drain time per slot
+        self._m_chunks_ahead = self.metrics.counter(
+            "serving_chunks_ahead_total",
+            help="decode chunks dispatched while their predecessor was still running",
+        )
+        self._m_chunks_ahead_share = self.metrics.gauge(
+            "serving_chunks_ahead_share",
+            help="serving_chunks_ahead_total over serving_chunks_total: ~1 under a backlog, "
+            "~0 with an empty queue",
+        )
+        self._m_lost_to_eos = self.metrics.counter(
+            "serving_slot_chunks_lost_to_eos_total",
+            help="chunks a slot sat inactive because its request stopped on an EOS the host "
+            "had not yet seen when it dispatched the next chunk",
+        )
 
         # Tracing (telemetry.tracing): one `serve.request` span per accepted
         # request from submit() to its terminal finish_reason, and one
@@ -737,10 +829,16 @@ class ContinuousBatcher:
         # the metrics (and TPU112 lints the annotations).
         self.tracer = tracer if tracer is not None else default_tracer()
         self._request_spans: Dict[int, Any] = {}
-        # Slots admitted in the step() now running, in admission order: their
-        # first tokens are still on the device (`_first_token`) until the
-        # step's one readback; _drain() hands them out and clears the list.
+        # Slots admitted since the last chunk was dispatched, in admission
+        # order, whose first tokens are still on the device (`_first_token`):
+        # the next chunk's `_Flight` takes the list; a step that dispatches no
+        # chunk reads the buffer itself and _drain() clears it.
         self._fresh: List[int] = []
+        # Decode chunks dispatched and not yet read back, oldest first: two at
+        # most inside a step() that runs ahead, one at most when it returns.
+        self._flights: deque = deque()
+        # When a request's tokens last reached the host, by request id.
+        self._last_event: Dict[int, float] = {}
         # Requests whose first token reached the host in the step() now
         # running: handed back, and timed, when it returns (_hand_back).
         self._first_tokens: List[RequestResult] = []
@@ -902,6 +1000,11 @@ class ContinuousBatcher:
     def _new_first_token(self):
         """The zeroed `int32[num_slots]` first-token buffer."""
         return self._carried(jnp.zeros((self.num_slots,), jnp.int32))
+
+    def _new_carry(self):
+        """`(token, pos, active, rem)` of an engine with every slot idle."""
+        idle = jnp.zeros((self.num_slots,), jnp.int32)
+        return tuple(self._carried(x) for x in (idle, idle, idle.astype(bool), idle))
 
     def _init_cache(self):
         """Create the slot cache — the [num_pages, page_size] pool (quantized
@@ -1102,10 +1205,11 @@ class ContinuousBatcher:
         config = self._sample_config
         mesh = self.mesh
 
-        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, first_token, fresh):
+        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, first_token, update):
             self.trace_counts["decode_chunk"] += 1
             cache = _zero_expert_token_counts(cache)
-            token, active = _start_fresh_slots(token, active, eos_ids, first_token, fresh)
+            token, pos, active, rem, fresh = _merge_slot_updates(token, pos, active, rem, eos_ids, first_token, update)
+            first = jnp.where(fresh, token, 0)
 
             def body(carry, _):
                 cache, presence, token, pos, active, rem, rng = carry
@@ -1146,11 +1250,17 @@ class ContinuousBatcher:
                     ],
                     axis=-1,
                 ).astype(jnp.int32)
-            out = (cache, presence, token, pos, active, rem, rng, packed, flat_valid.sum())
+            # What the next chunk starts from, and what the host reads back:
+            # `first` is the fresh slots' tokens as THIS chunk found them in
+            # the buffer — the next step's inserts are donated the buffer
+            # before this chunk is read.
+            read = {"active": active, "packed": packed, "count": flat_valid.sum(), "first": first}
             # A family with routed experts: the chunk's tokens an expert a
-            # layer ride the same readback, last.
+            # layer ride the same readback.
             counts = _expert_token_counts(cache)
-            return out if counts is None else out + (counts,)
+            if counts is not None:
+                read["expert_tokens"] = counts
+            return (cache, presence, token, pos, active, rem, rng), read
 
         donate = (1, 2) if use_pen else (1,)
         return jax.jit(decode_chunk, donate_argnums=donate)
@@ -1191,10 +1301,11 @@ class ContinuousBatcher:
         k_draft, m_gram = self.draft_tokens, self.draft_ngram
         mesh = self.mesh
 
-        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, first_token, fresh, history):
+        def decode_chunk(params, cache, presence, token, pos, active, rem, eos_ids, temperature, penalty, page_table, rng, first_token, update, history):
             self.trace_counts["decode_chunk"] += 1
             cache = _zero_expert_token_counts(cache)
-            token, active = _start_fresh_slots(token, active, eos_ids, first_token, fresh)
+            token, pos, active, rem, fresh = _merge_slot_updates(token, pos, active, rem, eos_ids, first_token, update)
+            first = jnp.where(fresh, token, 0)
             js = jnp.arange(k_draft + 1, dtype=jnp.int32)
             rows = jnp.arange(S)
             # A fresh slot's pending token belongs at history[pos]: the host
@@ -1257,12 +1368,16 @@ class ContinuousBatcher:
                     ],
                     axis=-1,
                 ).astype(jnp.int32)
-            out = (
-                cache, presence, token, pos, active, rem, rng, packed, flat_valid.sum(),
-                emitted_mat, proposed_mat,
-            )
+            # The host cannot predict a verified block's length: it adopts
+            # `pos` and `rem` from the readback (these engines never run ahead).
+            read = {
+                "active": active, "packed": packed, "count": flat_valid.sum(), "first": first,
+                "pos": pos, "rem": rem, "spec_emitted": emitted_mat, "spec_proposed": proposed_mat,
+            }
             counts = _expert_token_counts(cache)
-            return out if counts is None else out + (counts,)
+            if counts is not None:
+                read["expert_tokens"] = counts
+            return (cache, presence, token, pos, active, rem, rng), read
 
         return jax.jit(decode_chunk, donate_argnums=(1,))
 
@@ -1270,8 +1385,10 @@ class ContinuousBatcher:
 
     @property
     def pending(self) -> bool:
-        """Anything queued or in flight."""
-        return bool(self._queue) or bool(self._active.any()) or any(
+        """Anything queued, holding a slot, or dispatched and not read back: a
+        chunk left in flight by a step that ran ahead is pending work, whose
+        tokens only another `step()` (or `close()`) hands out."""
+        return bool(self._queue) or bool(self._flights) or any(
             r is not None for r in self._slot_request
         )
 
@@ -1367,11 +1484,19 @@ class ContinuousBatcher:
             "chunks": int(self._m_chunks.value),
             "decode_steps": int(self._m_decode_steps.value),
             "queue_peak": int(self._m_queue_peak.value),
-            # Blocking device reads a step() that dispatched anything: 1.0.
+            # Blocking device reads a step() that had anything to read back: 1.0.
             "waits_per_step": (
                 self._m_device_waits.value / self._m_dispatching_steps.value
                 if self._m_dispatching_steps.value else None
             ),
+            # Chunks dispatched while their predecessor ran, over all chunks:
+            # ~1 under a backlog, ~0 with an empty queue (step()).
+            "chunks_ahead_share": float(self._m_chunks_ahead_share.value),
+            "slot_chunks_lost_to_eos": int(self._m_lost_to_eos.value),
+            "run_ahead": {
+                "enabled": self.run_ahead_disabled_reason is None,
+                "disabled_reason": self.run_ahead_disabled_reason,
+            },
             "finish_reasons": {
                 reason: int(counter.value) for reason, counter in self._m_finish.items()
             },
@@ -1497,25 +1622,40 @@ class ContinuousBatcher:
         cache with it (the decode chunk and anything that surfaces at the
         step's one wait always; an insert's call only when its donated
         operands were consumed). Every in-flight request errors (partial tokens
-        kept) and the cache is rebuilt from zeros — the donated buffers may
+        kept) — those holding a slot, and those of a chunk not yet read back
+        whose slot was already vacated: a failure that surfaces at one chunk's
+        readback condemns the chunk and the inserts dispatched behind it too,
+        since they consumed the donated cache — and the cache is rebuilt from
+        zeros: the donated buffers may
         already be invalidated, and keeping the references would poison every
         later insert with a deleted-buffer error, leaving the engine up but
         failing every future request. New admissions overwrite their own rows
         before they are ever attended, exactly as at engine construction."""
         now = time.perf_counter() if now is None else now
+        condemned = [r for r in self._slot_request if r is not None]
+        for flight in self._flights:
+            condemned += [r for r in flight.tenants if r is not None and not r.finished]
+            flight.span.annotate(error=repr(exc)).end()
+        self._flights.clear()
         self.tracer.event(
             "serve.blast_radius", category="serve",
-            errored_requests=sum(r is not None for r in self._slot_request),
+            errored_requests=len({id(r) for r in condemned}),
             error=repr(exc),
         )
         for slot, result in enumerate(self._slot_request):
             if result is not None:
-                self._finish(result, "error", now=now, slot=slot, error=repr(exc))
-        self._active[:] = False
+                self._vacate(slot)
+        for result in condemned:
+            if not result.finished:
+                self._finish(result, "error", now=now, error=repr(exc))
         self._fresh.clear()  # they held slots: errored above, with no tokens
         self._cache = self._init_cache()
         self._first_token = self._new_first_token()
         self._presence = self._new_presence()
+        # Every slot idle on the device too; nothing of the host's is ahead of it.
+        self._carry = self._new_carry()
+        self._pos[:] = self._rem[:] = 0
+        self._active[:] = self._changed[:] = self._from_buffer[:] = False
         if self.speculative:
             # The speculative state dies with the cache: every slot's drafting
             # context belonged to a request that just errored. Admissions
@@ -1527,6 +1667,7 @@ class ContinuousBatcher:
         # "cached" prefix to the next shared-prompt request).
         self.pool.reset()
         self._page_table[:] = SCRATCH_PAGE
+        self._stale.add("table")
         self._slot_pages = [[] for _ in range(self.num_slots)]
         self._m_pages_in_use.set(0)
 
@@ -1536,11 +1677,40 @@ class ContinuousBatcher:
                 return slot
         return None
 
+    def _vacate(self, slot: int):
+        """Free a slot, its pages and its table row for the next `_admit` —
+        the half of a request's exit that may come BEFORE its last tokens:
+        a slot whose request will have ended when the chunk just dispatched
+        has run is vacated at that dispatch (_predict), and the insert of its
+        next tenant is enqueued behind the chunk, so the device's program
+        order keeps the old tenant's last writes ahead of the new one's. The
+        next dispatch tells the device (`_changed`)."""
+        self._slot_request[slot] = None
+        self._active[slot] = False
+        # An idle slot sits at position 0: the XLA read takes a row's
+        # live pages from its position, and a released slot left at its
+        # last one would pass for that many pages of scratch.
+        self._pos[slot] = self._rem[slot] = 0
+        self._changed[slot], self._from_buffer[slot] = True, False
+        # Release the slot's page references (a shared prefix page
+        # drops to CACHED at refcount 0, private pages go free) and
+        # point the table row at the scratch page so any residual
+        # write for this row is discarded.
+        if self._slot_pages[slot]:
+            self.pool.release(self._slot_pages[slot])
+            self._slot_pages[slot] = []
+            self._page_table[slot] = SCRATCH_PAGE
+            self._stale.add("table")
+
     def _finish(self, result: RequestResult, reason: str, now: Optional[float] = None,
                 slot: Optional[int] = None, error: Optional[str] = None):
-        """The single exit path for a request: stamp the result, bump the
-        per-reason counter, drop its deadline, and free its slot (if any) so the
-        next `_admit` can reuse the cache rows."""
+        """The single exit path for a request's RESULT: stamp it, bump the
+        per-reason counter, drop its deadline — and `_vacate(slot)` where the
+        request still holds one. A request that ends by length has been
+        vacated one drain earlier (_predict): this runs at the drain that
+        hands out its last tokens, and until then the result reads unfinished
+        (`release()` refuses it). Tokens of a finished request that a chunk
+        in flight still streams (a cancel, a deadline) are dropped by _drain."""
         result.finished = True
         result.finish_time = time.perf_counter() if now is None else now
         result.finish_reason = reason
@@ -1558,21 +1728,9 @@ class ContinuousBatcher:
                 span.end()
         self._m_finish[reason].inc()
         self._deadlines.pop(result.request_id, None)
+        self._last_event.pop(result.request_id, None)
         if slot is not None:
-            self._slot_request[slot] = None
-            self._active[slot] = False
-            # An idle slot sits at position 0: the XLA read takes a row's
-            # live pages from its position, and a released slot left at its
-            # last one would pass for that many pages of scratch.
-            self._pos[slot] = 0
-            # Release the slot's page references (a shared prefix page
-            # drops to CACHED at refcount 0, private pages go free) and
-            # point the table row at the scratch page so any residual
-            # write for this row is discarded.
-            if self._slot_pages[slot]:
-                self.pool.release(self._slot_pages[slot])
-                self._slot_pages[slot] = []
-            self._page_table[slot] = SCRATCH_PAGE
+            self._vacate(slot)
         self._update_occupancy_gauges()
 
     def _drop_queued(self, request_id: int) -> bool:
@@ -1582,7 +1740,8 @@ class ContinuousBatcher:
 
     def _expire_deadlines(self):
         """Step-boundary deadline sweep: queued requests time out without ever
-        occupying a slot; in-flight ones keep their partial tokens and free the slot."""
+        occupying a slot; in-flight ones keep the tokens handed out so far and
+        free the slot (what a chunk in flight still streams of them is dropped)."""
         if not self._deadlines:
             return
         now = time.perf_counter()
@@ -1596,9 +1755,11 @@ class ContinuousBatcher:
 
     def cancel(self, request_id: int) -> bool:
         """Cancel a queued or in-flight request: its result finishes with
-        `finish_reason="cancelled"` (partial tokens kept) and its slot frees for
-        the next admission. Returns False if it already finished; raises
-        KeyError for an unknown id."""
+        `finish_reason="cancelled"` (the tokens handed out so far kept) and its
+        slot frees for the next admission. A chunk in flight may still stream
+        up to one chunk of its tokens: no later step hands them out, and the
+        next dispatch clears the slot on the device. Returns False if it
+        already finished; raises KeyError for an unknown id."""
         result = self.results[request_id]
         if result.finished:
             return False
@@ -1609,13 +1770,17 @@ class ContinuousBatcher:
     def _admit(self):
         """Fill free slots from the queue (FIFO). Each admission is one insert
         DISPATCH and nothing more: the first token stays on the device
-        (`_first_token[slot]`), the slot joins `_fresh`, the step's decode
-        chunk starts from the token where it is, and the step's one readback
-        hands it to _drain(). So the host prepares the next admission, the
-        operand push and the chunk's launch while the inserts run. Every
-        admission holds its slot until that drain — a one-token request too,
-        so that no second admission of the step is given its entry of the
-        buffer.
+        (`_first_token[slot]`), the slot joins `_fresh`, the decode chunk
+        dispatched next starts from the token where it is and hands it back
+        with its own outputs. So the host prepares the next admission, the
+        operand push and the chunk's launch while the inserts run — and, when
+        the step before left a chunk in flight, while that chunk runs: the
+        inserts are enqueued BEHIND it. A free slot may be one `_vacate`d on a
+        prediction — its last tenant ends in the chunk still running, whose
+        writes the device's program order keeps ahead of this insert's. Every
+        admission holds its slot until the next chunk is dispatched (or, in a
+        step that dispatches none, until its drain) — a one-token request too,
+        so that no second admission is given its entry of the buffer first.
 
         Admission is PAGE-based, not slot-based: the request reserves
         `ceil((prompt + max_new) / page_size)` pool pages minus whatever its
@@ -1748,14 +1913,16 @@ class ContinuousBatcher:
             self._fresh.append(slot)
             self._slot_request[slot] = result
             self._slot_pages[slot] = pages
-            self._slot_last_event[slot] = 0.0  # no token of it has reached the host
+            # The device is told of the slot with the next dispatch: its state
+            # from the host, its token from the buffer.
+            self._changed[slot] = self._from_buffer[slot] = True
             self._rem[slot] = req.max_new_tokens - 1
-            self._eos[slot] = -1 if req.eos_token_id is None else int(req.eos_token_id)
+            self._set_row("eos", self._eos, slot, -1 if req.eos_token_id is None else int(req.eos_token_id))
             if self._rem[slot] > 0:
                 self._pos[slot] = p  # the first generated token's write position
                 self._active[slot] = True
-                self._temp[slot] = req.temperature
-                self._pen[slot] = req.repetition_penalty
+                self._set_row("temp", self._temp, slot, req.temperature)
+                self._set_row("pen", self._pen, slot, req.repetition_penalty)
                 if self.speculative:
                     # Seed the drafter's context: full prompt (prefix-cache
                     # hits included — the host has the whole prompt even when
@@ -1764,10 +1931,20 @@ class ContinuousBatcher:
                     self._history[slot, :p] = ids
                     self._history[slot, p:] = 0
                 self._page_table[slot] = page_row
+                self._stale.add("table")
             # else a one-token request: the chunk sees an idle slot (position
-            # 0, the scratch row); _drain() finishes it and releases its pages
-            # — a prefix it just registered stays CACHED for the next hit.
+            # 0, the scratch row); it is vacated when that chunk is dispatched
+            # and finished when its token is drained — a prefix it just
+            # registered stays CACHED for the next hit.
         self._update_occupancy_gauges()
+
+    def _set_row(self, name: str, mirror: np.ndarray, slot: int, value):
+        """Write one slot's entry of a host-only operand's mirror; the device's
+        copy goes stale only where the value is new."""
+        value = mirror.dtype.type(value)
+        if mirror[slot] != value:
+            mirror[slot] = value
+            self._stale.add(name)
 
     def _routed_pairs(self, bucket: int) -> Dict[str, int]:
         """`routed_pairs` of an insert, for its span: the (token, expert) pairs
@@ -1819,30 +1996,38 @@ class ContinuousBatcher:
         return result
 
     def _chunk_operands(self) -> List[Any]:
-        """The decode-chunk dispatch's operand list: device-resident state
-        (params, donated cache/presence, rng, the inserts' first tokens) plus
-        this cycle's push of the small per-slot host mirrors and of `fresh`,
-        the slots whose token is the one their insert left on the device."""
-        fresh = self._no_fresh
-        if self._fresh:
-            fresh = np.zeros(self.num_slots, bool)
-            fresh[self._fresh] = True
-            fresh = jnp.asarray(fresh)
+        """The decode-chunk dispatch's operand list. All of it is on the
+        device already — params, the donated cache/presence, the rng, the
+        inserts' first tokens, the slot state its predecessor returned
+        (`_carry`), the host-only operands' copies (`_pushed`) — but what the
+        host changed since the last dispatch: the `_stale` operands are pushed
+        again, and the `_changed` slots' rows go in `update`
+        (`_merge_slot_updates`). A step that changed no slot pushes nothing."""
+        mirrors = {"eos": self._eos, "temp": self._temp, "pen": self._pen, "table": self._page_table}
+        for name in self._stale:
+            # A copy of the mirror: on a CPU the device array may alias the
+            # numpy buffer it was made from (jnp.array too), and the host
+            # writes the mirror in place while a chunk that reads this is
+            # still running.
+            self._pushed[name] = jnp.asarray(mirrors[name].copy())
+        self._stale.clear()
+        update = self._no_update
+        if self._changed.any():
+            update = jnp.asarray(np.stack(
+                [self._changed, self._from_buffer, self._pos, self._active, self._rem]
+            ).astype(np.int32))
         args = [
             self.params,
             self._cache,
             self._presence,
-            jnp.asarray(self._token),
-            jnp.asarray(self._pos),
-            jnp.asarray(self._active),
-            jnp.asarray(self._rem),
-            jnp.asarray(self._eos),
-            jnp.asarray(self._temp),
-            jnp.asarray(self._pen),
-            jnp.asarray(self._page_table),
+            *self._carry,
+            self._pushed["eos"],
+            self._pushed["temp"],
+            self._pushed["pen"],
+            self._pushed["table"],
             self._rng,
             self._first_token,
-            fresh,
+            update,
         ]
         if self.speculative:
             args.append(jnp.asarray(self._history))
@@ -1856,21 +2041,51 @@ class ContinuousBatcher:
         return self._chunk_fn.lower(*self._chunk_operands())
 
     def step(self) -> List[Tuple[int, List[int]]]:
-        """One serving cycle: expire deadlines → admit → one decode-chunk
-        dispatch → ONE wait → drain the first tokens and the packed stream.
+        """One serving cycle: expire deadlines → admit → dispatch one decode
+        chunk → ONE wait → drain first tokens and a chunk's packed stream.
         Returns `(request_id, new_tokens)` events in stream order (admissions'
         first tokens included, each ahead of its request's chunk tokens).
 
         A step enqueues every device program it has — each admission's insert,
-        then the decode chunk — before it blocks on anything, and blocks once,
-        on the chunk's readback (which brings the inserts' first tokens too;
-        on the first-token buffer alone when nothing is left to decode).
-        Nothing is in flight when it returns.
+        then the decode chunk — before it blocks on anything, and blocks once.
+        WHICH chunk it reads back depends on one observation of its own input,
+        the queue after admission:
 
-        One span tree a step, each also a profiler annotation of its name:
+          - **Queue empty** (no request waits for a slot): the step reads back
+            the chunk it just dispatched, which brings the inserts' first
+            tokens too (the first-token buffer alone when nothing is left to
+            decode). Nothing is in flight when it returns. An arrival's insert
+            never waits out a chunk it did not have to.
+          - **Requests left in the queue** (a backlog: no arrival could have
+            been admitted sooner anyway): the step RUNS ONE CHUNK AHEAD. It
+            enqueues its inserts and its chunk behind the chunk the step
+            before left running, and only then reads that older chunk back;
+            the device holds its next program through the drain, the client
+            loop, the next admission, push and launch. The first such step has
+            no older chunk: it returns no events and does not wait. ONE CHUNK
+            IS IN FLIGHT WHEN SUCH A STEP RETURNS (`pending` stays True): its
+            tokens, and the first tokens of the requests admitted with it, are
+            handed out by the next `step()`; `drain()` and `run()` step until
+            nothing is left, `close()` reads it back before it cancels.
+          - A step that finds a chunk in flight and an empty queue reads it
+            back, dispatches no chunk, and the engine is synchronous again.
+
+        Speculative engines always take the first form (`stats["run_ahead"]`).
+
+        Running ahead, the host works from a PREDICTED mirror (_predict): a
+        request that ends by length is known a chunk early, its slot is
+        vacated at that dispatch and re-admitted in the next step, before its
+        last tokens are drained. A request that stops on its EOS is known only
+        at its drain, one chunk late: the chunk already in flight carries its
+        slot inactive (`stats["slot_chunks_lost_to_eos"]`).
+
+        One span tree a step, each also a profiler annotation of its name but
+        `serve.decode_chunk`:
 
             serve.step ⊃ serve.admit ⊃ serve.insert (one an admission: dispatch only)
-                       ⊃ serve.decode_chunk ⊃ serve.chunk.push, .dispatch, .wait
+                       ⊃ serve.decode_chunk (opened at its dispatch ⊃ serve.chunk.push, .dispatch;
+                                             ended at its readback, in this step or the next)
+                       ⊃ serve.chunk.wait (the step's one wait: for the chunk it reads back)
                        ⊃ serve.drain
 
         `serve.step`, `serve.insert` and `serve.decode_chunk` are recorded (the
@@ -1878,9 +2093,12 @@ class ContinuousBatcher:
         are annotations whose seconds ride `serve.step` as `admit_s`, `push_s`,
         `dispatch_s`, `drain_s` and `device_wait_s` (the step's one wait).
         `host_s` is the rest of the step: its own time, with the device's
-        taken out. `waits` counts the step's blocking device reads (1, or 0
-        for an idle step) and `dispatched_ahead` the programs enqueued before
-        the first of them (inserts + chunk)."""
+        taken out. `waits` counts the step's blocking device reads (1; 0 for an
+        idle step and for the step that starts running ahead),
+        `dispatched_ahead` the programs enqueued before it (inserts + chunk)
+        and `in_flight_at_return` the chunks left for the next step (0 or 1).
+        `serve.decode_chunk.ahead` says the chunk was dispatched while its
+        predecessor ran."""
         if self._closed:
             return []
         tracer = self.tracer
@@ -1893,44 +2111,72 @@ class ContinuousBatcher:
             step_span.annotate(inserts=inserts,
                                admit_s=round(admit_span.duration_s, 6), push_s=0.0, dispatch_s=0.0)
             events: List[Tuple[int, List[int]]] = []
-            drained, device_wait_s = None, 0.0
-            decoding = bool(self._active.any())
-            if decoding:
-                drained, device_wait_s = self._decode_chunk(step_span)
-            elif inserts:
-                drained, device_wait_s = self._await_first_tokens()
-            with tracer.span("serve.drain", category="serve", record=False) as drain_span:
-                if drained is not None:
-                    self._drain(events, *drained)
-                self._hand_back()
-            dispatched = inserts + decoding
-            if dispatched:
+            # The chunk the step before left running, if any; then whether this
+            # step leaves one: only with a backlog, and only an engine that can
+            # predict its slots.
+            older = self._flights[0] if self._flights else None
+            run_ahead = bool(self._queue) and self.run_ahead_disabled_reason is None
+            decoding = bool(self._active.any()) and (older is None or run_ahead)
+            newer = self._dispatch_chunk(step_span, ahead=older is not None) if decoding else None
+            if decoding and newer is None:  # the dispatch failed: everything in flight errored
+                older = None
+            flight = older if older is not None else (None if run_ahead else newer)
+            device_wait_s = drain_s = 0.0
+            if flight is not None or self._fresh:
                 self._m_dispatching_steps.inc()
+                drained, device_wait_s = self._read_back(flight)
+                with tracer.span("serve.drain", category="serve", record=False) as drain_span:
+                    if drained is not None:
+                        self._drain(events, flight, *drained)
+                    self._hand_back()
+                drain_s = drain_span.duration_s
             step_span.annotate(
-                drain_s=round(drain_span.duration_s, 6),
+                drain_s=round(drain_s, 6),
                 device_wait_s=round(device_wait_s, 6),
                 host_s=round(step_span.duration_s - device_wait_s, 6),
                 waits=int(self._m_device_waits.value - waits_before),
-                dispatched_ahead=dispatched,
+                dispatched_ahead=inserts + decoding,
+                in_flight_at_return=len(self._flights),
             )
         return events
 
-    def _read_back(self, span_name: str, values):
-        """The step's ONE blocking device read, under the annotation
-        `span_name`: `values` on the host, and the seconds it waited.
-        jax.device_get — np.asarray / int() on a device value are IMPLICIT
-        reads, which an armed transfer guard rejects on a TPU."""
+    def _read_back(self, flight: Optional[_Flight]):
+        """The step's ONE blocking device read: `flight`'s outputs (its fresh
+        slots' first tokens among them only where it has any) and, for
+        admissions that ride no chunk (`_fresh` still holds them: the step
+        dispatched none), the first-token buffer itself. Returns what _drain()
+        takes — None when the read failed: see _wait_failed() — and the
+        seconds it waited. jax.device_get — np.asarray / int() on a device
+        value are IMPLICIT reads, which an armed transfer guard rejects on a
+        TPU."""
+        read = None
+        if flight is not None:
+            read = {k: v for k, v in flight.read.items() if k != "first" or flight.fresh}
+        values = (read, self._first_token if self._fresh else None)
         self._m_device_waits.inc()
-        with self.tracer.span(span_name, category="serve", record=False) as wait_span:
-            host = jax.device_get(values)
+        name = "serve.chunk.wait" if flight is not None else "serve.first_tokens.wait"
+        try:
+            with self.tracer.span(name, category="serve", record=False) as wait_span:
+                host = jax.device_get(values)
+        except Exception as exc:  # noqa: BLE001
+            self._wait_failed(exc, "decode chunk readback" if flight is not None else "first-token readback")
+            return None, 0.0
+        if flight is not None:
+            self._flights.popleft()
+            flight.span.annotate(**self._chunk_counts(host[0])).end()
+            # The chunk's wall clock, dispatch through readback: real device
+            # work, not just the async enqueue — and, for a chunk dispatched
+            # ahead, what was left of its predecessor when it was enqueued.
+            self._m_chunk_latency.observe(max(flight.span.duration_s, 0.0))
         return host, wait_span.duration_s
 
     def _wait_failed(self, exc: Exception, what: str):
         """A failure at the step's dispatches or its wait: dispatch is async
-        on accelerators, so an insert's or the chunk's device-side failure
-        surfaces at the readback, and the programs share the donated cache —
+        on accelerators, so an insert's or a chunk's device-side failure
+        surfaces at a readback, and the programs share the donated cache —
         the in-flight state is unrecoverable, so every in-flight request
-        errors (partial tokens kept; this step's admissions with none). The
+        errors (partial tokens kept; this step's admissions with none), the
+        requests of a successor chunk already dispatched with them. The
         engine itself stays up: slots free, the queue keeps draining, new
         admissions rebuild their own cache rows from scratch."""
         if self.trace_guard is not None:
@@ -1938,64 +2184,75 @@ class ContinuousBatcher:
         in_flight = sum(r is not None for r in self._slot_request)
         logger.warning("%s failed; erroring %d in-flight request(s): %r", what, in_flight, exc)
         self._abort_in_flight(exc)
-        return None, 0.0
 
-    def _await_first_tokens(self):
-        """The wait of a step that admitted and has nothing to decode (only
-        one-token requests): the first-token buffer alone. Returns what
-        _drain() takes, or None when the read failed."""
-        try:
-            first, wait_s = self._read_back("serve.first_tokens.wait", self._first_token)
-        except Exception as exc:  # noqa: BLE001
-            return self._wait_failed(exc, "first-token readback")
-        return (first, None), wait_s
-
-    def _decode_chunk(self, step_span):
-        """Push the slot mirrors, dispatch the decode chunk and read its
-        outputs and the inserts' first tokens back, under
-        `serve.decode_chunk`; `push_s` and `dispatch_s` go on `step_span`.
-        Returns what _drain() takes — None when the dispatch or the wait
-        failed (every in-flight request then errored) — and the seconds the
-        readback waited for the device."""
+    def _dispatch_chunk(self, step_span, ahead: bool) -> Optional[_Flight]:
+        """Push what the host changed, dispatch the decode chunk on its
+        predecessor's outputs and move the host's mirror past it (_predict).
+        `serve.decode_chunk` opens here and is ended by the readback of its
+        `_Flight`, in this step() or the next; `push_s` and `dispatch_s` go on
+        `step_span`. Returns the flight, appended to `_flights` — None when
+        the dispatch failed (every in-flight request then errored)."""
         tracer = self.tracer
-        chunk_t0 = time.perf_counter()
-        pos_before = self._pos.copy()  # spec: where each slot's drained tokens append
+        # One batched span per chunk dispatch: every active request rides it
+        # (not N per-request spans). The readers of `chipbench/chunk_counters.py`
+        # take it by where it STARTS: at its dispatch.
+        chunk_span = tracer.start_span(
+            "serve.decode_chunk", category="serve",
+            chunk_size=self.chunk_size,
+            active_slots=int(self._active.sum()),
+            pages_in_use=self.pool.pages_in_use,
+            ahead=bool(ahead),
+            **self._live_page_counts(),
+        )
         try:
-            # One batched span per chunk dispatch: every active request rides
-            # it (not N per-request spans).
-            with tracer.span(
-                "serve.decode_chunk", category="serve",
-                chunk_size=self.chunk_size,
-                active_slots=int(self._active.sum()),
-                pages_in_use=self.pool.pages_in_use,
-                **self._live_page_counts(),
-            ) as chunk_span:
-                with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:
-                    operands = self._chunk_operands()
-                with tracer.span("serve.chunk.dispatch", category="serve", record=False) as dispatch_span:
-                    out = self._chunk_fn(*operands)
-                # ONE explicit drain of everything the host needs. It sits
-                # INSIDE the try: see _wait_failed().
-                (first, host), wait_s = self._read_back(
-                    "serve.chunk.wait",
-                    (self._first_token if self._fresh else None, out[2:6] + out[7:]),
-                )
-                step_span.annotate(push_s=round(push_span.duration_s, 6),
-                                   dispatch_s=round(dispatch_span.duration_s, 6))
-                chunk_span.annotate(**self._chunk_counts(host))
+            with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:
+                operands = self._chunk_operands()
+            with tracer.span("serve.chunk.dispatch", category="serve", record=False) as dispatch_span:
+                carry, read = self._chunk_fn(*operands)
         except Exception as exc:  # noqa: BLE001
-            return self._wait_failed(exc, "decode chunk dispatch")
-        self._cache, self._presence = out[0], out[1]
-        self._rng = out[6]
+            chunk_span.annotate(error=repr(exc)).end()
+            self._wait_failed(exc, "decode chunk dispatch")
+            return None
+        step_span.annotate(push_s=round(push_span.duration_s, 6),
+                           dispatch_s=round(dispatch_span.duration_s, 6))
+        self._cache, self._presence, *slot_state, self._rng = carry
+        self._carry = tuple(slot_state)
+        self._changed[:] = self._from_buffer[:] = False
         self._m_chunks.inc()
+        self._m_chunks_ahead.inc(int(ahead))
+        self._m_chunks_ahead_share.set(self._m_chunks_ahead.value / self._m_chunks.value)
         self._m_decode_steps.inc(self.chunk_size)
-        # The chunk's wall clock, through the readback: real device work, not
-        # just the async enqueue.
-        self._m_chunk_latency.observe(max(time.perf_counter() - chunk_t0, 0.0))
-        # np.array (copy): these mirrors are written in-place at the next
-        # admission, and a drained buffer may be a read-only view.
-        mirrors = tuple(np.array(x) for x in host[:4])
-        return (first, (mirrors, host[4][: int(host[5])], pos_before)), wait_s
+        # The host's state as the chunk was dispatched, before _predict() moves it on.
+        tenants, was_active, pos_before = list(self._slot_request), self._active.copy(), self._pos.copy()
+        flight = _Flight(
+            read=read, span=chunk_span, tenants=tenants, fresh=self._fresh, was_active=was_active,
+            ends=self._predict(), eos=self._eos.copy(), pos_before=pos_before,
+        )
+        self._fresh = []
+        self._flights.append(flight)
+        return flight
+
+    def _predict(self) -> np.ndarray:
+        """Move the host's mirror of `pos`, `active`, `rem` past the chunk just
+        dispatched, without the device: an active slot streams one token a
+        decode step until its budget ends, so both are known — unless it stops
+        on its EOS, which only the drain sees. A slot whose request WILL have
+        ended when the chunk has run (its budget is at most the chunk's steps;
+        a one-token request, which never decodes) is vacated now: the returned
+        `bool[num_slots]` marks them, for the drain that finishes their
+        results. A speculative engine predicts nothing (a verified block's
+        length is the device's): its drain adopts the readback."""
+        if not self.speculative:
+            steps = np.where(self._active, np.minimum(self._rem, self.chunk_size), 0)
+            self._pos += steps
+            self._rem -= steps
+            self._active &= self._rem > 0
+        ends = np.asarray([r is not None for r in self._slot_request]) & ~self._active
+        for slot in np.nonzero(ends)[0]:
+            self._vacate(int(slot))
+        if ends.any():
+            self._update_occupancy_gauges()
+        return ends
 
     def _live_page_counts(self) -> Dict[str, int]:
         """What the KV read is about to visit, from the host mirrors: the
@@ -2025,18 +2282,18 @@ class ContinuousBatcher:
                           kv_page_bytes=self.page_size * self._kv_bytes_per_token)
         return counts
 
-    def _chunk_counts(self, host) -> Dict[str, int]:
+    def _chunk_counts(self, host: Dict[str, Any]) -> Dict[str, int]:
         """What a chunk's readback counts, for its span: the tokens streamed,
         — a family with routed experts — `expert_tokens_max` / `_mean` and
         `experts_touched`, and
         — speculative engines — the fold of the per-(iteration, slot)
         emit/propose matrices into the spec ledger. Every count is a host
         scalar off the readback."""
-        counts = {"tokens_streamed": int(host[5])}
+        counts = {"tokens_streamed": int(host["count"])}
         if self._expert_layers:
             # Over the chunk's dispatches, every row the program ran, idle
             # slots' too — they are rows the experts multiply.
-            tokens, dispatches = np.asarray(host[-1])[:, 0], np.asarray(host[-1])[:, 1]
+            tokens, dispatches = (np.asarray(host["expert_tokens"])[:, i] for i in (0, 1))
             busiest, mean = float(tokens.max(axis=1).mean()), float(tokens.mean())
             self._m_expert_load.set(busiest / mean if mean else 0.0)
             counts.update(
@@ -2045,7 +2302,7 @@ class ContinuousBatcher:
                 experts_touched=float(dispatches.sum()) / (self._expert_layers * self.chunk_size),
             )
         if self.speculative:
-            spec_emitted, spec_proposed = host[6:8]
+            spec_emitted, spec_proposed = host["spec_emitted"], host["spec_proposed"]
             steps = int((spec_emitted > 0).sum())
             emitted_total = int(spec_emitted.sum())
             proposed_total = int(spec_proposed.sum())
@@ -2064,70 +2321,101 @@ class ContinuousBatcher:
             )
         return counts
 
-    def _drain(self, events: List[Tuple[int, List[int]]], first_token, chunk):
-        """Hand this step's admissions their first tokens (`first_token`: the
-        buffer the inserts wrote, on the host now) and then the chunk's packed
-        `(slot, token)` stream to its requests, adopt the device's slot state,
-        and finish what ended. `chunk` is None in a step that decoded nothing."""
+    def _first_token_to(self, events, result: RequestResult, token: int, now: float):
+        """Hand `result` the token its insert sampled, on the host since `now`."""
+        result.tokens.append(token)
+        result.first_token_time = now
+        self._first_tokens.append(result)
+        events.append((result.request_id, [token]))
+        span = self._request_spans.get(result.request_id)
+        if span is not None:
+            span.event("first_token")
+
+    def _drain(self, events: List[Tuple[int, List[int]]], flight: Optional[_Flight], read, first_token):
+        """Hand out what the step's one readback brought: `flight`'s chunk —
+        its admissions' first tokens, then its packed `(slot, token)` stream,
+        routed by the slot → request map AS IT WAS WHEN THE CHUNK WAS
+        DISPATCHED (a slot may have its next tenant by now) — then, from
+        `first_token` (the buffer itself), the first tokens of admissions
+        that ride no chunk. Requests that ended are finished: those the
+        dispatch had predicted (vacated there), and those only the device
+        could know — an EOS, a speculative block — which are vacated here.
+        What a chunk streams of a request finished meanwhile is dropped."""
         now = time.perf_counter()
         self.tracer.recorder.poll()  # serve the `trace dump` touch file
+        if flight is not None:
+            self._drain_chunk(events, flight, read, now)
+        # Admissions of a step that dispatched no chunk. A one-token request
+        # ends here; any other keeps `_from_buffer`: the next chunk starts it
+        # from the buffer, where a first token that is its EOS ends it.
         for slot in self._fresh:
             result = self._slot_request[slot]
-            token = int(first_token[slot])
-            result.tokens.append(token)
-            result.first_token_time = now
-            self._first_tokens.append(result)
-            events.append((result.request_id, [token]))
-            span = self._request_spans.get(result.request_id)
-            if span is not None:
-                span.event("first_token")
-            if self._rem[slot] == 0:  # a one-token request: no chunk ever saw it
-                self._finish(result, "eos" if token == self._eos[slot] else "length",
+            if result is None:
+                continue
+            self._first_token_to(events, result, int(first_token[slot]), now)
+            if self._rem[slot] == 0:
+                self._finish(result, "eos" if result.tokens[-1] == self._eos[slot] else "length",
                              now=now, slot=slot)
-            elif self.speculative:
-                self._history[slot, self._pos[slot]] = token  # as the chunk did on the device
-            # A first token that is the request's EOS: the chunk cleared the
-            # slot's `active`, and the sweep below finishes it as "eos".
         self._fresh.clear()
-        if chunk is None:
-            return
-        mirrors, packed, pos_before = chunk
+
+    def _drain_chunk(self, events, flight: _Flight, read, now: float):
+        tenants = flight.tenants
+        for slot in flight.fresh:
+            result = tenants[slot]
+            if result.finished:  # cancelled or timed out since its dispatch
+                continue
+            token = int(read["first"][slot])
+            self._first_token_to(events, result, token, now)
+            if self.speculative and flight.was_active[slot]:
+                self._history[slot, flight.pos_before[slot]] = token  # as the chunk did on the device
         per_slot: Dict[int, List[int]] = {}
-        for slot, tok in packed:
+        for slot, tok in read["packed"][: int(read["count"])]:
             per_slot.setdefault(int(slot), []).append(int(tok))
         for slot, toks in per_slot.items():
-            result = self._slot_request[slot]
-            if result is None:  # defensive: stream for a freed slot
+            result = tenants[slot]
+            if result is None or result.finished:
                 continue
             result.tokens.extend(toks)
             if self.speculative:
                 # Mirror the device-side history update (emitted token j of the
                 # chunk landed at history[pos_before + 1 + j]) so the next
                 # dispatch pushes an identical context.
-                start = int(pos_before[slot]) + 1
+                start = int(flight.pos_before[slot]) + 1
                 self._history[slot, start : start + len(toks)] = toks
             events.append((result.request_id, toks))
-            # Inter-token latency: the host drains a slot's tokens once per
+            # Inter-token latency: the host drains a request's tokens once per
             # chunk, so the per-token gap is the drain gap amortized over the
             # tokens it delivered, weighted by them.
-            last = self._slot_last_event[slot]
-            if last > 0.0 and toks:
+            last = self._last_event.get(result.request_id)
+            if last is not None:
                 self._m_inter_token.observe(max(now - last, 0.0) / len(toks), count=len(toks))
-            self._slot_last_event[slot] = now
+            self._last_event[result.request_id] = now
 
-        was_active = self._active
-        self._token, self._pos, self._active, self._rem = mirrors
-        for slot in np.nonzero(was_active & ~self._active)[0]:
-            result = self._slot_request[slot]
-            if result is not None:
-                reason = (
-                    "eos" if result.tokens and result.tokens[-1] == self._eos[slot] else "length"
-                )
-                self._finish(result, reason, now=now, slot=slot)
+        # Who ended in this chunk: the slots its dispatch vacated, and those the
+        # device alone could know of — decoding as dispatched, inactive after.
+        stopped = flight.was_active & ~np.asarray(read["active"])
+        for slot in np.nonzero(flight.ends | stopped)[0]:
+            result = tenants[slot]
+            if result is None or result.finished:
+                continue
+            if self._slot_request[slot] is result:
+                # Not predicted. Under a backlog the successor is in flight
+                # with this slot inactive: a chunk of a slot's time lost.
+                self._m_lost_to_eos.inc(sum(f.tenants[slot] is result for f in self._flights))
+                self._vacate(int(slot))
+            reason = "eos" if result.tokens and result.tokens[-1] == flight.eos[slot] else "length"
+            self._finish(result, reason, now=now)
+        if self.speculative:
+            # Where the device left the slots still decoding: the next
+            # dispatch's live pages and the drafter's context are read off it.
+            still = np.asarray([r is not None and self._slot_request[i] is r
+                                for i, r in enumerate(tenants)]) & np.asarray(read["active"])
+            self._pos[still], self._rem[still] = read["pos"][still], read["rem"][still]
 
     def run(self, requests: Optional[List[Request]] = None) -> Dict[int, np.ndarray]:
         """Drive to completion: submit `requests` (if given), loop `step()` until
-        the queue and every slot drain, return {request_id: generated tokens}."""
+        the queue, every slot and the chunk in flight drain, return
+        {request_id: generated tokens}."""
         for req in requests or ():
             self.submit(req)
         while self.pending:
@@ -2137,8 +2425,9 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------ lifecycle
     def drain(self) -> Dict[int, RequestResult]:
         """Flush: refuse new submissions while finishing everything queued and
-        in flight, then reopen. Returns the full results map (the caller
-        `release()`s what it has consumed)."""
+        in flight (a chunk a step left running included), then reopen.
+        Returns the full results map (the caller `release()`s what it has
+        consumed)."""
         self._draining = True
         try:
             while self.pending:
@@ -2148,21 +2437,28 @@ class ContinuousBatcher:
         return self.results
 
     def close(self) -> Dict[int, RequestResult]:
-        """Terminal shutdown: everything still queued or in flight finishes with
-        `finish_reason="cancelled"` (partial tokens kept), and the engine
-        permanently refuses new work (`submit` raises `EngineClosed`, `step`
-        no-ops). Idempotent."""
+        """Terminal shutdown: a chunk still in flight is read back (its tokens
+        go to their results; nobody is handed events), then everything still
+        queued or in flight finishes with `finish_reason="cancelled"` (partial
+        tokens kept), and the engine permanently refuses new work (`submit`
+        raises `EngineClosed`, `step` no-ops). Idempotent."""
         if self._closed:
             return self.results
-        now = time.perf_counter()
         self._queue.clear()
+        if self._flights or self._fresh:
+            flight = self._flights[0] if self._flights else None
+            self._m_dispatching_steps.inc()  # the read a step() would have made
+            drained, _ = self._read_back(flight)
+            if drained is not None:
+                self._drain([], flight, *drained)
+            self._hand_back()
+        now = time.perf_counter()
         for slot, result in enumerate(self._slot_request):
             if result is not None:
                 self._finish(result, "cancelled", now=now, slot=slot)
         for result in self.results.values():
             if not result.finished:  # still queued (never admitted)
                 self._finish(result, "cancelled", now=now)
-        self._active[:] = False
         self._closed = True
         self._update_occupancy_gauges()
         return self.results

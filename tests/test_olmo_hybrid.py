@@ -259,16 +259,25 @@ def _assert_greedy(reference, model, prompt, tokens, width=96):
     assert list(best[len(prompt) - 1: len(prompt) - 1 + len(tokens)]) == list(tokens)
 
 
-def test_a_reused_slot_serves_its_second_request_as_a_fresh_engine_would(reference, model):
+@pytest.mark.parametrize("backlog", [False, True], ids=["no-backlog", "backlog"])
+def test_a_reused_slot_serves_its_second_request_as_a_fresh_engine_would(reference, model, backlog):
     """Six requests through two slots: every slot is released and given to a
     later request, whose state must be its own insert's — the tokens are the
-    reference's greedy continuation, and a fresh engine's."""
+    reference's greedy continuation, and a fresh engine's. Submitted at once
+    (a backlog) the engine runs a chunk ahead, and a slot's next tenant writes
+    its state behind the chunk that still holds the last one's; submitted as
+    slots free up it never does."""
     from accelerate_tpu.telemetry import FlightRecorder, Tracer
+
+    from test_serving import _serve
 
     tracer = Tracer(recorder=FlightRecorder())
     engine = ContinuousBatcher(model, num_slots=2, max_length=96, chunk_size=4, page_size=PAGE, tracer=tracer)
     requests = _requests(1, (5, 17, 33, 20, 3, 40))
-    served = engine.run(requests)
+    _serve(engine, requests, backlog)
+    served = {rid: r.tokens for rid, r in engine.results.items()}
+    assert (engine.stats["chunks_ahead_share"] >= 0.5) is backlog
+    assert engine.stats["slot_chunks_lost_to_eos"] == 0
     assert engine.trace_counts["decode_chunk"] == 1  # mixed admissions, one decode program
     assert engine.trace_counts["insert"] == len({1 << (len(r.input_ids) - 1).bit_length() for r in requests})
     assert engine.stats["waits_per_step"] == 1.0

@@ -424,10 +424,8 @@ def test_idle_slot_at_position_zero_contributes_nothing(block_pages):
 def _decode_logits(engine):
     """One more decode step's logits off an engine's live state (its own
     un-jitted step program; nothing is donated or adopted)."""
-    args = [
-        engine.params, engine._cache, jnp.asarray(engine._token), jnp.asarray(engine._pos),
-        jnp.asarray(engine._page_table),
-    ]
+    token, pos, _active, _rem = engine._carry  # the slot state lives on the device
+    args = [engine.params, engine._cache, token, pos, jnp.asarray(engine._page_table)]
     logits, _cache = jax.jit(engine._step_raw)(*args)
     return np.asarray(logits, np.float32)
 
@@ -478,8 +476,9 @@ def test_paged_read_logits_match_oracle_and_contiguous(family, monkeypatch):
     monkeypatch.setattr(attention, "_live_page_attention", _gather_everything_attention)
     everything = two_chunks()
     np.testing.assert_allclose(live_logits, _decode_logits(everything)[busy], atol=2e-5)
-    np.testing.assert_array_equal(live._token, everything._token)
-    np.testing.assert_array_equal(live._pos, everything._pos)
+    for mine, theirs in zip(live._carry, everything._carry):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+    np.testing.assert_array_equal(live._pos, np.asarray(live._carry[1]))  # the host's prediction
 
 
 # ------------------------------------------------------------------ parity

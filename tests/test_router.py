@@ -161,6 +161,7 @@ def test_replica_death_redispatches_only_never_streamed():
     for i, p in enumerate(prompts):
         router.submit(Request(i, p, max_new_tokens=10))
     router.step()  # 0 and 1 in flight (one per replica); 2, 3 queued
+    router.step()  # with a queue behind them the engines run a chunk ahead: tokens from the second step
     victim_rid = 0 if router.results[0].tokens else 1
     victim_replica = next(
         a["replica"] for a in router._tracked[victim_rid]["attempts"]

@@ -369,14 +369,23 @@ def _count_device_gets(monkeypatch):
     return calls
 
 
+def _step_attrs(recorder):
+    return [r["attrs"] for r in recorder.records() if r["name"] == "serve.step"]
+
+
+@pytest.mark.parametrize("backlog", [False, True], ids=["no-backlog", "backlog"])
 @pytest.mark.parametrize("admissions", [0, 1, 3])
 @pytest.mark.parametrize("kind", list(_ENGINE_KINDS))
-def test_step_dispatches_everything_and_waits_once(monkeypatch, kind, admissions):
+def test_step_dispatches_everything_and_waits_once(monkeypatch, kind, admissions, backlog):
     """Whatever a step admits, it reads the device back once, after every
     insert and the chunk are enqueued (`waits` / `dispatched_ahead` of
     `serve.step` say the same); a request's first token comes ahead of its
-    chunk tokens in the step's events; nothing is traced twice over the run;
-    and the tokens are the static Generator's."""
+    chunk tokens in the events; nothing is traced twice over the run; and the
+    tokens are the static Generator's. With requests left in the queue
+    (`backlog`) the step that admits leaves its chunk in flight and reads
+    nothing — the first such step — and the NEXT step, which dispatches its
+    own chunk behind it, hands these admissions their tokens; a speculative
+    engine waits for its own chunk either way."""
     from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
     from accelerate_tpu.telemetry.tracing import Tracer
 
@@ -387,6 +396,9 @@ def test_step_dispatches_everything_and_waits_once(monkeypatch, kind, admissions
         model, num_slots=4, max_length=64, chunk_size=3,
         tracer=Tracer(recorder=recorder, category="serve"), **_ENGINE_KINDS[kind],
     )
+    ahead = backlog and kind != "speculative"
+    assert engine.stats["run_ahead"]["enabled"] is (kind != "speculative")
+    assert ("speculative" in (engine.stats["run_ahead"]["disabled_reason"] or "")) is (kind == "speculative")
     penalty = {"repetition_penalty": 1.5} if kind == "penalty" else {}
     resident = rng.integers(1, 128, (5,)).astype(np.int32)
     engine.submit(Request(100, resident, max_new_tokens=30, **penalty))
@@ -394,20 +406,35 @@ def test_step_dispatches_everything_and_waits_once(monkeypatch, kind, admissions
     prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 3, 11)[:admissions]]
     for i, p in enumerate(prompts):
         engine.submit(Request(i, p, max_new_tokens=6, **penalty))
+    queued = {}
+    if backlog:  # fill the other slots and leave two requests waiting
+        for i in range(200, 200 + (3 - admissions) + 2):
+            queued[i] = rng.integers(1, 128, (4,)).astype(np.int32)
+            engine.submit(Request(i, queued[i], max_new_tokens=7, **penalty))
+    fillers = 3 - admissions if backlog else 0
 
     calls = _count_device_gets(monkeypatch)
-    steps_before = len([r for r in recorder.records() if r["name"] == "serve.step"])
+    steps_before = len(_step_attrs(recorder))
     events = engine.step()
-    assert len(calls) == 1
+    step = _step_attrs(recorder)[steps_before]
+    assert (step["inserts"], step["dispatched_ahead"]) == (admissions + fillers, admissions + fillers + 1)
+    if ahead:
+        # the step that starts running ahead: everything enqueued, nothing read
+        assert len(calls) == 0 and events == []
+        assert (step["waits"], step["in_flight_at_return"]) == (0, 1) and len(engine._flights) == 1
+        events = engine.step()  # dispatches its chunk behind that one, then reads that one
+        step = _step_attrs(recorder)[steps_before + 1]
+        assert (step["inserts"], step["dispatched_ahead"], step["in_flight_at_return"]) == (0, 1, 1)
+    assert len(calls) == 1 and step["waits"] == 1
     monkeypatch.undo()
-    step = [r for r in recorder.records() if r["name"] == "serve.step"][steps_before]["attrs"]
-    assert (step["inserts"], step["waits"], step["dispatched_ahead"]) == (admissions, 1, admissions + 1)
-    assert not engine._fresh  # nothing is in flight when step() returns
+    if not ahead:
+        assert step["in_flight_at_return"] == 0 and not engine._flights
+    assert not engine._fresh  # every admission's first token has been handed out
 
     by_request = {}
     for rid, toks in events:
         by_request.setdefault(rid, []).append(toks)
-    assert set(by_request) == {100, *range(admissions)}
+    assert set(by_request) == {100, *range(admissions), *list(queued)[:fillers]}
     for i in range(admissions):
         first, *rest = by_request[i]
         assert len(first) == 1 and rest, by_request[i]  # the first token, then the chunk's
@@ -424,39 +451,58 @@ def test_step_dispatches_everything_and_waits_once(monkeypatch, kind, admissions
     outputs = engine.run()
     for i, p in enumerate(prompts):
         np.testing.assert_array_equal(outputs[i], _static_reference(model, p, 6, **penalty))
+    for i, p in queued.items():
+        np.testing.assert_array_equal(outputs[i], _static_reference(model, p, 7, **penalty))
     np.testing.assert_array_equal(outputs[100], _static_reference(model, resident, 30, **penalty))
-    assert engine.trace_counts["decode_chunk"] == 1
-    assert engine.trace_counts["insert"] == len(engine._insert_fns) == len({8, *(8, 4, 16)[:admissions]})
-    assert engine.stats["waits_per_step"] == 1.0
+    assert engine.trace_counts["decode_chunk"] == 1  # one chunk program, running ahead or not
+    assert engine.trace_counts["insert"] == len(engine._insert_fns) == len(
+        {8, *(8, 4, 16)[:admissions], *([4] if backlog else [])})
+    assert engine.stats["waits_per_step"] == 1.0 and not engine.pending
+    assert (engine.stats["chunks_ahead_share"] > 0) is ahead
+    assert all(s["waits"] <= 1 for s in _step_attrs(recorder))
     idle = engine.step()  # nothing queued, nothing active: no dispatch, no wait
     assert idle == [] and engine.stats["waits_per_step"] == 1.0
-    assert [r for r in recorder.records() if r["name"] == "serve.step"][-1]["attrs"]["waits"] == 0
+    assert _step_attrs(recorder)[-1]["waits"] == 0
 
 
 def _first_greedy_token(model, prompt):
     return int(_static_reference(model, prompt, 1)[0])
 
 
+@pytest.mark.parametrize("backlog", [False, True], ids=["no-backlog", "backlog"])
 @pytest.mark.parametrize("kind", list(_ENGINE_KINDS))
-def test_first_token_eos_ends_the_request_on_the_device(kind):
+def test_first_token_eos_ends_the_request_on_the_device(kind, backlog):
     """A first token that is the request's EOS: the host has not seen it when
     it pushes the chunk's operands, so the chunk clears the slot itself — the
     request ends with that one token, as "eos", beside a neighbour that
-    decodes on undisturbed."""
+    decodes on undisturbed. With a request waiting behind them the chunk is
+    read a step later, and the slot the host had predicted busy has by then
+    sat out the chunk dispatched meanwhile: counted."""
     model = _model()
     rng = np.random.default_rng(34)
-    prompt, other = (rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 9))
+    prompt, other, waiting = (rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 9, 5))
     eos = _first_greedy_token(model, prompt)
     engine = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=3, **_ENGINE_KINDS[kind])
     engine.submit(Request(0, prompt, max_new_tokens=8, eos_token_id=eos))
     engine.submit(Request(1, other, max_new_tokens=7))
+    if backlog:
+        engine.submit(Request(2, waiting, max_new_tokens=4))
     events = engine.step()
+    ahead = backlog and kind != "speculative"
+    if ahead:
+        assert events == [] and not engine.results[0].finished  # still on the device
+        events = engine.step()
     assert events[0] == (0, [eos]) and [rid for rid, _ in events].count(0) == 1
     assert engine.results[0].finished and engine.results[0].finish_reason == "eos"
     assert engine.results[0].tokens == [eos]
-    assert engine.free_slots == 1 and engine.pool.pages_in_use == len(engine._slot_pages[1])
+    assert engine.stats["slot_chunks_lost_to_eos"] == (1 if ahead else 0)
+    if not backlog:
+        assert engine.free_slots == 1 and engine.pool.pages_in_use == len(engine._slot_pages[1])
     outputs = engine.run()
     np.testing.assert_array_equal(outputs[1], _static_reference(model, other, 7))
+    if backlog:
+        np.testing.assert_array_equal(outputs[2], _static_reference(model, waiting, 4))
+    assert engine.pool.pages_in_use == 0 and engine.stats["slot_chunks_lost_to_eos"] == (1 if ahead else 0)
 
 
 @pytest.mark.parametrize("slots", [1, 2, 3])
@@ -465,7 +511,9 @@ def test_one_token_requests_in_one_step_each_get_their_own_token(monkeypatch, sl
     so that two of them admitted together never share an entry of the
     first-token buffer: with one slot they take a step each, with more they
     share a step — whose only work is their inserts: no chunk, and the one
-    wait is the read of the buffer alone. Pages all come back."""
+    wait is the read of the buffer alone. Pages all come back. With fewer
+    slots than requests a queue waits behind them — and with nothing to
+    decode there is no chunk to leave in flight: every step reads its own."""
     model = _model()
     rng = np.random.default_rng(35)
     prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (5, 12, 7)]
@@ -478,6 +526,7 @@ def test_one_token_requests_in_one_step_each_get_their_own_token(monkeypatch, sl
     steps = []
     while engine.pending:
         steps.append(engine.step())
+        assert not engine._flights
     assert [len(events) for events in steps] == {1: [1, 1, 1], 2: [2, 1], 3: [3]}[slots]
     assert len(calls) == len(steps)  # one wait a step
     assert [e for events in steps for e in events] == [(i, [t]) for i, t in enumerate(expected)]
@@ -511,6 +560,244 @@ def test_one_token_request_beside_a_decoding_slot_and_a_prefix_hit():
     for rid, (p, m) in enumerate([(single, 1), (long_a, 6), (long_b, 5), (long_b, 1)]):
         np.testing.assert_array_equal(outputs[rid], _static_reference(model, p, m))
     assert engine.pool.pages_in_use == 0
+
+
+# ------------------------------------------- one chunk ahead under a backlog (ISSUE 35)
+# While requests wait in the queue a step leaves its decode chunk in flight and
+# the next step enqueues its inserts and its own chunk BEHIND it before it
+# reads it back; slot state is carried on the device, the host works from a
+# predicted mirror. With an empty queue a step is what it was.
+
+
+def _recorded_engine(model, **kwargs):
+    from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
+    from accelerate_tpu.telemetry.tracing import Tracer
+
+    recorder = FlightRecorder()
+    return ContinuousBatcher(model, tracer=Tracer(recorder=recorder, category="serve"), **kwargs), recorder
+
+
+def _serve(engine, requests, backlog):
+    """Step `requests` through `engine` to the end: all submitted at once (more
+    than the slots take: a backlog), or each only when a slot is free for it,
+    so that no step ever finds a request left in its queue. Returns the events
+    of every step."""
+    waiting, steps = list(requests), []
+    while waiting or engine.pending:
+        while waiting and (backlog or engine.free_slots > engine.queue_depth):
+            engine.submit(waiting.pop(0))
+        steps.append(engine.step())
+    return steps
+
+
+_FAMILIES = {
+    "pythia-tiny": ("gpt-neox-tiny", {}),
+    "latent-tiny": ("latent-moe-tiny", {}),
+    "olmo-hybrid-tiny": ("olmo-hybrid-tiny", {}),
+}
+
+
+@pytest.mark.parametrize("sampling", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_a_backlog_is_served_the_synchronous_paths_tokens(family, sampling):
+    """Ten requests through two slots, submitted at once (the engine runs a
+    chunk ahead on nearly every step) and as slots free up (it never does):
+    the same programs run on the same operands in the same order, so every
+    request's tokens are equal — greedy, and sampled from one seed at
+    per-request temperatures — for a page-only family, the latent family and
+    the family with by-slot state. One decode program either way."""
+    from accelerate_tpu.models import create_named_model
+
+    name, kwargs = _FAMILIES[family]
+    model = create_named_model(name, **kwargs)
+    vocab = model.module.config.vocab_size
+    rng = np.random.default_rng(351)
+    lengths, budgets = (5, 17, 9, 3, 12, 7, 20, 4, 11, 6), (9, 4, 13, 6, 5, 11, 3, 8, 1, 7)
+
+    def requests():
+        return [Request(i, rng_i, max_new_tokens=m, temperature=0.7 + 0.1 * i if sampling else 1.0)
+                for i, (rng_i, m) in enumerate(zip(prompts, budgets))]
+
+    prompts = [rng.integers(1, vocab, (n,)).astype(np.int32) for n in lengths]
+    sampler = {"do_sample": True, "top_k": 8, "rng": jax.random.key(35)} if sampling else {}
+    served = {}
+    for backlog in (True, False):
+        engine, recorder = _recorded_engine(model, num_slots=2, max_length=48, chunk_size=4, page_size=8, **sampler)
+        _serve(engine, requests(), backlog)
+        served[backlog] = {i: list(r.tokens) for i, r in engine.results.items()}
+        assert all(r.finish_reason == "length" for r in engine.results.values())
+        assert engine.trace_counts["decode_chunk"] == 1 and engine.stats["waits_per_step"] == 1.0
+        share = engine.stats["chunks_ahead_share"]
+        assert share >= 0.5 if backlog else share == 0.0
+        in_flight = [a["in_flight_at_return"] for a in _step_attrs(recorder)]
+        assert max(in_flight) == (1 if backlog else 0) and in_flight[-1] == 0
+        assert engine.pool.pages_in_use == 0 and engine.stats["slot_chunks_lost_to_eos"] == 0
+    assert served[True] == served[False]
+    assert [len(served[True][i]) for i in range(len(budgets))] == list(budgets)
+
+
+def test_a_slot_predicted_free_is_given_away_before_its_last_tokens_are_drained(monkeypatch):
+    """A request that ends by length is known a chunk early: its slot, its
+    pages and its table row are vacated when its last chunk is DISPATCHED, the
+    next queued request's insert is enqueued behind that chunk, and only the
+    drain that hands out the last tokens finishes the result — until then it
+    reads unfinished and `release()` refuses it."""
+    model = _model()
+    rng = np.random.default_rng(352)
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (5, 9, 6)]
+    engine = ContinuousBatcher(model, num_slots=1, max_length=32, chunk_size=4, page_size=8)
+    for i, (p, m) in enumerate(zip(prompts, (6, 9, 3))):
+        engine.submit(Request(i, p, max_new_tokens=m))
+    assert engine.step() == [] and engine._slot_of(0) == 0  # chunk 1 of request 0 in flight
+    events = engine.step()  # chunk 2 — its last token — dispatched behind it; chunk 1 read
+    assert [toks for rid, toks in events if rid == 0] == [engine.results[0].tokens[:1], engine.results[0].tokens[1:5]]
+    assert engine.free_slots == 1 and engine.pool.pages_in_use == 0  # vacated on the prediction
+    assert not engine._pos.any() and not engine._page_table.any()
+    assert not engine.results[0].finished and len(engine.results[0].tokens) == 5
+    with pytest.raises(ValueError, match="in flight"):
+        engine.release(0)
+    tenants_at_drain = []
+    drain = engine._drain
+    monkeypatch.setattr(engine, "_drain", lambda *a: (
+        tenants_at_drain.append((engine._slot_request[0].request_id, engine.results[0].finished)), drain(*a))[1])
+    events = engine.step()
+    monkeypatch.undo()
+    assert tenants_at_drain == [(1, False)]  # request 1 holds the slot before request 0's last token is drained
+    assert (0, [engine.results[0].tokens[-1]]) in events
+    result = engine.release(0)
+    assert result.finished and result.finish_reason == "length"
+    np.testing.assert_array_equal(result.tokens, _static_reference(model, prompts[0], 6))
+    outputs = engine.run()
+    for i, m in ((1, 9), (2, 3)):
+        np.testing.assert_array_equal(outputs[i], _static_reference(model, prompts[i], m))
+    assert engine.stats["finish_reasons"]["length"] == 3 and engine.pool.pages_in_use == 0
+
+
+def test_an_eos_stop_costs_one_chunk_of_one_slot_under_a_backlog():
+    """The host cannot predict an EOS: under a backlog it learns of it one
+    chunk late, and the chunk already in flight carries the slot inactive —
+    one chunk of one slot, counted by `slot_chunks_lost_to_eos`, never paid
+    with an empty queue — while every request's tokens are what the
+    synchronous path serves."""
+    model = _model()
+    rng = np.random.default_rng(353)
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 9, 4, 7, 5)]
+    free_run = _static_reference(model, prompts[0], 12)
+    eos = int(free_run[4])  # lands inside its second chunk of 3, six tokens short of its budget
+    assert eos not in free_run[:4]
+
+    def requests():
+        return [Request(0, prompts[0], max_new_tokens=12, eos_token_id=eos)] + [
+            Request(i, p, max_new_tokens=7) for i, p in enumerate(prompts) if i]
+
+    stats, served = {}, {}
+    for backlog in (True, False):
+        engine = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=3)
+        _serve(engine, requests(), backlog)
+        stats[backlog], served[backlog] = engine.stats, {i: list(r.tokens) for i, r in engine.results.items()}
+        assert engine.results[0].finish_reason == "eos" and engine.pool.pages_in_use == 0
+    np.testing.assert_array_equal(served[True][0], _static_reference(model, prompts[0], 12, eos_token_id=eos))
+    assert served[True] == served[False]
+    assert stats[True]["slot_chunks_lost_to_eos"] == 1 and stats[False]["slot_chunks_lost_to_eos"] == 0
+    assert stats[True]["chunks"] <= stats[False]["chunks"] + 1
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_cancel_and_deadline_with_a_chunk_in_flight(how):
+    """A request that is cancelled, or times out, while its chunk is in flight:
+    no token of it is handed out by any later step (the chunk streams on; the
+    drain drops them), the device is told with the next dispatch, and its
+    pages — free for the next admission at once — are written by their next
+    tenant's insert only after the chunk in flight, so every other request is
+    served the static path's tokens."""
+    model = _model()
+    rng = np.random.default_rng(354)
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (5, 7, 6, 4, 9)]
+    engine = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=2, num_pages=5)
+    for i, p in enumerate(prompts):
+        engine.submit(Request(i, p, max_new_tokens=10, deadline_s=1000.0 if i == 0 else None))
+    engine.step(), engine.step(), engine.step()
+    assert engine._flights and engine._flights[0].tenants[engine._slot_of(0)] is engine.results[0]
+    kept, pages = list(engine.results[0].tokens), list(engine._slot_pages[engine._slot_of(0)])
+    assert kept and pages
+    if how == "cancel":
+        assert engine.cancel(0) is True
+        assert engine.results[0].finished and engine.free_slots == 1
+    else:
+        engine._deadlines[0] = 0.0  # the next step's sweep finds it expired
+    events = engine.step()  # reads the chunk that still held request 0; admits request 2 into its slot
+    assert all(rid != 0 for rid, _ in events)
+    assert engine.results[0].finish_reason == ("cancelled" if how == "cancel" else "timeout")
+    assert engine._slot_of(2) is not None and set(engine._slot_pages[engine._slot_of(2)]) & set(pages)
+    later = [rid for events in _serve(engine, [], backlog=True) for rid, _ in events]
+    assert 0 not in later and engine.results[0].tokens == kept
+    for i in range(1, 5):
+        np.testing.assert_array_equal(engine.results[i].tokens, _static_reference(model, prompts[i], 10))
+    assert engine.pool.pages_in_use == 0 and not engine.pending
+
+
+@pytest.mark.parametrize("how", ["drain", "close", "run"])
+def test_lifecycle_calls_with_a_chunk_in_flight(how):
+    """A chunk a step left running is pending work: `run()` and `drain()` step
+    until it is read back and everything finishes; `close()` reads it back —
+    its tokens reach their results — before it cancels what is left."""
+    model = _model()
+    rng = np.random.default_rng(355)
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (5, 8, 4)]
+    engine = ContinuousBatcher(model, num_slots=1, max_length=32, chunk_size=3)
+    for i, p in enumerate(prompts):
+        engine.submit(Request(i, p, max_new_tokens=6))
+    assert engine.step() == [] and engine._flights and engine.pending
+    if how == "close":
+        results = engine.close()
+        np.testing.assert_array_equal(results[0].tokens, _static_reference(model, prompts[0], 6)[:4])
+        assert [results[i].finish_reason for i in range(3)] == ["cancelled"] * 3
+        assert results[0].first_token_time is not None and not results[1].tokens
+        assert engine.closed and not engine.pending and not engine._flights
+        assert engine.pool.pages_in_use == 0 and engine.stats["waits_per_step"] == 1.0
+        return
+    results = engine.drain() if how == "drain" else (engine.run(), engine.results)[1]
+    assert not engine.pending and not engine._flights
+    for i, p in enumerate(prompts):
+        assert results[i].finish_reason == "length"
+        np.testing.assert_array_equal(results[i].tokens, _static_reference(model, p, 6))
+    engine.submit(Request(3, prompts[0], max_new_tokens=2))  # reopened, and synchronous again
+    assert [rid for rid, _ in engine.step()] == [3, 3] and not engine._flights
+
+
+@pytest.mark.faults
+def test_a_failing_chunk_condemns_the_successor_dispatched_behind_it(monkeypatch):
+    """A failure that surfaces at one chunk's readback while its successor —
+    and the inserts enqueued between them — is already in flight: they
+    consumed the donated cache, so both steps' requests error (a request
+    vacated on a prediction, whose last tokens that readback held, too); the
+    engine keeps serving what was queued."""
+    model = _model()
+    rng = np.random.default_rng(356)
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (5, 7, 6, 4)]
+    engine = ContinuousBatcher(model, num_slots=2, max_length=32, chunk_size=3)
+    for i, (p, m) in enumerate(zip(prompts, (3, 9, 5, 4))):
+        engine.submit(Request(i, p, max_new_tokens=m))
+    assert engine.step() == []  # requests 0 and 1 in the chunk in flight; 0 ends in it: vacated
+    assert engine.free_slots == 1 and not engine.results[0].finished
+
+    def dying_read(tree):
+        raise RuntimeError("device halted in a chunk")
+
+    monkeypatch.setattr(jax, "device_get", dying_read)
+    assert engine.step() == []  # admits 2, dispatches the successor, then reads the first chunk: dies
+    monkeypatch.undo()
+    for rid in (0, 1, 2):
+        assert engine.results[rid].finish_reason == "error", rid
+        assert "device halted" in engine.results[rid].error and engine.results[rid].tokens == []
+    assert not engine.results[3].finished and not engine._flights and not engine._fresh
+    assert engine.free_slots == 2 and engine.pool.pages_in_use == 0 and not engine._active.any()
+    engine.submit(Request(4, prompts[1], max_new_tokens=6))
+    outputs = engine.run()
+    np.testing.assert_array_equal(outputs[3], _static_reference(model, prompts[3], 4))
+    np.testing.assert_array_equal(outputs[4], _static_reference(model, prompts[1], 6))
+    assert engine.stats["finish_reasons"]["error"] == 3 and engine.stats["waits_per_step"] == 1.0
 
 
 # ------------------------------------------------------------- fault isolation
@@ -556,26 +843,39 @@ def test_inflight_deadline_keeps_partial_tokens_and_frees_slot():
 
 
 @pytest.mark.faults
-def test_cancel_queued_and_inflight_requests():
+@pytest.mark.parametrize("backlog", [False, True], ids=["no-backlog", "backlog"])
+def test_cancel_queued_and_inflight_requests(backlog):
+    """Cancel while queued: no tokens at all. Cancel mid-flight: the tokens
+    handed out so far are kept — with a request still queued behind it the
+    engine has a chunk in flight, whose tokens for the cancelled request no
+    later step hands out."""
     model = _model()
     rng = np.random.default_rng(12)
     engine = ContinuousBatcher(model, num_slots=1, max_length=64, chunk_size=2)
     prompt = rng.integers(1, 128, (4,)).astype(np.int32)
     engine.submit(Request(0, prompt, max_new_tokens=24))
-    engine.submit(Request(1, prompt, max_new_tokens=4))
+    if backlog:
+        engine.submit(Request(1, prompt, max_new_tokens=4))
     engine.step()  # 0 in flight, 1 queued
+    engine.step()
+    if not backlog:
+        engine.submit(Request(1, prompt, max_new_tokens=4))  # queued, and no step has seen it
     assert engine.cancel(1) is True  # cancel while queued: no tokens at all
     assert engine.results[1].finish_reason == "cancelled"
     assert engine.results[1].tokens == []
+    assert bool(engine._flights) is backlog
+    kept = list(engine.results[0].tokens)
     assert engine.cancel(0) is True  # cancel mid-flight: partial tokens kept
     assert engine.results[0].finish_reason == "cancelled"
-    assert engine.results[0].tokens and engine.free_slots == 1
+    assert kept and engine.free_slots == 1
     assert engine.cancel(0) is False  # already finished
     with pytest.raises(KeyError):
         engine.cancel(99)
     engine.submit(Request(2, prompt, max_new_tokens=4))
-    outputs = engine.run()
-    np.testing.assert_array_equal(outputs[2], _static_reference(model, prompt, 4))
+    streamed = [rid for _ in range(32) if engine.pending for rid, _ in engine.step()]
+    assert 0 not in streamed and engine.results[0].tokens == kept  # the chunk in flight streamed on: dropped
+    np.testing.assert_array_equal(engine.results[2].tokens, _static_reference(model, prompt, 4))
+    assert not engine.pending and engine.pool.pages_in_use == 0
 
 
 @pytest.mark.faults

@@ -284,15 +284,24 @@ def test_serving_request_lifecycle_spans():
     assert chunks and all(r["attrs"]["active_slots"] >= 1 for r in chunks)
 
 
-def test_serving_step_span_tree_and_hand_back():
+@pytest.mark.parametrize("backlog", [False, True], ids=["no-backlog", "backlog"])
+def test_serving_step_span_tree_and_hand_back(backlog):
     """One `serve.step` a step() over its inserts and its decode chunk; the
     step's host and device-wait seconds add up to it, and it waits once
     (`waits`), after everything it dispatches (`dispatched_ahead`): an insert
     is dispatch only, with no `serve.insert.wait` under it; every request is
-    handed back once, its first token on the host only since the step's drain
+    handed back once, its first token on the host only since a step's drain
     (`held_s`), and TTFT is observed there (a one-token request and one that
-    ends in its first chunk included: their spans wait for the event)."""
+    ends in its first chunk included: their spans wait for the event).
+
+    With an empty queue the tree is what it was: nothing is in flight when a
+    step returns (`in_flight_at_return` 0), no chunk is `ahead`, and a chunk's
+    span lies inside the step that dispatched it. With a backlog the chunk's
+    span — still ONE record a chunk, opened at its dispatch, a child of the
+    step that dispatched it — ends in the NEXT step, at its readback."""
     from accelerate_tpu.serving import ContinuousBatcher, Request
+
+    from test_serving import _serve  # all at once, or each request when a slot is free for it
 
     recorder = FlightRecorder()
     tracer = Tracer(recorder=recorder, category="serve")
@@ -302,13 +311,11 @@ def test_serving_step_span_tree_and_hand_back():
                                tracer=tracer)
     rng = np.random.default_rng(1)
     lengths = [9, 1, 3, 6, 12]
-    for i, n in enumerate(lengths):
-        engine.submit(Request(i, rng.integers(1, 128, (6,)).astype(np.int32), max_new_tokens=n))
-    stepped = 0
-    while engine.pending:
-        engine.step()
-        stepped += 1
+    stepped = len(_serve(engine, [
+        Request(i, rng.integers(1, 128, (6,)).astype(np.int32), max_new_tokens=n)
+        for i, n in enumerate(lengths)], backlog))
     results = dict(engine.results)
+    stats = engine.stats
     engine.close()
 
     records = recorder.records()
@@ -320,32 +327,48 @@ def test_serving_step_span_tree_and_hand_back():
             by_parent.setdefault(r["parent_id"], []).append(r)
     assert set(by_parent) <= {s["span_id"] for s in steps}  # nothing outside a step
     assert all(s["parent_id"] is None for s in steps)
+    in_flight = 0  # chunks the step before left running
     for step in steps:
         attrs = step["attrs"]
         children = by_parent.get(step["span_id"], [])
         inserts = [c for c in children if c["name"] == "serve.insert"]
         chunks = [c for c in children if c["name"] == "serve.decode_chunk"]
         assert len(inserts) == attrs["inserts"] and len(chunks) <= 1
-        assert attrs["host_s"] + attrs["device_wait_s"] == pytest.approx(step["duration_s"], abs=2e-4)
-        parts = (attrs["admit_s"] + attrs["push_s"] + attrs["dispatch_s"] + attrs["drain_s"]
-                 + (chunks[0]["duration_s"] - attrs["push_s"] - attrs["dispatch_s"] if chunks else 0.0))
-        assert parts <= step["duration_s"] + 2e-4
+        # (the span ends a moment after it is annotated: a millisecond of room under a loaded host)
+        assert attrs["host_s"] + attrs["device_wait_s"] == pytest.approx(step["duration_s"], abs=1e-3)
+        assert (attrs["admit_s"] + attrs["push_s"] + attrs["dispatch_s"] + attrs["device_wait_s"]
+                + attrs["drain_s"]) <= step["duration_s"] + 2e-4
         assert attrs["device_wait_s"] >= sum(c["attrs"]["device_wait_s"] for c in inserts) - 1e-5
         assert all(c["attrs"]["device_wait_s"] == 0.0 for c in inserts)  # dispatch only
         assert attrs["dispatched_ahead"] == len(inserts) + len(chunks)
-        assert attrs["waits"] == (1 if children else 0)
+        # one wait whenever there is something to read back: an older chunk, or this step's own work
+        leaves_one = attrs["in_flight_at_return"]
+        assert attrs["waits"] == (1 if in_flight or (children and not leaves_one) else 0)
+        assert [c["attrs"]["ahead"] for c in chunks] == [bool(in_flight)] * len(chunks)
         for child in children:
-            assert step["start_unix"] <= child["start_unix"] and child["end_unix"] <= step["end_unix"]
+            assert step["start_unix"] <= child["start_unix"]
+            if child["name"] == "serve.insert" or not leaves_one:
+                assert child["end_unix"] <= step["end_unix"]
+            else:  # left in flight: read back, and ended, by a later step
+                assert child["end_unix"] > step["end_unix"]
+        in_flight = leaves_one
+    assert in_flight == 0 and max(s["attrs"]["in_flight_at_return"] for s in steps) == int(backlog)
     assert sum(s["attrs"]["inserts"] for s in steps) == len(lengths)
+    chunks = [r for r in records if r["name"] == "serve.decode_chunk"]
+    assert len(chunks) == stats["chunks"]  # one record a chunk
+    assert stats["chunks_ahead_share"] == pytest.approx(
+        sum(c["attrs"]["ahead"] for c in chunks) / len(chunks))
+    assert (stats["chunks_ahead_share"] > 0) is backlog
     assert not [r for r in records if r["name"] in (
         "serve.admit", "serve.insert.wait", "serve.chunk.push", "serve.chunk.dispatch",
         "serve.chunk.wait", "serve.first_tokens.wait", "serve.drain")]  # annotations only
-    # the tree of a step: no wait under an insert, one wait under the chunk
-    assert set(opened) == {"serve.step", "serve.admit", "serve.insert", "serve.decode_chunk",
+    # the tree of a step: no wait under an insert, one wait a step (`serve.decode_chunk` outlives a
+    # call frame under a backlog, so it is opened by `start_span`, as `serve.request` is)
+    assert set(opened) == {"serve.step", "serve.admit", "serve.insert",
                            "serve.chunk.push", "serve.chunk.dispatch", "serve.chunk.wait",
                            "serve.drain"}
     assert opened.count("serve.chunk.wait") == len([s for s in steps if s["attrs"]["waits"]])
-    assert engine.stats["waits_per_step"] == 1.0
+    assert stats["waits_per_step"] == 1.0
 
     requests = {r["attrs"]["request_id"]: r for r in records if r["name"] == "serve.request"}
     ttft_sum = 0.0
@@ -354,7 +377,7 @@ def test_serving_step_span_tree_and_hand_back():
         assert [e["name"] for e in requests[rid]["events"]].count("handed_back") == 1
         handed = events["handed_back"]["attrs"]
         assert handed["held_s"] >= 0 and handed["ttft_s"] >= handed["held_s"]
-        # the token reaches the host as its step drains: held for part of that drain, no chunk
+        # the token reaches the host as a step drains: held for part of that drain, no chunk
         assert handed["held_s"] <= max(s["attrs"]["drain_s"] for s in steps) + 1e-4
         assert events["admitted"]["attrs"]["queue_wait_s"] <= handed["ttft_s"]
         assert requests[rid]["attrs"]["tokens"] == n
@@ -367,6 +390,64 @@ def test_serving_step_span_tree_and_hand_back():
     ttft = engine.metrics.get("serving_ttft_seconds")
     assert ttft.count == len(lengths)
     assert ttft.sum == pytest.approx(ttft_sum, abs=1e-9)
+
+
+@pytest.mark.parametrize("family", ["gpt-neox-tiny", "latent-moe-tiny", "olmo-hybrid-tiny"])
+def test_decode_chunk_span_carries_what_the_benchmarks_readers_take(family):
+    """`serve.decode_chunk` is one record a chunk, starts at its dispatch, and
+    carries — running ahead or not — every attribute `chipbench/chunk_counters.py`
+    and the roofline readers ask of it: the host's counts as it is dispatched,
+    the readback's as it is ended. The run switches from running ahead (a
+    backlog) to not (the queue runs dry) and back: one chunk program, compiled
+    once; the insert's signature is the parent's."""
+    import inspect
+
+    from accelerate_tpu.models import create_named_model
+    from accelerate_tpu.serving import ContinuousBatcher, Request
+
+    needs = {"chunk_size", "active_slots", "pages_in_use", "live_pages", "window_pages", "read_blocks",
+             "kv_row_values", "tokens_streamed", "ahead"}
+    needs |= {"latent-moe-tiny": {"expert_tokens_max", "expert_tokens_mean", "experts_touched"},
+              "olmo-hybrid-tiny": {"state_slots", "state_bytes_per_slot", "kv_page_bytes"}}.get(family, set())
+    model = create_named_model(family)
+    recorder = FlightRecorder()
+    tracer = Tracer(recorder=recorder, category="serve")
+    engine = ContinuousBatcher(model, num_slots=2, max_length=48, chunk_size=3, page_size=8, tracer=tracer)
+    rng = np.random.default_rng(7)
+    vocab = model.module.config.vocab_size
+
+    def wave(first_id):
+        for i in range(first_id, first_id + 4):
+            engine.submit(Request(i, rng.integers(1, vocab, (5 + i,)).astype(np.int32), max_new_tokens=7))
+        dispatched = []
+        while engine.pending:
+            before = tracer.now()
+            engine.step()
+            dispatched.append((before, tracer.now()))
+        return dispatched
+
+    windows = wave(0) + wave(10)  # a backlog, the queue dry, a backlog again
+    chunks = [r for r in recorder.records() if r["name"] == "serve.decode_chunk"]
+    steps = [r for r in recorder.records() if r["name"] == "serve.step"]
+    assert len(chunks) == engine.stats["chunks"] and engine.trace_counts["decode_chunk"] == 1
+    assert engine._chunk_fn._cache_size() == 1
+    ahead = [c["attrs"]["ahead"] for c in chunks]
+    assert True in ahead and False in ahead and ahead[0] is False
+    assert {s["attrs"]["in_flight_at_return"] for s in steps} == {0, 1}
+    assert engine.stats["chunks_ahead_share"] == pytest.approx(sum(ahead) / len(ahead))
+    assert engine.metrics.get("serving_chunks_ahead_share").value == engine.stats["chunks_ahead_share"]
+    by_id = {s["span_id"]: s for s in steps}
+    for chunk in chunks:
+        assert needs <= set(chunk["attrs"]), needs - set(chunk["attrs"])
+        step = by_id[chunk["parent_id"]]  # the step that dispatched it: the span starts inside it
+        assert step["start_unix"] <= chunk["start_unix"] <= step["end_unix"]
+        assert chunk["end_unix"] > chunk["start_unix"] and chunk["attrs"]["tokens_streamed"] >= 1
+        assert sum(a <= chunk["start_unix"] <= b for a, b in windows) == 1
+    insert = inspect.signature(engine._insert_fn(8).__wrapped__)
+    assert list(insert.parameters) == [
+        "params", "pool_cache", "presence", "suffix_ids", "real_len", "matched_len", "matched_pages",
+        "page_row", "slot", "temperature", "penalty", "rng", "first_token"]
+    engine.close()
 
 
 def test_engine_ttft_is_the_routers_on_one_replica():
