@@ -951,7 +951,7 @@ class Accelerator:
                 "train.step", category="train", step=counter["step"]
             ):
                 out = step_fn(*args, **kwargs)
-            timeline.step_done(out)
+            timeline.step_done()
             loader = gradient_state.active_dataloader
             waited = getattr(loader, "data_wait_s", None)
             if waited is not None:

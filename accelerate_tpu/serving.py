@@ -200,7 +200,7 @@ class _Flight:
     read: Dict[str, Any]  # the chunk's outputs the drain takes, on the device
     span: Any  # its open `serve.decode_chunk` span
     tenants: List[Optional["RequestResult"]]  # slot -> request
-    fresh: List[int]  # slots whose first token rides it, in admission order
+    fresh: List[Tuple[int, float, int]]  # slots whose first token rides it (`_fresh`), in admission order
     was_active: np.ndarray  # bool[num_slots]: decoding in this chunk
     ends: np.ndarray  # bool[num_slots]: vacated at its dispatch; the drain finishes the result
     eos: np.ndarray  # int32[num_slots]: the tenants' EOS ids
@@ -218,6 +218,10 @@ class EngineClosed(RuntimeError):
 
 #: Every value `RequestResult.finish_reason` can take.
 FINISH_REASONS = ("eos", "length", "timeout", "error", "cancelled")
+
+#: What the host was doing while the device had nothing enqueued (`step()`): the
+#: first four inside a step, the last two between two steps.
+STARVED_CAUSES = ("admit", "push", "dispatch", "drain", "client", "no_work")
 
 
 @dataclass
@@ -258,6 +262,9 @@ class RequestResult:
     # step() returns, once the drain is done: the request span's `handed_back`
     # event carries the difference.
     first_token_time: Optional[float] = None
+    # Host perf_counter when the call that dispatched its insert returned: the
+    # request's time on the device (`handed_back.on_device_s`) starts here.
+    insert_dispatched_time: Optional[float] = None
     finish_time: Optional[float] = None
     finished: bool = False
     finish_reason: Optional[str] = None  # one of FINISH_REASONS
@@ -795,8 +802,9 @@ class ContinuousBatcher:
         )
         self._m_chunk_latency = self.metrics.histogram(
             "serving_chunk_seconds",
-            help="decode-chunk wall clock, operand push to readback (the `serve.decode_chunk` span): "
-            "a chunk dispatched ahead is read back in the next step(), after its predecessor",
+            help="one decode chunk's cadence (`serve.decode_chunk.cadence_s`): since the previous readback "
+            "returned, at most operand push to readback — one chunk and the inserts enqueued ahead of it, "
+            "whether or not it was dispatched while its predecessor ran",
         )
         self._m_device_waits = self.metrics.counter(
             "serving_device_waits_total",
@@ -821,6 +829,26 @@ class ContinuousBatcher:
             help="chunks a slot sat inactive because its request stopped on an EOS the host "
             "had not yet seen when it dispatched the next chunk",
         )
+        self._m_starved = {
+            cause: self.metrics.counter(
+                "serving_device_starved_seconds_total",
+                help="wall time the engine knew the device had nothing enqueued, by what the host was "
+                "doing (a lower bound of the device's idle time: the readback's latency and a launch's "
+                "tail are not in it); `no_work` is the offered load's idle, the rest the host's",
+                labels={"cause": cause},
+            )
+            for cause in STARVED_CAUSES
+        }
+        # The account of a starved device (step()): since when the engine has
+        # known the device EMPTY — no chunk in flight, no insert dispatched
+        # since the last readback returned — or None while something it
+        # enqueued is unread; one mark, moved on by every charge.
+        self._empty_since: Optional[float] = None
+        self._first_step_at: Optional[float] = None
+        self._step_returned_at: Optional[float] = None
+        self._gap_cause = "no_work"  # whose the gap after the last step() is, where nothing covers it
+        self._starved_in_step: Dict[str, float] = {}
+        self._read_back_at: Optional[float] = None  # when the last readback returned
 
         # Tracing (telemetry.tracing): one `serve.request` span per accepted
         # request from submit() to its terminal finish_reason, and one
@@ -832,16 +860,20 @@ class ContinuousBatcher:
         # Slots admitted since the last chunk was dispatched, in admission
         # order, whose first tokens are still on the device (`_first_token`):
         # the next chunk's `_Flight` takes the list; a step that dispatches no
-        # chunk reads the buffer itself and _drain() clears it.
-        self._fresh: List[int] = []
+        # chunk reads the buffer itself and _drain() clears it. Each with when
+        # it was admitted (perf_counter) and how many admissions of its step
+        # came before it, for the request's `handed_back` event.
+        self._fresh: List[Tuple[int, float, int]] = []
         # Decode chunks dispatched and not yet read back, oldest first: two at
         # most inside a step() that runs ahead, one at most when it returns.
         self._flights: deque = deque()
         # When a request's tokens last reached the host, by request id.
         self._last_event: Dict[int, float] = {}
         # Requests whose first token reached the host in the step() now
-        # running: handed back, and timed, when it returns (_hand_back).
-        self._first_tokens: List[RequestResult] = []
+        # running — each with its `_fresh` entry's admission and whether a
+        # chunk's readback brought the token: handed back, and timed, when it
+        # returns (_hand_back).
+        self._first_tokens: List[Tuple[RequestResult, float, int, bool]] = []
 
         # Page-pool + prefix-cache telemetry and the host allocator itself
         # (all updates are host-scalar arithmetic).
@@ -1493,6 +1525,7 @@ class ContinuousBatcher:
             # ~1 under a backlog, ~0 with an empty queue (step()).
             "chunks_ahead_share": float(self._m_chunks_ahead_share.value),
             "slot_chunks_lost_to_eos": int(self._m_lost_to_eos.value),
+            "device_starved": self._device_starved(),
             "run_ahead": {
                 "enabled": self.run_ahead_disabled_reason is None,
                 "disabled_reason": self.run_ahead_disabled_reason,
@@ -1535,6 +1568,28 @@ class ContinuousBatcher:
             "cached_pages": self.pool.pages_cached,
         }
         return view
+
+    def _device_starved(self) -> Dict[str, Optional[float]]:
+        """`stats["device_starved"]`: the seconds the engine knew the device
+        had nothing enqueued, by cause (`serving_device_starved_seconds_total`),
+        and `share`: the host's part of them — every cause but `no_work` —
+        over the wall since the first step(). A lower bound of the device's
+        idle share: what the device idles inside a wait (the readback's
+        latency, a launch's tail) the host cannot see."""
+        view: Dict[str, Optional[float]] = {cause: c.value for cause, c in self._m_starved.items()}
+        wall = time.perf_counter() - self._first_step_at if self._first_step_at is not None else 0.0
+        view["share"] = (sum(view.values()) - view["no_work"]) / wall if wall > 0 else None
+        return view
+
+    def _charge(self, cause: str, now: float):
+        """Charge `cause` the wall time since the mark, where the device was
+        EMPTY through it (`_empty_since`), and move the mark to `now`."""
+        if self._empty_since is None:
+            return
+        seconds = now - self._empty_since
+        self._empty_since = now
+        self._m_starved[cause].inc(seconds)
+        self._starved_in_step[cause] = self._starved_in_step.get(cause, 0.0) + seconds
 
     def _update_occupancy_gauges(self):
         """Refresh the point-in-time gauges (queue depth, slot occupancy) —
@@ -1637,6 +1692,7 @@ class ContinuousBatcher:
             condemned += [r for r in flight.tenants if r is not None and not r.finished]
             flight.span.annotate(error=repr(exc)).end()
         self._flights.clear()
+        self._empty_since = now  # nothing the engine enqueued is left to read
         self.tracer.event(
             "serve.blast_radius", category="serve",
             errored_requests=len({id(r) for r in condemned}),
@@ -1723,7 +1779,7 @@ class ContinuousBatcher:
                 span.annotate(error=error)
             # A request that ends in the step() that admitted it keeps its span
             # open for the `handed_back` event: _hand_back() ends it.
-            if not any(r is result for r in self._first_tokens):
+            if not any(entry[0] is result for entry in self._first_tokens):
                 del self._request_spans[result.request_id]
                 span.end()
         self._m_finish[reason].inc()
@@ -1800,6 +1856,7 @@ class ContinuousBatcher:
         be told from the chunk's: it takes the blast-radius path
         (`_abort_in_flight`) as a chunk failure does, and the requests admitted
         in that step error with no tokens."""
+        fresh_before = len(self._fresh)
         while self._queue and self.free_slots:
             req = self._queue.popleft()
             slot = self._slot_request.index(None)
@@ -1847,11 +1904,12 @@ class ContinuousBatcher:
             padded[0, : p - matched_len] = ids[matched_len:]
             page_row = np.zeros((self.pages_per_slot,), np.int32)
             page_row[: len(pages)] = pages
+            admitted_at = time.perf_counter()
             rspan = self._request_spans.get(req.request_id)
             if rspan is not None:
                 rspan.event(
                     "admitted", slot=slot, bucket=int(bucket),
-                    queue_wait_s=round(time.perf_counter() - result.submit_time, 6),
+                    queue_wait_s=round(admitted_at - result.submit_time, 6),
                     prefix_hit_pages=int(matched_pages), pages_reserved=len(pages),
                 )
             try:
@@ -1861,9 +1919,6 @@ class ContinuousBatcher:
                     "serve.insert", category="serve",
                     request_id=int(req.request_id), slot=slot, bucket=int(bucket),
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
-                    # No wait in here since the token stays on the device; kept
-                    # so that a step's `device_wait_s` still bounds its inserts'.
-                    device_wait_s=0.0,
                     **self._routed_pairs(bucket), **self._scan_chunks(bucket),
                 ):
                     fn = self._insert_fn(bucket)
@@ -1882,6 +1937,11 @@ class ContinuousBatcher:
                         self._rng,
                         self._first_token,
                     )
+                # The device has work from here on: what it sat empty until now
+                # is the admission's (a step's FIRST insert alone can charge).
+                result.insert_dispatched_time = time.perf_counter()
+                self._charge("admit", result.insert_dispatched_time)
+                self._empty_since = None
             except Exception as exc:  # noqa: BLE001 — isolate, report, keep serving
                 self.pool.release(pages)
                 if self.trace_guard is not None:
@@ -1910,7 +1970,7 @@ class ContinuousBatcher:
                 # every full prompt page, so registered content stays frozen.
                 self.pool.register_prefix(hashes[: p // self.page_size], pages, start=matched_pages)
             self._m_inserts.inc()
-            self._fresh.append(slot)
+            self._fresh.append((slot, admitted_at, len(self._fresh) - fresh_before))
             self._slot_request[slot] = result
             self._slot_pages[slot] = pages
             # The device is told of the slot with the next dispatch: its state
@@ -1966,19 +2026,33 @@ class ContinuousBatcher:
 
     def _hand_back(self):
         """Runs as step() returns, which is when a client gets the first token
-        of every request admitted in it: observes TTFT there, and marks the
-        request's span with `handed_back` (`held_s`: how long the engine sat on
-        a token it had; `ttft_s`: what the client waited since submit())."""
+        of every request whose token the step drained: observes TTFT there,
+        and marks the request's span with `handed_back`, which says where the
+        request waited — `ttft_s`, what the client waited since submit(), in
+        four phases that add up to it: `queue_wait_s` (submit → admission),
+        `admit_host_s` (admission → its insert's dispatch call returned: the
+        host part of the insert; the `inserts_ahead` admissions of its step
+        before it were each dispatched before its own began), `on_device_s`
+        (→ the token on the host: the insert, the chunk it rides — `rode_chunk`
+        — under a backlog what was left of the chunk before that, and the
+        readback) and `held_s` (→ step() returns: how long the engine sat on a
+        token it had)."""
         if not self._first_tokens:
             return
         now = time.perf_counter()
-        for result in self._first_tokens:
+        for result, admitted_at, inserts_ahead, rode_chunk in self._first_tokens:
             ttft_s = round(now - result.submit_time, 6)
             self._m_ttft.observe(ttft_s)
             span = self._request_spans.get(result.request_id)
             if span is not None:
-                span.event("handed_back", held_s=round(now - result.first_token_time, 6),
-                           ttft_s=ttft_s)
+                dispatched = result.insert_dispatched_time
+                span.event("handed_back",
+                           queue_wait_s=round(admitted_at - result.submit_time, 6),
+                           admit_host_s=round(dispatched - admitted_at, 6),
+                           inserts_ahead=inserts_ahead,
+                           on_device_s=round(result.first_token_time - dispatched, 6),
+                           held_s=round(now - result.first_token_time, 6),
+                           ttft_s=ttft_s, rode_chunk=rode_chunk)
                 if result.finished:  # _finish() left the span open for this event
                     del self._request_spans[result.request_id]
                     span.end()
@@ -2098,15 +2172,56 @@ class ContinuousBatcher:
         `dispatched_ahead` the programs enqueued before it (inserts + chunk)
         and `in_flight_at_return` the chunks left for the next step (0 or 1).
         `serve.decode_chunk.ahead` says the chunk was dispatched while its
-        predecessor ran."""
+        predecessor ran; its `cadence_s` is the time since the previous
+        readback returned, at most its own extent: one chunk and the inserts
+        ahead of it either way (what `serving_chunk_seconds` observes).
+
+        **The account of a starved device.** The engine knows, without the
+        device, when nothing it enqueued is still unread: no chunk in flight
+        and no insert dispatched since the last readback returned. The wall
+        time it spends in that state goes to the cause that let it happen, at
+        the boundaries above (`serving_device_starved_seconds_total{cause}`,
+        `stats["device_starved"]`): `admit` (step start → the first insert's
+        dispatch call returns, or the end of an admission that admits
+        nothing), `push` then `dispatch` (→ the chunk's launch returns, where
+        no insert went out before it), `drain` (the readback's return →
+        step() returns, where it left nothing in flight) — `starved_admit_s`
+        / `_push_s` / `_dispatch_s` / `_drain_s`, summed in `starved_s`, at
+        most `host_s` — and between two steps `client` (work was pending when
+        the step before returned) or `no_work` (nothing was: the offered
+        load's idle, not the host's): `gap_s`, the whole gap before this step,
+        and `gap_cause`, who had it — or `"covered"`: a chunk was in flight
+        through it, nothing starved, nothing charged. A gap is its cause's
+        from its first instant: a request submitted into an engine that had
+        nothing pending waits in a `no_work` gap until the next step(). Time
+        inside the step's wait is never charged — the host cannot see when
+        the device finished — so the account is a LOWER BOUND of the device's
+        idle time, short by the readback's latency and the launch's tail. A
+        step that runs ahead charges nothing after its first dispatch, and one
+        that finds a chunk in flight and leaves one charges nothing at all:
+        in a steady backlog every cause reads 0. The step that ENDS a backlog
+        (it reads the last chunk in flight back and dispatches none) charges
+        its drain."""
         if self._closed:
             return []
         tracer = self.tracer
         with tracer.span("serve.step", category="serve") as step_span:
+            # The gap since the last step() returned: covered by a chunk left in
+            # flight, or the device sat empty through it — the client's, or
+            # nobody's where nothing was pending.
+            began = time.perf_counter()
+            if self._first_step_at is None:
+                self._first_step_at = self._step_returned_at = self._empty_since = began
+            gap_s = began - self._step_returned_at
+            gap_cause = "covered" if self._empty_since is None else self._gap_cause
+            self._charge(gap_cause, began)  # nothing where a chunk covers the gap
+            self._starved_in_step = {}  # the step's own parts, from here
             waits_before = self._m_device_waits.value
             self._expire_deadlines()
             with tracer.span("serve.admit", category="serve", record=False) as admit_span:
                 self._admit()
+            if self._empty_since is not None:  # admitted nothing
+                self._charge("admit", time.perf_counter())
             inserts = len(self._fresh)
             step_span.annotate(inserts=inserts,
                                admit_s=round(admit_span.duration_s, 6), push_s=0.0, dispatch_s=0.0)
@@ -2125,11 +2240,19 @@ class ContinuousBatcher:
             if flight is not None or self._fresh:
                 self._m_dispatching_steps.inc()
                 drained, device_wait_s = self._read_back(flight)
+                if drained is not None and not self._flights:  # everything enqueued has been read
+                    self._empty_since = self._read_back_at
                 with tracer.span("serve.drain", category="serve", record=False) as drain_span:
                     if drained is not None:
                         self._drain(events, flight, *drained)
                     self._hand_back()
                 drain_s = drain_span.duration_s
+            self._step_returned_at = time.perf_counter()
+            if self._empty_since is not None:
+                self._charge("drain", self._step_returned_at)
+                self._gap_cause = "client" if self.pending else "no_work"
+            starved = {cause: round(self._starved_in_step.get(cause, 0.0), 6)
+                       for cause in ("admit", "push", "dispatch", "drain")}
             step_span.annotate(
                 drain_s=round(drain_s, 6),
                 device_wait_s=round(device_wait_s, 6),
@@ -2137,6 +2260,10 @@ class ContinuousBatcher:
                 waits=int(self._m_device_waits.value - waits_before),
                 dispatched_ahead=inserts + decoding,
                 in_flight_at_return=len(self._flights),
+                starved_s=round(sum(starved.values()), 6),
+                starved_admit_s=starved["admit"], starved_push_s=starved["push"],
+                starved_dispatch_s=starved["dispatch"], starved_drain_s=starved["drain"],
+                gap_s=round(gap_s, 6), gap_cause=gap_cause,
             )
         return events
 
@@ -2155,19 +2282,25 @@ class ContinuousBatcher:
         values = (read, self._first_token if self._fresh else None)
         self._m_device_waits.inc()
         name = "serve.chunk.wait" if flight is not None else "serve.first_tokens.wait"
+        previous = self._read_back_at
         try:
             with self.tracer.span(name, category="serve", record=False) as wait_span:
                 host = jax.device_get(values)
         except Exception as exc:  # noqa: BLE001
             self._wait_failed(exc, "decode chunk readback" if flight is not None else "first-token readback")
             return None, 0.0
+        self._read_back_at = time.perf_counter()
         if flight is not None:
             self._flights.popleft()
-            flight.span.annotate(**self._chunk_counts(host[0])).end()
-            # The chunk's wall clock, dispatch through readback: real device
-            # work, not just the async enqueue — and, for a chunk dispatched
-            # ahead, what was left of its predecessor when it was enqueued.
-            self._m_chunk_latency.observe(max(flight.span.duration_s, 0.0))
+            # One chunk's cadence: since the previous readback returned, at most
+            # the span's own extent (operand push through readback) — the chunk
+            # and the inserts ahead of it, where the span of a chunk dispatched
+            # ahead also covers what was left of its predecessor.
+            cadence_s = max(flight.span.duration_s, 0.0)
+            if previous is not None:
+                cadence_s = min(cadence_s, self._read_back_at - previous)
+            flight.span.annotate(cadence_s=round(cadence_s, 6), **self._chunk_counts(host[0])).end()
+            self._m_chunk_latency.observe(cadence_s)
         return host, wait_span.duration_s
 
     def _wait_failed(self, exc: Exception, what: str):
@@ -2207,8 +2340,13 @@ class ContinuousBatcher:
         try:
             with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:
                 operands = self._chunk_operands()
+            if self._empty_since is not None:  # no insert went out before it
+                self._charge("push", time.perf_counter())
             with tracer.span("serve.chunk.dispatch", category="serve", record=False) as dispatch_span:
                 carry, read = self._chunk_fn(*operands)
+            if self._empty_since is not None:
+                self._charge("dispatch", time.perf_counter())
+                self._empty_since = None  # the device has work from here on
         except Exception as exc:  # noqa: BLE001
             chunk_span.annotate(error=repr(exc)).end()
             self._wait_failed(exc, "decode chunk dispatch")
@@ -2321,11 +2459,13 @@ class ContinuousBatcher:
             )
         return counts
 
-    def _first_token_to(self, events, result: RequestResult, token: int, now: float):
-        """Hand `result` the token its insert sampled, on the host since `now`."""
+    def _first_token_to(self, events, result: RequestResult, token: int, now: float,
+                        admitted_at: float, inserts_ahead: int, rode_chunk: bool):
+        """Hand `result` the token its insert sampled, on the host since `now`:
+        with a chunk's readback (`rode_chunk`), or the first-token buffer's."""
         result.tokens.append(token)
         result.first_token_time = now
-        self._first_tokens.append(result)
+        self._first_tokens.append((result, admitted_at, inserts_ahead, rode_chunk))
         events.append((result.request_id, [token]))
         span = self._request_spans.get(result.request_id)
         if span is not None:
@@ -2348,11 +2488,11 @@ class ContinuousBatcher:
         # Admissions of a step that dispatched no chunk. A one-token request
         # ends here; any other keeps `_from_buffer`: the next chunk starts it
         # from the buffer, where a first token that is its EOS ends it.
-        for slot in self._fresh:
+        for slot, *admission in self._fresh:
             result = self._slot_request[slot]
             if result is None:
                 continue
-            self._first_token_to(events, result, int(first_token[slot]), now)
+            self._first_token_to(events, result, int(first_token[slot]), now, *admission, rode_chunk=False)
             if self._rem[slot] == 0:
                 self._finish(result, "eos" if result.tokens[-1] == self._eos[slot] else "length",
                              now=now, slot=slot)
@@ -2360,12 +2500,12 @@ class ContinuousBatcher:
 
     def _drain_chunk(self, events, flight: _Flight, read, now: float):
         tenants = flight.tenants
-        for slot in flight.fresh:
+        for slot, *admission in flight.fresh:
             result = tenants[slot]
             if result.finished:  # cancelled or timed out since its dispatch
                 continue
             token = int(read["first"][slot])
-            self._first_token_to(events, result, token, now)
+            self._first_token_to(events, result, token, now, *admission, rode_chunk=True)
             if self.speculative and flight.was_active[slot]:
                 self._history[slot, flight.pos_before[slot]] = token  # as the chunk did on the device
         per_slot: Dict[int, List[int]] = {}

@@ -6,9 +6,9 @@ time that one `elapsed / steps` number conflates:
   - **data_wait** — the host blocked on the input pipeline (`next(loader)`);
   - **dispatch** — the host enqueued the jitted program (returns long before
     the device finishes: cheap when pipelined, a hang when the backend stalls);
-  - **block** — sampled `block_until_ready` on a step's outputs, the only
-    honest measure of device compute (never every step: a per-step sync
-    serializes dispatch against the device, rule TPU111).
+  - **block** — a wait for a step's outputs (`block_until_ready`) that the
+    caller timed itself (`record_phase("block", s)`; never every step: a
+    per-step sync serializes dispatch against the device, rule TPU111).
 
 `StepTimeline` splits per-step wall clock into those phases (latency
 histograms per phase, one shared log-spaced bucket layout) and keeps the
@@ -22,7 +22,7 @@ productive steps, what was charged to which overhead, and how much is
 unaccounted (the signature of an opaque backend hang).
 
 All timing is host-side `perf_counter` arithmetic — the timeline never touches
-device values except the explicitly-sampled `block_until_ready`.
+device values.
 """
 
 from __future__ import annotations
@@ -37,8 +37,9 @@ from .metrics import MetricsRegistry
 
 logger = get_logger(__name__)
 
-#: Step phases with first-class histograms (charge() accepts any cause).
-PHASES = ("data_wait", "dispatch", "block")
+#: Step phases with first-class histograms (any other phase name gets one at
+#: its first use; charge() accepts any cause).
+PHASES = ("data_wait", "dispatch")
 
 #: Well-known goodput loss causes (an arbitrary cause string is also accepted;
 #: these are the ones the framework charges itself).
@@ -50,32 +51,26 @@ class StepTimeline:
 
     Typical training wiring (what `Accelerator.train_step` instruments)::
 
-        timeline = StepTimeline(registry, prefix="train", sample_block_every=32)
+        timeline = StepTimeline(registry, prefix="train")
         for _ in range(steps):
             with timeline.phase("data_wait"):
                 batch = next(stream)
             with timeline.phase("dispatch"):
                 out = step_fn(batch)
-            timeline.step_done(out)   # sampled block_until_ready on `out`
+            timeline.step_done()
         report = timeline.goodput()
-
-    ``sample_block_every=K`` blocks on every K-th step's outputs (K=0 never
-    blocks): the sampled block time estimates the device-compute floor without
-    serializing the steady-state pipeline.
     """
 
     def __init__(
         self,
         registry: Optional[MetricsRegistry] = None,
         prefix: str = "step",
-        sample_block_every: int = 0,
         clock: Callable[[], float] = time.perf_counter,
         tracer=None,
         unaccounted_warn_s: Optional[float] = 60.0,
     ):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.prefix = prefix
-        self.sample_block_every = int(sample_block_every)
         self._clock = clock
         # The unaccounted-time alarm: `goodput()` reports `unaccounted_s` but
         # a number nobody reads is not a diagnostic. When a window's residual
@@ -146,29 +141,12 @@ class StepTimeline:
             self._phase_hists[name] = hist
         hist.observe(seconds)
 
-    def step_done(self, outputs=None) -> float:
-        """Close the current step; returns its wall-clock seconds. On sampled
-        steps (every `sample_block_every`-th, when `outputs` is given) blocks
-        until `outputs` are ready and records the wait as the "block" phase —
-        the sampled device-compute attribution."""
+    def step_done(self) -> float:
+        """Close the current step; returns its wall-clock seconds."""
         with self._lock:
             opened = self._step_open_since
             self._step_open_since = None
             self.steps += 1
-            sampled = (
-                outputs is not None
-                and self.sample_block_every > 0
-                and self.steps % self.sample_block_every == 0
-            )
-        if sampled:
-            import jax
-
-            t0 = self._clock()
-            jax.block_until_ready(outputs)
-            dt = self._clock() - t0
-            with self._lock:
-                self._phase_totals["block"] = self._phase_totals.get("block", 0.0) + dt
-            self._phase_hists["block"].observe(dt)
         now = self._clock()
         step_s = (now - opened) if opened is not None else 0.0
         with self._lock:

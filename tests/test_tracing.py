@@ -338,8 +338,8 @@ def test_serving_step_span_tree_and_hand_back(backlog):
         assert attrs["host_s"] + attrs["device_wait_s"] == pytest.approx(step["duration_s"], abs=1e-3)
         assert (attrs["admit_s"] + attrs["push_s"] + attrs["dispatch_s"] + attrs["device_wait_s"]
                 + attrs["drain_s"]) <= step["duration_s"] + 2e-4
-        assert attrs["device_wait_s"] >= sum(c["attrs"]["device_wait_s"] for c in inserts) - 1e-5
-        assert all(c["attrs"]["device_wait_s"] == 0.0 for c in inserts)  # dispatch only
+        assert 0.0 <= attrs["starved_s"] <= attrs["host_s"] + 1e-5  # empty-handed only on its own time
+        assert all("device_wait_s" not in c["attrs"] for c in inserts)  # dispatch only
         assert attrs["dispatched_ahead"] == len(inserts) + len(chunks)
         # one wait whenever there is something to read back: an older chunk, or this step's own work
         leaves_one = attrs["in_flight_at_return"]
