@@ -956,8 +956,9 @@ class _ModuleChecker:
 
     def _check_kernel_fallback(self):
         """TPU115: the slot cache has two reads, the XLA live-page read and
-        the Pallas paged-decode/block-verify kernels, and only the first has
-        been timed on the chip (ROADMAP D13 settles the choice). Flags (a) a
+        the Pallas page-walk kernel, and an engine that names neither chooses
+        (`ops.attention.slot_attention_impl`: the kernel on a TPU where it
+        reads the pool in place). Flags (a) a
         serving decode/verify construction whose read is pinned by a LITERAL
         attention_impl="xla", and (b) a kernel call forced into interpret
         mode with a literal interpret=True — the CPU-test shim; production

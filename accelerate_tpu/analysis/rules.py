@@ -178,11 +178,12 @@ RULES = (
         summary='serving decode/verify programs whose KV read is pinned by a '
         'literal attention_impl="xla", or a Pallas attention kernel forced '
         "into interpret mode outside test code",
-        fixit="thread attention_impl as a value the caller sets (the XLA "
-        "live-page read is the only read timed on the chip; the Pallas "
-        "kernels compile for the chip and run on it, and which is faster "
-        "there is not measured yet — ROADMAP D13) — or suppress where the pin "
-        "is deliberate; interpret=True is the CPU-test shim, production call "
+        fixit="thread attention_impl as a value the caller sets, or leave it "
+        "out (an engine that names no read takes the Pallas page-walk kernel "
+        "on a TPU where it can — about half the XLA read's device time a layer "
+        "on a v5e, PERF.md section 6, PR 37 — and the XLA read elsewhere) — or "
+        "suppress where the pin is deliberate; interpret=True is the CPU-test "
+        "shim, production call "
         "sites must let the kernel compile (interpret=None auto-selects)",
     ),
     Rule(

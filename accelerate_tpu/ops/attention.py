@@ -286,18 +286,176 @@ def read_run_pages(page_size: int, group: int) -> int:
     return max(1, _READ_RUN_TOKENS // page_size)
 
 
+#: Bytes of K, and as many of V, in the compute dtype, that one entry of the
+#: page-walk kernel's walk covers: a run of one slot's pages, copied into VMEM
+#: a page a DMA while the run before it is multiplied
+#: (`ops/paged_attention.py`). Two buffers a pool hold 4 runs in VMEM. The
+#: kernel's own device time, us, one layer on a v5e at 0.5 / 1 / 2 MiB
+#: (PERF.md §6, PR 37): pythia-1.4b's (32 slots x 88 pages of 16, 16 heads of
+#: 128) 167 / 164 / 166 with 870 pages live, 492 / 493 / 494 with every page
+#: live, 29 / 36 / 56 with every slot idle (an entry's products are over the
+#: whole run, however few of its pages are live); olmo-hybrid's (48 x 80, 32
+#: heads) 342 / 340 / 348 at 925 live. Flat where pages are live: 1 MiB.
+_KERNEL_RUN_BYTES = 1 << 20
+
+
+def kernel_run_pages(pages_per_slot: int, page_size: int, kv_heads: int, head_dim: int,
+                     itemsize: int) -> int:
+    """The consecutive pages of one slot that make ONE entry of the page-walk
+    kernel's list: `_KERNEL_RUN_BYTES` of the pool's K pages (16 pages at
+    pythia-1.4b's 16 heads of 128 in bf16, 8 at olmo-hybrid's 32), and never
+    more than a slot has."""
+    return max(1, min(pages_per_slot, _KERNEL_RUN_BYTES // (page_size * kv_heads * head_dim * itemsize)))
+
+
 def read_blocks(top_positions, pages_per_slot: int, page_size: int, kv_heads: int, head_dim: int,
-                itemsize: int, group: int) -> int:
-    """The trip count of `_live_page_attention`'s loop, on the host, for slots
-    whose queries attend up to `top_positions` (one a slot of the dispatch; an
-    idle slot sits at 0 and is one entry): the entries the read lists — a
-    page, or a run of `read_run_pages` — in blocks of `read_block_pages`,
-    from the numbers the read itself takes off its operands. The engine says
-    it on `serve.decode_chunk` as `read_blocks` without knowing either rule."""
-    top = np.asarray(top_positions)
+                itemsize: int, group: int, impl: str = "xla") -> int:
+    """The trip count of a dispatch's paged read, on the host, for slots whose
+    queries attend up to `top_positions` (one a slot of the dispatch; an idle
+    slot sits at 0 and is one entry), from the numbers the read itself takes
+    off its operands. The XLA read (`impl="xla"`): the turns of
+    `_live_page_attention`'s loop — the entries it lists, a page or a run of
+    `read_run_pages`, in blocks of `read_block_pages`. The kernel
+    (`"pallas_paged"`): the entries its loop walks, a run of
+    `kernel_run_pages` each. The engine says it on `serve.decode_chunk` as
+    `read_blocks` without knowing any of the rules."""
+    top = np.asarray(top_positions)[:, None]  # a slot's row of one position
+    if impl == "pallas_paged":
+        run = kernel_run_pages(pages_per_slot, page_size, kv_heads, head_dim, itemsize)
+        return int(live_entry_counts(top, run * page_size)[1].sum())
     run = read_run_pages(page_size, group)
     block = max(1, read_block_pages(top.size * pages_per_slot, page_size, kv_heads, head_dim, itemsize) // run)
-    return -(-int((top // (run * page_size) + 1).sum()) // block)
+    return -(-int(live_entry_counts(top, run * page_size)[1].sum()) // block)
+
+
+#: What the page-walk kernel may take of a v5e's 128 MiB of VMEM, and what the
+#: chip has of SMEM for the kernel's scalar operands (1 MiB: the compiler
+#: refuses the operand that passes it).
+_KERNEL_VMEM_BYTES = 64 << 20
+_KERNEL_SMEM_BYTES = 1 << 20
+
+
+def kernel_stages_pool(kv_heads: int, head_dim: int, pool_itemsize: int) -> bool:
+    """Whether the page-walk kernel must STAGE a pool before it can copy pages
+    out of it. The chip's compiler cuts a page out of a pool in HBM only where
+    the page's trailing `[Hkv, D]` is whole tiles: `D` whole 128-lane rows (a
+    head of 64 is stored padded to 128 lanes and Mosaic refuses the slice) and
+    the heads whole packed sublanes (1 head of fp32, 2 of bf16, 4 of int8 or
+    fp8). Any other pool is padded to that — a copy of the whole pool a layer
+    a dispatch, twice its bytes moved before a page is read — so a named
+    `"pallas_paged"` serves every shape, and the engine's own choice takes the
+    kernel only where it reads the pool in place (`slot_attention_impl`)."""
+    return bool(head_dim % 128 or kv_heads % (4 // pool_itemsize))
+
+
+def kernel_refuses(slots: int, pages_per_slot: int, page_size: int, block: int, heads: int,
+                   kv_heads: int, head_dim: int, itemsize: int) -> Optional[str]:
+    """Why the chip's compiler would refuse the page-walk kernel at a
+    dispatch's static shapes, or None where it takes them. The kernel is ONE
+    invocation a layer: the page tables and positions of ALL slots ride SMEM
+    as scalar operands, and all slots' queries (`block` positions x `heads` rows
+    a slot) and outputs sit in VMEM beside four run buffers and an entry's
+    scores. Compiled for a described v5e (`tests/test_tpu_compile.py`): 120
+    slots x 2,048 pages pass and 128 x 2,048 do not (SMEM; the rule stops at
+    15/16 of it); 32 slots x 1,152 query rows of 128 pass and 2,048 do not,
+    600 slots x 160 rows pass and 700 do not (VMEM; the estimate reads 3-10%
+    over what the compiler reported). A long window under very many slots
+    belongs to the XLA read, whose table stays in HBM."""
+    smem = 4 * (slots * pages_per_slot + slots * (block + 1) + 1)
+    if smem > _KERNEL_SMEM_BYTES * 15 // 16:
+        return (f"{slots} slots x {pages_per_slot} pages make {smem} bytes of page tables and "
+                f"positions, and the chip has {_KERNEL_SMEM_BYTES} bytes of SMEM")
+    # A run's columns and lanes as the pool is staged, at most (`kernel_stages_pool`).
+    rows, lanes, staged_heads = block * heads, -(-head_dim // 128) * 128, -(-kv_heads // 4) * 4
+    cols = kernel_run_pages(pages_per_slot, page_size, kv_heads, head_dim, itemsize) * page_size * staged_heads
+    vmem = (2 * slots * rows * lanes * itemsize  # queries and outputs
+            + 4 * cols * lanes * itemsize + 2 * cols * lanes * 4  # run buffers; a quantized run widened
+            + 2 * rows * cols * 4)  # an entry's scores and probabilities
+    if vmem > _KERNEL_VMEM_BYTES:
+        return (f"{slots} slots x {rows} query rows of {head_dim} need about {vmem} bytes of VMEM, "
+                f"and the kernel may take {_KERNEL_VMEM_BYTES}")
+    return None
+
+
+def slot_attention_impl(named: Optional[str], *, platform: str, latent: bool, tp: int, slots: int,
+                        pages_per_slot: int, page_size: int, block: int, heads: int, kv_heads: int,
+                        head_dim: int, itemsize: int, kv_cache_dtype: str) -> str:
+    """Which paged read an engine takes, and the ONE place a named read is
+    checked: `named` where the caller named one (`"xla"`, `"pallas_paged"`:
+    exactly that, or a `ValueError` that says what the kernel lacks — a latent
+    row, or on a TPU shapes the compiler would refuse, `kernel_refuses`), else
+    the engine's choice from what it can observe — the page-walk kernel where
+    the backend is a TPU, the cache is a K pool and a V pool of full heads
+    (`latent` rows have no kernel), bf16 or int8, that the kernel reads in
+    place (`kernel_stages_pool`) at shapes it can hold (`kernel_refuses`), and
+    the engine is on one device; the XLA read everywhere else. The shapes are
+    a dispatch's: `slots` rows of `block` query positions (a speculative
+    engine's verify block, else 1) x `heads` query heads over `kv_heads` (a
+    shard's, under `tp`), of `itemsize` bytes a value as the model computes
+    them; `kv_cache_dtype` is how the pool stores them.
+
+    The measurements (PERF.md §6, PR 37), ALL on a v5e — no other generation
+    was timed, and the kernel's one invocation runs on one core of a chip
+    that has two: one layer, device us, XLA read -> kernel, bf16 decode
+    unless said. pythia-1.4b's layer (32 slots x 88 pages of 16, 16 heads of
+    128): 385 -> 173 with 870 pages live, 190 -> 66 at 280, 97 -> 46 with
+    every slot idle, 98 -> 47 at the nearly idle window of `chat-open` (55
+    entries), 1,094 -> 503 with every page live (the live pages' bytes at 819
+    GB/s: 139 at 870, 451 at all); an int8 pool 381 -> 183 and 1,011 -> 394;
+    a verify block `s = 5` 582 -> 219 and 1,664 -> 555, which is why
+    speculative engines are not kept apart; grouped queries 32 over 8, 205 ->
+    103 and 474 -> 257; olmo-hybrid's layer (48 x 80, 32 heads) 767 -> 354 at
+    925 live, 126 -> 75 idle, 2,848 -> 1,350 full; fewer KV heads of 128, 870
+    live: 28 over 4, 215 -> 156 (int8 581 -> 336), a `tp = 4` shard's 4 over
+    4, 290 -> 138, 16 over 2, 212 -> 175. No bf16 or int8 pool read in place
+    has the kernel behind, so the rule asks those shapes only what the
+    compiler needs. Two kinds of pool LOSE and stay on the XLA read. An fp8
+    pool: 527 -> 1,158 at pythia's 870 live, 1,437 -> 3,044 all live, olmo's
+    1,017 -> 2,255 — a v5e has no fp8 unit and widening a run in VMEM costs
+    six times its copies (the kernel itself 1,078 us where int8 takes 118). A
+    STAGED pool: llama-1b's 32 over 8 heads of 64, 1,194 -> 1,755 and 1,443
+    -> 1,906 all live (two padded pools written first), one KV head of bf16
+    157 -> 303. `tp > 1` stays on the XLA read until a four-chip cell times
+    `_tp_paged_attention`; CPU and GPU have no such kernel (the interpreter is
+    a test shim)."""
+    if named is not None and named not in SLOT_ATTENTION_IMPLS:
+        raise ValueError(
+            f"unknown attention_impl {named!r}; expected one of {SLOT_ATTENTION_IMPLS} or None"
+        )
+    if named == "xla":
+        return named
+    if latent:
+        if named is not None:
+            raise ValueError(
+                f"attention_impl={named!r} on a latent cache: the page-walk kernel reads a K pool "
+                "and a V pool of full heads — a page-walk kernel for latent rows is not built; "
+                "use attention_impl=\"xla\""
+            )
+        return "xla"
+    refused = platform == "tpu" and kernel_refuses(
+        slots, pages_per_slot, page_size, block, heads, kv_heads, head_dim, itemsize)
+    if named is not None:
+        if refused:
+            raise ValueError(f"attention_impl={named!r}: {refused}; use attention_impl=\"xla\"")
+        return named
+    pool_itemsize = itemsize if kv_cache_dtype == "bf16" else 1  # "bf16": as the model computes
+    kernel = (platform == "tpu" and tp == 1 and not refused and kv_cache_dtype != "fp8_e4m3"
+              and not kernel_stages_pool(kv_heads, head_dim, pool_itemsize))
+    return "pallas_paged" if kernel else "xla"
+
+
+def live_entry_counts(pos, span: int):
+    """What is LIVE, for both paged reads: from `pos` [B, s], `top` [B], the
+    last position any query of a slot's row attends, and `count` [B], the
+    entries of `span` tokens (a page, or a run of pages) that hold a position
+    up to it — `top // span + 1`, so an idle slot (position 0) is one entry
+    and a full one all its window makes. The XLA read lists the entries flat
+    and walks the list in blocks (`_live_page_attention`); the kernel walks
+    the counts themselves, slot by slot, and takes their sum as its loop's
+    bound (`ops/paged_attention.py`); the host counts a dispatch's trips from
+    them too (`read_blocks`: numpy in, numpy out)."""
+    top = pos.max(axis=1)  # [B], the last position a slot's queries attend
+    return top, top // span + 1  # [B], 1..runs live entries a slot
 
 
 def _live_page_attention(q, pool_k, pool_v, pos, table, scales, scale=None, value_dim=None):
@@ -370,8 +528,7 @@ def _live_page_attention(q, pool_k, pool_v, pos, table, scales, scale=None, valu
 
     # The flat list. Entry i belongs to the slot whose run of live entries
     # [start, end) holds i; entries at and past n belong to nobody (owner B).
-    top = jnp.max(pos, axis=1)  # [B], the last position a slot's queries attend
-    count = top // span + 1  # [B], 1..runs live entries a slot
+    top, count = live_entry_counts(pos, span)
     end = jnp.cumsum(count)
     start = end - count
     n = end[-1]
@@ -497,9 +654,10 @@ def slot_cache_attention(
     """Write this dispatch's K/V into the slot cache AND attend — the fused
     serving-decode seam every slot-cache model family calls (llama, gpt_neox).
     One function covers decode steps (s == 1) and speculative verify blocks
-    (s == draft_tokens + 1); `attention_impl` picks the read-side engine:
+    (s == draft_tokens + 1); `attention_impl` picks the read-side engine (an
+    engine that names none chooses by `slot_attention_impl`):
 
-      - ``"xla"`` (default): `_write_slot_pool`, then
+      - ``"xla"``: `_write_slot_pool`, then
         `_live_page_attention` walks the LIVE pages of all slots in fixed
         blocks, in ONE loop under a trip count it computes from
         `positions`, so a dispatch's bytes follow the live tokens: a turn
@@ -511,12 +669,17 @@ def slot_cache_attention(
         none over the rest of the window (`tests/test_tpu_compile.py`
         holds the compiled program to it). An idle slot must sit at
         position 0 to count as one page: the engine's `_finish` keeps it
-        there. The engine's default, and the PARITY ORACLE the kernels are
-        pinned against.
-      - ``"pallas_paged"``: the pool write plus the
-        `ops/paged_attention` kernels, which walk each slot's page table
-        directly and never materialize the gathered cache. Greedy decode is
-        token-identical to the oracle (`tests/test_paged_kernel.py`).
+        there. The read of every backend and of a latent cache, and the
+        PARITY ORACLE the kernel is pinned against.
+      - ``"pallas_paged"``: the pool write plus the `ops/paged_attention`
+        kernel, which walks the same live entries with its own copies — a
+        run of one slot's live pages at a time out of the pool in HBM, the
+        next run's copies under this run's products — and never
+        materializes a gathered block: each live page crosses HBM once.
+        Greedy decode is token-identical to the oracle
+        (`tests/test_paged_kernel.py`); on a v5e it takes about half the XLA
+        read's device time for bf16 and int8 pools it reads in place, and
+        loses for fp8 and staged pools (`slot_attention_impl`).
 
     The cache is a page pool (`page_size >= 1` and a `page_table` are
     required; pool layout, scratch page and quantized pools:

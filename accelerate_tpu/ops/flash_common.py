@@ -35,43 +35,53 @@ def init_softmax_state(acc, m_scr, l_scr):
     l_scr[:] = jnp.zeros_like(l_scr)
 
 
-def online_softmax_update(s, v, acc, m_scr, l_scr, idx=()):
+def online_softmax_update(s, v, acc, m_scr, l_scr, valid=None, v_scale=None):
     """Fold one K/V block into the running softmax state.
 
     Args:
-        s: [rows, block_k] fp32 scores for this block, already scaled and
-            masked (masked lanes at `NEG_INF`).
-        v: [block_k, D] fp32 value block.
+        s: [rows, block_k] fp32 scores for this block, already scaled, and
+            masked (masked lanes at `NEG_INF`) unless `valid` says which
+            lanes are live.
+        v: [block_k, D] value block; the probabilities are cast to its dtype
+            for the product (bf16 operands, fp32 accumulation — a no-op for
+            an fp32 block).
         acc / m_scr / l_scr: scratch refs as in `init_softmax_state`.
-        idx: leading index into the scratch refs, e.g. ``(h,)`` for the paged
-            kernel's per-KV-head ``[Hkv, rows, ...]`` scratch. Indexed, not
-            `ref.at[h]`: Mosaic refuses a sub-view of a scratch whose last
-            dim is under a lane tile (head_dim 64).
+        valid: optional [rows, block_k] bool, the live lanes of an unmasked
+            `s`: the others are masked here AND their probabilities set to
+            exact zeros, so a row that has met no live lane yet (its maximum
+            still `NEG_INF`) adds nothing. Needed where a block may hold lanes
+            no row of it owns (the paged kernel's other heads' columns).
+        v_scale: optional [1, block_k] fp32 dequantization scale of `v`'s
+            rows, applied to the probabilities on their way into the product
+            (the row sum stays unscaled).
     """
-    at = (*idx, slice(None))
-    m_prev = m_scr[at][:, 0:1]  # [rows, 1] (lane dim is broadcast)
-    l_prev = l_scr[at][:, 0:1]
+    if valid is not None:
+        s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_scr[:, 0:1]  # [rows, 1] (lane dim is broadcast)
+    l_prev = l_scr[:, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)  # [rows, block_k]
+    if valid is not None:
+        p = jnp.where(valid, p, 0.0)
     correction = jnp.exp(m_prev - m_new)  # [rows, 1]
     l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-    acc[at] = acc[at] * correction + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    if v_scale is not None:
+        p = p * v_scale
+    acc[:] = acc[:] * correction + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    stat_shape = m_scr.shape[len(idx):]
-    m_scr[at] = jnp.broadcast_to(m_new, stat_shape)
-    l_scr[at] = jnp.broadcast_to(l_new, stat_shape)
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
-def finalize_softmax(acc, m_scr, l_scr, idx=()):
+def finalize_softmax(acc, m_scr, l_scr):
     """(normalized output [rows, D], logsumexp [rows, 1]) after the last block.
 
     Rows whose every lane was masked (l == 0) normalize against a tiny floor
     instead of dividing by zero — they come out ~0, never NaN, which is what
     lets inactive serving slots ride the same dispatch as live ones.
     """
-    at = (*idx, slice(None))
-    l = l_scr[at][:, 0:1]
+    l = l_scr[:, 0:1]
     safe_l = jnp.maximum(l, 1e-30)
-    lse = (m_scr[at][:, 0:1] + jnp.log(safe_l)).astype(jnp.float32)
-    return acc[at] / safe_l, lse
+    lse = (m_scr[:, 0:1] + jnp.log(safe_l)).astype(jnp.float32)
+    return acc[:] / safe_l, lse

@@ -1,52 +1,75 @@
-"""Pallas TPU kernels for serving decode: paged single-query attention and the
+"""Pallas TPU kernel for serving decode: paged single-query attention and the
 speculative block-verify variant, with the page-table gather FUSED into the
 attention walk.
 
 The XLA read (`ops/attention._live_page_attention`) walks the LIVE pages of
 all slots in fixed blocks, in one loop a layer: a turn gathers a block of K
-pages and a block of V pages from the pool, reads each back once (q·K,
-probs·V) and folds the block into a running softmax kept by slot — three
-passes over every live page, of which the v5e's compiler keeps two in fast
-memory (measured: PERF.md §5–§6). These kernels gather nothing: the grid
-walks each slot's ``page_table`` directly (the table rides as a
-SCALAR-PREFETCH operand, so the BlockSpec index maps pick which pool page to
-stream into VMEM for each grid step) and folds every page into the shared
-online-softmax accumulator (`ops/flash_common.py`), each live page read
-once. Which read is faster on the chip has not been measured
-(ROADMAP D13).
+pages and a block of V pages from the pool, reads each back once (q.K,
+probs.V) and folds the block into a running softmax kept by slot. The chip
+runs one fusion at a time, so a turn's gather and its arithmetic never
+overlap. This kernel gathers nothing: it walks the same live entries (ONE
+kernel invocation a layer, one loop under the count of live entries the
+caller computes on the device — no step exists for what is not live), copies
+each entry's live pages from the pool in HBM into VMEM with its own DMAs, and
+starts the next entry's copies before this entry's products, so every live
+page crosses HBM once, under the arithmetic of the entry before it.
+On a v5e it takes about half the XLA read's device time a layer at every
+bf16 or int8 shape it reads in place — 385 -> 173 us at pythia-1.4b's
+saturated cell's shape, of which the kernel itself 164 for bytes that take
+139 at the chip's 819 GB/s — and loses for an fp8 pool, which that chip widens
+in software (`ops.attention.slot_attention_impl` holds the table and keeps
+such pools on the XLA read; PERF.md §6, PR 37).
 
 Page-walk contract (mirrors the engine's host-side conventions, paging.py):
 
-  - ``page_table`` entries past a slot's reservation point at the scratch
-    page (page 0). Consecutive grid steps that map to the SAME pool page skip
-    the re-fetch (Pallas pipelines dedupe identical block indices), so the
-    tail of a short slot's walk costs one scratch-page read, not P of them.
+  - What is live is `ops.attention.live_entry_counts`, the rule the XLA read
+    lists its entries by: a slot's queries attend up to `top = max_j
+    positions[i, j]`, so its live pages are the table entries at or below
+    `top // page_size` — an idle slot (position 0) is one page. An entry is a
+    run of `ops.attention.kernel_run_pages` consecutive pages of one slot (1
+    MiB of K: 16 pages at pythia-1.4b's widths, 8 at olmo-hybrid's); of a
+    slot's last run only the live pages are copied. Table entries past a
+    slot's live pages (the scratch page, page 0) are never read.
   - Masking is positional, not structural: query j of row i attends exactly
     ``cols <= positions[i, j]``, the same per-query mask the XLA oracle
-    builds — scratch-page rows sit above every live position and contribute
-    exact zeros, so prefix-shared pages, ragged lengths, and freed slots all
-    come out token-identical to the gather path.
+    builds — rows of a run past a slot's last live page keep stale (finite)
+    buffer contents and contribute exact zeros, so prefix-shared pages,
+    ragged lengths, and freed slots all come out token-identical to the
+    gather path.
   - Rows whose every lane is masked normalize against a tiny floor
     (`finalize_softmax`), never NaN — inactive slots ride the same dispatch.
 
-Decode and verify are ONE kernel (decode is the ``s == 1`` block): grid
-``(B, pages)``, each step streaming one whole pool page — every KV head in one
-DMA — and looping the heads in VMEM. GQA is handled by grouping the
-``G = Hq // Hkv`` query heads of each KV head into the kernel's row axis (the
-pool is shared per KV head; repeating it like the XLA path does would multiply
-the very HBM traffic this kernel exists to remove). Every block's last two
-dims are the operand's own, which is what the chip's compiler requires
-(`tests/test_tpu_compile.py` compiles the kernel for a described v5e).
+Decode and verify are ONE kernel (decode is the ``s == 1`` block). An entry's
+arithmetic is two matrix products over all its KV heads at once — the run as
+the flat matrix ``[tokens * Hkv, D]``, the slot's queries as ``[s * Hq, D]``,
+the columns of other KV heads masked (`_paged_kernel`) — in the operands'
+dtype with fp32 accumulation, the shared online-softmax accumulator
+(`ops/flash_common.py`) kept in VMEM across a slot's entries. GQA needs no
+repeat of the pool: a query head's row keeps its KV head's columns. The pool
+keeps its layout ``[N, page_size, Hkv, D]``, and where a page's trailing
+``[Hkv, D]`` is whole tiles — ``D`` whole 128-lane rows, the heads whole packed
+sublanes (`ops.attention.kernel_stages_pool`) — the kernel copies pages out of
+it IN PLACE. The chip's compiler will not cut a page out of any other pool
+(llama-1b's heads of 64 are stored padded to 128 lanes, and Mosaic refuses the
+slice): such a pool is STAGED, padded with zero lanes and zero heads into one
+the kernel can copy from, which is a copy of the whole pool a call. A named
+`"pallas_paged"` therefore serves every shape; the engine's own choice takes
+the kernel only where nothing is staged (`ops.attention.slot_attention_impl`).
+The kernel is one invocation a layer, so every slot's page table rides SMEM
+and every slot's queries sit in VMEM: what that bounds is
+`ops.attention.kernel_refuses` (`tests/test_tpu_compile.py` compiles the
+kernel for a described v5e at the serving cells' shapes, at llama-1b's, at a
+tensor-parallel shard's and on both sides of those bounds).
 
 QUANTIZED pools (``k_scale``/``v_scale`` operands, `ops/quantization.py`):
-int8/fp8 pages stream through the same BlockSpec walk at 1 byte/value, their
-per-page-per-head scales ride ``[1, Hkv]`` blocks picked by the SAME
-``tbl[b, p]`` index map, and the dequant is one fused multiply on the
-VMEM-resident block before the score dot — the cache crosses HBM quantized,
-fp32 exists only inside the accumulator. Token-identical to the XLA
-dequantize-on-read oracle (`tests/test_quantization.py`).
+int8/fp8 pages are copied at 1 byte/value and widened in VMEM; their
+per-page-per-head scales, gathered by the caller into one ``[1, tokens *
+Hkv]`` row a (slot, run), are copied beside the pages and applied to the
+scores (K) and to the probabilities (V) — the cache crosses HBM quantized.
+Token-identical to the XLA dequantize-on-read oracle
+(`tests/test_quantization.py`).
 
-Interpret mode (`interpret=None` auto-enables off-TPU) runs the same kernels
+Interpret mode (`interpret=None` auto-enables off-TPU) runs the same kernel
 on CPU for the tier-1 parity sweeps (`tests/test_paged_kernel.py`), the
 `ring_attention.py` testing pattern. All accumulation is fp32.
 """
@@ -63,88 +86,157 @@ import jax.numpy as jnp
 
 from .flash_common import (
     LANE,
-    NEG_INF,
     finalize_softmax,
     init_softmax_state,
     online_softmax_update,
 )
 
 
-def _paged_kernel(
-    tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-    scale, page_size, hkv, quantized,
-):
-    """One (slot, page) step of the page walk, all KV heads at once.
+def _flat_rows(block, q):
+    """A run's [tokens, Hkv, D] block as the [tokens * Hkv, D] matrix the two
+    products take, in the queries' dtype. Merging the two leading axes moves nothing
+    where a token's heads fill whole sublane tiles (16 rows of bf16, 8 of
+    fp32); a pool that is stored narrower than it is computed in (int8/fp8),
+    or whose heads fill half a tile (8 KV heads of bf16), goes through fp32,
+    where they do."""
+    t, h, d = block.shape
+    if block.dtype != q.dtype or h % (32 // block.dtype.itemsize):
+        block = block.astype(jnp.float32)
+    return block.reshape(t * h, d).astype(q.dtype)
 
-    The page arrives as one ``[page_size, Hkv, D]`` block — the pool's own
-    trailing dims, so the block satisfies the Mosaic tiling rule at any head
-    count or page size and the pool needs no layout change; head ``h`` is a
-    strided sublane read of it. Rows are the ``s*G`` (query position, GQA
-    group) pairs of a KV head; row ``r`` attends ``cols <= limit[r]``, which
-    covers single-query decode (``s == 1``) and the speculative verify block
-    alike, so the speculative accept loop sees the XLA verify path's greedy
-    tokens. Quantized pools thread the page's ``[1, Hkv]`` K/V scales, picked
-    by the same ``tbl[b, p]`` index map that streams the page; the dequant is
-    one multiply on the VMEM-resident block, so the page crosses HBM at
-    int8/fp8 width and fp32 exists only inside the accumulator."""
+
+def _paged_kernel(
+    n_ref, top_ref, tbl_ref, pos_ref,  # scalar prefetch (SMEM)
+    q_ref, cols_ref, rows_ref, k_hbm, v_hbm, *rest,
+    scale, page_size, run, pages_per_slot, block, quantized,
+):
+    """The whole page walk of one layer: ONE loop over the live entries of
+    all slots, slot-major, under the count the caller computed on the device
+    (`n_ref`) — there are no steps for what is not live.
+
+    An entry is a run of up to `run` consecutive pages of one slot; slot `b`
+    has `top_ref[b] // (run * page_size) + 1` of them (`top_ref[b]` the last
+    position its queries attend: `ops.attention.live_entry_counts`, which the
+    XLA read lists its entries from too), and the loop carries (slot, run of
+    the slot) from entry to entry on the scalar core. The pools stay in HBM;
+    an entry's LIVE pages are copied, a page a DMA, into one of two VMEM
+    buffers a pool, and the next entry's copies are started before this
+    entry's products, so the chip's copy engine and its matrix unit work on
+    neighbouring entries at once and every live page crosses HBM once. Pages
+    of a run past the slot's last live one are not copied: their rows keep
+    what an earlier entry left there (zeros at first), which is finite, and
+    the positional mask gives them probability zero.
+
+    An entry's arithmetic is two matrix products over ALL its KV heads at
+    once. The run is the matrix `[tokens * Hkv, D]` (`_flat_rows`), a row a
+    (token, KV head); the slot's queries are `[s * Hq, D]`, a row a (query
+    position, query head). `q . K^T` is then `[s * Hq, tokens * Hkv]`, of
+    which a row keeps the columns of ITS KV head (`cols_ref[1] ==
+    rows_ref[:, 1]`) at tokens its query position attends (`cols_ref[0] <=
+    pos_ref[slot, rows_ref[:, 0]] - the run's first token`) and masks the
+    rest; the masked columns' probabilities are exact zeros, so `probs . V`
+    over the same flat matrix sums a row's own head alone. The matrix unit is
+    fed whole 128-wide tiles whatever the group size, where a product a head
+    would feed it one row. The running softmax of the slot lives in `acc` /
+    `m_scr` / `l_scr` across its entries and is written out at its last one.
+
+    Quantized pools: a run's per-(token, head) K and V scales arrive as
+    `[1, tokens * Hkv]` rows (gathered by the caller for every (slot, run),
+    copied beside the pages) and are applied to the scores and to the
+    probabilities — the pages cross HBM at int8/fp8 width and are widened in
+    VMEM."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quantized:
-        ks_ref, vs_ref, lim_ref, o_ref, acc, m_scr, l_scr = rest
+        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, acc, m_scr, l_scr = rest
     else:
-        lim_ref, o_ref, acc, m_scr, l_scr = rest
+        o_ref, k_buf, v_buf, sems, acc, m_scr, l_scr = rest
+    span, runs = run * page_size, -(-pages_per_slot // run)
 
-    bi = pl.program_id(0)
-    pi = pl.program_id(1)
+    def copies(slot, r, buf, fn):
+        """`fn` (start or wait) on the DMAs of slot `slot`'s run `r` into buffer `buf`."""
+        live = jnp.minimum(top_ref[slot] // page_size + 1 - r * run, run)
 
-    @pl.when(pi == 0)
-    def _init():
-        init_softmax_state(acc, m_scr, l_scr)
+        def page(j, _):
+            rows = pl.ds(j * page_size, page_size)
+            pid = tbl_ref[slot * pages_per_slot + r * run + j]
+            fn(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, rows], sems.at[0, buf]))
+            fn(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, rows], sems.at[1, buf]))
 
-    length = len_ref[bi]  # max attend limit + 1: pages past it hold no query's keys
-    base = pi * page_size
+        jax.lax.fori_loop(0, live, page, None)
+        if quantized:
+            fn(pltpu.make_async_copy(ks_hbm.at[slot * runs + r], ks_buf.at[buf], sems.at[0, buf]))
+            fn(pltpu.make_async_copy(vs_hbm.at[slot * runs + r], vs_buf.at[buf], sems.at[1, buf]))
 
-    @pl.when(base < length)
-    def _step():
-        limit = lim_ref[0]  # [rows, 1] int32 per-row attend limits
-        for h in range(hkv):
-            q = q_ref[0, h].astype(jnp.float32)  # [rows, D]
-            k = k_ref[0, :, h, :].astype(jnp.float32)  # [page_size, D]
-            v = v_ref[0, :, h, :].astype(jnp.float32)
-            if quantized:
-                k = k * ks_ref[0, :, h : h + 1]
-                v = v * vs_ref[0, :, h : h + 1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale  # [rows, page_size]
-            cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= limit, s, NEG_INF)
-            online_softmax_update(s, v, acc, m_scr, l_scr, idx=(h,))
+    k_buf[...] = jnp.zeros_like(k_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
+    copies(0, 0, 0, lambda dma: dma.start())
 
-    @pl.when(pi == pl.num_programs(1) - 1)
-    def _finish():
-        for h in range(hkv):
-            out, _ = finalize_softmax(acc, m_scr, l_scr, idx=(h,))
-            o_ref[0, h] = out.astype(o_ref.dtype)
+    def entry(e, walk):
+        slot, r = walk
+        buf = e % 2
+        more = (r + 1) * span <= top_ref[slot]  # the slot has another live run
+        nxt = (jnp.where(more, slot, slot + 1), jnp.where(more, r + 1, 0))
+
+        @pl.when(e + 1 < n_ref[0])
+        def _prefetch():
+            copies(*nxt, 1 - buf, lambda dma: dma.start())
+
+        @pl.when(r == 0)
+        def _init():
+            init_softmax_state(acc, m_scr, l_scr)
+
+        copies(slot, r, buf, lambda dma: dma.wait())
+        q = q_ref[slot]  # [s * Hq, D]
+        k = _flat_rows(k_buf[buf], q)
+        v = _flat_rows(v_buf[buf], q)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [s * Hq, tokens * Hkv]
+        if quantized:
+            s = s * ks_buf[buf]
+        # A row's attend limit within the run: its query position's, off SMEM.
+        limit = jnp.full((rows_ref.shape[0], 1), pos_ref[slot * block], jnp.int32)
+        for j in range(1, block):
+            limit = jnp.where(rows_ref[:, 0:1] == j, pos_ref[slot * block + j], limit)
+        valid = (cols_ref[1:2, :] == rows_ref[:, 1:2]) & (cols_ref[0:1, :] <= limit - r * span)
+        online_softmax_update(
+            s, v, acc, m_scr, l_scr, valid=valid, v_scale=vs_buf[buf] if quantized else None
+        )
+
+        @pl.when(jnp.logical_not(more))
+        def _finish():
+            out, _ = finalize_softmax(acc, m_scr, l_scr)
+            o_ref[slot] = out.astype(o_ref.dtype)
+
+        return nxt
+
+    jax.lax.fori_loop(0, n_ref[0], entry, (jnp.int32(0), jnp.int32(0)))
 
 
 def _paged_call(
     q, k_pool, v_pool, page_table, positions, scale, interpret,
     k_scale=None, v_scale=None,
 ):
-    """Shared wrapper: layout transforms, prefetch grid spec, pallas_call.
-    `k_scale`/`v_scale` ([num_pages, Hkv] f32 traced operands, never Python
-    scalars — TPU117) switch the kernel into fused-dequant mode.
+    """Shared wrapper: what is live, the layout of queries and masks, the
+    `pallas_call`. `k_scale`/`v_scale` ([num_pages, Hkv] f32 traced operands,
+    never Python scalars — TPU117) switch the kernel into fused-dequant mode.
 
-    Every block's last two dims equal the operand's own (the Mosaic tiling
-    rule), so the kernel compiles for the chip at any page size / head
-    count: per-slot scalars (page table, page-skip bound) ride SMEM as
-    scalar-prefetch operands, everything else is a whole-trailing-dims VMEM
-    block."""
+    What is live is `ops.attention.live_entry_counts` at the kernel's own run
+    (`kernel_run_pages`): the last position a slot attends and the entries
+    that makes, whose sum bounds the kernel's loop. It rides SMEM as
+    scalar-prefetch operands beside the flattened page table and positions.
+    Queries go in as `[B, s * Hq, D]` (a reshape: row `j * Hq + h` is query
+    position `j`, head `h`). The column maps of the flat run (`cols`: token of
+    its run, KV head) and of the query rows (`row_maps`: query position, KV
+    head) are constants of the shapes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, s, hq, d = q.shape
+    from .attention import _KERNEL_VMEM_BYTES, kernel_run_pages, kernel_stages_pool, live_entry_counts
+
+    b, s, hq, head_dim = q.shape
     n_pages_pool, page_size, hkv, _ = k_pool.shape
     if hq % hkv:
         raise ValueError(f"GQA requires Hq % Hkv == 0, got {hq}, {hkv}")
@@ -158,65 +250,72 @@ def _paged_call(
                     f"per-page-per-head {name} must be [num_pages, Hkv] = "
                     f"{(n_pages_pool, hkv)}, got {sc.shape}"
                 )
-    gsize = hq // hkv
-    rows = s * gsize
-    pages_per_slot = page_table.shape[-1]
+    # Query heads a KV head, and a run's pages: of the pool as it is, not as it is staged.
+    group, pages_per_slot = hq // hkv, page_table.shape[-1]
+    run = kernel_run_pages(pages_per_slot, page_size, hkv, head_dim, q.dtype.itemsize)
+    if kernel_stages_pool(hkv, head_dim, k_pool.dtype.itemsize):
+        # STAGED: zero lanes up to whole 128-lane rows (the queries' too, so the
+        # products ignore them) and zero KV heads, which no query row reads, up
+        # to whole packed sublanes. A copy of both pools, made for this call.
+        lanes, heads = -head_dim % LANE, -hkv % (4 // k_pool.dtype.itemsize)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, lanes)))
+        k_pool, v_pool = (jnp.pad(pool, ((0, 0), (0, 0), (0, heads), (0, lanes))) for pool in (k_pool, v_pool))
+        if quantized:
+            k_scale, v_scale = (jnp.pad(sc, ((0, 0), (0, heads))) for sc in (k_scale, v_scale))
+    hkv, d = k_pool.shape[2:]
+    rows = s * hq
+    runs = -(-pages_per_slot // run)  # entries a full slot makes
+    span = run * page_size
 
-    # [B, s, Hq, D] -> [B, Hkv, s*G, D]: query head h*G+g rides kv head h's
-    # walk; row j*G+g of a head carries query position j's attend limit.
-    qt = (
-        q.reshape(b, s, hkv, gsize, d)
-        .transpose(0, 2, 1, 3, 4)
-        .reshape(b, hkv, rows, d)
-    )
     table = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, n_pages_pool - 1)
     pos = jnp.asarray(positions, jnp.int32).reshape(b, s)
-    limits = jnp.repeat(pos, gsize, axis=1)[:, :, None]  # [B, rows, 1]
-    lengths = jnp.max(pos, axis=1) + 1  # [B] scalar page-skip bound per slot
+    top, count = live_entry_counts(pos, span)
+    col, row = np.arange(span * hkv, dtype=np.int32), np.arange(rows, dtype=np.int32)
+    cols = np.stack([col // hkv, col % hkv])  # [2, span * Hkv]: token of the run, KV head
+    row_maps = np.stack([row // hq, row % hq // group], axis=1)  # [s * Hq, 2]: query position, KV head
 
     kernel = functools.partial(
-        _paged_kernel, scale=float(scale), page_size=page_size, hkv=hkv,
-        quantized=quantized,
+        _paged_kernel, scale=float(scale), page_size=page_size, run=run,
+        pages_per_slot=pages_per_slot, block=s, quantized=quantized,
     )
-    q_spec = pl.BlockSpec((1, hkv, rows, d), lambda bi, pi, tbl, ln: (bi, 0, 0, 0))
-    # THE fused page-table gather: grid step (b, p) streams pool page
-    # table[b, p], every KV head in one DMA. Table entries past a slot's
-    # reservation are the scratch page — identical consecutive block indices,
-    # which the Pallas pipeline fetches once, not P times.
-    page_spec = pl.BlockSpec(
-        (1, page_size, hkv, d), lambda bi, pi, tbl, ln: (tbl[bi, pi], 0, 0, 0)
-    )
-    in_specs = [q_spec, page_spec, page_spec]
-    operands = [qt, k_pool, v_pool]
+    vmem, hbm = pl.BlockSpec(memory_space=pltpu.VMEM), pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [vmem, vmem, vmem, hbm, hbm]
+    operands = [q.reshape(b, rows, d), jnp.asarray(cols), jnp.asarray(row_maps), k_pool, v_pool]
+    scratch = [
+        pltpu.VMEM((2, span, hkv, d), k_pool.dtype),
+        pltpu.VMEM((2, span, hkv, d), v_pool.dtype),
+    ]
     if quantized:
-        # The streamed page's per-head scales ride the SAME tbl[b, p] walk as
-        # the page itself — the dequant is fused, not a second gather.
-        scale_spec = pl.BlockSpec((1, 1, hkv), lambda bi, pi, tbl, ln: (tbl[bi, pi], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale[:, None, :], v_scale[:, None, :]]
-    in_specs.append(pl.BlockSpec((1, rows, 1), lambda bi, pi, tbl, ln: (bi, 0, 0)))
-    operands.append(limits)
+        # [B * runs, 1, span * Hkv]: every (slot, run)'s scales a (token, head)
+        # column; a table row is padded to whole runs with the scratch page.
+        padded = jnp.pad(table, ((0, 0), (0, runs * run - pages_per_slot)))
+
+        def scale_rows(pool):
+            picked = jnp.take(pool, padded.reshape(-1), axis=0, mode="clip").reshape(b * runs, run, 1, hkv)
+            return jnp.broadcast_to(picked, (b * runs, run, page_size, hkv)).reshape(b * runs, 1, span * hkv)
+
+        in_specs += [hbm, hbm]
+        operands += [scale_rows(k_scale), scale_rows(v_scale)]
+        scratch += [pltpu.VMEM((2, 1, span * hkv), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((rows, d), jnp.float32),
+        pltpu.VMEM((rows, LANE), jnp.float32),
+        pltpu.VMEM((rows, LANE), jnp.float32),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, pages_per_slot),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((hkv, rows, d), jnp.float32),
-            pltpu.VMEM((hkv, rows, LANE), jnp.float32),
-            pltpu.VMEM((hkv, rows, LANE), jnp.float32),
-        ],
+        num_scalar_prefetch=4, grid=(1,), in_specs=in_specs, out_specs=vmem,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_KERNEL_VMEM_BYTES),
         name="paged_attention",
-    )(table, lengths, *operands)
-    return (
-        out.reshape(b, hkv, s, gsize, d).transpose(0, 2, 1, 3, 4).reshape(b, s, hq, d)
-    )
+    )(jnp.sum(count).reshape(1), top, table.reshape(-1), pos.reshape(-1), *operands)
+    return out.reshape(b, s, hq, d)[..., :head_dim]
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:

@@ -122,6 +122,7 @@ from .generation import (
     make_causal_programs,
 )
 from .logging import get_logger
+from .ops import attention as attention_ops
 from .paging import SCRATCH_PAGE, PagePool, chain_hashes, pages_for
 from .parallel.sharding import constrain_tp_cache, tree_device_nbytes
 from .speculative import (
@@ -316,7 +317,7 @@ class ContinuousBatcher:
         speculative: bool = False,
         draft_tokens: int = DEFAULT_DRAFT_TOKENS,
         draft_ngram: int = DEFAULT_DRAFT_NGRAM,
-        attention_impl: str = "xla",
+        attention_impl: Optional[str] = None,
         weight_dtype: str = "bf16",
         kv_cache_dtype: str = "bf16",
         tp: int = 1,
@@ -364,17 +365,11 @@ class ContinuousBatcher:
             )
         # A latent cache (MLA: one `[c | k_pe]` row a token a layer, which the
         # config says by `decode_kv_row_values`) is read by the XLA loop on one
-        # device, unquantized. The three other combinations name what is missing.
+        # device, unquantized. The other combinations name what is missing
+        # (a named page-walk kernel: `ops.attention.slot_attention_impl`, below).
         latent_row = getattr(base, "decode_kv_row_values", None)
         if latent_row is not None:
             family = type(model.module).__name__
-            if str(attention_impl) == "pallas_paged":
-                raise ValueError(
-                    f"attention_impl={attention_impl!r} with {family}: its cache is one "
-                    f"latent row of {latent_row} values a token, and the page-walk "
-                    "kernels read a K pool and a V pool of full heads — a page-walk kernel "
-                    "for latent rows is not built; use attention_impl=\"xla\""
-                )
             if self.kv_cache_dtype != "bf16":
                 raise ValueError(
                     f"kv_cache_dtype={self.kv_cache_dtype!r} with {family}: the quantized pool "
@@ -482,19 +477,13 @@ class ContinuousBatcher:
                     "(the presence update is order-dependent across a verified "
                     "block); disable one of the two"
                 )
-        # Decode/verify attention implementation: "xla" keeps the gather-then-
-        # attend oracle; "pallas_paged" fuses the page-table walk into the
-        # ops/paged_attention kernels. Either way the ONE decode executable
-        # and the traced-operand page tables are unchanged — the impl only
-        # swaps the attention read inside the compiled program.
-        from .ops.attention import SLOT_ATTENTION_IMPLS
-
-        self.attention_impl = str(attention_impl)
-        if self.attention_impl not in SLOT_ATTENTION_IMPLS:
-            raise ValueError(
-                f"unknown attention_impl {attention_impl!r}; expected one of "
-                f"{SLOT_ATTENTION_IMPLS}"
-            )
+        # Decode/verify attention read: "xla" is the loop over blocks of live
+        # pages (the parity oracle), "pallas_paged" the ops/paged_attention
+        # page-walk kernel, None the engine's choice between them
+        # (`ops.attention.slot_attention_impl`, resolved and checked below once
+        # the cache's shapes are known). Either way the ONE decode executable and the
+        # traced-operand page tables are unchanged — the impl only swaps the
+        # attention read inside the compiled program.
         self.page_size = int(page_size)
         if self.page_size < 1:
             raise ValueError("page_size must be >= 1")
@@ -647,6 +636,23 @@ class ContinuousBatcher:
                     "support the quantized KV page pool yet"
                 )
             quant_cfg["decode_kv_cache_dtype"] = self.kv_cache_dtype
+        # What the paged read is sized from (`_live_page_counts`) and chosen by:
+        # the prefill cache is K as the model computes it — [1, length, KV
+        # heads, head_dim] in the compute dtype ([1, length, row] for a latent
+        # family), the very operands the read takes its block and its run from.
+        key = _cached_key_leaf(self._dense_cache_struct)
+        kv_heads = key.shape[2] if key.ndim == 4 else 1  # latent rows [1, length, row]: no head axis
+        self.attention_impl = attention_ops.slot_attention_impl(
+            None if attention_impl is None else str(attention_impl), platform=self._home_device.platform,
+            latent=latent_row is not None, tp=self.tp, slots=self.num_slots,
+            pages_per_slot=self.pages_per_slot, page_size=self.page_size,
+            block=self.draft_tokens + 1 if self.speculative else 1,
+            heads=base.num_attention_heads // self.tp, kv_heads=max(1, kv_heads // self.tp),
+            head_dim=key.shape[-1], itemsize=np.dtype(key.dtype).itemsize, kv_cache_dtype=self.kv_cache_dtype,
+        )
+        self._read_shape = (self.pages_per_slot, self.page_size, kv_heads, key.shape[-1],
+                            np.dtype(key.dtype).itemsize, base.num_attention_heads // kv_heads,
+                            self.attention_impl)
         step_cfg = dataclasses.replace(
             base, decode_cache_length=cache_len, decode_slot_cache=True,
             decode_page_size=self.page_size, decode_num_pages=self.num_pages,
@@ -657,19 +663,14 @@ class ContinuousBatcher:
             step_module, resolve, step_mask_operand=True, verify_block=True
         )
         self._step_module = step_module
-        # What the XLA read's loop is sized from (`_live_page_counts`): the
-        # prefill cache is K as the model computes it — [1, length, KV heads,
-        # head_dim] in the compute dtype ([1, length, row] for a latent
-        # family), the very operands the read takes its block and its run from.
-        key = _cached_key_leaf(self._dense_cache_struct)
-        kv_heads = key.shape[2] if key.ndim == 4 else 1  # latent rows [1, length, row]: no head axis
-        self._read_shape = (self.pages_per_slot, self.page_size, kv_heads, key.shape[-1],
-                            np.dtype(key.dtype).itemsize, base.num_attention_heads // kv_heads)
 
         self._sample_config = GenerationConfig(do_sample=do_sample, top_k=top_k, top_p=top_p)
         # Python-side effects run at TRACE time: these count compiles, and the
         # serving tests pin "decode compiled once across mixed admissions" on them.
         self.trace_counts: Dict[str, int] = {"insert": 0, "decode_chunk": 0}
+        #: `ops.attention.LAST_DISPATCH` as the decode chunk was traced: the
+        #: read its program holds, on `serve.decode_chunk` as `read_impl`.
+        self.read_impl: Optional[str] = None
 
         self._rng = rng if rng is not None else jax.random.key(0)
         self._insert_fns: Dict[int, Any] = {}
@@ -1265,6 +1266,7 @@ class ContinuousBatcher:
 
             carry = (cache, presence, token, pos, active, rem, rng)
             carry, (toks, valids) = jax.lax.scan(body, carry, None, length=chunk)
+            self.read_impl = attention_ops.LAST_DISPATCH  # trace time: the read the program holds
             cache, presence, token, pos, active, rem, rng = carry
             cache = constrain_tp_cache(cache, mesh)
             # Pack the [chunk, S] stream TIME-major so each slot's tokens stay in
@@ -1382,6 +1384,7 @@ class ContinuousBatcher:
 
             carry = (cache, token, pos, active, rem, history)
             carry, (toks, valids, emitted_mat, proposed_mat) = jax.lax.scan(body, carry, None, length=chunk)
+            self.read_impl = attention_ops.LAST_DISPATCH  # trace time: the read the program holds
             cache, token, pos, active, rem, history = carry
             cache = constrain_tp_cache(cache, mesh)
             # Pack [chunk, S, k+1] -> (slot, token) stream, time-major per slot
@@ -2344,6 +2347,7 @@ class ContinuousBatcher:
                 self._charge("push", time.perf_counter())
             with tracer.span("serve.chunk.dispatch", category="serve", record=False) as dispatch_span:
                 carry, read = self._chunk_fn(*operands)
+            chunk_span.annotate(read_impl=self.read_impl)  # known once the first dispatch has traced it
             if self._empty_since is not None:
                 self._charge("dispatch", time.perf_counter())
                 self._empty_since = None  # the device has work from here on
@@ -2401,13 +2405,11 @@ class ContinuousBatcher:
         read's own module counts it (`ops.attention.read_blocks`: an idle
         slot is the one scratch page the device visits). A slot's pages grow
         inside the chunk: that is not counted."""
-        from .ops.attention import read_blocks
-
         live = int((self._pos[self._active] // self.page_size + 1).sum())
         window = self.num_slots * self.pages_per_slot
         self._m_kv_live_page_share.set(live / window)
         counts = {"live_pages": live, "window_pages": window,
-                  "read_blocks": read_blocks(np.where(self._active, self._pos, 0), *self._read_shape),
+                  "read_blocks": attention_ops.read_blocks(np.where(self._active, self._pos, 0), *self._read_shape),
                   "kv_row_values": self.kv_row_values}
         if self._state_bytes_per_slot:
             # A family with recurrent state: what the chunk's first step reads

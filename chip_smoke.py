@@ -416,8 +416,10 @@ def compare_tokens(name, requests, got, oracle, ref_rows, tol: float) -> dict:
 
 
 def phase_serve(ledger, sizes: Sizes, seed: int, ctx: dict) -> None:
-    """llama through `Router` -> `ContinuousBatcher` (default attention_impl="xla"),
-    held to the static `Generator` path; steady state under a recording TraceGuard."""
+    """llama through `Router` -> `ContinuousBatcher` with no `attention_impl` named
+    (the engine's choice: llama-1b's heads of 64 would have to be staged for the
+    kernel, so it stays on the XLA read, on the chip too), held to the static
+    `Generator` path; steady state under a recording TraceGuard."""
     import jax
 
     from accelerate_tpu.analysis import TraceGuard
@@ -429,6 +431,7 @@ def phase_serve(ledger, sizes: Sizes, seed: int, ctx: dict) -> None:
         requests = make_requests(sizes, cfg.vocab_size, seed + 2)
         guard = TraceGuard(on_violation="record", name="chip-smoke-serve")
         router = make_router(model, sizes, max_length, trace_guard=guard)
+        read = router.replica_set.replicas[0].engine.stats["attention_impl"]
         t0 = time.perf_counter()
         serve_requests(router, warm)  # compiles the decode chunk + every insert bucket used
         warm_s = time.perf_counter() - t0
@@ -455,6 +458,7 @@ def phase_serve(ledger, sizes: Sizes, seed: int, ctx: dict) -> None:
             vs_static_generator=compare_tokens(
                 "engine vs static Generator", requests, tokens, oracle, ref_rows, LOGIT_TOL
             ),
+            attention_impl=read,
             peak_hbm_gb=peak_hbm_gb(),
         )
         ctx.update(model=model, cfg=cfg, max_length=max_length, requests=requests,
@@ -488,8 +492,10 @@ def kernel_engine_tokens(ctx, sizes: Sizes, expect_kernel: bool, **engine_kwargs
 
 
 def phase_serve_kernel(ledger, sizes: Sizes, ctx: dict) -> None:
-    """The same requests through the Pallas page-walk kernels: bf16 pages against
-    the XLA gather engine, int8 pages against the quantized XLA oracle."""
+    """The same requests through the Pallas page-walk kernel, named (llama-1b's
+    heads of 64 are half a lane row: the kernel stages such a pool, which the
+    engine's own choice never does): bf16 pages against the serve phase's XLA
+    engine, int8 pages against the quantized XLA oracle."""
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -502,7 +508,7 @@ def phase_serve_kernel(ledger, sizes: Sizes, ctx: dict) -> None:
             **compare_tokens("pallas_paged bf16 vs xla", requests, bf16, ctx["xla_tokens"],
                              reference.rows(requests, ctx["xla_tokens"]), LOGIT_TOL),
         }
-        int8_oracle, _ = kernel_engine_tokens(ctx, sizes, on_tpu, kv_cache_dtype="int8")
+        int8_oracle, _ = kernel_engine_tokens(ctx, sizes, on_tpu, attention_impl="xla", kv_cache_dtype="int8")
         int8, evidence = kernel_engine_tokens(
             ctx, sizes, on_tpu, attention_impl="pallas_paged", kv_cache_dtype="int8"
         )

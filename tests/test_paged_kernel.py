@@ -184,6 +184,165 @@ def test_prefix_shared_pages_read_identically():
     np.testing.assert_allclose(out, _oracle(q, pool_k, pool_v, table, pos), atol=2e-5)
 
 
+# ---------------------------------------------------------------- the page walk
+# What the walk of runs newly depends on (ops/paged_attention.py): 5 slots x 6
+# pages of 4 tokens, a run of 2 pages, so a full slot is 3 entries. Each case
+# is the LAST position a slot's queries attend (0: an idle slot, one entry).
+WALK_PAGES, WALK_PAGE_SIZE, WALK_RUN = 6, 4, 2
+_WALKS = {
+    "ragged_with_idle_slots_between": [13, 0, 22, 0, 5],  # 2 + 1 + 3 + 1 + 1 entries
+    "live_pages_end_inside_a_run": [9, 17, 1, 10, 19],  # 3, 5, 1, 3, 5 live pages: the last run holds one
+    "every_slot_at_the_full_window": [23, 23, 23, 23, 23],
+    "every_slot_idle": [0, 0, 0, 0, 0],
+    "list_ends_on_a_buffer_pair": [15, 7, 16, 0, 8],  # 2 + 1 + 3 + 1 + 2 = 8 entries: both buffers end used
+    "list_ends_one_entry_past_it": [16, 7, 16, 0, 8],  # 9 entries
+    "shared_prefix_pages": [14, 18, 21, 0, 3],
+}
+
+
+def _walk_operands(walk, s, hq, hkv, dtype, d=8, seed=11):
+    rng = np.random.default_rng(seed)
+    slots = len(_WALKS[walk])
+    num_pages = slots * WALK_PAGES + 1
+    pool_k, pool_v = _random_pool(rng, num_pages, WALK_PAGE_SIZE, hkv, d)
+    table = rng.permutation(np.arange(1, num_pages)).reshape(slots, WALK_PAGES).astype(np.int32)
+    if walk == "shared_prefix_pages":
+        table[1, :3] = table[0, :3]  # a cached system prompt in two slots' tables, one of them
+        table[2, :2] = table[0, :2]  # reading on past it into pages of its own
+    top = np.asarray(_WALKS[walk], np.int32)
+    # a verify block's queries sit at consecutive positions ending at `top` (an idle slot's from 0)
+    base = np.maximum(top - (s - 1), 0)
+    pos = (base[:, None] + np.arange(s)[None, :]).astype(np.int32)
+    q = rng.normal(size=(slots, s, hq, d)).astype(np.float32)
+    return jnp.asarray(q, dtype), pool_k, pool_v, jnp.asarray(table), jnp.asarray(pos)
+
+
+def _both_reads(monkeypatch, q, pool_k, pool_v, table, pos, scales=None):
+    """The kernel and `_live_page_attention` on the same operands, the kernel
+    in runs of `WALK_RUN` pages and the XLA loop in blocks of three."""
+    from accelerate_tpu.ops import attention
+
+    itemsize = q.dtype.itemsize
+    page_bytes = WALK_PAGE_SIZE * pool_k.shape[2] * pool_k.shape[3] * itemsize
+    monkeypatch.setattr(attention, "_KERNEL_RUN_BYTES", WALK_RUN * page_bytes)
+    monkeypatch.setattr(attention, "_READ_BLOCK_BYTES", 3 * page_bytes)
+    k_scale, v_scale = scales if scales is not None else (None, None)
+    got = paged_verify_attention(q, pool_k, pool_v, table, pos, k_scale=k_scale, v_scale=v_scale)
+    want = attention._live_page_attention(q, pool_k, pool_v, pos, table, scales)
+    return np.asarray(got.astype(jnp.float32)), np.asarray(want.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("walk", list(_WALKS))
+def test_walk_of_runs_matches_the_xla_read(monkeypatch, walk, s):
+    """Every shape of live list the walk meets, f32, multi-head: the kernel's
+    output is the XLA read's on the same pools, table and positions."""
+    q, pool_k, pool_v, table, pos = _walk_operands(walk, s, 4, 4, jnp.float32)
+    got, want = _both_reads(monkeypatch, q, jnp.asarray(pool_k), jnp.asarray(pool_v), table, pos)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # and both are the gather oracle's
+    np.testing.assert_allclose(
+        got, _oracle(np.asarray(q), pool_k, pool_v, np.asarray(table), np.asarray(pos)), atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("pool", ["bf16", "int8", "fp8_e4m3"])
+def test_walk_of_runs_by_pool_dtype_and_group(monkeypatch, pool, group, s):
+    """The ragged walk again with the pool stored in bf16 (bf16 products, fp32
+    accumulation: bf16 tolerance), int8 and fp8 (scales applied to scores and
+    probabilities; fp32 queries: tight), one and four query heads a KV head."""
+    from accelerate_tpu.ops.quantization import kv_quant_spec, quantize_kv_pages
+
+    hkv = 2
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    q, pool_k, pool_v, table, pos = _walk_operands(
+        "ragged_with_idle_slots_between", s, hkv * group, hkv, dtype
+    )
+    if pool == "bf16":
+        got, want = _both_reads(
+            monkeypatch, q, jnp.asarray(pool_k, dtype), jnp.asarray(pool_v, dtype), table, pos
+        )
+        np.testing.assert_allclose(got, want, atol=3e-2, rtol=3e-2)
+        return
+    spec = kv_quant_spec(pool)
+    kq, ks = quantize_kv_pages(jnp.asarray(pool_k), spec)
+    vq, vs = quantize_kv_pages(jnp.asarray(pool_v), spec)
+    got, want = _both_reads(monkeypatch, q, kq, vq, table, pos, scales=(ks, vs))
+    np.testing.assert_allclose(got, want, atol=5e-5)
+
+
+@pytest.mark.parametrize(
+    "pool,hkv,d,staged",
+    [
+        # whole tiles — D whole 128-lane rows, heads whole packed sublanes: read in place
+        ("f32", 1, 128, False), ("bf16", 2, 128, False), ("int8", 4, 128, False), ("fp8_e4m3", 4, 128, False),
+        # anything else is padded to that first: llama-1b's heads of 64, one KV head
+        # of bf16, two of int8, and a shape that is short on both axes
+        ("bf16", 2, 64, True), ("bf16", 1, 128, True), ("int8", 2, 128, True), ("fp8_e4m3", 3, 96, True),
+    ],
+)
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify5"])
+def test_walk_reads_a_pool_in_place_or_staged(monkeypatch, s, pool, hkv, d, staged):
+    """The ragged walk with two query heads a KV head over pools the kernel
+    copies pages out of as they are and pools it must stage
+    (`ops.attention.kernel_stages_pool`: zero lanes, zero heads): the XLA
+    read's output either way, and the staged pool is the only one padded."""
+    from accelerate_tpu.ops import attention, paged_attention
+    from accelerate_tpu.ops.quantization import kv_quant_spec, quantize_kv_pages
+
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    q, pool_k, pool_v, table, pos = _walk_operands(
+        "ragged_with_idle_slots_between", s, 2 * hkv, hkv, dtype, d=d
+    )
+    assert attention.kernel_stages_pool(hkv, d, {"f32": 4, "bf16": 2}.get(pool, 1)) == staged
+    padded, pad = [], jnp.pad
+    monkeypatch.setattr(paged_attention.jnp, "pad", lambda x, widths: padded.append(x.shape) or pad(x, widths))
+    if pool in ("f32", "bf16"):
+        got, want = _both_reads(monkeypatch, q, jnp.asarray(pool_k, dtype), jnp.asarray(pool_v, dtype), table, pos)
+        tol = dict(atol=3e-2, rtol=3e-2) if pool == "bf16" else dict(atol=2e-5)
+    else:
+        spec = kv_quant_spec(pool)
+        kq, ks = quantize_kv_pages(jnp.asarray(pool_k), spec)
+        vq, vs = quantize_kv_pages(jnp.asarray(pool_v), spec)
+        got, want = _both_reads(monkeypatch, q, kq, vq, table, pos, scales=(ks, vs))
+        tol = dict(atol=5e-5)
+    np.testing.assert_allclose(got, want, **tol)
+    assert (pool_k.shape in padded) == staged
+
+
+@pytest.mark.parametrize("run_pages", [2, 3])
+def test_engine_greedy_token_parity_in_runs_of_pages(monkeypatch, run_pages):
+    """Greedy decode through `ContinuousBatcher` with a slot's 8 pages walked
+    in runs of 2 and of 3 (the last run then holds 2): token-identical to the
+    XLA read, one decode program, and `read_blocks` counts the kernel's
+    entries."""
+    from accelerate_tpu.ops import attention
+    from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
+    from accelerate_tpu.telemetry.tracing import Tracer
+
+    model = create_llama_model(_tiny_config(), seq_len=32)
+    cfg = model.module.config
+    page_bytes = 4 * cfg.num_key_value_heads * cfg.head_dim * 4
+    monkeypatch.setattr(attention, "_KERNEL_RUN_BYTES", run_pages * page_bytes)
+    requests = _mixed_requests(np.random.default_rng(12), 5, prompt_lo=6, prompt_hi=22)
+    common = dict(num_slots=3, max_length=32, chunk_size=4, page_size=4)
+    _, xla_tokens = _run_engine(model, requests, attention_impl="xla", **common)
+    recorder = FlightRecorder()
+    engine, kernel_tokens = _run_engine(
+        model, requests, attention_impl="pallas_paged",
+        tracer=Tracer(recorder=recorder, category="serve"), **common,
+    )
+    assert kernel_tokens == xla_tokens
+    assert engine.trace_counts["decode_chunk"] == 1
+    chunks = [r["attrs"] for r in recorder.records() if r["name"] == "serve.decode_chunk"]
+    assert chunks and all(c["read_impl"] == "pallas_paged" for c in chunks)
+    # three slots, each 1..ceil(8 / run) entries
+    assert all(3 <= c["read_blocks"] <= 3 * -(-8 // run_pages) for c in chunks)
+    assert max(c["read_blocks"] for c in chunks) > 3  # some slot was read in more than one run
+
+
 # -------------------------------------------------------------- program-level
 def _tiny_config(**overrides):
     base = dict(

@@ -64,43 +64,102 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("page_size", [16, 64])
-@pytest.mark.parametrize("batch", [4, 8])
-@pytest.mark.parametrize("pool", ["bf16", "int8"])
-@pytest.mark.parametrize("s_block", [1, 5], ids=["decode", "verify5"])
-def test_paged_attention_compiles_for_v5e(v5e, s_block, pool, batch, page_size):
-    """`paged_decode_attention` (s=1) and `paged_verify_attention` (a speculative
-    block of 5: rows = s*G = 20) at llama-1b widths, bf16 pools and int8 pools with
-    scale operands. `interpret=False` is explicit: `default_backend()` is cpu here."""
+#: (slots, pages a slot, page size, query heads, KV heads, head_dim) the page-walk kernel is compiled at.
+PAGED_SHAPES = {
+    # llama-1b's widths: heads of 64 are half a lane row, so these pools are STAGED (`kernel_stages_pool`)
+    "llama_1b-4-16": (4, PAGES_PER_SLOT, 16, HQ, HKV, D),
+    "llama_1b-8-16": (8, PAGES_PER_SLOT, 16, HQ, HKV, D),
+    "llama_1b-4-64": (4, PAGES_PER_SLOT, 64, HQ, HKV, D),
+    "llama_1b-8-64": (8, PAGES_PER_SLOT, 64, HQ, HKV, D),
+    "pythia_cell": (32, 88, 16, 16, 16, 128),  # chipbench/workloads/pythia-1.4b.*.json: a run of 16 pages
+    "olmo_cell": (48, 80, 16, 32, 32, 128),  # olmo-hybrid-7b.chat-saturated, a full-attention layer: a run of 8
+    "grouped_32_over_8": (32, 88, 16, 32, 8, 128),  # a run of 32 pages; 8 KV heads of bf16 half-fill a tile
+    "pages_of_64": (8, 32, 64, 32, 8, 128),
+    "pythia_tp4_shard": (32, 88, 16, 4, 4, 128),  # what `_tp_paged_attention` hands a chip of four
+    "one_kv_head": (8, 32, 16, 8, 1, 128),  # staged for every pool but fp32: a packed sublane holds 2 or 4 heads
+}
+POOL_DTYPES = {"bf16": jnp.bfloat16, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
+
+
+def _paged_args(v5e, shape, s_block, pool):
+    slots, pages_per_slot, page_size, hq, hkv, d = shape
+
+    def spec(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    num_pages = slots * pages_per_slot + 1
+    args = [
+        spec((slots, s_block, hq, d), jnp.bfloat16),
+        spec((num_pages, page_size, hkv, d), POOL_DTYPES[pool]),
+        spec((num_pages, page_size, hkv, d), POOL_DTYPES[pool]),
+        spec((slots, pages_per_slot), jnp.int32),
+        spec((slots, s_block), jnp.int32),
+    ]
+    if pool != "bf16":
+        args += [spec((num_pages, hkv), jnp.float32)] * 2
+    return args
+
+
+def _paged_fn(s_block, pool):
     from accelerate_tpu.ops.paged_attention import (
         paged_decode_attention,
         paged_verify_attention,
     )
 
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    num_pages = batch * PAGES_PER_SLOT + 1
-    pool_dtype = jnp.int8 if pool == "int8" else jnp.bfloat16
-    args = [
-        spec((batch, s_block, HQ, D), jnp.bfloat16),
-        spec((num_pages, page_size, HKV, D), pool_dtype),
-        spec((num_pages, page_size, HKV, D), pool_dtype),
-        spec((batch, PAGES_PER_SLOT), jnp.int32),
-        spec((batch, s_block), jnp.int32),
-    ]
     kernel = paged_decode_attention if s_block == 1 else paged_verify_attention
-    if pool == "int8":
-        args += [spec((num_pages, HKV), jnp.float32)] * 2
+    if pool != "bf16":
+        return lambda q, k, v, table, pos, k_scale, v_scale: kernel(
+            q, k, v, table, pos, interpret=False, k_scale=k_scale, v_scale=v_scale)
+    return lambda q, k, v, table, pos: kernel(q, k, v, table, pos, interpret=False)
 
-        def fn(q, k, v, table, pos, k_scale, v_scale):
-            return kernel(q, k, v, table, pos, interpret=False, k_scale=k_scale, v_scale=v_scale)
+
+@pytest.mark.parametrize("shape", list(PAGED_SHAPES))
+@pytest.mark.parametrize("pool", list(POOL_DTYPES))
+@pytest.mark.parametrize("s_block", [1, 5], ids=["decode", "verify5"])
+def test_paged_attention_compiles_for_v5e(v5e, s_block, pool, shape):
+    """`paged_decode_attention` (s=1) and `paged_verify_attention` (a speculative
+    block of 5: up to 160 query rows) at llama-1b's widths, the serving cells'
+    shapes, grouped-query ones and a tensor-parallel shard's, bf16 pools and
+    int8 / fp8 pools with scale operands: the page copies out of an HBM pool,
+    the merge of a run's (token, head) rows and the VMEM the products take are
+    what interpret mode cannot see. `interpret=False` is explicit:
+    `default_backend()` is cpu here."""
+    from accelerate_tpu.ops.attention import kernel_refuses, kernel_stages_pool
+
+    slots, pages_per_slot, page_size, hq, hkv, d = PAGED_SHAPES[shape]
+    assert kernel_refuses(slots, pages_per_slot, page_size, s_block, hq, hkv, d, 2) is None
+    compiled = _compile(_paged_fn(s_block, pool), *_paged_args(v5e, PAGED_SHAPES[shape], s_block, pool))
+    pool_itemsize = jnp.dtype(POOL_DTYPES[pool]).itemsize
+    if not kernel_stages_pool(hkv, d, pool_itemsize):
+        # read in place: no copy of a pool is made on the way to the kernel
+        pool_bytes = (slots * pages_per_slot + 1) * page_size * hkv * d * pool_itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+
+
+@pytest.mark.parametrize(
+    "shape,s_block,fits",
+    [
+        ((112, 2048, 16, 32, 8, 128), 1, True),  # 896 KiB of page tables in SMEM
+        ((128, 2048, 16, 32, 8, 128), 1, False),  # 1 MiB: the compiler refuses the operand
+        ((32, 88, 16, 64, 8, 128), 18, True),  # 1,152 query rows: ~61 MB of VMEM
+        ((700, 88, 16, 32, 32, 128), 5, False),  # queries and outputs alone are 57 MB
+    ],
+    ids=["smem_fits", "smem_over", "vmem_fits", "vmem_over"],
+)
+def test_paged_attention_refusal_is_the_compilers(v5e, shape, s_block, fits):
+    """`ops.attention.kernel_refuses` — which keeps the engine's choice off the
+    kernel, and refuses a NAMED kernel in the engine's constructor — says what
+    the chip's compiler says about SMEM and VMEM, on either side of each edge."""
+    from accelerate_tpu.ops.attention import kernel_refuses
+
+    slots, pages_per_slot, page_size, hq, hkv, d = shape
+    assert (kernel_refuses(slots, pages_per_slot, page_size, s_block, hq, hkv, d, 2) is None) == fits
+    lowered = jax.jit(_paged_fn(s_block, "bf16")).lower(*_paged_args(v5e, shape, s_block, "bf16"))
+    if fits:
+        lowered.compile()
     else:
-
-        def fn(q, k, v, table, pos):
-            return kernel(q, k, v, table, pos, interpret=False)
-
-    _compile(fn, *args)
+        with pytest.raises(Exception, match="smem|vmem"):
+            lowered.compile()
 
 
 # One layer of the benchmark's serving cells (chipbench/workloads/pythia-1.4b.*.json):
