@@ -35,6 +35,13 @@ from .latent_moe import (
     kimi_vl_a3b_text,
     latent_moe_tiny,
 )
+from .falcon_h1 import (
+    FalconH1Config,
+    FalconH1ForCausalLM,
+    create_falcon_h1_model,
+    falcon_h1_34b,
+    falcon_h1_tiny,
+)
 from .olmo_hybrid import (
     OlmoHybridConfig,
     OlmoHybridForCausalLM,
@@ -70,6 +77,8 @@ MODEL_REGISTRY = {
     "gpt-neox-tiny": ("gpt_neox", gpt_neox_tiny),
     "kimi-vl-a3b-text": ("latent_moe", kimi_vl_a3b_text),
     "latent-moe-tiny": ("latent_moe", latent_moe_tiny),
+    "falcon-h1-34b": ("falcon_h1", falcon_h1_34b),
+    "falcon-h1-tiny": ("falcon_h1", falcon_h1_tiny),
     "olmo-hybrid-7b": ("olmo_hybrid", olmo_hybrid_7b),
     "olmo-hybrid-tiny": ("olmo_hybrid", olmo_hybrid_tiny),
     "opt-30b": ("opt", opt_30b),
@@ -91,6 +100,7 @@ CREATE_BY_FAMILY = {
     "t5": create_t5_model,
     "latent_moe": create_latent_moe_model,
     "olmo_hybrid": create_olmo_hybrid_model,
+    "falcon_h1": create_falcon_h1_model,
 }
 
 # family -> (flax module class name, LayeredApply class) for models shipping a
@@ -285,6 +295,28 @@ def _olmo_hybrid_cfg(c: OlmoHybridConfig) -> dict:
     }
 
 
+def _falcon_h1_cfg(c: FalconH1Config) -> dict:
+    return {
+        "model_type": "falcon_h1",
+        "vocab_size": c.vocab_size,
+        "hidden_size": c.hidden_size,
+        "num_hidden_layers": c.num_hidden_layers,
+        "num_attention_heads": c.num_attention_heads,
+        "num_key_value_heads": c.num_key_value_heads,
+        "head_dim": c.head_dim,
+        "intermediate_size": c.intermediate_size,
+        "mamba_d_ssm": c.mamba_d_ssm,
+        "mamba_n_heads": c.mamba_n_heads,
+        "mamba_d_head": c.mamba_d_head,
+        "mamba_n_groups": c.mamba_n_groups,
+        "mamba_d_state": c.mamba_d_state,
+        "mamba_d_conv": c.mamba_d_conv,
+        "mamba_chunk_size": c.mamba_chunk_size,
+        "hidden_act": "silu",
+        "tie_word_embeddings": False,
+    }
+
+
 def _bert_cfg(c: BertConfig) -> dict:
     return {
         "model_type": "bert",
@@ -322,6 +354,7 @@ _CFG_BUILDERS = {
     "t5": _t5_cfg,
     "latent_moe": _latent_moe_cfg,
     "olmo_hybrid": _olmo_hybrid_cfg,
+    "falcon_h1": _falcon_h1_cfg,
 }
 
 

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from ..modeling import Model
 from ..ops.attention import dot_product_attention, slot_cache_attention, update_decode_cache
 from ..ops.delta_rule import (
+    CHUNK,
     causal_conv,
     from_slot_layout,
     gated_delta_chunked,
@@ -147,6 +148,11 @@ class OlmoHybridConfig:
     def linear_conv_channels(self) -> int:
         """Channels the short convolution runs over: ``[q | k | v]``."""
         return self.linear_num_key_heads * (2 * self.linear_key_head_dim + self.linear_value_head_dim)
+
+    @property
+    def decode_scan_chunk(self) -> int:
+        """Tokens a chunk of the recurrence's prefill form (`serve.insert.scan_chunks`)."""
+        return CHUNK
 
     @property
     def _pdtype(self):

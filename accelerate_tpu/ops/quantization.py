@@ -187,15 +187,24 @@ def is_quantized_kernel(value) -> bool:
     return isinstance(value, dict) and set(value.keys()) == set(_QKEYS)
 
 
-def quantize_weight_int8(w) -> Dict[str, Any]:
-    """Per-output-channel symmetric int8: scales over every axis but the last
-    (the output-feature axis of a flax Dense kernel ``[K, N]``), computed once
-    at load time. ``w ~= q * scale`` with `scale` shaped ``[N]``."""
-    w32 = jnp.asarray(w).astype(jnp.float32)
+@jax.jit
+def _quantize_int8(w):
+    w32 = w.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(w32), axis=tuple(range(w32.ndim - 1)))
     scale = absmax / INT8_MAX
     q = jnp.clip(jnp.round(w32 / jnp.maximum(scale, _TINY)), -INT8_MAX, INT8_MAX)
-    return {"q": q.astype(jnp.int8), "scale": scale}
+    return q.astype(jnp.int8), scale
+
+
+def quantize_weight_int8(w) -> Dict[str, Any]:
+    """Per-output-channel symmetric int8: scales over every axis but the last
+    (the output-feature axis of a flax Dense kernel ``[K, N]``), computed once
+    at load time. ``w ~= q * scale`` with `scale` shaped ``[N]``. One compiled
+    program a shape: run operation by operation, a head of 261,120 x 5,120
+    held several float32 copies of itself (5.35 GB each) at once and a chip
+    that fits the int8 model could not load it."""
+    q, scale = _quantize_int8(jnp.asarray(w))
+    return {"q": q, "scale": scale}
 
 
 def dequantize_weight_int8(entry, dtype=jnp.float32):

@@ -2019,13 +2019,12 @@ class ContinuousBatcher:
 
     def _scan_chunks(self, bucket: int) -> Dict[str, int]:
         """`scan_chunks` of an insert, for its span: the chunks its bucket is
-        for a layer's chunked recurrence, pads included. Nothing for a family
-        without recurrent state."""
+        for a layer's chunked recurrence, pads included — chunks of the served
+        family's own size (`decode_scan_chunk` of its config). Nothing for a
+        family without recurrent state."""
         if not self._state_bytes_per_slot:
             return {}
-        from .ops.delta_rule import CHUNK
-
-        return {"scan_chunks": -(-int(bucket) // CHUNK)}
+        return {"scan_chunks": -(-int(bucket) // int(self.base_config.decode_scan_chunk))}
 
     def _hand_back(self):
         """Runs as step() returns, which is when a client gets the first token

@@ -316,6 +316,7 @@ _FAMILY_BY_MODULE = {
     "GPTNeoXForCausalLM": "gpt_neox",
     "LatentMoEForCausalLM": "latent_moe",
     "OlmoHybridForCausalLM": "olmo_hybrid",
+    "FalconH1ForCausalLM": "falcon_h1",
 }
 
 

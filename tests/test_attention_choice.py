@@ -31,6 +31,8 @@ LATENT_ROW = dict(latent=True, heads=16, kv_heads=1, head_dim=576)
         (None, dict(platform="tpu", heads=32, kv_heads=32), "pallas_paged"),
         (None, dict(platform="tpu", heads=32, kv_heads=8), "pallas_paged"),
         (None, dict(platform="tpu", heads=28, kv_heads=4, kv_cache_dtype="int8"), "pallas_paged"),
+        # falcon-h1-34b's cell: 80 slots x 80 pages, 20 query heads over 4 KV heads of 128 (timed: PERF.md section 6, PR 38)
+        (None, dict(platform="tpu", slots=80, pages_per_slot=80, heads=20, kv_heads=4), "pallas_paged"),
         (None, dict(platform="tpu", block=5), "pallas_paged"),  # a speculative engine's verify block
         (None, dict(platform="cpu"), "xla"),
         (None, dict(platform="gpu"), "xla"),
@@ -107,7 +109,15 @@ def _olmo_hybrid():
     return harness.load_module("adapters", "olmo_hybrid").build_model(TINY, params, "float32"), TINY["vocab_size"]
 
 
-@pytest.mark.parametrize("family", [_neox, _olmo_hybrid], ids=["gpt_neox", "olmo_hybrid"])
+def _falcon_h1():
+    from chipbench import harness
+    from test_falcon_h1 import TINY
+
+    params = harness.load_module("reference", "falcon_h1").init_params(TINY, jax.random.key(11), "float32")
+    return harness.load_module("adapters", "falcon_h1").build_model(TINY, params, "float32"), TINY["vocab_size"]
+
+
+@pytest.mark.parametrize("family", [_neox, _olmo_hybrid, _falcon_h1], ids=["gpt_neox", "olmo_hybrid", "falcon_h1"])
 def test_an_engine_that_names_no_read_is_the_xla_engine_on_the_cpu(family, monkeypatch):
     """The bypass: off the TPU the engine's choice is the XLA read, so the
     decode chunk of an engine built with no `attention_impl` is, as text, the

@@ -594,6 +594,7 @@ _FAMILIES = {
     "pythia-tiny": ("gpt-neox-tiny", {}),
     "latent-tiny": ("latent-moe-tiny", {}),
     "olmo-hybrid-tiny": ("olmo-hybrid-tiny", {}),
+    "falcon-h1-tiny": ("falcon-h1-tiny", {}),
 }
 
 

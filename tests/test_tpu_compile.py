@@ -73,6 +73,7 @@ PAGED_SHAPES = {
     "llama_1b-8-64": (8, PAGES_PER_SLOT, 64, HQ, HKV, D),
     "pythia_cell": (32, 88, 16, 16, 16, 128),  # chipbench/workloads/pythia-1.4b.*.json: a run of 16 pages
     "olmo_cell": (48, 80, 16, 32, 32, 128),  # olmo-hybrid-7b.chat-saturated, a full-attention layer: a run of 8
+    "falcon_cell": (80, 80, 16, 20, 4, 128),  # falcon-h1-34b.chat-saturated, every layer: a run of 64 pages, 5 queries a KV head
     "grouped_32_over_8": (32, 88, 16, 32, 8, 128),  # a run of 32 pages; 8 KV heads of bf16 half-fill a tile
     "pages_of_64": (8, 32, 64, 32, 8, 128),
     "pythia_tp4_shard": (32, 88, 16, 4, 4, 128),  # what `_tp_paged_attention` hands a chip of four
@@ -491,3 +492,106 @@ def test_hybrid_linear_layer_prefill_compiles_for_v5e(v5e, tokens):
     compiled = jax.jit(prefill).lower(described["params"], described["cache"], hidden, real).compile()
     assert "tpu_custom_call" not in compiled.as_text()  # the scan is XLA's; the kernel is the decode step's
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+# The parallel state-space cell's shapes (`falcon-h1-34b.chat-saturated`): 80
+# slots, 32 heads of a 128 x 256 float32 state in 2 groups, 4 KV heads of 128.
+SSM_SLOTS, SSM_HEADS, SSM_P, SSM_GROUPS, SSM_N = 80, 32, 128, 2, 256
+SSM_STATE_BYTES = SSM_SLOTS * SSM_HEADS * SSM_P * SSM_N * 4
+
+
+def test_ssm_step_kernel_keeps_the_state_at_its_bytes(v5e):
+    """`ops.ssm`'s one-token update at the cell's shapes: ONE Mosaic kernel, the
+    state `[80, 256, 4096]` float32 held at its values' bytes, updated in
+    place, nothing of its size made beside it (written head-major in
+    `jax.numpy` the step made two broadcasts of the state's size a layer)."""
+    from accelerate_tpu.ops.ssm import _ssm_step_pallas
+
+    def step(state, x, dt, a, b_in, c_in):
+        return _ssm_step_pallas(state, x, dt, a, b_in, c_in, interpret=False)
+
+    def operand(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        operand((SSM_SLOTS, SSM_N, SSM_HEADS * SSM_P), jnp.float32),
+        operand((SSM_SLOTS, SSM_HEADS, SSM_P), jnp.bfloat16), operand((SSM_SLOTS, SSM_HEADS), jnp.float32),
+        operand((SSM_HEADS,), jnp.float32), operand((SSM_SLOTS, SSM_GROUPS, SSM_N), jnp.bfloat16),
+        operand((SSM_SLOTS, SSM_GROUPS, SSM_N), jnp.bfloat16),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ssm_step[.\d]* = [^\n]*custom-call\(", text)) == 1
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < SSM_STATE_BYTES * 1.02  # no padded layout: 335.5 MB
+    assert memory.alias_size_in_bytes >= SSM_STATE_BYTES  # in place
+    assert memory.temp_size_in_bytes < 8 << 20
+
+
+def _falcon_layer(**decode):
+    import dataclasses
+
+    from accelerate_tpu.models.falcon_h1 import FalconH1Config, FalconH1Layer
+
+    cfg = dataclasses.replace(FalconH1Config(num_hidden_layers=6, param_dtype="bfloat16"), **decode)
+    return cfg, FalconH1Layer(cfg)
+
+
+def test_parallel_hybrid_layer_decodes_with_both_kinds_of_leaf_at_their_bytes(v5e, monkeypatch):
+    """One block's decode step as the engine's chunk holds it
+    (`models.falcon_h1.FalconH1Layer` against 80 slots): its cache holds page
+    leaves AND by-slot leaves, the state's kernel and the page-walk kernel are
+    both in the program, and no operation makes, copies or re-lays-out an array
+    of the state's size or of a pool's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the layer picks its kernel by the backend
+    pages = SSM_SLOTS * 80 + 1
+    cfg, layer = _falcon_layer(decode_cache_length=1280, decode_slot_cache=True, decode_page_size=16,
+                               decode_num_pages=pages, decode_attention_impl="pallas_paged")
+    hidden = jax.ShapeDtypeStruct((SSM_SLOTS, 1, cfg.hidden_size), jnp.bfloat16, sharding=v5e)
+    positions = jax.ShapeDtypeStruct((SSM_SLOTS, 1), jnp.int32, sharding=v5e)
+    table = jax.ShapeDtypeStruct((SSM_SLOTS, 80), jnp.int32, sharding=v5e)
+    variables = jax.eval_shape(lambda h, p, t: layer.init(jax.random.key(0), h, p, t), hidden, positions, table)
+    described = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e), variables)
+    cache = described["cache"]
+    assert cache["mixer"]["recurrent_state"].shape == (SSM_SLOTS, SSM_N, SSM_HEADS * SSM_P)
+    assert cache["mixer"]["conv_state"].shape == (SSM_SLOTS, 3, cfg.conv_channels)
+    assert cache["attention"]["cached_key"].shape == (pages, 16, 4, 128)
+
+    def decode(params, cache, h, p, t):
+        return layer.apply({"params": params, "cache": cache}, h, p, t, mutable=["cache"])
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        described["params"], cache, hidden, positions, table).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ssm_step[.\d]* = [^\n]*custom-call\(", text)) == 1
+    assert text.count("tpu_custom_call") >= 2  # the state's kernel and the page walk
+    state_shaped = re.findall(r"= f32\[80,(?:256,4096|32,128,256|4096,256)\]\S* (\w+)\(", text)
+    assert set(state_shaped) <= {"custom-call", "parameter", "get-tuple-element", "bitcast"}, state_shaped
+    pool_shaped = re.findall(rf"= bf16\[{pages},16,4,128\]\S* (\w+)\(", text)
+    assert "copy" not in pool_shaped and "transpose" not in pool_shaped, pool_shaped
+    memory = compiled.memory_analysis()
+    stored = SSM_STATE_BYTES + SSM_SLOTS * 3 * cfg.conv_channels * 2 + 2 * pages * 16 * 4 * 128 * 2
+    weights = sum(math.prod(s.shape) * s.dtype.itemsize for s in jax.tree_util.tree_leaves(described["params"]))
+    assert memory.argument_size_in_bytes < (stored + weights) * 1.02  # every leaf at its bytes
+    assert memory.temp_size_in_bytes < SSM_STATE_BYTES // 4
+
+
+@pytest.mark.parametrize("tokens", [128, 512])
+def test_parallel_hybrid_layer_prefill_compiles_for_v5e(v5e, tokens):
+    """The chunked scan over an insert bucket (batch 1, chunks of 128, float32)
+    beside the attention half's dense prefill compiles for the chip and stays
+    small: the program around it holds 13.8 GB of weights, pool and state."""
+    cfg, layer = _falcon_layer(decode_cache_length=1280)
+    hidden = jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.bfloat16, sharding=v5e)
+    positions = jax.ShapeDtypeStruct((1, tokens), jnp.int32, sharding=v5e)
+    real = jax.ShapeDtypeStruct((1, tokens), jnp.bool_, sharding=v5e)
+    variables = jax.eval_shape(lambda h, p, m: layer.init(jax.random.key(0), h, p, m), hidden, positions, real)
+    described = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e), variables)
+
+    def prefill(params, cache, h, p, m):
+        return layer.apply({"params": params, "cache": cache}, h, p, m, mutable=["cache"])
+
+    compiled = jax.jit(prefill).lower(described["params"], described["cache"], hidden, positions, real).compile()
+    assert "ssm_step" not in compiled.as_text()  # the scan is XLA's; the kernel is the decode step's
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

@@ -392,7 +392,7 @@ def test_serving_step_span_tree_and_hand_back(backlog):
     assert ttft.sum == pytest.approx(ttft_sum, abs=1e-9)
 
 
-@pytest.mark.parametrize("family", ["gpt-neox-tiny", "latent-moe-tiny", "olmo-hybrid-tiny"])
+@pytest.mark.parametrize("family", ["gpt-neox-tiny", "latent-moe-tiny", "olmo-hybrid-tiny", "falcon-h1-tiny"])
 def test_decode_chunk_span_carries_what_the_benchmarks_readers_take(family):
     """`serve.decode_chunk` is one record a chunk, starts at its dispatch, and
     carries — running ahead or not — every attribute `chipbench/chunk_counters.py`
@@ -408,7 +408,8 @@ def test_decode_chunk_span_carries_what_the_benchmarks_readers_take(family):
     needs = {"chunk_size", "active_slots", "pages_in_use", "live_pages", "window_pages", "read_blocks",
              "kv_row_values", "tokens_streamed", "ahead"}
     needs |= {"latent-moe-tiny": {"expert_tokens_max", "expert_tokens_mean", "experts_touched"},
-              "olmo-hybrid-tiny": {"state_slots", "state_bytes_per_slot", "kv_page_bytes"}}.get(family, set())
+              "olmo-hybrid-tiny": {"state_slots", "state_bytes_per_slot", "kv_page_bytes"},
+              "falcon-h1-tiny": {"state_slots", "state_bytes_per_slot", "kv_page_bytes"}}.get(family, set())
     model = create_named_model(family)
     recorder = FlightRecorder()
     tracer = Tracer(recorder=recorder, category="serve")
