@@ -299,17 +299,49 @@ def read_run_pages(page_size: int, group: int) -> int:
 _KERNEL_RUN_BYTES = 1 << 20
 
 
+#: Tokens of a LATENT run that the page-walk kernel's products grow by
+#: (`kernel_piece_pages`), and whose pages its copies are started and waited
+#: for together.
+_KERNEL_PIECE_TOKENS = 256
+
+
+def kernel_piece_pages(page_size: int) -> int:
+    """The pages in a PIECE of a latent run, `_KERNEL_PIECE_TOKENS` tokens: the
+    step by which the page-walk kernel bounds an entry's products to what is
+    live of its run, and the group of pages whose copies it starts back to
+    back and waits for once. A latent row is keys and values both, so an
+    entry's two products load every byte of the run into the matrix unit twice
+    for one copy out of HBM, and they, not the copies, set the kernel's pace:
+    they run over the run's first pieces that hold a live position and no
+    others, where a K/V entry multiplies its whole run."""
+    return max(1, _KERNEL_PIECE_TOKENS // page_size)
+
+
 def kernel_run_pages(pages_per_slot: int, page_size: int, kv_heads: int, head_dim: int,
-                     itemsize: int) -> int:
+                     itemsize: int, latent: bool = False) -> int:
     """The consecutive pages of one slot that make ONE entry of the page-walk
     kernel's list: `_KERNEL_RUN_BYTES` of the pool's K pages (16 pages at
     pythia-1.4b's 16 heads of 128 in bf16, 8 at olmo-hybrid's 32), and never
-    more than a slot has."""
-    return max(1, min(pages_per_slot, _KERNEL_RUN_BYTES // (page_size * kv_heads * head_dim * itemsize)))
+    more than a slot has. A pool of `latent` rows (`kv_heads` 1, `head_dim`
+    the row) is keys and values both, so its run holds what a K run and a V
+    run hold together — an entry copies 2 MiB either way — in whole pieces of
+    `kernel_piece_pages` where it holds one: 96 pages of 16 rows of 640 in
+    bf16. The kernel's device time, us, one layer on a v5e at the kimi cell's
+    shape (128 slots x 128 pages, 16 heads; PERF.md §6, PR 39), runs of 1 / 2
+    / 4 MiB in pieces of 256 tokens: 242 / 233 / 235 at 4,700 live pages, 295
+    / 266 / 268 at 6,000, 113 / 115 / 117 with every slot idle, 312 / 312 /
+    322 for a verify block of 5; pieces of 128 tokens read within 2% of 256
+    at a decode step (6% behind at a verify block) and pieces of 512 10–20%
+    behind (a run's last pages, past its last whole piece, are copied one by
+    one). Flat between 1 and 4 MiB: the K/V entry's 2 MiB."""
+    pool_bytes = _KERNEL_RUN_BYTES * (2 if latent else 1)
+    run = max(1, min(pages_per_slot, pool_bytes // (page_size * kv_heads * head_dim * itemsize)))
+    piece = kernel_piece_pages(page_size)
+    return run // piece * piece if latent and run > piece else run
 
 
 def read_blocks(top_positions, pages_per_slot: int, page_size: int, kv_heads: int, head_dim: int,
-                itemsize: int, group: int, impl: str = "xla") -> int:
+                itemsize: int, group: int, impl: str = "xla", latent: bool = False) -> int:
     """The trip count of a dispatch's paged read, on the host, for slots whose
     queries attend up to `top_positions` (one a slot of the dispatch; an idle
     slot sits at 0 and is one entry), from the numbers the read itself takes
@@ -317,11 +349,12 @@ def read_blocks(top_positions, pages_per_slot: int, page_size: int, kv_heads: in
     `_live_page_attention`'s loop — the entries it lists, a page or a run of
     `read_run_pages`, in blocks of `read_block_pages`. The kernel
     (`"pallas_paged"`): the entries its loop walks, a run of
-    `kernel_run_pages` each. The engine says it on `serve.decode_chunk` as
+    `kernel_run_pages` each (`latent`: of a pool of latent rows, `kv_heads` 1
+    and `head_dim` the row). The engine says it on `serve.decode_chunk` as
     `read_blocks` without knowing any of the rules."""
     top = np.asarray(top_positions)[:, None]  # a slot's row of one position
     if impl == "pallas_paged":
-        run = kernel_run_pages(pages_per_slot, page_size, kv_heads, head_dim, itemsize)
+        run = kernel_run_pages(pages_per_slot, page_size, kv_heads, head_dim, itemsize, latent)
         return int(live_entry_counts(top, run * page_size)[1].sum())
     run = read_run_pages(page_size, group)
     block = max(1, read_block_pages(top.size * pages_per_slot, page_size, kv_heads, head_dim, itemsize) // run)
@@ -348,8 +381,23 @@ def kernel_stages_pool(kv_heads: int, head_dim: int, pool_itemsize: int) -> bool
     return bool(head_dim % 128 or kv_heads % (4 // pool_itemsize))
 
 
+def kernel_refuses_rows(page_size: int, row: int, pool_itemsize: int) -> Optional[str]:
+    """Why the page-walk kernel cannot read a pool of LATENT rows, `[num_pages,
+    page_size, row]` with no head axis, or None where it can: a page's
+    trailing `[page_size, row]` must be whole tiles for the chip's compiler to
+    cut it out of the pool — `row` whole 128-lane rows (a row of 576 is not)
+    and `page_size` whole packed sublanes (8 rows of fp32, 16 of bf16) — as
+    `kernel_stages_pool` asks of `[Hkv, D]`. But such a pool is NEVER staged:
+    a staged pool loses to the XLA read (`slot_attention_impl`), so the
+    engine's choice leaves it there and a named kernel is refused by name."""
+    if row % 128 or page_size % (32 // pool_itemsize):
+        return (f"pages of {page_size} rows of {row} values of {pool_itemsize} bytes are not whole tiles, so "
+                "the page-walk kernel cannot read the pool in place, and latent rows are never staged")
+    return None
+
+
 def kernel_refuses(slots: int, pages_per_slot: int, page_size: int, block: int, heads: int,
-                   kv_heads: int, head_dim: int, itemsize: int) -> Optional[str]:
+                   kv_heads: int, head_dim: int, itemsize: int, latent: bool = False) -> Optional[str]:
     """Why the chip's compiler would refuse the page-walk kernel at a
     dispatch's static shapes, or None where it takes them. The kernel is ONE
     invocation a layer: the page tables and positions of ALL slots ride SMEM
@@ -360,17 +408,26 @@ def kernel_refuses(slots: int, pages_per_slot: int, page_size: int, block: int, 
     15/16 of it); 32 slots x 1,152 query rows of 128 pass and 2,048 do not,
     600 slots x 160 rows pass and 700 do not (VMEM; the estimate reads 3-10%
     over what the compiler reported). A long window under very many slots
-    belongs to the XLA read, whose table stays in HBM."""
+    belongs to the XLA read, whose table stays in HBM. A `latent` pool
+    (`kv_heads` 1, `head_dim` the row) is one pool read in place: two run
+    buffers, nothing staged or widened, the live part of a run loaded once
+    for both products."""
     smem = 4 * (slots * pages_per_slot + slots * (block + 1) + 1)
     if smem > _KERNEL_SMEM_BYTES * 15 // 16:
         return (f"{slots} slots x {pages_per_slot} pages make {smem} bytes of page tables and "
                 f"positions, and the chip has {_KERNEL_SMEM_BYTES} bytes of SMEM")
     # A run's columns and lanes as the pool is staged, at most (`kernel_stages_pool`).
     rows, lanes, staged_heads = block * heads, -(-head_dim // 128) * 128, -(-kv_heads // 4) * 4
-    cols = kernel_run_pages(pages_per_slot, page_size, kv_heads, head_dim, itemsize) * page_size * staged_heads
-    vmem = (2 * slots * rows * lanes * itemsize  # queries and outputs
-            + 4 * cols * lanes * itemsize + 2 * cols * lanes * 4  # run buffers; a quantized run widened
-            + 2 * rows * cols * 4)  # an entry's scores and probabilities
+    run = kernel_run_pages(pages_per_slot, page_size, kv_heads, head_dim, itemsize, latent)
+    vmem = 2 * slots * rows * lanes * itemsize  # queries and outputs
+    if latent:
+        span = run * page_size
+        vmem += (3 * span * lanes * itemsize  # the one pool's two run buffers; a run as the products' operand
+                 + 2 * rows * span * 4)  # an entry's scores and probabilities
+    else:
+        cols = run * page_size * staged_heads
+        vmem += (4 * cols * lanes * itemsize + 2 * cols * lanes * 4  # run buffers; a quantized run widened
+                 + 2 * rows * cols * 4)  # an entry's scores and probabilities
     if vmem > _KERNEL_VMEM_BYTES:
         return (f"{slots} slots x {rows} query rows of {head_dim} need about {vmem} bytes of VMEM, "
                 f"and the kernel may take {_KERNEL_VMEM_BYTES}")
@@ -382,17 +439,20 @@ def slot_attention_impl(named: Optional[str], *, platform: str, latent: bool, tp
                         head_dim: int, itemsize: int, kv_cache_dtype: str) -> str:
     """Which paged read an engine takes, and the ONE place a named read is
     checked: `named` where the caller named one (`"xla"`, `"pallas_paged"`:
-    exactly that, or a `ValueError` that says what the kernel lacks — a latent
-    row, or on a TPU shapes the compiler would refuse, `kernel_refuses`), else
-    the engine's choice from what it can observe — the page-walk kernel where
-    the backend is a TPU, the cache is a K pool and a V pool of full heads
-    (`latent` rows have no kernel), bf16 or int8, that the kernel reads in
-    place (`kernel_stages_pool`) at shapes it can hold (`kernel_refuses`), and
-    the engine is on one device; the XLA read everywhere else. The shapes are
-    a dispatch's: `slots` rows of `block` query positions (a speculative
-    engine's verify block, else 1) x `heads` query heads over `kv_heads` (a
-    shard's, under `tp`), of `itemsize` bytes a value as the model computes
-    them; `kv_cache_dtype` is how the pool stores them.
+    exactly that, or a `ValueError` that says what the kernel lacks — a pool
+    of `latent` rows that is not whole tiles, which is never staged
+    (`kernel_refuses_rows`), or on a TPU shapes the compiler would refuse,
+    `kernel_refuses`), else the engine's choice from what it can observe — the
+    page-walk kernel where the backend is a TPU, the cache is a K pool and a V
+    pool of full heads, bf16 or int8, or ONE bf16 pool of `latent` rows
+    (`kv_heads` 1, `head_dim` the row), that the kernel reads in place
+    (`kernel_stages_pool`, `kernel_refuses_rows`) at shapes it can hold
+    (`kernel_refuses`), and the engine is on one device; the XLA read
+    everywhere else. The shapes are a dispatch's: `slots` rows of `block`
+    query positions (a speculative engine's verify block, else 1) x `heads`
+    query heads over `kv_heads` (a shard's, under `tp`), of `itemsize` bytes a
+    value as the model computes them; `kv_cache_dtype` is how the pool stores
+    them.
 
     The measurements (PERF.md §6, PR 37), ALL on a v5e — no other generation
     was timed, and the kernel's one invocation runs on one core of a chip
@@ -415,32 +475,33 @@ def slot_attention_impl(named: Optional[str], *, platform: str, latent: bool, tp
     six times its copies (the kernel itself 1,078 us where int8 takes 118). A
     STAGED pool: llama-1b's 32 over 8 heads of 64, 1,194 -> 1,755 and 1,443
     -> 1,906 all live (two padded pools written first), one KV head of bf16
-    157 -> 303. `tp > 1` stays on the XLA read until a four-chip cell times
-    `_tp_paged_attention`; CPU and GPU have no such kernel (the interpreter is
-    a test shim)."""
+    157 -> 303. ONE pool of latent rows (PERF.md §6, PR 39; kimi-vl-a3b's
+    layer, 128 slots x 128 pages of 16 rows of 640 bf16, 16 heads, values the
+    first 512 columns): 670 -> 251 at 4,700 live pages (the kernel itself 233
+    for bytes that take 117), 784 -> 284 at 6,000, 368 -> 176 at 1,500, 294 ->
+    133 with every slot idle, 1,732 -> 611 with every page live, a verify block
+    `s = 5` 1,259 -> 398 and 1,486 -> 437: read in place it wins at every
+    shape timed, and it is never staged. `tp > 1` stays on the XLA read until
+    a four-chip cell times `_tp_paged_attention`; CPU and GPU have no such
+    kernel (the interpreter is a test shim)."""
     if named is not None and named not in SLOT_ATTENTION_IMPLS:
         raise ValueError(
             f"unknown attention_impl {named!r}; expected one of {SLOT_ATTENTION_IMPLS} or None"
         )
     if named == "xla":
         return named
-    if latent:
-        if named is not None:
-            raise ValueError(
-                f"attention_impl={named!r} on a latent cache: the page-walk kernel reads a K pool "
-                "and a V pool of full heads — a page-walk kernel for latent rows is not built; "
-                "use attention_impl=\"xla\""
-            )
-        return "xla"
-    refused = platform == "tpu" and kernel_refuses(
-        slots, pages_per_slot, page_size, block, heads, kv_heads, head_dim, itemsize)
+    pool_itemsize = itemsize if kv_cache_dtype == "bf16" else 1  # "bf16": as the model computes
+    # Shapes the chip's compiler would refuse; a pool of latent rows that is not whole tiles, anywhere.
+    refused = (latent and kernel_refuses_rows(page_size, head_dim, pool_itemsize)) or (
+        platform == "tpu" and kernel_refuses(
+            slots, pages_per_slot, page_size, block, heads, kv_heads, head_dim, itemsize, latent))
     if named is not None:
         if refused:
             raise ValueError(f"attention_impl={named!r}: {refused}; use attention_impl=\"xla\"")
         return named
-    pool_itemsize = itemsize if kv_cache_dtype == "bf16" else 1  # "bf16": as the model computes
-    kernel = (platform == "tpu" and tp == 1 and not refused and kv_cache_dtype != "fp8_e4m3"
-              and not kernel_stages_pool(kv_heads, head_dim, pool_itemsize))
+    kernel = (platform == "tpu" and tp == 1 and not refused
+              and (kv_cache_dtype == "bf16" if latent  # no quantized pool of latent rows is built
+                   else kv_cache_dtype != "fp8_e4m3" and not kernel_stages_pool(kv_heads, head_dim, pool_itemsize)))
     return "pallas_paged" if kernel else "xla"
 
 
@@ -669,8 +730,8 @@ def slot_cache_attention(
         none over the rest of the window (`tests/test_tpu_compile.py`
         holds the compiled program to it). An idle slot must sit at
         position 0 to count as one page: the engine's `_finish` keeps it
-        there. The read of every backend and of a latent cache, and the
-        PARITY ORACLE the kernel is pinned against.
+        there. The read of every backend, and the PARITY ORACLE the kernel
+        is pinned against.
       - ``"pallas_paged"``: the pool write plus the `ops/paged_attention`
         kernel, which walks the same live entries with its own copies — a
         run of one slot's live pages at a time out of the pool in HBM, the
@@ -701,12 +762,15 @@ def slot_cache_attention(
     whole.
 
     A LATENT cache (`v=None`, `k` [B, s, row], `q` [B, s, Hq, row] already
-    in the row's space — MLA's absorbed form) keeps one pool of rows and is
-    read by the `"xla"` loop alone: a turn gathers ONE block, which is keys
-    and values both (`_live_page_attention`); `scale` and `value_dim` are the
-    family's softmax scale and the row's leading columns that are values.
-    `"pallas_paged"` and a quantized pool refuse it: the kernels and the
-    scale pools are written for a K pool and a V pool of full heads.
+    in the row's space — MLA's absorbed form) keeps one pool of rows, keys and
+    values both, read by either engine: a turn of the `"xla"` loop gathers
+    ONE block (`_live_page_attention`); the kernel copies a run of the one
+    pool once and takes scores and `probs . run[:, :value_dim]` from the same
+    copy, where the pool is whole tiles (`kernel_refuses_rows`: latent rows are
+    never staged, and the kernel refuses any other pool by name). `scale` and
+    `value_dim` are the family's softmax scale and the row's leading columns
+    that are values. A quantized pool refuses it: the scale pools are written
+    a page a KV head.
 
     Args:
         positions: [B, s] int32 — each token's absolute write/attend position.
@@ -722,19 +786,13 @@ def slot_cache_attention(
             f"unknown attention_impl {attention_impl!r}; expected one of {SLOT_ATTENTION_IMPLS}"
         )
     _check_slot_positions(positions, *k.shape[:2])
-    if v is None and attention_impl != "xla":
-        raise ValueError(
-            f"attention_impl={attention_impl!r} on a latent cache: the page-walk kernels "
-            "read a K pool and a V pool of full heads — a page-walk kernel for latent "
-            "rows is not built; use attention_impl=\"xla\""
-        )
     pool_k, pool_v, pos, table, scales = _write_slot_pool(
         module, k, v, positions, page_table, page_size, num_pages,
         kv_cache_dtype=kv_cache_dtype,
     )
+    latent = {} if v is not None else {"scale": scale, "value_dim": value_dim}
     if attention_impl == "xla":
         LAST_DISPATCH = "xla"
-        latent = {} if v is not None else {"scale": scale, "value_dim": value_dim}
         return _live_page_attention(q, pool_k, pool_v, pos, table, scales, **latent)
     from .paged_attention import paged_decode_attention, paged_verify_attention
 
@@ -745,7 +803,7 @@ def slot_cache_attention(
         return _tp_paged_attention(
             fn, q, pool_k, pool_v, table, pos, k_scale, v_scale, mesh
         )
-    return fn(q, pool_k, pool_v, table, pos, k_scale=k_scale, v_scale=v_scale)
+    return fn(q, pool_k, pool_v, table, pos, k_scale=k_scale, v_scale=v_scale, **latent)
 
 
 def _auto_sequence_parallel(batch: int, seq_len: int):

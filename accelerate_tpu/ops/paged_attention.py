@@ -69,6 +69,34 @@ scores (K) and to the probabilities (V) — the cache crosses HBM quantized.
 Token-identical to the XLA dequantize-on-read oracle
 (`tests/test_quantization.py`).
 
+LATENT pools (`v_pool=None`; `ops.attention.slot_cache_attention` with
+`v=None`, MLA's absorbed form): ONE pool `[num_pages, page_size, row]` with no
+head axis, keys and values both. The same walk with one pool and one pair of
+run buffers: a page `[page_size, row]` is copied out of the pool in place
+(whole tiles or not at all, `ops.attention.kernel_refuses_rows`: latent rows
+are never staged), the run in VMEM is already the flat matrix `[tokens, row]`
+and every query head reads every column, so `_flat_rows` and the head mask
+have nothing to do; scores are `q . run^T` at the family's own `scale` and the
+output `probs . run[:, :value_dim]` from the SAME copy, so each live row
+crosses HBM once a layer and the rope columns never reach the output. With 16
+query rows both products are bound by loading the run into the matrix unit —
+twice for one copy — so there the products, not the copies, set the pace, and
+a page is a quarter of a K/V page's bytes, so the scalar core's turn a page
+costs what the page's copy does. Hence the two things the latent arm does of
+its own (`_paged_kernel`: `latent_products`, `copies`): an entry's products
+run over the first pieces of its run that hold a live position
+(`ops.attention.kernel_piece_pages`: 256 tokens), as one pair of products at
+one of a few static widths; and a piece's pages are started back to back and
+waited for once.
+One layer on a v5e at kimi-vl-a3b's cell (128 slots x 128 pages of 16 rows of
+640 bf16, 16 heads; device us, XLA read -> kernel, of which the kernel itself):
+670 -> 251 (233) at 4,700 live pages, whose bytes take 117 at 819 GB/s; 784 ->
+284 (266) at 6,000; 294 -> 133 (115) with every slot idle; 1,732 -> 611 (593)
+with every page live; a verify block of 5, 1,259 -> 398 (312). The same walk
+with the products looped a piece at a time and every page waited for alone
+took 491 for the kernel at 4,700 and LOST to the XLA read in the cell (PERF.md
+§6, PR 39: what each of the two cost).
+
 Interpret mode (`interpret=None` auto-enables off-TPU) runs the same kernel
 on CPU for the tier-1 parity sweeps (`tests/test_paged_kernel.py`), the
 `ring_attention.py` testing pattern. All accumulation is fp32.
@@ -107,8 +135,8 @@ def _flat_rows(block, q):
 
 def _paged_kernel(
     n_ref, top_ref, tbl_ref, pos_ref,  # scalar prefetch (SMEM)
-    q_ref, cols_ref, rows_ref, k_hbm, v_hbm, *rest,
-    scale, page_size, run, pages_per_slot, block, quantized,
+    q_ref, cols_ref, rows_ref, k_hbm, *rest,
+    scale, page_size, run, pages_per_slot, block, quantized, piece,
 ):
     """The whole page walk of one layer: ONE loop over the live entries of
     all slots, slot-major, under the count the caller computed on the device
@@ -144,34 +172,107 @@ def _paged_kernel(
     `[1, tokens * Hkv]` rows (gathered by the caller for every (slot, run),
     copied beside the pages) and are applied to the scores and to the
     probabilities — the pages cross HBM at int8/fp8 width and are widened in
-    VMEM."""
+    VMEM.
+
+    A LATENT pool (`piece` > 0, the tokens in a piece of its run): the same
+    walk — entries, copies, prefetch, the running softmax and its finish —
+    over ONE pool and one pair of buffers, with an entry's arithmetic its own
+    (`latent_products`) and a piece's pages copied as a group (`copies`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if quantized:
-        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, acc, m_scr, l_scr = rest
+    latent = bool(piece)  # ONE pool of rows: no V pool, no head axis
+    if latent:
+        o_ref, k_buf, sems, acc, m_scr, l_scr = rest
+    elif quantized:
+        v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, acc, m_scr, l_scr = rest
     else:
-        o_ref, k_buf, v_buf, sems, acc, m_scr, l_scr = rest
+        v_hbm, o_ref, k_buf, v_buf, sems, acc, m_scr, l_scr = rest
     span, runs = run * page_size, -(-pages_per_slot // run)
 
     def copies(slot, r, buf, fn):
-        """`fn` (start or wait) on the DMAs of slot `slot`'s run `r` into buffer `buf`."""
+        """`fn` (`start` or `wait`) on the DMAs of slot `slot`'s run `r` into buffer `buf`."""
         live = jnp.minimum(top_ref[slot] // page_size + 1 - r * run, run)
 
         def page(j, _):
             rows = pl.ds(j * page_size, page_size)
             pid = tbl_ref[slot * pages_per_slot + r * run + j]
             fn(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, rows], sems.at[0, buf]))
-            fn(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, rows], sems.at[1, buf]))
+            if not latent:
+                fn(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, rows], sems.at[1, buf]))
 
-        jax.lax.fori_loop(0, live, page, None)
+        if latent:
+            # A page of latent rows is a quarter of a K/V page's bytes, and the
+            # scalar core's turn a page (the table entry, the descriptor, the
+            # loop) costs what its copy does: a piece's pages are started back
+            # to back, unrolled, and waited for ONCE — they signal one semaphore,
+            # which counts bytes, so one wait for the piece's bytes stands for
+            # its pages' (of the descriptor a wait reads only the size and the
+            # semaphore). What is left of a run's live pages goes page by page.
+            pages = piece // page_size
+
+            def piece_pages(g, _):
+                if fn is wait:
+                    whole = k_buf.at[buf, pl.ds(pl.multiple_of(g * piece, piece), piece)]
+                    wait(pltpu.make_async_copy(whole, whole, sems.at[0, buf]))
+                else:
+                    for j in range(pages):
+                        page(g * pages + j, None)
+
+            jax.lax.fori_loop(0, live // pages, piece_pages, None)
+            jax.lax.fori_loop(live // pages * pages, live, page, None)
+        else:
+            jax.lax.fori_loop(0, live, page, None)
         if quantized:
             fn(pltpu.make_async_copy(ks_hbm.at[slot * runs + r], ks_buf.at[buf], sems.at[0, buf]))
             fn(pltpu.make_async_copy(vs_hbm.at[slot * runs + r], vs_buf.at[buf], sems.at[1, buf]))
 
+    def start(dma):
+        dma.start()
+
+    def wait(dma):
+        dma.wait()
+
     k_buf[...] = jnp.zeros_like(k_buf)
-    v_buf[...] = jnp.zeros_like(v_buf)
-    copies(0, 0, 0, lambda dma: dma.start())
+    if not latent:
+        v_buf[...] = jnp.zeros_like(v_buf)
+    copies(0, 0, 0, start)
+
+    def attend_limit(slot):
+        """[s * Hq, 1]: the last position a row's query attends, its query position's off SMEM."""
+        limit = jnp.full((rows_ref.shape[0], 1), pos_ref[slot * block], jnp.int32)
+        for j in range(1, block):
+            limit = jnp.where(rows_ref[:, 0:1] == j, pos_ref[slot * block + j], limit)
+        return limit
+
+    def latent_products(slot, r, buf):
+        """An entry of a LATENT pool: the run in VMEM is already the flat
+        matrix `[tokens, row]` and every query head reads every column, so
+        there is no merge of rows and no head mask. Keys and values are the
+        SAME copy: scores over the whole row, then `probs . run[:, :values]`,
+        the row's leading columns (whole lane tiles, `acc`'s width: a free
+        slice in VMEM), so the rope columns never reach the output. The two
+        products run over the run's LIVE pieces alone — its first `n` pieces
+        of `piece` tokens, `n` from the slot's position — as ONE pair of
+        products at one of the `span // piece` static widths: what an entry
+        costs on a v5e is mostly the chain of its two products and the
+        softmax between them (~0.5 us whatever their width; a loop over the
+        pieces pays it a piece: 491 us a layer against 288, PERF.md §6, PR
+        39), and a slot's last run costs what is live of it."""
+        q = q_ref[slot]  # [s * Hq, row]
+        limit = attend_limit(slot) - r * span  # within the run
+        pieces = (jnp.minimum(top_ref[slot] - r * span, span - 1) + piece) // piece  # that hold a live position
+
+        for n in range(1, span // piece + 1):
+            @pl.when(pieces == n)
+            def _(width=n * piece):
+                live = k_buf[buf, :width, :]  # [width, row]
+                s = jax.lax.dot_general(
+                    q, live, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                ) * scale  # [s * Hq, width]
+                online_softmax_update(
+                    s, live[:, :acc.shape[1]], acc, m_scr, l_scr, valid=cols_ref[0:1, :width] <= limit
+                )
 
     def entry(e, walk):
         slot, r = walk
@@ -181,29 +282,29 @@ def _paged_kernel(
 
         @pl.when(e + 1 < n_ref[0])
         def _prefetch():
-            copies(*nxt, 1 - buf, lambda dma: dma.start())
+            copies(*nxt, 1 - buf, start)
 
         @pl.when(r == 0)
         def _init():
             init_softmax_state(acc, m_scr, l_scr)
 
-        copies(slot, r, buf, lambda dma: dma.wait())
-        q = q_ref[slot]  # [s * Hq, D]
-        k = _flat_rows(k_buf[buf], q)
-        v = _flat_rows(v_buf[buf], q)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [s * Hq, tokens * Hkv]
-        if quantized:
-            s = s * ks_buf[buf]
-        # A row's attend limit within the run: its query position's, off SMEM.
-        limit = jnp.full((rows_ref.shape[0], 1), pos_ref[slot * block], jnp.int32)
-        for j in range(1, block):
-            limit = jnp.where(rows_ref[:, 0:1] == j, pos_ref[slot * block + j], limit)
-        valid = (cols_ref[1:2, :] == rows_ref[:, 1:2]) & (cols_ref[0:1, :] <= limit - r * span)
-        online_softmax_update(
-            s, v, acc, m_scr, l_scr, valid=valid, v_scale=vs_buf[buf] if quantized else None
-        )
+        copies(slot, r, buf, wait)
+        if latent:
+            latent_products(slot, r, buf)
+        else:
+            q = q_ref[slot]  # [s * Hq, D]
+            k = _flat_rows(k_buf[buf], q)
+            v = _flat_rows(v_buf[buf], q)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # [s * Hq, tokens * Hkv]
+            if quantized:
+                s = s * ks_buf[buf]
+            limit = attend_limit(slot)
+            valid = (cols_ref[1:2, :] == rows_ref[:, 1:2]) & (cols_ref[0:1, :] <= limit - r * span)
+            online_softmax_update(
+                s, v, acc, m_scr, l_scr, valid=valid, v_scale=vs_buf[buf] if quantized else None
+            )
 
         @pl.when(jnp.logical_not(more))
         def _finish():
@@ -216,15 +317,20 @@ def _paged_kernel(
 
 
 def _paged_call(
-    q, k_pool, v_pool, page_table, positions, scale, interpret,
-    k_scale=None, v_scale=None,
+    q, k_pool, v_pool, page_table, positions, scale, interpret, run, piece,
+    k_scale=None, v_scale=None, value_dim=None,
 ):
     """Shared wrapper: what is live, the layout of queries and masks, the
-    `pallas_call`. `k_scale`/`v_scale` ([num_pages, Hkv] f32 traced operands,
-    never Python scalars — TPU117) switch the kernel into fused-dequant mode.
+    `pallas_call` (`scale` a Python float). `k_scale`/`v_scale` ([num_pages, Hkv] f32 traced operands,
+    never Python scalars — TPU117) switch the kernel into fused-dequant mode;
+    `v_pool=None` says `k_pool` is a LATENT pool, `[num_pages, page_size,
+    row]`, keys and values both (`value_dim` leading columns of a row the
+    values), which the kernel reads in place or not at all.
 
     What is live is `ops.attention.live_entry_counts` at the kernel's own run
-    (`kernel_run_pages`): the last position a slot attends and the entries
+    (`run` pages, the caller's `kernel_run_pages`, and for a latent pool the
+    `piece` of it in tokens: statics of the call, like every number its
+    program depends on): the last position a slot attends and the entries
     that makes, whose sum bounds the kernel's loop. It rides SMEM as
     scalar-prefetch operands beside the flattened page table and positions.
     Queries go in as `[B, s * Hq, D]` (a reshape: row `j * Hq + h` is query
@@ -234,10 +340,17 @@ def _paged_call(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from .attention import _KERNEL_VMEM_BYTES, kernel_run_pages, kernel_stages_pool, live_entry_counts
+    from .attention import (
+        _KERNEL_VMEM_BYTES,
+        kernel_refuses_rows,
+        kernel_stages_pool,
+        live_entry_counts,
+    )
 
     b, s, hq, head_dim = q.shape
-    n_pages_pool, page_size, hkv, _ = k_pool.shape
+    latent = v_pool is None  # [num_pages, page_size, row]: no head axis
+    n_pages_pool, page_size = k_pool.shape[:2]
+    hkv = 1 if latent else k_pool.shape[2]
     if hq % hkv:
         raise ValueError(f"GQA requires Hq % Hkv == 0, got {hq}, {hkv}")
     if (k_scale is None) != (v_scale is None):
@@ -250,10 +363,11 @@ def _paged_call(
                     f"per-page-per-head {name} must be [num_pages, Hkv] = "
                     f"{(n_pages_pool, hkv)}, got {sc.shape}"
                 )
-    # Query heads a KV head, and a run's pages: of the pool as it is, not as it is staged.
-    group, pages_per_slot = hq // hkv, page_table.shape[-1]
-    run = kernel_run_pages(pages_per_slot, page_size, hkv, head_dim, q.dtype.itemsize)
-    if kernel_stages_pool(hkv, head_dim, k_pool.dtype.itemsize):
+    refused = latent and kernel_refuses_rows(page_size, head_dim, k_pool.dtype.itemsize)
+    if refused:
+        raise ValueError(f"a latent pool: {refused}; use attention_impl=\"xla\"")
+    group, pages_per_slot = hq // hkv, page_table.shape[-1]  # query heads a KV head
+    if not latent and kernel_stages_pool(hkv, head_dim, k_pool.dtype.itemsize):
         # STAGED: zero lanes up to whole 128-lane rows (the queries' too, so the
         # products ignore them) and zero KV heads, which no query row reads, up
         # to whole packed sublanes. A copy of both pools, made for this call.
@@ -262,10 +376,12 @@ def _paged_call(
         k_pool, v_pool = (jnp.pad(pool, ((0, 0), (0, 0), (0, heads), (0, lanes))) for pool in (k_pool, v_pool))
         if quantized:
             k_scale, v_scale = (jnp.pad(sc, ((0, 0), (0, heads))) for sc in (k_scale, v_scale))
-    hkv, d = k_pool.shape[2:]
+    hkv, d = (1, k_pool.shape[-1]) if latent else k_pool.shape[2:]
     rows = s * hq
     runs = -(-pages_per_slot // run)  # entries a full slot makes
     span = run * page_size
+    # What the kernel writes a row: `d` lanes, or a latent row's values in whole lane tiles.
+    out_d = d if value_dim is None else min(d, -(-value_dim // LANE) * LANE)
 
     table = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, n_pages_pool - 1)
     pos = jnp.asarray(positions, jnp.int32).reshape(b, s)
@@ -275,16 +391,14 @@ def _paged_call(
     row_maps = np.stack([row // hq, row % hq // group], axis=1)  # [s * Hq, 2]: query position, KV head
 
     kernel = functools.partial(
-        _paged_kernel, scale=float(scale), page_size=page_size, run=run,
-        pages_per_slot=pages_per_slot, block=s, quantized=quantized,
+        _paged_kernel, scale=scale, page_size=page_size, run=run,
+        pages_per_slot=pages_per_slot, block=s, quantized=quantized, piece=piece,
     )
     vmem, hbm = pl.BlockSpec(memory_space=pltpu.VMEM), pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [vmem, vmem, vmem, hbm, hbm]
-    operands = [q.reshape(b, rows, d), jnp.asarray(cols), jnp.asarray(row_maps), k_pool, v_pool]
-    scratch = [
-        pltpu.VMEM((2, span, hkv, d), k_pool.dtype),
-        pltpu.VMEM((2, span, hkv, d), v_pool.dtype),
-    ]
+    pools = [k_pool] if latent else [k_pool, v_pool]
+    in_specs = [vmem, vmem, vmem] + [hbm] * len(pools)
+    operands = [q.reshape(b, rows, d), jnp.asarray(cols), jnp.asarray(row_maps), *pools]
+    scratch = [pltpu.VMEM((2, span) + pool.shape[2:], pool.dtype) for pool in pools]  # two run buffers a pool
     if quantized:
         # [B * runs, 1, span * Hkv]: every (slot, run)'s scales a (token, head)
         # column; a table row is padded to whole runs with the scratch page.
@@ -299,7 +413,7 @@ def _paged_call(
         scratch += [pltpu.VMEM((2, 1, span * hkv), jnp.float32)] * 2
     scratch += [
         pltpu.SemaphoreType.DMA((2, 2)),
-        pltpu.VMEM((rows, d), jnp.float32),
+        pltpu.VMEM((rows, out_d), jnp.float32),
         pltpu.VMEM((rows, LANE), jnp.float32),
         pltpu.VMEM((rows, LANE), jnp.float32),
     ]
@@ -309,13 +423,24 @@ def _paged_call(
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, out_d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_KERNEL_VMEM_BYTES),
         name="paged_attention",
     )(jnp.sum(count).reshape(1), top, table.reshape(-1), pos.reshape(-1), *operands)
-    return out.reshape(b, s, hq, d)[..., :head_dim]
+    return out.reshape(b, s, hq, out_d)[..., :head_dim if value_dim is None else value_dim]
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_call():
+    """A LATENT pool's call, traced and lowered ONCE for all the layers of a
+    program that share its shapes: the latent arm's body is several times the
+    K/V arm's to trace (its products at every static width, a piece's copies
+    unrolled), and a call a layer cost the kimi cell's set-up 7.8 s of 56
+    (PERF.md §6, PR 39). The K/V arm is traced a call a layer, as its
+    programs were."""
+    return jax.jit(_paged_call, static_argnums=(5, 6, 7, 8), static_argnames=("value_dim",))
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
@@ -326,7 +451,7 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
 
 def paged_decode_attention(
     q, k_pool, v_pool, page_table, positions, *, scale=None, interpret=None,
-    k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None, value_dim=None,
 ):
     """Single-query paged decode attention over a pool-resident KV cache.
 
@@ -352,13 +477,13 @@ def paged_decode_attention(
         raise ValueError(f"paged_decode_attention takes [B, 1, Hq, D] queries, got {q.shape}")
     return paged_verify_attention(
         q, k_pool, v_pool, page_table, positions, scale=scale, interpret=interpret,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, value_dim=value_dim,
     )
 
 
 def paged_verify_attention(
     q, k_pool, v_pool, page_table, positions, *, scale=None, interpret=None,
-    k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None, value_dim=None,
 ):
     """Block-verify paged attention: the [B, s] multi-token variant used by
     speculative decoding's verify step (s = draft_tokens + 1).
@@ -371,15 +496,26 @@ def paged_verify_attention(
             ``cols <= positions[i, j]`` (its accepted prefix plus the block
             tokens at or before it, all written by this same dispatch).
         k_scale / v_scale: as `paged_decode_attention` (quantized pools).
+        value_dim: a LATENT pool's (`v_pool=None`, `k_pool` [num_pages,
+            page_size, row], `q` [B, s, Hq, row] in the row's space): the
+            leading columns of a row that are its values. `scale` is then the
+            family's own, not 1/sqrt(row).
 
-    Returns [B, s, Hq, D].
+    Returns [B, s, Hq, D], or [B, s, Hq, value_dim].
     """
     if q.ndim != 4:
         raise ValueError(f"paged_verify_attention takes [B, s, Hq, D] queries, got {q.shape}")
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
 
-    return _paged_call(
-        q, k_pool, v_pool, page_table, positions, scale, _auto_interpret(interpret),
-        k_scale=k_scale, v_scale=v_scale,
+    from .attention import kernel_piece_pages, kernel_run_pages
+
+    # A run's pages, of the pool as it is, not as it is staged; the tokens in a piece of a latent run.
+    latent, page_size = v_pool is None, k_pool.shape[1]
+    run = kernel_run_pages(page_table.shape[-1], page_size, 1 if latent else k_pool.shape[2], q.shape[-1],
+                           q.dtype.itemsize, latent=latent)
+    piece = min(run, kernel_piece_pages(page_size)) * page_size if latent else 0
+    return (_latent_call() if latent else _paged_call)(
+        q, k_pool, v_pool, page_table, positions, float(scale), _auto_interpret(interpret), run, piece,
+        k_scale=k_scale, v_scale=v_scale, value_dim=value_dim,
     )

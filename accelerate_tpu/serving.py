@@ -364,9 +364,11 @@ class ContinuousBatcher:
                 f"unknown kv_cache_dtype {kv_cache_dtype!r}; expected one of {KV_CACHE_DTYPES}"
             )
         # A latent cache (MLA: one `[c | k_pe]` row a token a layer, which the
-        # config says by `decode_kv_row_values`) is read by the XLA loop on one
-        # device, unquantized. The other combinations name what is missing
-        # (a named page-walk kernel: `ops.attention.slot_attention_impl`, below).
+        # config says by `decode_kv_row_values`) is read on one device,
+        # unquantized — by the page-walk kernel where it reads the pool of rows
+        # in place, else by the XLA loop (`ops.attention.slot_attention_impl`,
+        # below: latent rows are never staged). The other combinations name
+        # what is missing.
         latent_row = getattr(base, "decode_kv_row_values", None)
         if latent_row is not None:
             family = type(model.module).__name__
@@ -652,7 +654,7 @@ class ContinuousBatcher:
         )
         self._read_shape = (self.pages_per_slot, self.page_size, kv_heads, key.shape[-1],
                             np.dtype(key.dtype).itemsize, base.num_attention_heads // kv_heads,
-                            self.attention_impl)
+                            self.attention_impl, latent_row is not None)
         step_cfg = dataclasses.replace(
             base, decode_cache_length=cache_len, decode_slot_cache=True,
             decode_page_size=self.page_size, decode_num_pages=self.num_pages,

@@ -20,7 +20,8 @@ from accelerate_tpu.telemetry.tracing import Tracer  # noqa: E402
 #: pythia-1.4b's saturated cell: 32 slots x 88 pages of 16, 16 heads of 128, bf16 pools.
 FULL_HEADS = dict(latent=False, tp=1, slots=32, pages_per_slot=88, page_size=16, block=1, heads=16,
                   kv_heads=16, head_dim=128, itemsize=2, kv_cache_dtype="bf16")
-LATENT_ROW = dict(latent=True, heads=16, kv_heads=1, head_dim=576)
+#: kimi-vl-a3b's saturated cell: 128 slots x 128 pages of 16 latent rows of 640 values (576 in whole lane tiles), 16 heads.
+LATENT_ROW = dict(latent=True, slots=128, pages_per_slot=128, heads=16, kv_heads=1, head_dim=640)
 
 
 @pytest.mark.parametrize(
@@ -36,8 +37,18 @@ LATENT_ROW = dict(latent=True, heads=16, kv_heads=1, head_dim=576)
         (None, dict(platform="tpu", block=5), "pallas_paged"),  # a speculative engine's verify block
         (None, dict(platform="cpu"), "xla"),
         (None, dict(platform="gpu"), "xla"),
-        (None, dict(platform="tpu", **LATENT_ROW), "xla"),
         (None, dict(platform="tpu", tp=4, heads=4, kv_heads=4), "xla"),
+        # ONE pool of latent rows (MLA): the kernel where it reads the pool in place (timed: PERF.md section 6, PR 39)
+        (None, dict(platform="tpu", **LATENT_ROW), "pallas_paged"),
+        (None, dict(platform="tpu", **LATENT_ROW, block=5), "pallas_paged"),
+        (None, dict(platform="cpu", **LATENT_ROW), "xla"),
+        (None, dict(platform="tpu", **LATENT_ROW, tp=4), "xla"),
+        # latent rows are never staged: a row that is not whole lanes, pages that are not whole packed sublanes
+        (None, dict(platform="tpu", **{**LATENT_ROW, "head_dim": 576}), "xla"),
+        (None, dict(platform="tpu", **LATENT_ROW, page_size=8), "xla"),
+        (None, dict(platform="tpu", **LATENT_ROW, kv_cache_dtype="int8"), "xla"),  # no quantized latent pool is built
+        (None, dict(platform="tpu", **{**LATENT_ROW, "pages_per_slot": 2048}), "xla"),  # SMEM
+        (None, dict(platform="tpu", **{**LATENT_ROW, "slots": 4096, "pages_per_slot": 32}, block=5), "xla"),  # VMEM
         # a pool the kernel would have to stage stays on the XLA read
         (None, dict(platform="tpu", heads=32, kv_heads=8, head_dim=64), "xla"),  # half a lane row
         (None, dict(platform="tpu", heads=8, kv_heads=1), "xla"),  # one head of bf16: half a packed sublane
@@ -51,6 +62,9 @@ LATENT_ROW = dict(latent=True, heads=16, kv_heads=1, head_dim=576)
         ("xla", dict(platform="tpu"), "xla"),
         ("xla", dict(platform="tpu", slots=128, pages_per_slot=2048), "xla"),
         ("xla", dict(platform="cpu", **LATENT_ROW), "xla"),
+        ("xla", dict(platform="tpu", **{**LATENT_ROW, "head_dim": 576}), "xla"),
+        ("pallas_paged", dict(platform="tpu", **LATENT_ROW), "pallas_paged"),
+        ("pallas_paged", dict(platform="cpu", **LATENT_ROW, page_size=8, itemsize=4), "pallas_paged"),  # fp32: 8 rows a tile
         ("pallas_paged", dict(platform="cpu"), "pallas_paged"),
         ("pallas_paged", dict(platform="tpu", tp=4, heads=4, kv_heads=4), "pallas_paged"),
         ("pallas_paged", dict(platform="tpu", heads=32, kv_heads=8, head_dim=64), "pallas_paged"),  # staged
@@ -66,7 +80,11 @@ def test_the_engines_choice_of_read_is_a_table(named, observed, want):
     "named,observed,says",
     [
         ("mosaic", dict(platform="tpu"), "attention_impl 'mosaic'"),
-        ("pallas_paged", dict(platform="cpu", **LATENT_ROW), "page-walk kernel for latent rows is not built"),
+        ("pallas_paged", dict(platform="cpu", **{**LATENT_ROW, "head_dim": 576}), "latent rows are never staged"),
+        ("pallas_paged", dict(platform="tpu", **LATENT_ROW, page_size=8), "pages of 8 rows of 640 values.*never staged"),
+        ("pallas_paged", dict(platform="tpu", **{**LATENT_ROW, "pages_per_slot": 2048}), "bytes of SMEM"),
+        ("pallas_paged", dict(platform="tpu", **{**LATENT_ROW, "slots": 4096, "pages_per_slot": 32}, block=5),
+         "bytes of VMEM"),
         ("pallas_paged", dict(platform="tpu", slots=128, pages_per_slot=2048), "bytes of SMEM"),
         ("pallas_paged", dict(platform="tpu", slots=1024, block=5, heads=32, kv_heads=32), "bytes of VMEM"),
     ],

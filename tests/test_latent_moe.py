@@ -11,7 +11,7 @@ decompressed attention, every expert on every token, no cache):
   (c) `ContinuousBatcher` end to end on mixed admissions: decode compiled
       once, tokens equal a dense per-request decode, counters on the spans;
   (d) the three combinations a latent cache refuses, each naming what is
-      missing.
+      missing, and the page-walk kernel serving the XLA engine's tokens.
 """
 
 import dataclasses
@@ -281,13 +281,49 @@ def test_int8_weights_reach_the_absorbed_projection_and_the_expert_stacks(model)
 
 # ------------------------------------------------------------ (d) the refusals
 @pytest.mark.parametrize("argument,names", [
-    ({"attention_impl": "pallas_paged"}, "page-walk kernel for latent rows is not built"),
+    # the tiny model computes in float32: a page of 4 rows is half a tile, which the kernel cannot cut out of the pool
+    ({"attention_impl": "pallas_paged", "page_size": 4}, "pages of 4 rows of 128 values.*latent rows are never staged"),
     ({"kv_cache_dtype": "int8"}, "quantized pool for latent rows is not built"),
     ({"tp": 2}, "layout is not built"),
 ])
 def test_a_latent_cache_refuses_what_is_not_built_and_names_it(model, argument, names):
     with pytest.raises(ValueError, match=names):
-        ContinuousBatcher(model, num_slots=2, max_length=32, page_size=PAGE, **argument)
+        ContinuousBatcher(model, **{"num_slots": 2, "max_length": 32, "page_size": PAGE, **argument})
+
+
+@pytest.mark.parametrize("speculative", [False, True], ids=["plain", "speculative"])
+def test_the_page_walk_kernel_serves_the_xla_engines_tokens(model, monkeypatch, speculative):
+    """A named `"pallas_paged"` on a latent cache the kernel reads in place
+    (interpreted here): the tokens of the `"xla"` engine, request for request,
+    plain and with verify blocks of 4 rows a slot, one decode program, the
+    chunk span saying which read it holds and counting the kernel's entries —
+    a slot's 12 pages walked in runs of 4, the products in pieces of 2."""
+    from accelerate_tpu.ops import attention
+    from accelerate_tpu.telemetry import FlightRecorder, Tracer
+
+    monkeypatch.setattr(attention, "_KERNEL_RUN_BYTES", 2 * PAGE * 128 * 4)  # a latent run is a K run and a V run
+    monkeypatch.setattr(attention, "_KERNEL_PIECE_TOKENS", 2 * PAGE)
+    rng = np.random.default_rng(5)
+    motif = rng.integers(1, 512, 6)
+    prompts = [np.tile(motif, 6)[: int(n)] for n in (9, 30, 21)] + [rng.integers(1, 512, n) for n in (5, 44, 17)]
+    mode = dict(speculative=True, draft_tokens=3) if speculative else {}
+    tokens = {}
+    for impl in ("xla", "pallas_paged"):
+        recorder = FlightRecorder()
+        engine = ContinuousBatcher(model, num_slots=3, max_length=96, chunk_size=4, page_size=PAGE,
+                                   attention_impl=impl, tracer=Tracer(recorder=recorder), **mode)
+        assert engine.attention_impl == impl and engine.stats["attention_impl"] == impl
+        out = engine.run([Request(i, p, max_new_tokens=14) for i, p in enumerate(prompts)])
+        tokens[impl] = {rid: list(map(int, toks)) for rid, toks in out.items()}
+        assert engine.trace_counts["decode_chunk"] == 1
+        chunks = [r["attrs"] for r in recorder.records() if r["name"] == "serve.decode_chunk"]
+        assert chunks and all(c["read_impl"] == impl for c in chunks)
+        if speculative:
+            assert engine.stats["speculative"]["verify_steps"] > 0
+    assert tokens["pallas_paged"] == tokens["xla"]
+    # three slots of 1..3 entries (runs of 4 pages of a window of 12): the kernel's own count
+    assert all(3 <= c["read_blocks"] <= 9 for c in chunks) and max(c["read_blocks"] for c in chunks) > 3
+    assert attention.read_blocks(np.asarray([0, 31, 32, 95]), *engine._read_shape) == 1 + 1 + 2 + 3
 
 
 def test_the_read_itself_refuses_a_latent_pool_it_cannot_serve():
@@ -307,12 +343,14 @@ def test_the_read_itself_refuses_a_latent_pool_it_cannot_serve():
 
     q, row = jnp.zeros((2, 1, 4, 128)), jnp.zeros((2, 1, 128))
     positions, table = jnp.zeros((2, 1), jnp.int32), jnp.zeros((2, 4), jnp.int32)
-    with pytest.raises(ValueError, match="page-walk kernel for latent rows"):
-        Layer(impl="pallas_paged").init(jax.random.key(0), q, row, positions, table)
+    # a row of 96 values is not whole lanes: the kernel would have to stage the pool, and never does
+    with pytest.raises(ValueError, match="rows of 96 values.*latent rows are never staged"):
+        Layer(impl="pallas_paged").init(jax.random.key(0), q[..., :96], row[..., :96], positions, table)
     with pytest.raises(ValueError, match="quantized pool for latent rows"):
         Layer(pool="int8").init(jax.random.key(0), q, row, positions, table)
-    out = Layer().init_with_output(jax.random.key(0), q, row, positions, table)[0]
-    assert out.shape == (2, 1, 4, 32)
+    for impl in ("xla", "pallas_paged"):  # a pool of whole tiles is served by both reads
+        out = Layer(impl=impl).init_with_output(jax.random.key(0), q, row, positions, table)[0]
+        assert out.shape == (2, 1, 4, 32)
 
 
 def test_registry_names_the_family():

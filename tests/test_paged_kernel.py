@@ -312,6 +312,115 @@ def test_walk_reads_a_pool_in_place_or_staged(monkeypatch, s, pool, hkv, d, stag
     assert (pool_k.shape in padded) == staged
 
 
+# ------------------------------------------------------------- a latent pool
+# ONE pool of rows, no head axis, keys and values both (`v_pool=None`; MLA's
+# absorbed form): the same walk over 5 slots x 6 pages (of 8 rows: a tile of
+# float32, which the kernel copies in place), a run of 2 pages in pieces of
+# one (the products run over one piece or two, whichever hold a live position;
+# a piece's pages are copied as a group), against `_live_page_attention` on
+# the same operands. A row is 128 values of which the first 96 are its
+# values, and the softmax scale is the family's own, not 1 / sqrt(row).
+LATENT_ROW, LATENT_VALUES, LATENT_SCALE = 128, 96, 0.05
+
+
+def _latent_reads(monkeypatch, walk, s, dtype, page_size=8, row=LATENT_ROW, value_dim=LATENT_VALUES,
+                  run=WALK_RUN, piece=1, hq=4):
+    from accelerate_tpu.ops import attention
+
+    rng = np.random.default_rng(17)
+    top = np.asarray(_WALKS[walk], np.int32) * page_size // WALK_PAGE_SIZE  # the same pages live at any page size
+    slots, num_pages = len(top), len(top) * WALK_PAGES + 1
+    pool = jnp.asarray(rng.normal(size=(num_pages, page_size, row)).astype(np.float32), dtype)
+    table = rng.permutation(np.arange(1, num_pages)).reshape(slots, WALK_PAGES).astype(np.int32)
+    if walk == "shared_prefix_pages":
+        table[1, :3] = table[0, :3]
+        table[2, :2] = table[0, :2]
+    pos = jnp.asarray(np.maximum(top - (s - 1), 0)[:, None] + np.arange(s)[None, :], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(slots, s, hq, row)).astype(np.float32), dtype)
+    page_bytes = page_size * row * q.dtype.itemsize
+    monkeypatch.setattr(attention, "_KERNEL_RUN_BYTES", run * page_bytes // 2)  # a latent run is a K run and a V run
+    monkeypatch.setattr(attention, "_KERNEL_PIECE_TOKENS", piece * page_size)
+    monkeypatch.setattr(attention, "_READ_BLOCK_BYTES", 3 * page_bytes)
+    assert attention.kernel_run_pages(WALK_PAGES, page_size, 1, row, q.dtype.itemsize, latent=True) == run
+    table = jnp.asarray(table)
+    got = paged_verify_attention(q, pool, None, table, pos, scale=LATENT_SCALE, value_dim=value_dim)
+    want = attention._live_page_attention(q, pool, None, pos, table, None, scale=LATENT_SCALE, value_dim=value_dim)
+    assert got.shape == want.shape == (slots, s, hq, row if value_dim is None else value_dim)
+    return np.asarray(got.astype(jnp.float32)), np.asarray(want.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("walk", list(_WALKS))
+def test_latent_walk_matches_the_xla_read(monkeypatch, walk, s):
+    """Every shape of live list again, over a pool of latent rows: ragged
+    positions, idle slots, every page live, a last run of one page, shared
+    prefix pages, lists that end on either buffer — the XLA read's output,
+    the rope columns (96..127) scored and never summed into it."""
+    got, want = _latent_reads(monkeypatch, walk, s, jnp.float32)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "dtype,page_size,row,value_dim,run,piece",
+    [
+        ("f32", 8, 128, 128, 2, 1),  # every column a value
+        ("f32", 8, 256, 128, 2, 2),  # the values whole lane tiles of a wider row (kimi's 512 of 640); a piece the run
+        ("f32", 8, 256, None, 3, 1),  # no `value_dim`: the whole row comes back
+        ("f32", 8, 128, 40, 4, 2),  # runs of two pieces; a last run shorter than a piece's pages
+        ("f32", 8, 128, 40, 6, 4),  # the window one run of a piece and a half: rounded down to one piece
+        ("bf16", 16, 256, 128, 2, 1),  # bf16 rows in pages of whole packed sublanes: bf16 tolerance
+        ("bf16", 16, 128, 96, 4, 2),
+    ],
+)
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify5"])
+def test_latent_walk_by_row_values_run_and_piece(monkeypatch, s, dtype, page_size, row, value_dim, run, piece):
+    """The ragged walk over latent pools by what the kernel's program depends
+    on: the row's width against its values', the pages a run and a piece of it
+    hold (`kernel_run_pages` makes a run whole pieces), the pool's dtype."""
+    from accelerate_tpu.ops import attention
+
+    kept = run // piece * piece  # what `kernel_run_pages` keeps of a run of more than a piece
+    got, want = _latent_reads(monkeypatch, "live_pages_end_inside_a_run", s, jnp.bfloat16 if dtype == "bf16" else jnp.float32,
+                              page_size=page_size, row=row, value_dim=value_dim, run=kept, piece=piece)
+    monkeypatch.setattr(attention, "_KERNEL_RUN_BYTES", run * page_size * row * (2 if dtype == "bf16" else 4) // 2)
+    assert attention.kernel_run_pages(WALK_PAGES, page_size, 1, row, 2 if dtype == "bf16" else 4, latent=True) == kept
+    np.testing.assert_allclose(got, want, **(dict(atol=3e-2, rtol=3e-2) if dtype == "bf16" else dict(atol=2e-5)))
+
+
+def test_latent_scratch_page_rows_contribute_zero(monkeypatch):
+    """Poison the scratch page and every page no table row names: the latent
+    read does not move — pages past a slot's live ones are never copied, and
+    rows of a live page past the slot's position are masked."""
+    from accelerate_tpu.ops import attention
+
+    rng = np.random.default_rng(23)
+    pool = rng.normal(size=(9, 8, 128)).astype(np.float32)
+    table = jnp.asarray([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
+    pos = jnp.asarray([[11], [2]], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(2, 1, 4, 128)).astype(np.float32))
+    monkeypatch.setattr(attention, "_KERNEL_RUN_BYTES", 8 * 128 * 4)  # runs of 2 pages
+
+    def read(pool):
+        return np.asarray(paged_decode_attention(q, jnp.asarray(pool), None, table, pos, scale=0.2, value_dim=64))
+
+    clean = read(pool)
+    poisoned = pool.copy()
+    poisoned[[0, 4, 5, 6, 7, 8]] = 1e4
+    poisoned[2, 4:] = 1e4  # slot 0 attends rows 0..3 of its second page
+    poisoned[3, 3:] = 1e4
+    np.testing.assert_array_equal(clean, read(poisoned))
+
+
+def test_latent_pool_the_kernel_cannot_read_in_place_is_refused_not_staged():
+    from accelerate_tpu.ops import attention
+
+    assert attention.kernel_refuses_rows(16, 640, 2) is None and attention.kernel_refuses_rows(8, 128, 4) is None
+    assert attention.kernel_refuses_rows(16, 576, 2) and attention.kernel_refuses_rows(8, 640, 2)
+    q, table, pos = jnp.zeros((2, 1, 4, 96)), jnp.zeros((2, 3), jnp.int32), jnp.zeros((2, 1), jnp.int32)
+    with pytest.raises(ValueError, match="rows of 96 values.*never staged"):
+        paged_decode_attention(q, jnp.zeros((7, 8, 96)), None, table, pos, scale=0.2, value_dim=32)
+
+
 @pytest.mark.parametrize("run_pages", [2, 3])
 def test_engine_greedy_token_parity_in_runs_of_pages(monkeypatch, run_pages):
     """Greedy decode through `ContinuousBatcher` with a slot's 8 pages walked

@@ -362,6 +362,76 @@ def test_latent_read_gathers_one_block_a_turn(v5e, s_block):
     assert compiled.memory_analysis().temp_size_in_bytes <= 4 * block_pages * CELL_PAGE_SIZE * LATENT_ROW * 2
 
 
+def _latent_paged(v5e, slots, pages_per_slot, s_block, page_size=CELL_PAGE_SIZE, row=LATENT_ROW):
+    """The page-walk kernel over ONE pool of latent rows, lowered for the
+    described chip: (lowered, why `kernel_refuses` would refuse the shape)."""
+    from accelerate_tpu.ops.attention import kernel_refuses
+    from accelerate_tpu.ops.paged_attention import paged_verify_attention
+
+    def read(q, pool, table, pos):
+        return paged_verify_attention(q, pool, None, table, pos, scale=1.0 / math.sqrt(192),
+                                      value_dim=LATENT_VALUES, interpret=False)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    lowered = jax.jit(read).lower(
+        spec((slots, s_block, CELL_HEADS, row), jnp.bfloat16),
+        spec((slots * pages_per_slot + 1, page_size, row), jnp.bfloat16),
+        spec((slots, pages_per_slot), jnp.int32), spec((slots, s_block), jnp.int32))
+    return lowered, kernel_refuses(slots, pages_per_slot, page_size, s_block, CELL_HEADS, 1, row, 2, latent=True)
+
+
+@pytest.mark.parametrize("s_block", [1, 5], ids=["decode", "verify5"])
+def test_latent_paged_attention_compiles_for_v5e(v5e, s_block):
+    """The kimi cell's read as the engine chooses it on a TPU since PR 39: the
+    page-walk kernel over the one pool of latent rows, 128 slots x 128 pages of
+    [16, 640] bf16 (five whole tiles a page, cut out of the pool in place),
+    runs of 96 pages (2 MiB: a K run and a V run) in pieces of 256 tokens, the
+    values a row's first 512 columns. One Mosaic kernel and NO copy of the pool on the way in: the
+    program's temporaries are a few KB where the XLA read keeps an 8 MB block."""
+    from accelerate_tpu.ops import attention
+
+    assert attention.kernel_run_pages(LATENT_PAGES_PER_SLOT, CELL_PAGE_SIZE, 1, LATENT_ROW, 2, latent=True) == 96
+    assert attention.kernel_refuses_rows(CELL_PAGE_SIZE, LATENT_ROW, 2) is None
+    lowered, refused = _latent_paged(v5e, LATENT_SLOTS, LATENT_PAGES_PER_SLOT, s_block)
+    assert refused is None
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1 and " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize(
+    "slots,pages_per_slot,s_block,fits",
+    [
+        (112, 2048, 1, True),  # 896 KiB of page tables in SMEM
+        (128, 2048, 1, False),  # 1 MiB: the compiler refuses the operand
+        (280, 128, 5, True),  # 22,400 query rows of 640 and their outputs, ONE pool's two run buffers: ~60 MB of VMEM
+        (400, 128, 5, False),  # queries and outputs alone are 74 MB
+    ],
+    ids=["smem_fits", "smem_over", "vmem_fits", "vmem_over"],
+)
+def test_latent_paged_attention_refusal_is_the_compilers(v5e, slots, pages_per_slot, s_block, fits):
+    """`kernel_refuses(..., latent=True)` — one pool's buffers, nothing staged
+    or widened — against the chip's compiler on either side of each edge."""
+    lowered, refused = _latent_paged(v5e, slots, pages_per_slot, s_block)
+    assert (refused is None) == fits, refused
+    if fits:
+        lowered.compile()
+    else:
+        with pytest.raises(Exception, match="smem|vmem"):
+            lowered.compile()
+
+
+@pytest.mark.parametrize("page_size,row", [(8, 640), (16, 576)], ids=["half_a_tile_of_rows", "row_of_576"])
+def test_latent_pool_not_of_whole_tiles_is_refused_before_the_compiler(v5e, page_size, row):
+    """What the rule keeps on the XLA read and a named kernel is refused for:
+    the read itself raises by name, and never pads the pool."""
+    with pytest.raises(ValueError, match="latent rows are never staged"):
+        _latent_paged(v5e, 8, 32, 1, page_size=page_size, row=row)
+
+
 @pytest.mark.parametrize("rows", [768, 192, 6144], ids=["decode128x6", "insert32x6", "insert1024x6"])
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)], ids=["gate_up", "down"])
 def test_grouped_expert_matmul_compiles_for_v5e(v5e, rows, k, n):
