@@ -33,7 +33,9 @@ from .latent_moe import (
     LatentMoEForCausalLM,
     create_latent_moe_model,
     kimi_vl_a3b_text,
+    latent_moe_hc_tiny,
     latent_moe_tiny,
+    xing4_29b_a4b,
 )
 from .falcon_h1 import (
     FalconH1Config,
@@ -77,6 +79,8 @@ MODEL_REGISTRY = {
     "gpt-neox-tiny": ("gpt_neox", gpt_neox_tiny),
     "kimi-vl-a3b-text": ("latent_moe", kimi_vl_a3b_text),
     "latent-moe-tiny": ("latent_moe", latent_moe_tiny),
+    "xing4-29b-a4b": ("latent_moe", xing4_29b_a4b),
+    "latent-moe-hc-tiny": ("latent_moe", latent_moe_hc_tiny),
     "falcon-h1-34b": ("falcon_h1", falcon_h1_34b),
     "falcon-h1-tiny": ("falcon_h1", falcon_h1_tiny),
     "olmo-hybrid-7b": ("olmo_hybrid", olmo_hybrid_7b),
@@ -87,6 +91,12 @@ MODEL_REGISTRY = {
     "t5-tiny": ("t5", t5_tiny),
     "t5-small": ("t5", t5_small_v1_0),
     "t5-tiny-v1-0": ("t5", t5_tiny_v1_0),
+}
+
+# A published `config.json`'s `model_type` -> the in-tree family that runs its
+# language model, where the two names differ.
+PUBLISHED_MODEL_TYPES = {
+    "xing4_0": "latent_moe",
 }
 
 # family -> Model-bundle creator (the `create_*` entry points above).
@@ -270,6 +280,9 @@ def _latent_moe_cfg(c: LatentMoEConfig) -> dict:
         "qk_nope_head_dim": c.qk_nope_head_dim,
         "qk_rope_head_dim": c.qk_rope_head_dim,
         "v_head_dim": c.v_head_dim,
+        "q_lora_rank": c.q_lora_rank,
+        "rope_scaling": c.rope_scaling,
+        "hc_mult": c.hc_mult,
         "hidden_act": "silu",
         "tie_word_embeddings": False,
     }

@@ -104,10 +104,15 @@ class LlamaConfig:
         return self.hidden_size // self.num_attention_heads
 
 
-def rotary_embedding(x, positions, theta: float):
-    """Apply RoPE to [B, S, H, D] given [B, S] positions."""
+def rotary_embedding(x, positions, theta: float, inv_freq=None):
+    """Apply RoPE to [B, S, H, D] given [B, S] positions. `inv_freq` [D/2]
+    gives the pairs' frequencies where they are not `theta`'s own (a scaled
+    RoPE such as YaRN: `models/latent_moe.yarn_inv_freq`)."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]

@@ -696,6 +696,10 @@ class ContinuousBatcher:
         self.kv_row_values //= layers  # a layer's: 2 x KV heads x head_dim, or the latent row
         counts = _expert_token_counts(self._cache)
         self._expert_layers = 0 if counts is None else int(counts.shape[0])
+        # A family whose residual path is several streams says so by `hc_mult`,
+        # and how many mixes a token passes by `hc_sublayers`.
+        self._hc_streams = int(getattr(self.base_config, "hc_mult", 1))
+        self._hc_sublayers = int(getattr(self.base_config, "hc_sublayers", 0))
         self._rng = self._carried(self._rng)
         self._presence = self._new_presence()
         # Where an insert leaves its sampled token, by slot: the decode chunk
@@ -916,6 +920,12 @@ class ContinuousBatcher:
             "mean expert's, layers averaged: what dropless routing pays under imbalance "
             "(0 for a family without routed experts)",
         )
+        self._m_residual_streams = self.metrics.gauge(
+            "serving_residual_streams",
+            help="streams of the served family's residual path (1: the plain residual; more: "
+            "hyper-connections mix them around every sub-layer, `serve.insert.hc_rows`)",
+        )
+        self._m_residual_streams.set(self._hc_streams)
         self._m_prefix_hits = self.metrics.counter(
             "serving_prefix_cache_hits_total",
             help="prompt pages served from the shared-prefix cache",
@@ -1562,6 +1572,8 @@ class ContinuousBatcher:
             view["state_share_of_cache"] = float(self._m_state_share.value)
         if self._expert_layers:
             view["expert_load_max_over_mean"] = float(self._m_expert_load.value)
+        if self._hc_streams > 1:
+            view["residual_streams"] = self._hc_streams
         view["prefix_cache"] = {
             "enabled": self.use_prefix_cache,
             "disabled_reason": self.prefix_cache_disabled_reason,
@@ -1924,7 +1936,7 @@ class ContinuousBatcher:
                     "serve.insert", category="serve",
                     request_id=int(req.request_id), slot=slot, bucket=int(bucket),
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
-                    **self._routed_pairs(bucket), **self._scan_chunks(bucket),
+                    **self._routed_pairs(bucket), **self._scan_chunks(bucket), **self._hc_rows(bucket),
                 ):
                     fn = self._insert_fn(bucket)
                     self._first_token, self._cache, self._presence, self._rng = fn(
@@ -2018,6 +2030,16 @@ class ContinuousBatcher:
         if not self._expert_layers:
             return {}
         return {"routed_pairs": int(bucket) * int(self.base_config.num_experts_per_tok) * self._expert_layers}
+
+    def _hc_rows(self, rows: int) -> Dict[str, int]:
+        """`hc_streams` and `hc_rows` of an insert or a chunk, for its span: the
+        residual streams, and the rows their mixes process — the program's
+        rows (an insert's bucket, pads included; a chunk's busy slots times
+        its steps) times the sub-layers, two a layer. Nothing for a plain
+        residual."""
+        if not self._hc_sublayers:
+            return {}
+        return {"hc_streams": self._hc_streams, "hc_rows": int(rows) * self._hc_sublayers}
 
     def _scan_chunks(self, bucket: int) -> Dict[str, int]:
         """`scan_chunks` of an insert, for its span: the chunks its bucket is
@@ -2339,7 +2361,7 @@ class ContinuousBatcher:
             active_slots=int(self._active.sum()),
             pages_in_use=self.pool.pages_in_use,
             ahead=bool(ahead),
-            **self._live_page_counts(),
+            **self._live_page_counts(), **self._hc_rows(int(self._active.sum()) * self.chunk_size),
         )
         try:
             with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:

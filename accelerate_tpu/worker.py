@@ -352,7 +352,7 @@ def build_model_from_spec(spec: Dict[str, Any]):
     if "name" in spec:
         model = models.create_named_model(spec["name"], seq_len=int(spec.get("seq_len", 8)))
     else:
-        family = spec["family"]
+        family = models.PUBLISHED_MODEL_TYPES.get(spec["family"], spec["family"])  # a published `model_type` will do
         create = models.CREATE_BY_FAMILY.get(family)
         if create is None:
             raise ValueError(f"unknown model family {family!r} in worker spec")

@@ -665,3 +665,42 @@ def test_parallel_hybrid_layer_prefill_compiles_for_v5e(v5e, tokens):
     compiled = jax.jit(prefill).lower(described["params"], described["cache"], hidden, positions, real).compile()
     assert "ssm_step" not in compiled.as_text()  # the scan is XLA's; the kernel is the decode step's
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+# ------------------------------------------------- the residual streams' mixes (PR 40)
+HC_STREAMS, HC_HIDDEN = 4, 3584  # xing4-29b-a4b: four streams of 3,584, one row of 14,336 a token
+
+
+@pytest.mark.parametrize("rows", [2048, 256, 64, 200], ids=["insert2048", "insert256", "chunk64", "ragged200"])
+def test_hyper_connection_kernels_compile_for_v5e(v5e, rows):
+    """`hc_pre` and `hc_post` at the prompt-heavy cell's shapes (bfloat16
+    streams, float32 maps), a row count short of one block and one that is no
+    whole number of blocks: Mosaic takes the 24-row products, the whole-tile
+    transposes and the single-row stores, in 64 MB of fast memory; each kernel
+    is one custom call, and the streams are stored at their bytes (no `[rows,
+    4, 3584]` array with 4 on a tiled axis anywhere)."""
+    from accelerate_tpu.ops import hyper_connection as hc
+
+    width = HC_STREAMS * HC_HIDDEN
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)  # noqa: E731
+    x, y = shape((rows, width), jnp.bfloat16), shape((rows, HC_HIDDEN), jnp.bfloat16)
+    phi_t, alpha, bias = shape((24, width), jnp.float32), shape((3,), jnp.float32), shape((24,), jnp.float32)
+
+    def mix(x, y, phi_t, alpha, bias):
+        u, maps = hc.hc_pre(x, phi_t, alpha, bias, n=HC_STREAMS, iters=20, eps=1e-6, impl="pallas")
+        return hc.hc_post(x, y + u, maps, n=HC_STREAMS, impl="pallas"), maps
+
+    # off a TPU "pallas" means the interpreter: steer the two wrappers to the compiler as the chip would
+    import unittest.mock
+
+    with unittest.mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = _compile(mix, x, y, phi_t, alpha, bias)
+    text = compiled.as_text()
+    assert len(re.findall(r"%hc_pre[.\d]* = [^\n]*custom-call\(", text)) == 1
+    assert len(re.findall(r"%hc_post[.\d]* = [^\n]*custom-call\(", text)) == 1
+    assert not re.findall(rf"\[{rows},{HC_STREAMS},{HC_HIDDEN}\]", text)
+    memory = compiled.memory_analysis()
+    streams = rows * width * 2
+    stored = streams + rows * hc.MAP_LANES * 4  # X' and a packed row of maps a token, and the pair's table
+    assert stored <= memory.output_size_in_bytes <= stored + 4096
+    assert memory.temp_size_in_bytes <= rows * (HC_HIDDEN * 2 * 2 + hc.MAP_LANES * 4) + (1 << 20)  # u and y + u
