@@ -208,11 +208,15 @@ def make_causal_programs(
     def prefill(params, input_ids, positions, attention_mask=None):
         # attention_mask (left-padded batch prompts): rides into the cached
         # attention as the persistent pad mask (update_decode_cache).
+        # The head runs on the last column alone (`models.llama.rows_for_head`):
+        # the one row whose logits are returned.
+        batch, length = input_ids.shape
         with weight_autocast(weight_dtype):
             logits, mutated = module.apply(
-                resolve(params), input_ids, attention_mask, positions, mutable=["cache"]
+                resolve(params), input_ids, attention_mask, positions, mutable=["cache"],
+                logits_at=jnp.full((batch,), length - 1, jnp.int32),
             )
-        return logits[:, -1, :], mutated["cache"]
+        return logits[:, 0, :], mutated["cache"]
 
     def step(params, cache, token, position):
         with weight_autocast(weight_dtype):
@@ -250,22 +254,24 @@ def make_causal_programs(
 
 
 def make_cached_prefill_program(module, resolve):
-    """`prefill_with_cache(params, cache, input_ids, positions, attention_mask=None)` — prefill a
-    token block INTO AN EXISTING dense decode cache, continuing at the cache's
-    own `cache_index` instead of position 0, and return the full `[B, S, V]`
-    logits plus the mutated cache. The paged serving engine's shared-prefix
+    """`prefill_with_cache(params, cache, input_ids, positions, attention_mask,
+    logits_at)` — prefill a token block INTO AN EXISTING dense decode cache,
+    continuing at the cache's own `cache_index` instead of position 0, and return
+    `[B, 1, V]` logits plus the mutated cache: the rows `logits_at` (`[B]` int32
+    into `S`) names, the only ones the module applies its final norm and head to
+    (`models.llama.rows_for_head`). The paged serving engine's shared-prefix
     insert drives this: the prefix pages are gathered into a batch-1 dense cache
     (`cache_index` = matched length), only the unmatched SUFFIX runs through the
     model here — the prefill FLOPs a shared system prompt would have cost are
     simply never issued — and the result is scattered back into pool pages.
-    `attention_mask` ([B, S], 1 = real) is for a family whose layers run a
+    `attention_mask` ([B, S], 1 = real; else None) is for a family whose layers run a
     recurrence over the block: a bucket's padding must leave its state alone."""
 
     from .ops.quantization import weight_autocast
 
     weight_dtype = getattr(getattr(module, "config", None), "weight_dtype", "bf16")
 
-    def prefill_with_cache(params, cache, input_ids, positions, attention_mask=None):
+    def prefill_with_cache(params, cache, input_ids, positions, attention_mask, logits_at):
         with weight_autocast(weight_dtype):
             logits, mutated = module.apply(
                 {**resolve(params), "cache": cache},
@@ -273,6 +279,7 @@ def make_cached_prefill_program(module, resolve):
                 attention_mask,
                 positions,
                 mutable=["cache"],
+                logits_at=logits_at,
             )
         return logits, mutated["cache"]
 

@@ -58,7 +58,7 @@ from ..ops.delta_rule import causal_conv
 from ..ops.remat import maybe_remat
 from ..ops.ssm import from_slot_layout, ssd_chunked, ssm_step, to_slot_layout
 from ..parallel.sharding import constrain_activation
-from .llama import RMSNorm, causal_lm_loss, rotary_embedding
+from .llama import RMSNorm, causal_lm_loss, rotary_embedding, rows_for_head
 from .olmo_hybrid import _a_log_init, _dt_bias_init
 
 FALCON_H1_SHARDING_RULES = [
@@ -292,7 +292,7 @@ class FalconH1ForCausalLM(nn.Module):
     config: FalconH1Config
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None):
+    def __call__(self, input_ids, attention_mask=None, positions=None, logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -302,7 +302,7 @@ class FalconH1ForCausalLM(nn.Module):
         Layer = maybe_remat(FalconH1Layer)
         for i in range(cfg.num_hidden_layers):
             hidden = Layer(cfg, name=f"layer_{i}")(hidden, positions, attention_mask)
-        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(hidden)
+        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(rows_for_head(hidden, logits_at))
         logits = _dense(cfg.vocab_size, cfg, "lm_head")(hidden)
         return logits * jnp.asarray(cfg.lm_head_multiplier, logits.dtype)
 

@@ -25,7 +25,7 @@ from ..ops.attention import (
 )
 from ..parallel.sharding import constrain_activation
 from ..ops.remat import maybe_remat
-from .llama import causal_lm_loss
+from .llama import causal_lm_loss, rows_for_head
 
 # Parity oracle for the sharding planner (see LLAMA_SHARDING_RULES).
 GPT_NEOX_SHARDING_RULES = [
@@ -194,7 +194,7 @@ class GPTNeoXForCausalLM(nn.Module):
     config: GPTNeoXConfig
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None):
+    def __call__(self, input_ids, attention_mask=None, positions=None, logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -215,7 +215,8 @@ class GPTNeoXForCausalLM(nn.Module):
             Block = maybe_remat(GPTNeoXBlock)
             for i in range(cfg.num_hidden_layers):
                 hidden = Block(cfg, name=f"layer_{i}")(hidden, positions, attention_mask)
-        hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, param_dtype=cfg._pdtype, name="final_norm")(hidden)
+        hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, param_dtype=cfg._pdtype, name="final_norm")(
+            rows_for_head(hidden, logits_at))
         return nn.Dense(cfg.vocab_size, use_bias=False, param_dtype=cfg._pdtype, name="embed_out")(hidden)
 
 

@@ -24,7 +24,7 @@ from ..modeling import Model
 from ..ops.attention import dot_product_attention, update_decode_cache
 from ..parallel.sharding import constrain_activation
 from ..ops.remat import maybe_remat
-from .llama import causal_lm_loss
+from .llama import causal_lm_loss, rows_for_head
 
 GPTJ_SHARDING_RULES = [
     (r"(wq|wk|wv)/kernel", (None, "model")),
@@ -137,7 +137,7 @@ class GPTJForCausalLM(nn.Module):
     config: GPTJConfig
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None):
+    def __call__(self, input_ids, attention_mask=None, positions=None, logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -158,7 +158,8 @@ class GPTJForCausalLM(nn.Module):
             Block = maybe_remat(GPTJBlock)
             for i in range(cfg.num_hidden_layers):
                 hidden = Block(cfg, name=f"layer_{i}")(hidden, positions, attention_mask)
-        hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, param_dtype=cfg._pdtype, name="ln_f")(hidden)
+        hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, param_dtype=cfg._pdtype, name="ln_f")(
+            rows_for_head(hidden, logits_at))
         return nn.Dense(cfg.vocab_size, param_dtype=cfg._pdtype, name="lm_head")(hidden)  # biased, per GPT-J
 
 

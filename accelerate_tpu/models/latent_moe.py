@@ -62,7 +62,7 @@ from ..ops.quantization import dequantize_weight_int8, is_quantized_kernel
 from ..ops.remat import maybe_remat
 from ..parallel.expert import EXPERT_SHARDING_RULES, dropless_expert_ffn, sigmoid_top_k_routing
 from ..parallel.sharding import constrain_activation
-from .llama import RMSNorm, causal_lm_loss, rotary_embedding
+from .llama import RMSNorm, causal_lm_loss, rotary_embedding, rows_for_head
 
 LATENT_MOE_SHARDING_RULES = [
     (r"(wq|wq_b|wkv_b)/kernel", (None, "model")),
@@ -404,7 +404,7 @@ class LatentMoEForCausalLM(nn.Module):
     config: LatentMoEConfig
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None):
+    def __call__(self, input_ids, attention_mask=None, positions=None, logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -418,6 +418,7 @@ class LatentMoEForCausalLM(nn.Module):
         for i in range(cfg.num_hidden_layers):
             hidden = Layer(cfg, i < cfg.first_k_dense_replace, name=f"layer_{i}")(
                 hidden, positions, attention_mask)
+        hidden = rows_for_head(hidden, logits_at)
         if cfg.hc_mult > 1:  # and their sum is what the head reads
             c = cfg.hidden_size
             hidden = sum(hidden[..., j * c:(j + 1) * c].astype(jnp.float32)

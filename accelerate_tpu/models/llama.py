@@ -132,6 +132,20 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(x.dtype)
 
 
+def rows_for_head(hidden, logits_at):
+    """The rows of `hidden` `[B, S, ...]` a causal LM's tail — the final norm, the
+    head, whatever a family has around them — is applied to. `logits_at` None:
+    every row (training, a decode step and a verify block read them all). A `[B]`
+    int32 index into `S`: that one row of each entry, `[B, 1, ...]`, taken BEFORE
+    the tail, so a program that samples one token (an insert at its prompt's
+    last real row, `generate()`'s prefill at its last column) never computes
+    `[B, S, V]`. An index outside `S` is clamped, as `dynamic_slice` clamps."""
+    if logits_at is None:
+        return hidden
+    index = jnp.asarray(logits_at, jnp.int32).reshape(hidden.shape[0], 1, 1)
+    return jnp.take_along_axis(hidden, index, axis=1, mode="clip")
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
 
@@ -210,7 +224,7 @@ class LlamaForCausalLM(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None):
+    def __call__(self, input_ids, attention_mask=None, positions=None, logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -231,7 +245,7 @@ class LlamaForCausalLM(nn.Module):
             Layer = maybe_remat(LlamaLayer)
             for i in range(cfg.num_hidden_layers):
                 hidden = Layer(cfg, name=f"layer_{i}")(hidden, positions, attention_mask)
-        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(hidden)
+        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(rows_for_head(hidden, logits_at))
         if cfg.tie_word_embeddings:
             embed = self.variables["params"]["embed_tokens"]["embedding"]
             return hidden @ embed.T

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from ..modeling import Model
 from ..parallel.expert import EXPERT_SHARDING_RULES, MoEBlock
 from ..ops.remat import maybe_remat
-from .llama import LlamaAttention, LlamaConfig, RMSNorm
+from .llama import LlamaAttention, LlamaConfig, RMSNorm, rows_for_head
 
 MIXTRAL_SHARDING_RULES = [
     (r"(wq|wk|wv)/kernel", (None, "model")),
@@ -94,7 +94,8 @@ class MixtralForCausalLM(nn.Module):
     config: MixtralConfig
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None, return_aux: bool = False):
+    def __call__(self, input_ids, attention_mask=None, positions=None, return_aux: bool = False,
+                 logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -105,7 +106,7 @@ class MixtralForCausalLM(nn.Module):
         for i in range(cfg.num_hidden_layers):
             hidden, aux = Layer(cfg, name=f"layer_{i}")(hidden, positions, attention_mask)
             total_aux = {k: total_aux[k] + aux[k] for k in total_aux}
-        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(hidden)
+        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(rows_for_head(hidden, logits_at))
         logits = nn.Dense(cfg.vocab_size, use_bias=False, name="lm_head")(hidden)
         if return_aux:
             n = jnp.float32(max(cfg.num_hidden_layers, 1))

@@ -59,7 +59,7 @@ from ..ops.delta_rule import (
 )
 from ..ops.remat import maybe_remat
 from ..parallel.sharding import constrain_activation
-from .llama import RMSNorm, causal_lm_loss
+from .llama import RMSNorm, causal_lm_loss, rows_for_head
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
@@ -309,7 +309,7 @@ class OlmoHybridForCausalLM(nn.Module):
     config: OlmoHybridConfig
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None):
+    def __call__(self, input_ids, attention_mask=None, positions=None, logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -320,7 +320,7 @@ class OlmoHybridForCausalLM(nn.Module):
         Layer = maybe_remat(OlmoHybridLayer)
         for i, kind in enumerate(cfg.layer_types):
             hidden = Layer(cfg, kind, name=f"layer_{i}")(hidden, positions, attention_mask)
-        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(hidden)
+        hidden = RMSNorm(cfg.rms_norm_eps, name="final_norm")(rows_for_head(hidden, logits_at))
         return _dense(cfg.vocab_size, cfg, "lm_head")(hidden)
 
 

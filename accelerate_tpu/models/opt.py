@@ -20,7 +20,7 @@ from ..modeling import Model
 from ..ops.attention import dot_product_attention, update_decode_cache
 from ..parallel.sharding import constrain_activation
 from ..ops.remat import maybe_remat
-from .llama import causal_lm_loss
+from .llama import causal_lm_loss, rows_for_head
 
 OPT_SHARDING_RULES = [
     (r"(wq|wk|wv)/kernel", (None, "model")),
@@ -109,7 +109,7 @@ class OPTForCausalLM(nn.Module):
     config: OPTConfig
 
     @nn.compact
-    def __call__(self, input_ids, attention_mask=None, positions=None):
+    def __call__(self, input_ids, attention_mask=None, positions=None, logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if positions is None:
@@ -135,7 +135,8 @@ class OPTForCausalLM(nn.Module):
             Block = maybe_remat(OPTBlock)
             for i in range(cfg.num_hidden_layers):
                 hidden = Block(cfg, name=f"layer_{i}")(hidden, positions, attention_mask)
-        hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, param_dtype=cfg._pdtype, name="final_norm")(hidden)
+        hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, param_dtype=cfg._pdtype, name="final_norm")(
+            rows_for_head(hidden, logits_at))
         # Tied head: logits against the token embedding (OPT ties by default).
         embedding = self.variables["params"]["embed_tokens"]["embedding"]
         return hidden @ embedding.T.astype(hidden.dtype)

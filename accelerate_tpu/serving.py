@@ -101,6 +101,7 @@ same blast-radius recovery; otherwise admission failures stay per-request.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -334,6 +335,12 @@ class ContinuousBatcher:
                 f"{type(model.module).__name__}'s config has no `decode_page_size` "
                 "field — this model family doesn't support slot-batched serving "
                 "yet (the slot cache is a page pool)"
+            )
+        if "logits_at" not in inspect.signature(type(model.module).__call__).parameters:
+            raise ValueError(
+                f"{type(model.module).__name__}.__call__ takes no `logits_at` — an insert "
+                "computes the head for the one row it samples (`models.llama.rows_for_head`), "
+                "and the engine has no full-logits insert to fall back to"
             )
         # `paged` and `self.paged` stay only because the benchmark's workload
         # files pass `paged=True` by value and its driver reads `engine.paged`;
@@ -1173,7 +1180,9 @@ class ContinuousBatcher:
         unmatched suffix through it, scatter the result back into pool pages —
         with every already-matched table entry redirected to the scratch page,
         so a shared read-only prefix page is never rewritten — and sample the
-        first token from the suffix's real last logits. A full prefix hit still
+        first token from the suffix's real last logits, the ONE row of the
+        bucket the final norm and the head are computed for (`head_rows` 1 on
+        the `serve.insert` span). A full prefix hit still
         recomputes the prompt's final token (matching is capped below the whole
         prompt), so first-token logits always exist. The token is written into
         the donated `first_token` buffer at `slot` and stays on the device:
@@ -1202,7 +1211,13 @@ class ContinuousBatcher:
             # positions are real, so that the padding leaves the slot's state
             # as the last real token left it. Attention needs no such mark.
             real = (jnp.arange(bucket) < real_len)[None, :] if recurrent else None
-            logits, dense = cached_prefill(params, dense, suffix_ids, positions, real)
+            # The head is computed for the one row that is sampled: the REAL
+            # last suffix token (bucket pads sit above it and, being causal,
+            # never influenced it).
+            logits, dense = cached_prefill(
+                params, dense, suffix_ids, positions, real, jnp.reshape(real_len - 1, (1,))
+            )
+            last = logits[:, 0, :]
             # Zero rows past the prompt before the write-back: the gather
             # resurrects a recycled page's stale content (never attended, but
             # a QUANTIZED scatter folds it into the boundary page's amax
@@ -1215,9 +1230,6 @@ class ContinuousBatcher:
                 pool_cache = constrain_tp_cache(
                     tree_scatter_pages(pool_cache, dense, write_row, slot), mesh
                 )
-            # Logits at the REAL last suffix token (bucket pads sit above it
-            # and, being causal, never influenced it).
-            last = jax.lax.dynamic_slice_in_dim(logits, real_len - 1, 1, axis=1)[:, 0, :]
             row = None
             with jax.named_scope("sample"):
                 if use_pen:
@@ -1936,6 +1948,7 @@ class ContinuousBatcher:
                     "serve.insert", category="serve",
                     request_id=int(req.request_id), slot=slot, bucket=int(bucket),
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
+                    head_rows=1,
                     **self._routed_pairs(bucket), **self._scan_chunks(bucket), **self._hc_rows(bucket),
                 ):
                     fn = self._insert_fn(bucket)
