@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..modeling import Model
+from ..ops import frontier_attention as frontier
 from ..ops.attention import dot_product_attention, slot_cache_attention, update_decode_cache
 from ..ops.hyper_connection import hc_post, hc_pre, map_count
 from ..ops.quantization import dequantize_weight_int8, is_quantized_kernel
@@ -170,6 +171,24 @@ class LatentMoEConfig:
         """What a cache of decompressed keys and values would hold instead."""
         return self.num_attention_heads * (self.qk_head_dim + self.v_head_dim)
 
+    def prefill_walks_frontier(self, rows: int, window: Optional[int] = None) -> bool:
+        """Whether `rows` new tokens prefilled into a dense cache of `window`
+        positions (`decode_cache_length` where none is given) attend through
+        `ops.frontier_attention`: on a TPU, more rows than one, shapes the
+        kernel takes. The module asks it as it is traced, and an engine for
+        its insert's span."""
+        return frontier.frontier_serves(
+            rows, window or self.decode_cache_length, self.qk_nope_head_dim, self.v_head_dim,
+            self.qk_rope_head_dim, self._pdtype.itemsize)
+
+    def prefill_key_blocks(self, cur: int, rows: int, window: int) -> Optional[Tuple[int, int]]:
+        """(key blocks such a prefill's attention visits, key blocks the window
+        holds) a layer and a head, for `rows` new tokens behind `cur` cached
+        ones; None where the prefill is the masked XLA attention."""
+        if not self.prefill_walks_frontier(rows, window):
+            return None
+        return frontier.frontier_key_blocks(cur, rows, window)
+
     @property
     def num_moe_layers(self) -> int:
         return max(self.num_hidden_layers - self.first_k_dense_replace, 0)
@@ -277,14 +296,30 @@ class LatentAttention(nn.Module):
             decode_mask = mask
             if cfg.decode_cache_length:
                 row, _, decode_mask = update_decode_cache(self, row, None, cfg.decode_cache_length, pad_mask=mask)
-            kv = jnp.einsum("btr,rhn->bthn", row[..., :rank], w_kvb)
-            t = row.shape[1]
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(row[:, :, None, rank:rank + rope], (b, t, heads, rope))], axis=-1)
-            q = jnp.concatenate([q_nope, q_pe], axis=-1)
-            # "xla": the flash kernel takes one head size for keys and values
-            out = dot_product_attention(q, k, kv[..., nope:], mask=decode_mask, scale=scale,
-                                        causal=not cfg.decode_cache_length, implementation="xla")
+            # A block of new rows in a cache whose mask is the causal one alone (no
+            # key-padding mask, given or kept from an earlier call): on a TPU the
+            # kernel that stops at each row block's causal frontier.
+            if (cfg.decode_cache_length and mask is None and not self.has_variable("cache", "pad_mask")
+                    and cfg.prefill_walks_frontier(s)):
+                with jax.named_scope("mla_prefill"):
+                    # the kernel's layouts: keys head-major, queries and values transposed as well
+                    k_nope = jnp.einsum("btr,rhn->bhtn", row[..., :rank], w_kvb[..., :nope])
+                    v_t = jnp.einsum("btr,rhn->bhnt", row[..., :rank], w_kvb[..., nope:])
+                    q_t = jnp.concatenate([q_nope, q_pe], axis=-1).transpose(0, 2, 3, 1)
+                    out = frontier.frontier_attention(
+                        q_t, k_nope, v_t, self.get_variable("cache", "cache_index") - s,
+                        scale=scale, shared_k=row[..., rank:rank + rope])  # `k_pe`: one row for all heads
+            else:
+                kv = jnp.einsum("btr,rhn->bthn", row[..., :rank], w_kvb)
+                t = row.shape[1]
+                k = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(row[:, :, None, rank:rank + rope], (b, t, heads, rope))],
+                    axis=-1)
+                q = jnp.concatenate([q_nope, q_pe], axis=-1)
+                # "xla": training's causal block and a decode step's one row (and every
+                # cached call off a TPU) are the masked product over all of `t`
+                out = dot_product_attention(q, k, kv[..., nope:], mask=decode_mask, scale=scale,
+                                            causal=not cfg.decode_cache_length, implementation="xla")
         return _dense(cfg.hidden_size, cfg, "wo")(out.reshape(b, s, heads * vd))
 
 

@@ -85,3 +85,41 @@ def finalize_softmax(acc, m_scr, l_scr):
     safe_l = jnp.maximum(l, 1e-30)
     lse = (m_scr[:, 0:1] + jnp.log(safe_l)).astype(jnp.float32)
     return acc[:] / safe_l, lse
+
+
+# ---- The keys-first form (PR 43), for kernels whose query rows are many: the
+# same state and the same update with a block held transposed. It sits below
+# the row-major form so that the older kernels' source locations stay put.
+
+#: Broadcast leading-sublane height for the stats of the keys-first form.
+SUBLANE = 8
+
+
+def online_softmax_update_keys_first(s, v_t, acc, m_scr, l_scr):
+    """`online_softmax_update` for a block held TRANSPOSED, keys on sublanes
+    and query rows on lanes: `s` [block_k, rows] fp32 (scaled and masked),
+    `v_t` [D, block_k], `acc` [D, rows], `m_scr` / `l_scr` [SUBLANE, rows] (a
+    row's stat repeated down the minimum tile's 8 sublanes). The same update,
+    laid out so that the maximum and the sum over a block's keys are
+    elementwise across vregs and one 8-sublane fold a block; the row-major
+    form folds 128 lanes for every 8 rows of every block, which on a v5e held
+    a [512, 256] block of a prefill to four times its products' time (PERF.md
+    section 6, PR 43). For kernels whose rows are many and whose blocks are
+    whole tiles both ways; a decode step's few rows stay row-major."""
+    m_prev = m_scr[0:1, :]  # [1, rows]
+    l_prev = l_scr[0:1, :]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)  # [block_k, rows]
+    correction = jnp.exp(m_prev - m_new)
+    l_new = l_prev * correction + jnp.sum(p, axis=0, keepdims=True)
+    acc[:] = acc[:] * correction + jax.lax.dot_general(
+        v_t, p.astype(v_t.dtype), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def finalize_softmax_keys_first(acc, l_scr):
+    """The normalized output [D, rows] of the keys-first form; a row that met
+    no live key comes out ~0, never NaN, as `finalize_softmax`'s does."""
+    return acc[:] / jnp.maximum(l_scr[0:1, :], 1e-30)

@@ -1950,6 +1950,7 @@ class ContinuousBatcher:
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
                     head_rows=1,
                     **self._routed_pairs(bucket), **self._scan_chunks(bucket), **self._hc_rows(bucket),
+                    **self._attn_key_blocks(matched_len, bucket),
                 ):
                     fn = self._insert_fn(bucket)
                     self._first_token, self._cache, self._presence, self._rng = fn(
@@ -2053,6 +2054,20 @@ class ContinuousBatcher:
         if not self._hc_sublayers:
             return {}
         return {"hc_streams": self._hc_streams, "hc_rows": int(rows) * self._hc_sublayers}
+
+    def _attn_key_blocks(self, matched_len: int, bucket: int) -> Dict[str, int]:
+        """`attn_key_blocks` and `attn_key_blocks_window` of an insert, for its
+        span: the key blocks its attention visits a layer and a head — those at
+        or before each block of query rows' causal frontier, behind
+        `matched_len` cached positions — and the blocks the slot's window holds
+        (host arithmetic over the kernel's block sizes). Nothing for a family
+        whose prefill scores the whole window under a mask: the config says
+        which it is (`prefill_key_blocks`), from what the module itself asks."""
+        count = getattr(self.base_config, "prefill_key_blocks", None)
+        blocks = count(int(matched_len), int(bucket), self._padded_length) if count else None
+        if blocks is None:
+            return {}
+        return {"attn_key_blocks": blocks[0], "attn_key_blocks_window": blocks[1]}
 
     def _scan_chunks(self, bucket: int) -> Dict[str, int]:
         """`scan_chunks` of an insert, for its span: the chunks its bucket is

@@ -704,3 +704,86 @@ def test_hyper_connection_kernels_compile_for_v5e(v5e, rows):
     stored = streams + rows * hc.MAP_LANES * 4  # X' and a packed row of maps a token, and the pair's table
     assert stored <= memory.output_size_in_bytes <= stored + 4096
     assert memory.temp_size_in_bytes <= rows * (HC_HIDDEN * 2 * 2 + hc.MAP_LANES * 4) + (1 << 20)  # u and y + u
+
+
+# ------------------------------------- the latent prefill's attention at the causal frontier (PR 43)
+# `xing4-29b-a4b.prompt-heavy-saturated`: 32 heads, a slot's window of 136 pages of 16, keys of 128 + the 64
+# rotary columns all heads share, values of 128; an insert's suffix buckets.
+FRONTIER_HEADS, FRONTIER_WINDOW = 32, 2176
+FRONTIER_KEY, FRONTIER_SHARED, FRONTIER_VALUE = 128, 64, 128
+
+
+@pytest.mark.parametrize("rows", [256, 512, 1024, 2048])
+def test_frontier_attention_compiles_for_v5e(v5e, rows):
+    """`ops.frontier_attention` at the prompt-heavy cell's four insert shapes,
+    at its shipped block sizes: ONE Mosaic call whose output is already
+    `[rows, heads * 128]` (what `wo` multiplies), no `[32, rows, 2176]` value
+    and no temporary beside it — the head's whole window of keys and values
+    sits in the kernel's VMEM, under its stated limit."""
+    from accelerate_tpu.ops import frontier_attention as frontier
+
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=v5e)  # noqa: E731
+    q = shape(1, FRONTIER_HEADS, FRONTIER_KEY + FRONTIER_SHARED, rows)  # queries and values come transposed
+    k = shape(1, FRONTIER_HEADS, FRONTIER_WINDOW, FRONTIER_KEY)
+    v = shape(1, FRONTIER_HEADS, FRONTIER_VALUE, FRONTIER_WINDOW)
+    shared = shape(1, FRONTIER_WINDOW, FRONTIER_SHARED)
+    cur = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
+
+    def attend(q, k, v, shared, cur):
+        return frontier.frontier_attention(q, k, v, cur, scale=0.07, shared_k=shared, interpret=False)
+
+    compiled = _compile(attend, q, k, v, shared, cur)
+    text = compiled.as_text()
+    assert len(re.findall(r"%frontier_attention[.\d]* = [^\n]*custom-call\(", text)) == 1
+    assert not re.search(rf"\[(1,)?{FRONTIER_HEADS},{rows},{FRONTIER_WINDOW}\]", text)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == rows * FRONTIER_HEADS * FRONTIER_VALUE * 2
+    assert memory.temp_size_in_bytes < 1 << 20
+
+
+def _tiny_latent_engine(hc: bool):
+    """An engine of the tiny latent preset at the CELL's attention shapes (32
+    heads of 128 + 64 and 128, a window of 2,176) and nothing else of its size."""
+    import dataclasses
+
+    from accelerate_tpu.models import latent_moe
+    from accelerate_tpu.serving import ContinuousBatcher
+
+    tiny = latent_moe.latent_moe_hc_tiny() if hc else latent_moe.latent_moe_tiny()
+    config = dataclasses.replace(
+        tiny, num_hidden_layers=2, first_k_dense_replace=1, num_attention_heads=FRONTIER_HEADS,
+        qk_nope_head_dim=FRONTIER_KEY, qk_rope_head_dim=FRONTIER_SHARED, v_head_dim=FRONTIER_VALUE,
+        max_position_embeddings=4096, param_dtype="bfloat16")
+    model = latent_moe.create_latent_moe_model(config, jax.random.key(0))
+    return ContinuousBatcher(model, num_slots=2, max_length=FRONTIER_WINDOW, chunk_size=4, page_size=16)
+
+
+@pytest.mark.parametrize("rows", [256, 512, 1024, 2048])
+def test_the_latent_insert_compiled_for_v5e_holds_no_scores_of_the_window(v5e, rows, monkeypatch):
+    """An engine's insert of the four-stream latent family at the cell's
+    attention shapes, compiled for the described v5e as the chip would trace
+    it (`jax.default_backend` is "tpu" there): a `frontier_attention` call a
+    layer and NO value of shape `[32, rows, 2176]`, lowered or compiled. The
+    same insert traced as the CPU traces it holds one: the pattern can see."""
+    held = rf"\[(1,)?{FRONTIER_HEADS},{rows},{FRONTIER_WINDOW}\]"
+    lowered_held = f"x{FRONTIER_HEADS}x{rows}x{FRONTIER_WINDOW}x"
+
+    def insert(engine):
+        args = (engine.params, engine._cache, engine._presence, jnp.zeros((1, rows), jnp.int32),
+                jnp.int32(1), jnp.int32(0), jnp.int32(0), jnp.zeros((engine.pages_per_slot,), jnp.int32),
+                jnp.int32(0), jnp.float32(1), jnp.float32(1), engine._rng, engine._new_first_token())
+        described = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), args)
+        return engine._insert_fn(rows).lower(*described)
+
+    if rows == 1024:  # once: the masked XLA product over the window, as every insert was before PR 43
+        before = insert(_tiny_latent_engine(hc=True))
+        assert lowered_held in before.as_text() and "frontier_attention" not in before.as_text()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine = _tiny_latent_engine(hc=True)
+    assert engine._padded_length == FRONTIER_WINDOW
+    lowered = insert(engine)
+    assert lowered_held not in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert len(re.findall(r"%frontier_attention[.\d]* = [^\n]*custom-call\(", text)) == 2  # a layer
+    assert not re.search(held, text)
