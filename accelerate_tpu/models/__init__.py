@@ -7,6 +7,7 @@ from .bert import BertConfig, BertForSequenceClassification, bert_base, bert_tin
 from .llama import (
     LlamaConfig,
     LlamaForCausalLM,
+    ServedConfig,
     create_llama_model,
     llama3_8b,
     llama_1b,
@@ -111,6 +112,21 @@ CREATE_BY_FAMILY = {
     "latent_moe": create_latent_moe_model,
     "olmo_hybrid": create_olmo_hybrid_model,
     "falcon_h1": create_falcon_h1_model,
+}
+
+# family -> its config dataclass: which family a live bundle is (the worker's
+# `spec_for_model`) and the class a config is rebuilt with from its fields.
+CONFIG_BY_FAMILY = {
+    "bert": BertConfig,
+    "llama": LlamaConfig,
+    "mixtral": MixtralConfig,
+    "gptj": GPTJConfig,
+    "gpt_neox": GPTNeoXConfig,
+    "opt": OPTConfig,
+    "t5": T5Config,
+    "latent_moe": LatentMoEConfig,
+    "olmo_hybrid": OlmoHybridConfig,
+    "falcon_h1": FalconH1Config,
 }
 
 # family -> (flax module class name, LayeredApply class) for models shipping a

@@ -58,7 +58,7 @@ from ..ops.delta_rule import causal_conv
 from ..ops.remat import maybe_remat
 from ..ops.ssm import from_slot_layout, ssd_chunked, ssm_step, to_slot_layout
 from ..parallel.sharding import constrain_activation
-from .llama import RMSNorm, causal_lm_loss, rotary_embedding, rows_for_head
+from .llama import RMSNorm, ServedConfig, causal_lm_loss, rotary_embedding, rows_for_head
 from .olmo_hybrid import _a_log_init, _dt_bias_init
 
 FALCON_H1_SHARDING_RULES = [
@@ -74,7 +74,7 @@ FALCON_H1_SHARDING_RULES = [
 
 
 @dataclass
-class FalconH1Config:
+class FalconH1Config(ServedConfig):
     """Keys as the published config names them; defaults are Falcon-H1-34B-Instruct's."""
 
     vocab_size: int = 261120
@@ -104,25 +104,15 @@ class FalconH1Config:
     ssm_out_multiplier: float = 0.08838834764831845
     ssm_multipliers: Tuple[float, ...] = (0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738)
     mlp_multipliers: Tuple[float, ...] = (0.1767766952966369, 0.011160714285714284)
-    # Serving (see LlamaConfig for the semantics of each): the dense decode
-    # cache, the slot cache's page pool for the attention half of every layer
-    # and its read, int8 weights, a quantized pool. No `decode_tp_mesh`: by-slot
-    # state has no tensor-parallel layout, and the engine's admission says so.
-    decode_cache_length: int = 0
-    decode_slot_cache: bool = False
-    decode_page_size: int = 0
-    decode_num_pages: int = 0
-    decode_attention_impl: str = "xla"
+    # Serving, beyond `ServedConfig` (whose page pool holds the attention half of every layer): a
+    # quantized pool, int8 weights. No `decode_tp_mesh`: by-slot state has no
+    # tensor-parallel layout, and the engine's admission says so.
     decode_kv_cache_dtype: str = "bf16"
     weight_dtype: str = "bf16"
     param_dtype: str = "float32"
 
     def __post_init__(self):
-        if self.decode_slot_cache and self.decode_page_size < 1:
-            raise ValueError(
-                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
-                "cache is a page pool"
-            )
+        super().__post_init__()
         # a JSON round trip hands back lists
         self.ssm_multipliers = tuple(float(m) for m in self.ssm_multipliers)
         self.mlp_multipliers = tuple(float(m) for m in self.mlp_multipliers)
@@ -150,6 +140,10 @@ class FalconH1Config:
     def decode_scan_chunk(self) -> int:
         """Tokens a chunk of the recurrence's prefill form (`serve.insert.scan_chunks`)."""
         return self.mamba_chunk_size
+
+    def insert_span_counts(self, bucket: int, suffix_tokens: int, matched_len: int, window: int) -> dict:
+        """`scan_chunks`: the chunks the bucket is for a layer's chunked recurrence, pads included."""
+        return {"scan_chunks": -(-bucket // self.decode_scan_chunk)}
 
     @property
     def _pdtype(self):

@@ -25,7 +25,7 @@ from ..ops.attention import (
 )
 from ..parallel.sharding import constrain_activation
 from ..ops.remat import maybe_remat
-from .llama import causal_lm_loss, rows_for_head
+from .llama import ServedConfig, causal_lm_loss, rows_for_head
 
 # Parity oracle for the sharding planner (see LLAMA_SHARDING_RULES).
 GPT_NEOX_SHARDING_RULES = [
@@ -39,7 +39,7 @@ GPT_NEOX_SHARDING_RULES = [
 
 
 @dataclass
-class GPTNeoXConfig:
+class GPTNeoXConfig(ServedConfig):
     vocab_size: int = 50432
     hidden_size: int = 6144
     intermediate_size: int = 24576
@@ -51,31 +51,12 @@ class GPTNeoXConfig:
     layer_norm_eps: float = 1e-5
     use_parallel_residual: bool = True
     scan_layers: bool = False
-    decode_cache_length: int = 0
-    # Per-row slot-cache decode for continuous batching (see LlamaConfig).
-    decode_slot_cache: bool = False
-    # The slot cache's pool: geometry + page tables via the mask seam (see
-    # LlamaConfig for the full semantics).
-    decode_page_size: int = 0
-    decode_num_pages: int = 0
-    # Serving-decode attention implementation (see LlamaConfig): "xla" gather
-    # oracle or the "pallas_paged" fused page-walk kernels.
-    decode_attention_impl: str = "xla"
-    # Quantized serving (see LlamaConfig): KV page-pool storage dtype and
-    # weight storage dtype for the serving programs.
+    # Serving, beyond `ServedConfig` (see LlamaConfig for each): a quantized
+    # page pool, int8 weights, a tensor-parallel engine.
     decode_kv_cache_dtype: str = "bf16"
     weight_dtype: str = "bf16"
-    # Tensor-parallel decode submesh (see LlamaConfig.decode_tp_mesh): the
-    # 1-axis ("model",) Mesh the Pallas page-walk kernels shard_map over.
     decode_tp_mesh: Optional[Any] = None
     param_dtype: str = "float32"
-
-    def __post_init__(self):
-        if self.decode_slot_cache and self.decode_page_size < 1:
-            raise ValueError(
-                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
-                "cache is a page pool"
-            )
 
     @property
     def head_dim(self) -> int:

@@ -63,7 +63,7 @@ from ..ops.quantization import dequantize_weight_int8, is_quantized_kernel
 from ..ops.remat import maybe_remat
 from ..parallel.expert import EXPERT_SHARDING_RULES, dropless_expert_ffn, sigmoid_top_k_routing
 from ..parallel.sharding import constrain_activation
-from .llama import RMSNorm, causal_lm_loss, rotary_embedding, rows_for_head
+from .llama import RMSNorm, ServedConfig, causal_lm_loss, rotary_embedding, rows_for_head
 
 LATENT_MOE_SHARDING_RULES = [
     (r"(wq|wq_b|wkv_b)/kernel", (None, "model")),
@@ -78,7 +78,7 @@ LATENT_MOE_SHARDING_RULES = [
 
 
 @dataclass
-class LatentMoEConfig:
+class LatentMoEConfig(ServedConfig):
     """Keys as the published config names them; defaults are Kimi-VL-A3B's."""
 
     vocab_size: int = 163840
@@ -113,25 +113,15 @@ class LatentMoEConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
-    # Serving (see LlamaConfig for the semantics of each): the dense decode
-    # cache, the slot cache's page pool, its read, int8 weights. There is no
+    # Serving, beyond `ServedConfig`: int8 weights. There is no
     # `decode_kv_cache_dtype` and no `decode_tp_mesh`: a quantized pool and a
     # tensor-parallel split of latent rows are not built, and the engine's
     # admission says so.
-    decode_cache_length: int = 0
-    decode_slot_cache: bool = False
-    decode_page_size: int = 0
-    decode_num_pages: int = 0
-    decode_attention_impl: str = "xla"
     weight_dtype: str = "bf16"
     param_dtype: str = "float32"
 
     def __post_init__(self):
-        if self.decode_slot_cache and self.decode_page_size < 1:
-            raise ValueError(
-                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
-                "cache is a page pool"
-            )
+        super().__post_init__()
         if self.rope_scaling is not None and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"rope_scaling {self.rope_scaling!r}: only DeepSeek-V3's \"yarn\" is built")
         if self.hc_mult < 1 or map_count(self.hc_mult) > 128:
@@ -192,6 +182,25 @@ class LatentMoEConfig:
     @property
     def num_moe_layers(self) -> int:
         return max(self.num_hidden_layers - self.first_k_dense_replace, 0)
+
+    def insert_span_counts(self, bucket: int, suffix_tokens: int, matched_len: int, window: int) -> dict:
+        """`routed_pairs`: the (token, expert) pairs the bucket sends through the
+        routed experts, pads included — dropless routing computes every one.
+        `hc_streams` / `hc_rows` as a chunk's. `attn_key_blocks` /
+        `attn_key_blocks_window`: the key blocks the attention visits a layer
+        and a head, and those the slot's window holds, where the prefill walks
+        the causal frontier (`prefill_key_blocks`)."""
+        counts = {"routed_pairs": bucket * self.num_experts_per_tok * self.num_moe_layers} if self.num_moe_layers else {}
+        counts.update(self.chunk_span_counts(bucket))
+        blocks = self.prefill_key_blocks(matched_len, bucket, window)
+        if blocks is not None:
+            counts.update(attn_key_blocks=blocks[0], attn_key_blocks_window=blocks[1])
+        return counts
+
+    def chunk_span_counts(self, rows: int) -> dict:
+        """`hc_streams`, and `hc_rows`: the rows the streams' mixes process — the
+        program's rows times the sub-layers. Nothing on the plain residual."""
+        return {"hc_streams": self.hc_mult, "hc_rows": rows * self.hc_sublayers} if self.hc_sublayers else {}
 
     @property
     def _pdtype(self):

@@ -43,7 +43,56 @@ LLAMA_SHARDING_RULES = [
 
 
 @dataclass
-class LlamaConfig:
+class ServedConfig:
+    """What every family that `serving.ContinuousBatcher` serves declares: the
+    five fields the engine sets on the prefill and the decode module's config,
+    their one check, and what the family's layers add to a span's counts.
+
+    What a family supports beyond that is what its own config carries — the
+    engine asks once (`serving._family_facts`) and refuses by name what is
+    missing: `weight_dtype` (int8 weights), `decode_kv_cache_dtype` (a
+    quantized page pool), `decode_tp_mesh` (a tensor-parallel engine), and a
+    `decode_kv_row_values` property for a cache of latent rows."""
+
+    # When set, attention keeps a [B, decode_cache_length] KV cache in the flax
+    # "cache" collection (incremental decoding); 0 = normal training/forward path.
+    decode_cache_length: int = 0
+    # Slot-batched serving: every batch row is an independent request slot whose
+    # decode position comes from the `positions` argument (per-row scatter
+    # writes) instead of the shared `cache_index`.
+    decode_slot_cache: bool = False
+    # The slot cache's pool: K/V live in decode_num_pages fixed-size pages
+    # ([num_pages, page_size, h, d]), and the per-slot page tables ride in
+    # through the `attention_mask` argument as [B, pages_per_slot] int32 traced
+    # operands (slot decode never carries a boolean mask, so the seam is free).
+    decode_page_size: int = 0
+    decode_num_pages: int = 0
+    # The decode read: "xla" = the loop over blocks of live pages (the parity
+    # oracle); "pallas_paged" = the ops/paged_attention kernels, which walk the
+    # page table inside the kernel. Resolved by the engine (`attention_impl=`).
+    decode_attention_impl: str = "xla"
+
+    def __post_init__(self):
+        if self.decode_slot_cache and self.decode_page_size < 1:
+            raise ValueError(
+                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
+                "cache is a page pool"
+            )
+
+    def insert_span_counts(self, bucket: int, suffix_tokens: int, matched_len: int, window: int) -> dict:
+        """What this family's layers add to a `serve.insert` span: counts of the
+        work an insert of `bucket` rows (`suffix_tokens` of them real) does behind
+        `matched_len` cached positions, in a slot whose padded window is `window`."""
+        return {}
+
+    def chunk_span_counts(self, rows: int) -> dict:
+        """What this family's layers add to a `serve.decode_chunk` span, from the
+        chunk's `rows`: busy slots times steps."""
+        return {}
+
+
+@dataclass
+class LlamaConfig(ServedConfig):
     vocab_size: int = 128256
     hidden_size: int = 4096
     intermediate_size: int = 14336
@@ -55,26 +104,6 @@ class LlamaConfig:
     rms_norm_eps: float = 1e-5
     tie_word_embeddings: bool = False
     scan_layers: bool = False
-    # When set, attention keeps a [B, decode_cache_length] KV cache in the flax
-    # "cache" collection (incremental decoding); 0 = normal training/forward path.
-    decode_cache_length: int = 0
-    # Slot-batched serving (serving.ContinuousBatcher): every batch row is an
-    # independent request slot whose decode position comes from the `positions`
-    # argument (per-row scatter writes) instead of the shared `cache_index`.
-    # Needs decode_page_size > 0: the slot cache is a page pool.
-    decode_slot_cache: bool = False
-    # The slot cache's pool: K/V live in decode_num_pages fixed-size pages
-    # ([num_pages, page_size, h, d]), and the per-slot page tables ride in
-    # through the `attention_mask` argument as [B, pages_per_slot] int32 traced
-    # operands (slot decode never carries a boolean mask, so the seam is free).
-    decode_page_size: int = 0
-    decode_num_pages: int = 0
-    # Serving-decode attention implementation:
-    # "xla" = gather the slot's pages into a logical buffer then attend (the
-    # parity oracle); "pallas_paged" = the ops/paged_attention kernels, which
-    # walk the page table inside the kernel and never materialize the gather.
-    # Threaded from serving.ContinuousBatcher(attention_impl=...).
-    decode_attention_impl: str = "xla"
     # KV page-pool storage dtype: "bf16" keeps the
     # model compute dtype; "int8"/"fp8_e4m3" store pages quantized with
     # per-page-per-head scale pools riding in the cache collection
@@ -91,13 +120,6 @@ class LlamaConfig:
     # the KV-head grid, since pallas_call has no GSPMD partitioning rule.
     # None = single-device serving, byte-for-byte the pre-TP behavior.
     decode_tp_mesh: Optional[Any] = None
-
-    def __post_init__(self):
-        if self.decode_slot_cache and self.decode_page_size < 1:
-            raise ValueError(
-                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
-                "cache is a page pool"
-            )
 
     @property
     def head_dim(self) -> int:

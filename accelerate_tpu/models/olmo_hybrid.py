@@ -59,7 +59,7 @@ from ..ops.delta_rule import (
 )
 from ..ops.remat import maybe_remat
 from ..parallel.sharding import constrain_activation
-from .llama import RMSNorm, causal_lm_loss, rows_for_head
+from .llama import RMSNorm, ServedConfig, causal_lm_loss, rows_for_head
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
@@ -74,7 +74,7 @@ OLMO_HYBRID_SHARDING_RULES = [
 
 
 @dataclass
-class OlmoHybridConfig:
+class OlmoHybridConfig(ServedConfig):
     """Keys as the published config names them; defaults are Olmo-Hybrid-7B's."""
 
     vocab_size: int = 100352
@@ -93,25 +93,15 @@ class OlmoHybridConfig:
     linear_value_head_dim: int = 192
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = True
-    # Serving (see LlamaConfig for the semantics of each): the dense decode
-    # cache, the slot cache's page pool for the full-attention layers and its
-    # read, int8 weights, a quantized pool. No `decode_tp_mesh`: by-slot state
-    # has no tensor-parallel layout, and the engine's admission says so.
-    decode_cache_length: int = 0
-    decode_slot_cache: bool = False
-    decode_page_size: int = 0
-    decode_num_pages: int = 0
-    decode_attention_impl: str = "xla"
+    # Serving, beyond `ServedConfig` (whose page pool holds the full-attention layers): a
+    # quantized pool, int8 weights. No `decode_tp_mesh`: by-slot state has no
+    # tensor-parallel layout, and the engine's admission says so.
     decode_kv_cache_dtype: str = "bf16"
     weight_dtype: str = "bf16"
     param_dtype: str = "float32"
 
     def __post_init__(self):
-        if self.decode_slot_cache and self.decode_page_size < 1:
-            raise ValueError(
-                "decode_slot_cache=True needs decode_page_size >= 1: the slot "
-                "cache is a page pool"
-            )
+        super().__post_init__()
         if self.layer_types is None:
             self.layer_types = tuple(
                 FULL if i % 4 == 3 else LINEAR for i in range(self.num_hidden_layers))
@@ -153,6 +143,10 @@ class OlmoHybridConfig:
     def decode_scan_chunk(self) -> int:
         """Tokens a chunk of the recurrence's prefill form (`serve.insert.scan_chunks`)."""
         return CHUNK
+
+    def insert_span_counts(self, bucket: int, suffix_tokens: int, matched_len: int, window: int) -> dict:
+        """`scan_chunks`: the chunks the bucket is for a layer's chunked recurrence, pads included."""
+        return {"scan_chunks": -(-bucket // self.decode_scan_chunk)} if LINEAR in self.layer_types else {}
 
     @property
     def _pdtype(self):

@@ -437,6 +437,86 @@ def serving_tp_mesh(tp: int, devices=None, group: int = 0):
     return Mesh(np.asarray(devices), ("model",))
 
 
+def resolve_serving_sharding(
+    model, params, *, tp: int, tp_devices=None, tp_group: int = 0, sharding_rules: Any = None,
+    sharding_refine_top_k: int = 0, kv_heads: int, num_slots: int, page_size: int, num_pages: int,
+    kv_cache_dtype: str = "bf16", weight_dtype: str = "bf16",
+):
+    """`(mesh, rules, plan, mode)` of a serving engine: the submesh it spans
+    (None: the plain single-device engine) and the rule table its weights are
+    placed by.
+
+    `tp > 1`: one engine over a `tp`-device submesh whose single "model" axis
+    carries the family's Megatron column/row-parallel rules. Weights, the KV
+    pool (by KV head) and the quantized scale pools are placed sharded and
+    GSPMD inserts the collectives into the same programs — page tables,
+    sampling scalars and token operands stay replicated host pushes, so
+    admissions still never recompile. `tp == 1` with `tp_devices` or a
+    `tp_group` that is not the first is a replica of an in-process fleet (the
+    router hands replica r `tp_group=r`): pinned to its own device through a
+    1-device submesh, so N replicas on an N-chip host do not pile onto chip 0.
+
+    `sharding_rules`: None / "rules" -> the family's hand-written table (the
+    parity oracle); an explicit list is a caller override (`mode`
+    "explicit"); "auto" -> the cost-model planner (parallel/planner.py)
+    searches the layout from shapes + mesh topology, pricing the KV pool at
+    the live cache dtype, and emits a table the same derivations consume —
+    swap-in weights, cache init and the TPU118 audit all behave exactly as
+    with a hand table. With `sharding_refine_top_k` > 1 the top-k candidates
+    are compiled as one-token forwards and the measured-best wins (cost model
+    proposes, hardware disposes); 1 still measures its single candidate
+    (`plan.measured_step_s`)."""
+    import jax
+
+    tp = int(tp)
+    if tp < 1:
+        raise ValueError("tp must be >= 1")
+    mode = "rules" if sharding_rules is None else sharding_rules
+    if isinstance(mode, (list, tuple)):
+        rules, mode = list(mode), "explicit"
+    elif mode in ("rules", "auto"):
+        rules = list(getattr(model, "sharding_rules", None) or [])
+    else:
+        raise ValueError(
+            f"sharding_rules must be a rules list, None, 'rules' or 'auto'; "
+            f"got {sharding_rules!r}"
+        )
+    mesh = plan = None
+    if tp > 1:
+        if not rules and mode != "auto":
+            raise ValueError(
+                f"{type(model.module).__name__}'s Model bundle carries no "
+                "sharding_rules — this model family has no Megatron TP "
+                "layout to span a mesh with; pass tp=1 or "
+                "sharding_rules=\"auto\" to let the planner derive one"
+            )
+        if kv_heads % tp:
+            raise ValueError(
+                f"tp={tp} must divide the model's KV head count "
+                f"({kv_heads}): the KV pool shards by KV head over the "
+                "\"model\" axis"
+            )
+        mesh = serving_tp_mesh(tp, devices=tp_devices, group=tp_group)
+    elif tp_devices is not None or int(tp_group) % jax.device_count():
+        mesh = serving_tp_mesh(1, devices=tp_devices, group=tp_group)
+    if tp > 1 and mode == "auto":
+        from .planner import measure_forward_step, plan_serving_sharding, refine_plans
+
+        refine = int(sharding_refine_top_k)
+        plan = plan_serving_sharding(
+            params, mesh, model.module.config, num_slots=num_slots, page_size=page_size,
+            num_pages=num_pages, kv_cache_dtype=kv_cache_dtype, weight_dtype=weight_dtype,
+            top_k=max(1, refine),
+        )
+        if refine >= 1:
+            plan, _ = refine_plans(
+                plan if isinstance(plan, list) else [plan],
+                lambda candidate: measure_forward_step(model.apply_fn, params, mesh, candidate.rules, batch=1),
+            )
+        rules = list(plan.rules)
+    return mesh, rules, plan, mode
+
+
 def _check_tp_divisible(path: str, shape, spec, mesh):
     """A rule-sharded dim must divide by its axis group — silently dropping
     the axis would be exactly the full-replication fallback TPU118 warns

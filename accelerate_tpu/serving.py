@@ -53,11 +53,10 @@ late-arriving request starts decoding while earlier long requests are still
 mid-flight. Stale K/V from a slot's previous occupant is never visible: each row
 attends only `cols <= its own position`, and insert overwrites the prompt rows.
 
-**What may be in flight when `step()` returns.** Slot state (`token`, `pos`,
-`active`, `rem`) is carried ON THE DEVICE from chunk to chunk — a chunk is
-dispatched on its predecessor's outputs, and the host pushes only the rows it
-changed (admitted, vacated, cancelled, timed out), merged in the program under
-a mask — so a chunk can be enqueued before the one ahead of it has been read.
+**What may be in flight when `step()` returns.** Slot state is carried ON THE
+DEVICE from chunk to chunk — a chunk is dispatched on its predecessor's
+outputs, and the host pushes only the rows it changed (`_SlotMirror`) — so a
+chunk can be enqueued before the one ahead of it has been read.
 `step()` does that exactly when requests are LEFT IN THE QUEUE after admission
 (a backlog: no arrival could have been admitted sooner anyway): it enqueues
 its inserts and its decode chunk behind the chunk that is still running and
@@ -68,13 +67,13 @@ with it — are handed out by the next `step()`. Whoever drives the engine
 flushes it by stepping while `pending` (`run()`, `drain()`), or by `close()`,
 which reads the chunk back before it cancels. With an EMPTY queue a step reads
 back the chunk it dispatched itself and nothing is in flight when it returns,
-so an arrival's insert never waits behind a chunk queued ahead of it. The host
-then works from a PREDICTED mirror of `pos` / `rem` (one token a decode step
-until the budget ends): a request that ends by length is known a chunk early,
-its slot is vacated at that dispatch and re-admitted before its last tokens
-are drained; one that stops on its EOS is known at its drain, one chunk late
-(`stats["slot_chunks_lost_to_eos"]`). Speculative engines, whose blocks the
-host cannot predict, always read their own chunk (`stats["run_ahead"]`).
+so an arrival's insert never waits behind a chunk queued ahead of it. Running
+ahead, the host works from a PREDICTED mirror: a request that ends by length is
+known a chunk early, its slot is vacated at that dispatch and re-admitted
+before its last tokens are drained; one that stops on its EOS is known at its
+drain, one chunk late (`stats["slot_chunks_lost_to_eos"]`). Speculative engines,
+whose blocks the host cannot predict, always read their own chunk
+(`stats["run_ahead"]`).
 
 Greedy outputs are token-identical to the static `Generator` path (pads
 contribute exact zeros under the f32 softmax; rows are independent in every
@@ -124,8 +123,9 @@ from .generation import (
 )
 from .logging import get_logger
 from .ops import attention as attention_ops
+from .ops.quantization import KV_CACHE_DTYPES, WEIGHT_DTYPES, weight_autocast
 from .paging import SCRATCH_PAGE, PagePool, chain_hashes, pages_for
-from .parallel.sharding import constrain_tp_cache, tree_device_nbytes
+from .parallel.sharding import constrain_tp_cache, resolve_serving_sharding, tree_device_nbytes
 from .speculative import (
     DEFAULT_DRAFT_NGRAM,
     DEFAULT_DRAFT_TOKENS,
@@ -209,6 +209,364 @@ class _Flight:
     pos_before: np.ndarray  # speculative: where each slot's drained tokens append
 
 
+def _pool_token_sizes(cache) -> Tuple[int, int]:
+    """`(stored bytes one token holds in the pool over all layers, values one
+    layer holds of it)`: keys and values of full heads ([..., pages,
+    page_size, heads, head_dim]) or a latent family's one row a layer ([...,
+    pages, page_size, row]); nn.scan puts its layers in front. A layer's values
+    are 2 x KV heads x head_dim, or the latent row."""
+    held = {"cached_key": 2, "cached_value": 2, "cached_latent": 1}
+    layers = nbytes = row_values = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        trailing = held.get(_leaf_name(path))
+        if trailing is None:
+            continue
+        stacked = int(np.prod(leaf.shape[: leaf.ndim - trailing - 2]))
+        values = stacked * int(np.prod(leaf.shape[leaf.ndim - trailing:]))
+        row_values += values
+        nbytes += values * np.dtype(leaf.dtype).itemsize
+        layers += stacked * (_leaf_name(path) != "cached_value")
+    return nbytes, row_values // layers
+
+
+#: Config fields a family may carry, and what it serves by carrying them.
+_OPTIONAL_FIELDS = {
+    "weight_dtype": "int8 weight-only serving",
+    "decode_tp_mesh": "tensor-parallel serving",
+    "decode_kv_cache_dtype": "the quantized KV page pool",
+}
+
+
+@dataclass(frozen=True)
+class _FamilyFacts:
+    """What the engine knows of the served family: asked of the model's config
+    once (`_family_facts`), read from here ever after."""
+
+    name: str  # the flax module's class name, as refusals spell the family
+    config: Any  # the module's config: `dataclasses.replace`d for the engine's modules, asked for span counts
+    vocab_size: int
+    max_positions: int
+    heads: int
+    kv_heads: int
+    latent: bool  # the cache holds one latent row a token a layer (MLA), not keys and values by head
+    residual_streams: int  # 1: the plain residual; more: hyper-connections mix them
+    optional: frozenset  # the `_OPTIONAL_FIELDS` its config carries
+
+    def fields(self, **wanted) -> Dict[str, Any]:
+        """`wanted` (optional config fields and their values) for
+        `dataclasses.replace`; a field the family's config lacks is refused."""
+        for name in wanted:
+            if name not in self.optional:
+                raise ValueError(
+                    f"{self.name}'s config has no `{name}` field — this model family "
+                    f"doesn't support {_OPTIONAL_FIELDS[name]} yet"
+                )
+        return wanted
+
+
+def _family_facts(model, kv_cache_dtype: str, tp: int) -> _FamilyFacts:
+    """The ONE place the engine probes a model's config (`models.llama.ServedConfig`
+    says what a family declares): what it needs of every family is refused here
+    where missing, what a family may carry is recorded (`_FamilyFacts.optional`)
+    and refused where the engine is asked for it."""
+    if getattr(model, "module", None) is None or not hasattr(model.module, "config"):
+        raise ValueError("ContinuousBatcher needs a Model bundle built from an in-tree flax module")
+    base, family = model.module.config, type(model.module).__name__
+    if not hasattr(base, "decode_page_size"):
+        raise ValueError(
+            f"{family}'s config has no `decode_page_size` "
+            "field — this model family doesn't support slot-batched serving "
+            "yet (the slot cache is a page pool)"
+        )
+    if "logits_at" not in inspect.signature(type(model.module).__call__).parameters:
+        raise ValueError(
+            f"{family}.__call__ takes no `logits_at` — an insert "
+            "computes the head for the one row it samples (`models.llama.rows_for_head`), "
+            "and the engine has no full-logits insert to fall back to"
+        )
+    # A latent cache (MLA: one `[c | k_pe]` row a token a layer, which the
+    # config says by `decode_kv_row_values`) is read on one device,
+    # unquantized — by the page-walk kernel where it reads the pool of rows
+    # in place, else by the XLA loop (`ops.attention.slot_attention_impl`:
+    # latent rows are never staged). The other combinations name what is
+    # missing.
+    latent = getattr(base, "decode_kv_row_values", None) is not None
+    if latent:
+        if kv_cache_dtype != "bf16":
+            raise ValueError(
+                f"kv_cache_dtype={kv_cache_dtype!r} with {family}: the quantized pool "
+                "keeps one scale a page a KV head, and a latent row has no heads — a "
+                "quantized pool for latent rows is not built; use kv_cache_dtype=\"bf16\""
+            )
+        if tp > 1:
+            raise ValueError(
+                f"tp={tp} with {family}: every head reads the same latent row, so a "
+                "tensor-parallel engine needs the row replicated and the absorbed "
+                "projections split by head (and the experts an \"expert\" axis) — that "
+                "layout is not built; use tp=1"
+            )
+    return _FamilyFacts(
+        name=family, config=base, vocab_size=int(base.vocab_size),
+        max_positions=int(base.max_position_embeddings), heads=int(base.num_attention_heads),
+        kv_heads=int(getattr(base, "num_key_value_heads", base.num_attention_heads)), latent=latent,
+        residual_streams=int(getattr(base, "hc_mult", 1)),
+        optional=frozenset(name for name in _OPTIONAL_FIELDS if hasattr(base, name)),
+    )
+
+
+class _SlotMirror:
+    """The host's side of the slot state, and the one place it is pushed from.
+
+    Slot state (`token`, `pos`, `active`, `rem`) lives ON THE DEVICE from
+    chunk to chunk (`ContinuousBatcher._carry`). The host keeps a PREDICTED
+    mirror of `pos`, `active`, `rem`: what they will be once every dispatched
+    chunk has run (`dispatched`), put right at the drain where the device
+    alone could know (`adopt`). Only the rows the host itself changed since
+    the last dispatch — `changed`; admissions also `from_buffer`: their token
+    is the one their insert left on the device — are pushed, as the chunk's
+    `update` operand (`_merge_slot_updates`); a step that changed none pushes
+    one array of zeros, made once.
+
+    What only the host writes (`eos`, `temp`, `pen`, `page_table`) has a
+    mirror each, its copy on the device, and its name among the stale while
+    the mirror has changed since that copy. All-zeros table rows point at the
+    scratch page, so a freed or idle slot's discarded decode writes can never
+    land in a live request's pages. A speculative engine also mirrors each
+    slot's observed context (`history`: prompt + generated, packed from index
+    0) and pushes it with every chunk: the device updates its copy inside the
+    scan (drafts must see tokens emitted earlier in the SAME chunk), the host
+    re-derives identical content from the drained stream (`saw`), so nothing
+    is ever read back.
+
+    Every push goes through `_push`, which COPIES: on a CPU the device array
+    may alias the numpy buffer it was made from, and the host writes its
+    mirrors in place while a chunk that reads the pushed array is still
+    running."""
+
+    def __init__(self, num_slots: int, pages_per_slot: int, history_length: Optional[int] = None):
+        S = num_slots
+        self.pos = np.zeros(S, np.int32)
+        self.active = np.zeros(S, bool)
+        self.rem = np.zeros(S, np.int32)
+        self.changed = np.zeros(S, bool)
+        self.from_buffer = np.zeros(S, bool)
+        self.eos = np.full(S, -1, np.int32)
+        self.temp = np.ones(S, np.float32)
+        self.pen = np.ones(S, np.float32)
+        self.page_table = np.zeros((S, pages_per_slot), np.int32)
+        self.history = None if history_length is None else np.zeros((S, history_length), np.int32)
+        self._host_only = {"eos": self.eos, "temp": self.temp, "pen": self.pen, "table": self.page_table}
+        self._pushed: Dict[str, Any] = {}
+        self._stale = set(self._host_only)
+        self._no_update = jnp.zeros((5, S), jnp.int32)
+
+    @staticmethod
+    def _push(host: np.ndarray):
+        return jnp.asarray(host.copy())
+
+    def set_row(self, name: str, slot: int, value):
+        """Write one slot's entry of a host-only operand's mirror; the device's
+        copy goes stale only where the value is new."""
+        mirror = self._host_only[name]
+        value = mirror.dtype.type(value)
+        if mirror[slot] != value:
+            mirror[slot] = value
+            self._stale.add(name)
+
+    def admit(self, slot: int, prompt: np.ndarray, budget: int, eos: int, temperature: float,
+              penalty: float, page_row: np.ndarray):
+        """A request takes `slot`: the device is told with the next dispatch,
+        its state from the host, its token from the buffer. `budget` is what it
+        may decode after the token its insert samples; 0 is a one-token
+        request, which the chunk sees as an idle slot (position 0, the scratch
+        row)."""
+        self.changed[slot] = self.from_buffer[slot] = True
+        self.rem[slot] = budget
+        self.set_row("eos", slot, eos)
+        if budget > 0:
+            p = int(prompt.size)
+            self.pos[slot] = p  # the first generated token's write position
+            self.active[slot] = True
+            self.set_row("temp", slot, temperature)
+            self.set_row("pen", slot, penalty)
+            if self.history is not None:
+                # The drafter's context: the full prompt (prefix-cache hits
+                # included — the host has the whole prompt even when the insert
+                # only saw the suffix). The chunk puts the first token at
+                # [slot, p] on the device, the drain here (`saw`).
+                self.history[slot, :p] = prompt
+                self.history[slot, p:] = 0
+            self.page_table[slot] = page_row
+            self._stale.add("table")
+
+    def vacate(self, slot: int, held_pages: bool):
+        """`slot` is idle from the next dispatch on. An idle slot sits at
+        position 0: the XLA read takes a row's live pages from its position,
+        and a released slot left at its last one would pass for that many pages
+        of scratch. The row of a slot that `held_pages` points at the scratch
+        page again, so any residual write for it is discarded."""
+        self.active[slot] = False
+        self.pos[slot] = self.rem[slot] = 0
+        self.changed[slot], self.from_buffer[slot] = True, False
+        if held_pages:
+            self.page_table[slot] = SCRATCH_PAGE
+            self._stale.add("table")
+
+    def dispatched(self, steps: Optional[int]):
+        """A chunk went out with `operands()`: the device has every change, and
+        the prediction moves past the chunk's `steps` decode steps — an active
+        slot streams one token a step until its budget ends, unless it stops on
+        its EOS, which only the drain sees. None: a speculative engine predicts
+        nothing (a verified block's length is the device's) and adopts the
+        readback."""
+        self.changed[:] = self.from_buffer[:] = False
+        if steps is not None:
+            took = np.where(self.active, np.minimum(self.rem, steps), 0)
+            self.pos += took
+            self.rem -= took
+            self.active &= self.rem > 0
+
+    def saw(self, slot: int, start: int, tokens):
+        """The drain saw `tokens` of `slot`, which the chunk put at
+        `history[start:]` on the device: the next push holds the same context."""
+        self.history[slot, start : start + len(tokens)] = tokens
+
+    def adopt(self, slots: np.ndarray, pos: np.ndarray, rem: np.ndarray):
+        """Where the device left `slots` (a mask), off a readback."""
+        self.pos[slots], self.rem[slots] = pos[slots], rem[slots]
+
+    def reset(self):
+        """Every slot idle, as on a device whose state was rebuilt from zeros:
+        nothing of the host's is ahead of it."""
+        self.pos[:] = self.rem[:] = 0
+        self.active[:] = self.changed[:] = self.from_buffer[:] = False
+        if self.history is not None:
+            self.history[:] = 0
+        self.page_table[:] = SCRATCH_PAGE
+        self._stale.add("table")
+
+    def operands(self) -> Tuple[List[Any], Any, Optional[Any]]:
+        """`([eos, temp, pen, page table], update, history)` on the device for
+        the next chunk: the stale host-only operands pushed again, the others
+        as they were; the changed slots' rows (`changed`, `from_buffer`, `pos`,
+        `active`, `rem`) or the array of zeros; a speculative engine's context
+        (else None)."""
+        for name in self._stale:
+            self._pushed[name] = self._push(self._host_only[name])
+        self._stale.clear()
+        update = self._no_update
+        if self.changed.any():
+            update = self._push(np.stack(
+                [self.changed, self.from_buffer, self.pos, self.active, self.rem]
+            ).astype(np.int32, copy=False))
+        history = None if self.history is None else self._push(self.history)
+        return [self._pushed[name] for name in ("eos", "temp", "pen", "table")], update, history
+
+
+class _StarvedAccount:
+    """The account of a starved device.
+
+    The engine knows, without the device, when nothing it enqueued is still
+    unread: no chunk in flight and no insert dispatched since the last
+    readback returned. The wall time it spends in that state goes to the cause
+    that let it happen (`serving_device_starved_seconds_total{cause}`,
+    `stats["device_starved"]`), at the boundaries of `step()`: `admit` (step
+    start → the first insert's dispatch call returns, or the end of an
+    admission that admits nothing), `push` then `dispatch` (→ the chunk's
+    launch returns, where no insert went out before it), `drain` (the
+    readback's return → step() returns, where it left nothing in flight) —
+    `starved_admit_s` / `_push_s` / `_dispatch_s` / `_drain_s` on `serve.step`,
+    summed in `starved_s`, at most `host_s` — and between two steps `client`
+    (work was pending when the step before returned) or `no_work` (nothing
+    was: the offered load's idle, not the host's): `gap_s`, the whole gap
+    before a step, and `gap_cause`, who had it — or `"covered"`: a chunk was in
+    flight through it, nothing starved, nothing charged. A gap is its cause's
+    from its first instant: a request submitted into an engine that had nothing
+    pending waits in a `no_work` gap until the next step(). Time inside the
+    step's wait is never charged — the host cannot see when the device
+    finished — so the account is a LOWER BOUND of the device's idle time, short
+    by the readback's latency and the launch's tail. A step that runs ahead
+    charges nothing after its first dispatch, and one that finds a chunk in
+    flight and leaves one charges nothing at all: in a steady backlog every
+    cause reads 0. The step that ENDS a backlog (it reads the last chunk in
+    flight back and dispatches none) charges its drain.
+
+    One mark, `_empty_since`: since when the engine has known the device
+    EMPTY, or None while something it enqueued is unread; every charge moves
+    it on."""
+
+    def __init__(self, counters: Dict[str, Any]):
+        self._counters = counters  # by cause (`STARVED_CAUSES`)
+        self._empty_since: Optional[float] = None
+        self._first_step_at: Optional[float] = None
+        self._step_returned_at: Optional[float] = None
+        self._gap_cause = "no_work"  # whose the gap after the last step() is, where nothing covers it
+        self._in_step: Dict[str, float] = {}  # the running step's own charges
+        self._read_back_at: Optional[float] = None  # when the last readback returned
+
+    def charge(self, cause: str, now: Optional[float] = None):
+        """Charge `cause` the wall time since the mark, where the device was
+        EMPTY through it, and move the mark to `now`."""
+        if self._empty_since is None:
+            return
+        now = time.perf_counter() if now is None else now
+        seconds = now - self._empty_since
+        self._empty_since = now
+        self._counters[cause].inc(seconds)
+        self._in_step[cause] = self._in_step.get(cause, 0.0) + seconds
+
+    def enqueued(self, cause: str, now: Optional[float] = None):
+        """A program's dispatch call returned: what the device sat empty until
+        `now` is `cause`'s, and it has work from here on."""
+        self.charge(cause, now)
+        self._empty_since = None
+
+    def read_returned(self) -> Optional[float]:
+        """The step's readback returned, now: seconds since the one before it
+        did (None for the first)."""
+        previous, self._read_back_at = self._read_back_at, time.perf_counter()
+        return None if previous is None else self._read_back_at - previous
+
+    def all_read(self, at: Optional[float] = None):
+        """Nothing the engine enqueued is left to read, since `at` (the last
+        readback's return where none is given)."""
+        self._empty_since = self._read_back_at if at is None else at
+
+    def begin_step(self, now: float) -> Tuple[float, str]:
+        """`(gap_s, gap_cause)` of the gap since the last step() returned:
+        covered by a chunk left in flight, or the device sat empty through it —
+        the client's, or nobody's where nothing was pending. Charged; the
+        step's own parts count from here."""
+        if self._first_step_at is None:
+            self._first_step_at = self._step_returned_at = self._empty_since = now
+        gap_s = now - self._step_returned_at
+        gap_cause = "covered" if self._empty_since is None else self._gap_cause
+        self.charge(gap_cause, now)  # nothing where a chunk covers the gap
+        self._in_step = {}
+        return gap_s, gap_cause
+
+    def end_step(self, pending: bool) -> Dict[str, float]:
+        """step() returns, now: what it left empty is its drain's, and the gap
+        that starts is the client's where work is `pending`. Returns the step's
+        own parts, for its span."""
+        self._step_returned_at = time.perf_counter()
+        if self._empty_since is not None:
+            self.charge("drain", self._step_returned_at)
+            self._gap_cause = "client" if pending else "no_work"
+        parts = {cause: round(self._in_step.get(cause, 0.0), 6) for cause in ("admit", "push", "dispatch", "drain")}
+        return {"starved_s": round(sum(parts.values()), 6),
+                **{f"starved_{cause}_s": seconds for cause, seconds in parts.items()}}
+
+    def view(self) -> Dict[str, Optional[float]]:
+        """`stats["device_starved"]`: the seconds by cause, and `share`: the
+        host's part of them — every cause but `no_work` — over the wall since
+        the first step(). A lower bound of the device's idle share."""
+        view: Dict[str, Optional[float]] = {cause: c.value for cause, c in self._counters.items()}
+        wall = time.perf_counter() - self._first_step_at if self._first_step_at is not None else 0.0
+        view["share"] = (sum(view.values()) - view["no_work"]) / wall if wall > 0 else None
+        return view
+
+
 class QueueFull(RuntimeError):
     """Bounded-queue backpressure: the engine's wait queue is at `max_queue`.
     Callers shed load (HTTP 429 / retry-after) instead of growing host memory."""
@@ -224,6 +582,105 @@ FINISH_REASONS = ("eos", "length", "timeout", "error", "cancelled")
 #: What the host was doing while the device had nothing enqueued (`step()`): the
 #: first four inside a step, the last two between two steps.
 STARVED_CAUSES = ("admit", "push", "dispatch", "drain", "client", "no_work")
+
+
+#: The engine's instruments, one row each: the attribute it is kept as, its kind
+#: (`MetricsRegistry.counter` / `.gauge` / `.histogram`), its name, its help and
+#: — where it is one instrument a label value, kept as a dict by value — the
+#: label and its values. This table is the source; docs/observability.md
+#: describes it.
+_INSTRUMENTS = (
+    ("_m_submitted", "counter", "serving_requests_submitted_total",
+     "requests accepted by submit()"),
+    ("_m_inserts", "counter", "serving_inserts_total",
+     "successful insert (prefill+admit) dispatches"),
+    ("_m_chunks", "counter", "serving_chunks_total",
+     "decode-chunk dispatches"),
+    ("_m_decode_steps", "counter", "serving_decode_steps_total",
+     "decode loop iterations (chunks * chunk_size)"),
+    ("_m_finish", "counter", "serving_requests_finished_total",
+     "finished requests by finish_reason", ("reason", FINISH_REASONS)),
+    ("_m_queue_depth", "gauge", "serving_queue_depth",
+     "requests waiting for a slot"),
+    ("_m_queue_peak", "gauge", "serving_queue_peak",
+     "queue-depth high-water mark (sized against max_queue)"),
+    ("_m_slots_in_use", "gauge", "serving_slots_in_use",
+     "slots occupied by in-flight requests"),
+    ("_m_slot_utilization", "gauge", "serving_slot_utilization",
+     "slots_in_use / num_slots"),
+    ("_m_ttft", "histogram", "serving_ttft_seconds",
+     "submit() -> the step() that carries the first token returns (host wall clock)"),
+    ("_m_inter_token", "histogram", "serving_inter_token_seconds",
+     "per-token gap between stream drains for an in-flight slot"),
+    ("_m_chunk_latency", "histogram", "serving_chunk_seconds",
+     "one decode chunk's cadence (`serve.decode_chunk.cadence_s`): since the previous readback "
+     "returned, at most operand push to readback — one chunk and the inserts enqueued ahead of it, "
+     "whether or not it was dispatched while its predecessor ran"),
+    ("_m_device_waits", "counter", "serving_device_waits_total",
+     "blocking device reads: one a step() that had a dispatched program to read back"),
+    ("_m_dispatching_steps", "counter", "serving_dispatching_steps_total",
+     "step() calls that had a program to read back: their own inserts and chunk, or the chunk the "
+     "step before left in flight (not the step that only starts running ahead)"),
+    ("_m_chunks_ahead", "counter", "serving_chunks_ahead_total",
+     "decode chunks dispatched while their predecessor was still running"),
+    ("_m_chunks_ahead_share", "gauge", "serving_chunks_ahead_share",
+     "serving_chunks_ahead_total over serving_chunks_total: ~1 under a backlog, ~0 with an empty "
+     "queue"),
+    ("_m_lost_to_eos", "counter", "serving_slot_chunks_lost_to_eos_total",
+     "chunks a slot sat inactive because its request stopped on an EOS the host had not yet seen "
+     "when it dispatched the next chunk"),
+    ("_m_starved", "counter", "serving_device_starved_seconds_total",
+     "wall time the engine knew the device had nothing enqueued, by what the host was doing (a "
+     "lower bound of the device's idle time: the readback's latency and a launch's tail are not in "
+     "it); `no_work` is the offered load's idle, the rest the host's", ("cause", STARVED_CAUSES)),
+    ("_m_pages_total", "gauge", "serving_pages_total",
+     "usable KV pool pages (excludes the scratch page)"),
+    ("_m_pages_in_use", "gauge", "serving_pages_in_use",
+     "pool pages referenced by in-flight requests"),
+    ("_m_kv_live_page_share", "gauge", "serving_kv_live_page_share",
+     "live pages of the active slots over num_slots * pages_per_slot, as the last decode chunk was "
+     "dispatched: the share of the window the paged XLA read visits"),
+    ("_m_kv_bytes_per_token", "gauge", "serving_kv_bytes_per_token",
+     "stored bytes one token holds in the page pool, all layers: keys and values of full heads, or "
+     "a latent family's one row a layer"),
+    ("_m_state_bytes_per_slot", "gauge", "serving_state_bytes_per_slot",
+     "stored bytes a slot holds in by-slot leaves (recurrent and convolution state of layers with "
+     "a recurrence), all layers, whatever the request's length (0 for a family that keeps pages "
+     "alone)"),
+    ("_m_state_share", "gauge", "serving_state_share_of_cache",
+     "by-slot state of the active slots over that state plus their live pages' bytes, as the last "
+     "decode chunk was dispatched"),
+    ("_m_expert_load", "gauge", "serving_expert_load_max_over_mean",
+     "the last decode chunk's tokens of the busiest routed expert over the mean expert's, layers "
+     "averaged: what dropless routing pays under imbalance (0 for a family without routed experts)"),
+    ("_m_residual_streams", "gauge", "serving_residual_streams",
+     "streams of the served family's residual path (1: the plain residual; more: hyper-connections "
+     "mix them around every sub-layer, `serve.insert.hc_rows`)"),
+    ("_m_prefix_hits", "counter", "serving_prefix_cache_hits_total",
+     "prompt pages served from the shared-prefix cache"),
+    ("_m_prefix_misses", "counter", "serving_prefix_cache_misses_total",
+     "full prompt pages that had to be prefilled (no cached prefix)"),
+    ("_m_prefix_evictions", "counter", "serving_prefix_cache_evictions_total",
+     "unreferenced cached prefix pages reclaimed by the allocator"),
+    ("_m_prefill_saved", "counter", "prefill_tokens_saved_total",
+     "prompt tokens whose prefill FLOPs the prefix cache skipped"),
+)
+
+#: Those of a speculative engine (host-scalar arithmetic over the chunk
+#: readback). The headline derived number — accepted_tokens_per_step — is
+#: (verify_steps + accepted) / verify_steps, surfaced in `stats`.
+_SPECULATIVE_INSTRUMENTS = (
+    ("_m_spec_steps", "counter", "serving_spec_verify_steps_total",
+     "verify-block loop iterations with an active slot (each emits >= 1 token)"),
+    ("_m_spec_drafted", "counter", "serving_spec_draft_tokens_total",
+     "draft tokens proposed by the n-gram drafter (valid proposals only)"),
+    ("_m_spec_accepted", "counter", "serving_spec_accepted_draft_tokens_total",
+     "draft tokens confirmed by verification and emitted"),
+    ("_m_spec_rejected", "counter", "serving_spec_rejected_draft_tokens_total",
+     "draft tokens the verify step discarded"),
+    ("_m_spec_hist", "histogram", "serving_spec_accepted_tokens",
+     "tokens emitted per verify step (accepted drafts + 1 bonus)"),
+)
 
 
 @dataclass
@@ -294,6 +751,14 @@ class ContinuousBatcher:
     executable is compiled exactly once per (num_slots, chunk_size, sampler
     shape); admission compiles one insert executable per power-of-two prompt
     bucket and never touches the decode program (`trace_counts` proves it).
+
+    What it is made of: the served family's facts, asked of the model's config
+    once (`_family_facts`); the host's side of the slot state (`_SlotMirror`);
+    the account of a starved device (`_StarvedAccount`); its instruments
+    (`_INSTRUMENTS`); the mesh and the rules its weights are placed by
+    (`parallel.sharding.resolve_serving_sharding`). The engine itself keeps the
+    device values its programs donate, the queue and the slots' tenants, and
+    `step()`'s control flow.
     """
 
     def __init__(
@@ -327,39 +792,14 @@ class ContinuousBatcher:
         sharding_rules: Any = None,
         sharding_refine_top_k: int = 0,
     ):
-        if getattr(model, "module", None) is None or not hasattr(model.module, "config"):
-            raise ValueError("ContinuousBatcher needs a Model bundle built from an in-tree flax module")
-        base = model.module.config
-        if not hasattr(base, "decode_page_size"):
-            raise ValueError(
-                f"{type(model.module).__name__}'s config has no `decode_page_size` "
-                "field — this model family doesn't support slot-batched serving "
-                "yet (the slot cache is a page pool)"
-            )
-        if "logits_at" not in inspect.signature(type(model.module).__call__).parameters:
-            raise ValueError(
-                f"{type(model.module).__name__}.__call__ takes no `logits_at` — an insert "
-                "computes the head for the one row it samples (`models.llama.rows_for_head`), "
-                "and the engine has no full-logits insert to fall back to"
-            )
-        # `paged` and `self.paged` stay only because the benchmark's workload
-        # files pass `paged=True` by value and its driver reads `engine.paged`;
-        # ROADMAP B0 removes both.
         if paged is not True:
             raise ValueError(
                 f"paged={paged!r}: the contiguous per-slot KV layout is gone — the "
                 "page pool is the engine's only KV store; drop the argument"
             )
         self.paged = True
-        self.base_config = base
-        # Quantized serving (ops/quantization.py): `weight_dtype="int8"`
-        # quantizes the params ONCE at load/swap time (the `params` setter
-        # below) and routes every Dense through the int8-epilogue matmul;
-        # `kv_cache_dtype` picks the page pool's storage dtype, with
-        # per-page-per-head scales riding the cache collection as traced
-        # operands. Both are static config — dtypes never retrace.
-        from .ops.quantization import KV_CACHE_DTYPES, WEIGHT_DTYPES
-
+        # Static config, both — dtypes never retrace: int8 weights are quantized ONCE at
+        # load/swap time (the `params` setter); a quantized pool adds scale operands.
         self.weight_dtype = str(weight_dtype)
         if self.weight_dtype not in WEIGHT_DTYPES:
             raise ValueError(
@@ -370,89 +810,12 @@ class ContinuousBatcher:
             raise ValueError(
                 f"unknown kv_cache_dtype {kv_cache_dtype!r}; expected one of {KV_CACHE_DTYPES}"
             )
-        # A latent cache (MLA: one `[c | k_pe]` row a token a layer, which the
-        # config says by `decode_kv_row_values`) is read on one device,
-        # unquantized — by the page-walk kernel where it reads the pool of rows
-        # in place, else by the XLA loop (`ops.attention.slot_attention_impl`,
-        # below: latent rows are never staged). The other combinations name
-        # what is missing.
-        latent_row = getattr(base, "decode_kv_row_values", None)
-        if latent_row is not None:
-            family = type(model.module).__name__
-            if self.kv_cache_dtype != "bf16":
-                raise ValueError(
-                    f"kv_cache_dtype={self.kv_cache_dtype!r} with {family}: the quantized pool "
-                    "keeps one scale a page a KV head, and a latent row has no heads — a "
-                    "quantized pool for latent rows is not built; use kv_cache_dtype=\"bf16\""
-                )
-            if int(tp) > 1:
-                raise ValueError(
-                    f"tp={tp} with {family}: every head reads the same latent row, so a "
-                    "tensor-parallel engine needs the row replicated and the absorbed "
-                    "projections split by head (and the experts an \"expert\" axis) — that "
-                    "layout is not built; use tp=1"
-                )
-        # Tensor-parallel decode: one engine spanning a `tp`-device submesh
-        # whose single "model" axis carries the model family's Megatron
-        # column/row-parallel rules (parallel/sharding.py). Weights, the KV
-        # pool (by KV head) and the quantized scale pools are placed sharded;
-        # GSPMD inserts the collectives into the SAME one-decode-executable
-        # programs — page tables, sampling scalars and token operands stay
-        # replicated host pushes, so admissions still never recompile. tp=1
-        # is byte-for-byte the single-device engine (mesh is None).
         self.tp = int(tp)
-        if self.tp < 1:
-            raise ValueError("tp must be >= 1")
-        self.mesh = None
-        self._param_shardings = None
-        self._cache_shardings = None
-        # Sharding-rule source: None / "rules" -> the model family's
-        # hand-written table (the parity oracle); "auto" -> the cost-model
-        # planner (parallel/planner.py) searches the layout from shapes +
-        # mesh topology and emits an equivalent table; an explicit list is a
-        # caller override. The planner call itself happens below, once the
-        # pool geometry it prices is known.
-        self.sharding_mode = "rules" if sharding_rules is None else sharding_rules
-        if isinstance(self.sharding_mode, (list, tuple)):
-            self._tp_rules = list(self.sharding_mode)
-            self.sharding_mode = "explicit"
-        elif self.sharding_mode in ("rules", "auto"):
-            self._tp_rules = list(getattr(model, "sharding_rules", None) or [])
-        else:
-            raise ValueError(
-                f"sharding_rules must be a rules list, None, 'rules' or 'auto'; "
-                f"got {sharding_rules!r}"
-            )
-        self.sharding_plan = None
-        self.sharding_refine_top_k = int(sharding_refine_top_k)
-        if self.tp > 1:
-            from .parallel.sharding import serving_tp_mesh
+        family = self._family = _family_facts(model, self.kv_cache_dtype, self.tp)
+        self.base_config = family.config
 
-            if not self._tp_rules and self.sharding_mode != "auto":
-                raise ValueError(
-                    f"{type(model.module).__name__}'s Model bundle carries no "
-                    "sharding_rules — this model family has no Megatron TP "
-                    "layout to span a mesh with; pass tp=1 or "
-                    "sharding_rules=\"auto\" to let the planner derive one"
-                )
-            kv_heads = getattr(base, "num_key_value_heads", base.num_attention_heads)
-            if kv_heads % self.tp:
-                raise ValueError(
-                    f"tp={self.tp} must divide the model's KV head count "
-                    f"({kv_heads}): the KV pool shards by KV head over the "
-                    "\"model\" axis"
-                )
-            self.mesh = serving_tp_mesh(self.tp, devices=tp_devices, group=tp_group)
-        elif tp_devices is not None or int(tp_group) % jax.device_count():
-            # A tp=1 replica of an in-process fleet (the router hands replica
-            # r `tp_group=r`): pin it to its own device through a 1-device
-            # submesh, so N replicas on an N-chip host do not pile onto chip
-            # 0. Group 0 stays mesh-free — the plain single-device engine.
-            from .parallel.sharding import serving_tp_mesh
-
-            self.mesh = serving_tp_mesh(1, devices=tp_devices, group=tp_group)
         self.num_slots = int(num_slots)
-        self.max_length = int(max_length or base.max_position_embeddings)
+        self.max_length = int(max_length or family.max_positions)
         self.chunk_size = int(chunk_size)
         self.do_sample = do_sample
         self.top_k = top_k
@@ -486,32 +849,21 @@ class ContinuousBatcher:
                     "(the presence update is order-dependent across a verified "
                     "block); disable one of the two"
                 )
-        # Decode/verify attention read: "xla" is the loop over blocks of live
-        # pages (the parity oracle), "pallas_paged" the ops/paged_attention
-        # page-walk kernel, None the engine's choice between them
-        # (`ops.attention.slot_attention_impl`, resolved and checked below once
-        # the cache's shapes are known). Either way the ONE decode executable and the
-        # traced-operand page tables are unchanged — the impl only swaps the
-        # attention read inside the compiled program.
         self.page_size = int(page_size)
         if self.page_size < 1:
             raise ValueError("page_size must be >= 1")
         self.pages_per_slot = -(-self.max_length // self.page_size)
-        # Per-slot logical capacity rounded up to whole pages; columns past
-        # max_length stay masked (exact zeros under the f32 softmax), so
-        # decode is token-identical to a dense max_length row.
+        # A slot's capacity in whole pages; columns past max_length stay masked
+        # (exact zeros under the f32 softmax): token-identical to a dense row.
         self._padded_length = self.pages_per_slot * self.page_size
-        # Default pool: the worst case (every slot at max_length) plus the
-        # scratch page, so admission never waits on pages. Size it DOWN for
-        # real HBM savings: any request mix whose actual token footprint fits
-        # still completes.
+        # Default pool: the worst case (every slot at max_length) plus the scratch
+        # page, so admission never waits on pages. Size it DOWN to save HBM.
         self.num_pages = (
             int(num_pages) if num_pages is not None
             else self.num_slots * self.pages_per_slot + 1
         )
-        # Prefix sharing needs the suffix-only insert to seed presence from the
-        # WHOLE prompt, which the suffix program never sees — repetition-penalty
-        # engines therefore run without prefix reuse.
+        # A suffix-only insert cannot seed presence from the WHOLE prompt:
+        # repetition-penalty engines run without prefix reuse.
         self.use_prefix_cache = bool(prefix_cache)
         self.prefix_cache_disabled_reason: Optional[str] = None
         if not prefix_cache:
@@ -522,239 +874,43 @@ class ContinuousBatcher:
                 "shared-prefix inserts cannot provide"
             )
 
+        # tp=1 on the first device group is byte-for-byte the single-device
+        # engine (mesh is None); `sharding_plan` is the planner's, under "auto".
         params_tree = model.params if "params" in model.params else {"params": model.params}
-        if self.tp > 1 and self.sharding_mode == "auto":
-            # The planner searches the Megatron layout from shapes + mesh
-            # topology, pricing the KV pool at the live cache dtype, and
-            # emits a table the SAME derivation below consumes — swap-in
-            # weights, cache init and the TPU118 audit all behave exactly as
-            # with a hand table. With sharding_refine_top_k > 1, the top-k
-            # candidates are compiled as one-token forwards and the
-            # measured-best wins (cost model proposes, hardware disposes).
-            from .parallel.planner import (
-                measure_forward_step,
-                plan_serving_sharding,
-                refine_plans,
-            )
-
-            top_k = max(1, self.sharding_refine_top_k)
-            planned = plan_serving_sharding(
-                params_tree,
-                self.mesh,
-                base,
-                num_slots=self.num_slots,
-                page_size=self.page_size,
-                num_pages=self.num_pages,
-                kv_cache_dtype=self.kv_cache_dtype,
-                weight_dtype=self.weight_dtype,
-                top_k=top_k,
-            )
-            if self.sharding_refine_top_k >= 1:
-                # refine_top_k=1 still measures: the single candidate gets a
-                # real compiled-forward timing stamped on measured_step_s.
-                best, _ = refine_plans(
-                    planned if isinstance(planned, list) else [planned],
-                    lambda plan: measure_forward_step(
-                        model.apply_fn, params_tree, self.mesh, plan.rules, batch=1
-                    ),
-                )
-                self.sharding_plan = best
-            else:
-                self.sharding_plan = planned
-            self._tp_rules = list(self.sharding_plan.rules)
-
+        self.sharding_refine_top_k = int(sharding_refine_top_k)
+        self.mesh, self._tp_rules, self.sharding_plan, self.sharding_mode = resolve_serving_sharding(
+            model, params_tree, tp=self.tp, tp_devices=tp_devices, tp_group=tp_group,
+            sharding_rules=sharding_rules, sharding_refine_top_k=self.sharding_refine_top_k,
+            kv_heads=family.kv_heads, num_slots=self.num_slots, page_size=self.page_size,
+            num_pages=self.num_pages, kv_cache_dtype=self.kv_cache_dtype, weight_dtype=self.weight_dtype,
+        )
+        self._param_shardings = self._cache_shardings = None
         self.params = params_tree
-        resolve = _params_resolver(model)
-        # Prefill rides the ORDINARY decode-cache path on a batch-1 cache (shared
-        # scalar cache_index); decode steps ride the per-row slot cache. Same
-        # logical cache capacity so the prefilled rows line up for the scatter
-        # into pool pages.
-        cache_len = self._padded_length
-        quant_cfg = {}
-        if self.weight_dtype != "bf16":
-            if not hasattr(base, "weight_dtype"):
-                raise ValueError(
-                    f"{type(model.module).__name__}'s config has no `weight_dtype` "
-                    "field — this model family doesn't support int8 weight-only "
-                    "serving yet"
-                )
-            quant_cfg["weight_dtype"] = self.weight_dtype
-        prefill_cfg = dataclasses.replace(base, decode_cache_length=cache_len, **quant_cfg)
-        prefill_module = type(model.module)(prefill_cfg)
-        self._resolve = resolve
-        self._cached_prefill_raw = make_cached_prefill_program(prefill_module, resolve)
-        # The dense batch-1 cache STRUCTURE the insert materializes by
-        # gathering pool pages (zero compute/compile: eval_shape only). The
-        # weight_autocast wrap matters even for eval_shape: int8 engines
-        # hold quantized kernel entries the raw Dense can't consume.
-        from .ops.quantization import weight_autocast
 
-        dummy = jnp.zeros((1, 1), jnp.int32)
-        dpos = jnp.zeros((1, 1), jnp.int32)
-        with weight_autocast(self.weight_dtype):
-            self._dense_cache_struct = jax.eval_shape(
-                lambda p: prefill_module.apply(resolve(p), dummy, None, dpos, mutable=["cache"])[1]["cache"],
-                self.params,
-            )
-        # What a family keeps a request is read off its cache tree: page
-        # leaves (`cached_key` / `cached_value` / `cached_latent`) and, for a
-        # layer with a recurrence, BY-SLOT leaves (`recurrent_state`,
-        # `conv_state`: utils/operations) — a fixed state a slot that the
-        # insert writes whole and the decode chunk carries and updates. What
-        # is not built for such a state is refused here, by name.
-        self._state_bytes_per_slot = tree_slot_state_nbytes(self._dense_cache_struct)
-        if self._state_bytes_per_slot:
-            family = type(model.module).__name__
-            if self.speculative:
-                raise ValueError(
-                    f"speculative=True with {family}: its cache holds recurrent state by slot, "
-                    "which a verify block advances past drafts it may reject — speculative "
-                    "verify with a state roll-back (speculative.py) is not built; use "
-                    "speculative=False"
-                )
-            if self.tp > 1:
-                raise ValueError(
-                    f"tp={self.tp} with {family}: its cache holds recurrent state by slot, and "
-                    "the cache shardings (parallel/sharding.derive_tp_cache_shardings) place "
-                    "page pools by KV head only — a tensor-parallel layout for by-slot state is "
-                    "not built; use tp=1"
-                )
-            if self.use_prefix_cache:
-                self._disable_prefix_cache(
-                    f"{family} keeps recurrent state by slot, and a shared prefix hands back "
-                    "pages of tokens but not the state at that boundary (state snapshots are "
-                    "not built)"
-                )
-        if self.mesh is not None:
-            # The slot-decode modules carry the submesh so the Pallas page-walk
-            # kernels can shard_map over the KV-head grid; prefill stays
-            # mesh-free in config (its XLA paths partition off the sharded
-            # operands alone).
-            if not hasattr(base, "decode_tp_mesh"):
-                raise ValueError(
-                    f"{type(model.module).__name__}'s config has no "
-                    "`decode_tp_mesh` field — this model family doesn't "
-                    "support tensor-parallel serving yet"
-                )
-            quant_cfg["decode_tp_mesh"] = self.mesh
-        if self.kv_cache_dtype != "bf16":
-            if not hasattr(base, "decode_kv_cache_dtype"):
-                raise ValueError(
-                    f"{type(model.module).__name__}'s config has no "
-                    "`decode_kv_cache_dtype` field — this model family doesn't "
-                    "support the quantized KV page pool yet"
-                )
-            quant_cfg["decode_kv_cache_dtype"] = self.kv_cache_dtype
-        # What the paged read is sized from (`_live_page_counts`) and chosen by:
-        # the prefill cache is K as the model computes it — [1, length, KV
-        # heads, head_dim] in the compute dtype ([1, length, row] for a latent
-        # family), the very operands the read takes its block and its run from.
-        key = _cached_key_leaf(self._dense_cache_struct)
-        kv_heads = key.shape[2] if key.ndim == 4 else 1  # latent rows [1, length, row]: no head axis
-        self.attention_impl = attention_ops.slot_attention_impl(
-            None if attention_impl is None else str(attention_impl), platform=self._home_device.platform,
-            latent=latent_row is not None, tp=self.tp, slots=self.num_slots,
-            pages_per_slot=self.pages_per_slot, page_size=self.page_size,
-            block=self.draft_tokens + 1 if self.speculative else 1,
-            heads=base.num_attention_heads // self.tp, kv_heads=max(1, kv_heads // self.tp),
-            head_dim=key.shape[-1], itemsize=np.dtype(key.dtype).itemsize, kv_cache_dtype=self.kv_cache_dtype,
-        )
-        self._read_shape = (self.pages_per_slot, self.page_size, kv_heads, key.shape[-1],
-                            np.dtype(key.dtype).itemsize, base.num_attention_heads // kv_heads,
-                            self.attention_impl, latent_row is not None)
-        step_cfg = dataclasses.replace(
-            base, decode_cache_length=cache_len, decode_slot_cache=True,
-            decode_page_size=self.page_size, decode_num_pages=self.num_pages,
-            decode_attention_impl=self.attention_impl, **quant_cfg,
-        )
-        step_module = type(model.module)(step_cfg)
-        _, self._step_raw, self._verify_raw = make_causal_programs(
-            step_module, resolve, step_mask_operand=True, verify_block=True
-        )
-        self._step_module = step_module
-
-        self._sample_config = GenerationConfig(do_sample=do_sample, top_k=top_k, top_p=top_p)
-        # Python-side effects run at TRACE time: these count compiles, and the
-        # serving tests pin "decode compiled once across mixed admissions" on them.
+        # Counted at TRACE time: compiles ("decode compiled once across mixed admissions").
         self.trace_counts: Dict[str, int] = {"insert": 0, "decode_chunk": 0}
-        #: `ops.attention.LAST_DISPATCH` as the decode chunk was traced: the
-        #: read its program holds, on `serve.decode_chunk` as `read_impl`.
+        #: `ops.attention.LAST_DISPATCH` as the decode chunk was traced (`serve.decode_chunk.read_impl`).
         self.read_impl: Optional[str] = None
-
-        self._rng = rng if rng is not None else jax.random.key(0)
+        self._sample_config = GenerationConfig(do_sample=do_sample, top_k=top_k, top_p=top_p)
         self._insert_fns: Dict[int, Any] = {}
-        self._chunk_fn = self._build_spec_chunk() if self.speculative else self._build_chunk()
+        self._build_programs(model, attention_impl)
         self._cache = self._init_cache()
-        # Values and stored bytes one token holds in the pool over all layers:
-        # keys and values of full heads ([..., pages, page_size, heads,
-        # head_dim]) or a latent family's one row a layer ([..., pages,
-        # page_size, row]); nn.scan puts its layers in front.
-        held, layers = {"cached_key": 2, "cached_value": 2, "cached_latent": 1}, 0
-        self._kv_bytes_per_token = self.kv_row_values = 0
-        for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]:
-            trailing = held.get(_leaf_name(path))
-            if trailing is None:
-                continue
-            stacked = int(np.prod(leaf.shape[: leaf.ndim - trailing - 2]))
-            values = stacked * int(np.prod(leaf.shape[leaf.ndim - trailing:]))
-            self.kv_row_values += values
-            self._kv_bytes_per_token += values * np.dtype(leaf.dtype).itemsize
-            layers += stacked * (_leaf_name(path) != "cached_value")
-        self.kv_row_values //= layers  # a layer's: 2 x KV heads x head_dim, or the latent row
+        self._kv_bytes_per_token, self.kv_row_values = _pool_token_sizes(self._cache)
         counts = _expert_token_counts(self._cache)
         self._expert_layers = 0 if counts is None else int(counts.shape[0])
-        # A family whose residual path is several streams says so by `hc_mult`,
-        # and how many mixes a token passes by `hc_sublayers`.
-        self._hc_streams = int(getattr(self.base_config, "hc_mult", 1))
-        self._hc_sublayers = int(getattr(self.base_config, "hc_sublayers", 0))
-        self._rng = self._carried(self._rng)
+        # Device values that programs donate. `_first_token`: where an insert leaves
+        # its sampled token, by slot — the next chunk starts from it and hands it back
+        # (`_Flight.read["first"]`). `_carry`: `(token, pos, active, rem)` as the last
+        # dispatched chunk returned them — maybe not computed yet.
+        self._rng = self._carried(rng if rng is not None else jax.random.key(0))
         self._presence = self._new_presence()
-        # Where an insert leaves its sampled token, by slot: the decode chunk
-        # dispatched next starts from it and hands it back among its own
-        # outputs (`_Flight.read["first"]`), which _drain() gives the request.
-        # Donated through every insert.
         self._first_token = self._new_first_token()
-
-        S = self.num_slots
-        # Slot state lives ON THE DEVICE from chunk to chunk: `_carry` is
-        # `(token, pos, active, rem)` as the last dispatched chunk returned
-        # them — maybe not computed yet: the next chunk is dispatched on them.
         self._carry = self._new_carry()
-        # The host keeps a PREDICTED mirror of `pos`, `active`, `rem`: what
-        # they will be once every dispatched chunk has run (_predict), put
-        # right at the drain where a request stopped on an EOS the host had
-        # not seen. Only the rows the host itself changed since the last
-        # dispatch — `_changed`; admissions also `_from_buffer` — are pushed,
-        # as the chunk's `update` operand; a step that changed none pushes
-        # `_no_update`, made once.
-        self._pos = np.zeros(S, np.int32)
-        self._active = np.zeros(S, bool)
-        self._rem = np.zeros(S, np.int32)
-        self._changed = np.zeros(S, bool)
-        self._from_buffer = np.zeros(S, bool)
-        self._no_update = jnp.zeros((5, S), jnp.int32)
-        # What only the host writes (`eos`, `temperature`, `penalty`, the page
-        # table): a host mirror each, its copy on the device in `_pushed`, and
-        # its name in `_stale` when the mirror has changed since that copy.
-        self._eos = np.full(S, -1, np.int32)
-        self._temp = np.ones(S, np.float32)
-        self._pen = np.ones(S, np.float32)
-        self._pushed: Dict[str, Any] = {}
-        self._stale = {"eos", "temp", "pen", "table"}
-        # Per-slot page tables: all-zeros rows point at the scratch page, so a
-        # freed/inactive slot's discarded decode writes can never land in a
-        # live request's pages.
-        self._page_table = np.zeros((S, self.pages_per_slot), np.int32)
-        self._slot_pages: List[List[int]] = [[] for _ in range(S)]
-        # Speculative engines: host mirror of each slot's observed context
-        # (prompt + generated, packed from index 0), pushed as a traced operand
-        # each chunk dispatch — the same mirror discipline as _token/_pos. The
-        # device updates its copy inside the scan (drafts must see tokens
-        # emitted earlier in the SAME chunk); the host re-derives identical
-        # content from the drained stream, so nothing is ever read back.
-        self._history = np.zeros((S, self.max_length if self.speculative else 1), np.int32)
-
-        self._slot_request: List[Optional[RequestResult]] = [None] * S
+        self._slots = _SlotMirror(
+            self.num_slots, self.pages_per_slot, self.max_length if self.speculative else None
+        )
+        self._slot_pages: List[List[int]] = [[] for _ in range(self.num_slots)]
+        self._slot_request: List[Optional[RequestResult]] = [None] * self.num_slots
         self._queue: deque = deque()
         self.results: Dict[int, RequestResult] = {}
         self.max_queue = None if max_queue is None else int(max_queue)
@@ -763,192 +919,22 @@ class ContinuousBatcher:
         self._deadlines: Dict[int, float] = {}  # request_id -> absolute perf_counter deadline
         self._closed = False
         self._draining = False
-        # Optional analysis.TraceGuard (assignable after construction too): the
-        # engine's fault isolation swallows per-step exceptions, so guarded
-        # transfer violations are `observe()`d before being isolated — the
-        # analysis ledger sees them even though serving keeps running.
+        # Optional analysis.TraceGuard (assignable later too): fault isolation
+        # swallows a step's exceptions, so guarded transfer violations are
+        # `observe()`d first — the analysis ledger sees them while serving goes on.
         self.trace_guard = trace_guard
-        # Telemetry: every health counter lives in a MetricsRegistry (shareable
-        # with the Accelerator's, exportable via telemetry.export); the public
-        # `stats` dict is now a read-only VIEW over these instruments. All
-        # updates are host-scalar arithmetic — nothing here syncs the device.
+        # Every health counter lives in a MetricsRegistry (shareable, exportable);
+        # `stats` is a read-only VIEW over it. Host-scalar arithmetic, no device sync.
         self.metrics = registry if registry is not None else MetricsRegistry()
-        self._m_submitted = self.metrics.counter(
-            "serving_requests_submitted_total", help="requests accepted by submit()"
-        )
-        self._m_inserts = self.metrics.counter(
-            "serving_inserts_total", help="successful insert (prefill+admit) dispatches"
-        )
-        self._m_chunks = self.metrics.counter(
-            "serving_chunks_total", help="decode-chunk dispatches"
-        )
-        self._m_decode_steps = self.metrics.counter(
-            "serving_decode_steps_total", help="decode loop iterations (chunks * chunk_size)"
-        )
-        self._m_finish = {
-            reason: self.metrics.counter(
-                "serving_requests_finished_total",
-                help="finished requests by finish_reason",
-                labels={"reason": reason},
+        self._register_instruments(_INSTRUMENTS)
+        if self.speculative:
+            self._register_instruments(
+                _SPECULATIVE_INSTRUMENTS, buckets=[float(i) for i in range(1, self.draft_tokens + 2)]
             )
-            for reason in FINISH_REASONS
-        }
-        self._m_queue_depth = self.metrics.gauge(
-            "serving_queue_depth", help="requests waiting for a slot"
-        )
-        self._m_queue_peak = self.metrics.gauge(
-            "serving_queue_peak",
-            help="queue-depth high-water mark (sized against max_queue)",
-        )
-        self._m_slots_in_use = self.metrics.gauge(
-            "serving_slots_in_use", help="slots occupied by in-flight requests"
-        )
-        self._m_slot_utilization = self.metrics.gauge(
-            "serving_slot_utilization", help="slots_in_use / num_slots"
-        )
-        self._m_ttft = self.metrics.histogram(
-            "serving_ttft_seconds",
-            help="submit() -> the step() that carries the first token returns (host wall clock)",
-        )
-        self._m_inter_token = self.metrics.histogram(
-            "serving_inter_token_seconds",
-            help="per-token gap between stream drains for an in-flight slot",
-        )
-        self._m_chunk_latency = self.metrics.histogram(
-            "serving_chunk_seconds",
-            help="one decode chunk's cadence (`serve.decode_chunk.cadence_s`): since the previous readback "
-            "returned, at most operand push to readback — one chunk and the inserts enqueued ahead of it, "
-            "whether or not it was dispatched while its predecessor ran",
-        )
-        self._m_device_waits = self.metrics.counter(
-            "serving_device_waits_total",
-            help="blocking device reads: one a step() that had a dispatched program to read back",
-        )
-        self._m_dispatching_steps = self.metrics.counter(
-            "serving_dispatching_steps_total",
-            help="step() calls that had a program to read back: their own inserts and chunk, or "
-            "the chunk the step before left in flight (not the step that only starts running ahead)",
-        )
-        self._m_chunks_ahead = self.metrics.counter(
-            "serving_chunks_ahead_total",
-            help="decode chunks dispatched while their predecessor was still running",
-        )
-        self._m_chunks_ahead_share = self.metrics.gauge(
-            "serving_chunks_ahead_share",
-            help="serving_chunks_ahead_total over serving_chunks_total: ~1 under a backlog, "
-            "~0 with an empty queue",
-        )
-        self._m_lost_to_eos = self.metrics.counter(
-            "serving_slot_chunks_lost_to_eos_total",
-            help="chunks a slot sat inactive because its request stopped on an EOS the host "
-            "had not yet seen when it dispatched the next chunk",
-        )
-        self._m_starved = {
-            cause: self.metrics.counter(
-                "serving_device_starved_seconds_total",
-                help="wall time the engine knew the device had nothing enqueued, by what the host was "
-                "doing (a lower bound of the device's idle time: the readback's latency and a launch's "
-                "tail are not in it); `no_work` is the offered load's idle, the rest the host's",
-                labels={"cause": cause},
-            )
-            for cause in STARVED_CAUSES
-        }
-        # The account of a starved device (step()): since when the engine has
-        # known the device EMPTY — no chunk in flight, no insert dispatched
-        # since the last readback returned — or None while something it
-        # enqueued is unread; one mark, moved on by every charge.
-        self._empty_since: Optional[float] = None
-        self._first_step_at: Optional[float] = None
-        self._step_returned_at: Optional[float] = None
-        self._gap_cause = "no_work"  # whose the gap after the last step() is, where nothing covers it
-        self._starved_in_step: Dict[str, float] = {}
-        self._read_back_at: Optional[float] = None  # when the last readback returned
-
-        # Tracing (telemetry.tracing): one `serve.request` span per accepted
-        # request from submit() to its terminal finish_reason, and one
-        # `serve.step` tree per step() — see step(). Everything is host-clock
-        # arithmetic — the spans ride the same zero-device-sync discipline as
-        # the metrics (and TPU112 lints the annotations).
-        self.tracer = tracer if tracer is not None else default_tracer()
-        self._request_spans: Dict[int, Any] = {}
-        # Slots admitted since the last chunk was dispatched, in admission
-        # order, whose first tokens are still on the device (`_first_token`):
-        # the next chunk's `_Flight` takes the list; a step that dispatches no
-        # chunk reads the buffer itself and _drain() clears it. Each with when
-        # it was admitted (perf_counter) and how many admissions of its step
-        # came before it, for the request's `handed_back` event.
-        self._fresh: List[Tuple[int, float, int]] = []
-        # Decode chunks dispatched and not yet read back, oldest first: two at
-        # most inside a step() that runs ahead, one at most when it returns.
-        self._flights: deque = deque()
-        # When a request's tokens last reached the host, by request id.
-        self._last_event: Dict[int, float] = {}
-        # Requests whose first token reached the host in the step() now
-        # running — each with its `_fresh` entry's admission and whether a
-        # chunk's readback brought the token: handed back, and timed, when it
-        # returns (_hand_back).
-        self._first_tokens: List[Tuple[RequestResult, float, int, bool]] = []
-
-        # Page-pool + prefix-cache telemetry and the host allocator itself
-        # (all updates are host-scalar arithmetic).
-        self._m_pages_total = self.metrics.gauge(
-            "serving_pages_total", help="usable KV pool pages (excludes the scratch page)"
-        )
-        self._m_pages_in_use = self.metrics.gauge(
-            "serving_pages_in_use", help="pool pages referenced by in-flight requests"
-        )
-        self._m_kv_live_page_share = self.metrics.gauge(
-            "serving_kv_live_page_share",
-            help="live pages of the active slots over num_slots * pages_per_slot, "
-            "as the last decode chunk was dispatched: the share of the window "
-            "the paged XLA read visits",
-        )
-        self._m_kv_bytes_per_token = self.metrics.gauge(
-            "serving_kv_bytes_per_token",
-            help="stored bytes one token holds in the page pool, all layers: keys and "
-            "values of full heads, or a latent family's one row a layer",
-        )
         self._m_kv_bytes_per_token.set(self._kv_bytes_per_token)
-        self._m_state_bytes_per_slot = self.metrics.gauge(
-            "serving_state_bytes_per_slot",
-            help="stored bytes a slot holds in by-slot leaves (recurrent and convolution "
-            "state of layers with a recurrence), all layers, whatever the request's length "
-            "(0 for a family that keeps pages alone)",
-        )
         self._m_state_bytes_per_slot.set(self._state_bytes_per_slot)
-        self._m_state_share = self.metrics.gauge(
-            "serving_state_share_of_cache",
-            help="by-slot state of the active slots over that state plus their live pages' "
-            "bytes, as the last decode chunk was dispatched",
-        )
-        self._m_expert_load = self.metrics.gauge(
-            "serving_expert_load_max_over_mean",
-            help="the last decode chunk's tokens of the busiest routed expert over the "
-            "mean expert's, layers averaged: what dropless routing pays under imbalance "
-            "(0 for a family without routed experts)",
-        )
-        self._m_residual_streams = self.metrics.gauge(
-            "serving_residual_streams",
-            help="streams of the served family's residual path (1: the plain residual; more: "
-            "hyper-connections mix them around every sub-layer, `serve.insert.hc_rows`)",
-        )
-        self._m_residual_streams.set(self._hc_streams)
-        self._m_prefix_hits = self.metrics.counter(
-            "serving_prefix_cache_hits_total",
-            help="prompt pages served from the shared-prefix cache",
-        )
-        self._m_prefix_misses = self.metrics.counter(
-            "serving_prefix_cache_misses_total",
-            help="full prompt pages that had to be prefilled (no cached prefix)",
-        )
-        self._m_prefix_evictions = self.metrics.counter(
-            "serving_prefix_cache_evictions_total",
-            help="unreferenced cached prefix pages reclaimed by the allocator",
-        )
-        self._m_prefill_saved = self.metrics.counter(
-            "prefill_tokens_saved_total",
-            help="prompt tokens whose prefill FLOPs the prefix cache skipped",
-        )
+        self._m_residual_streams.set(family.residual_streams)
+        self._starved = _StarvedAccount(self._m_starved)
         self.pool = PagePool(
             self.num_pages, self.page_size,
             on_evict=self._m_prefix_evictions.inc,
@@ -956,34 +942,131 @@ class ContinuousBatcher:
         )
         self._m_pages_total.set(self.pool.pages_total)
 
-        # Speculative-decode telemetry (host-scalar arithmetic over the chunk
-        # readback; docs/observability.md documents the instruments). The
-        # headline derived number — accepted_tokens_per_step — is
-        # (verify_steps + accepted) / verify_steps, surfaced in `stats`.
-        if self.speculative:
-            self._m_spec_steps = self.metrics.counter(
-                "serving_spec_verify_steps_total",
-                help="verify-block loop iterations with an active slot (each emits >= 1 token)",
-            )
-            self._m_spec_drafted = self.metrics.counter(
-                "serving_spec_draft_tokens_total",
-                help="draft tokens proposed by the n-gram drafter (valid proposals only)",
-            )
-            self._m_spec_accepted = self.metrics.counter(
-                "serving_spec_accepted_draft_tokens_total",
-                help="draft tokens confirmed by verification and emitted",
-            )
-            self._m_spec_rejected = self.metrics.counter(
-                "serving_spec_rejected_draft_tokens_total",
-                help="draft tokens the verify step discarded",
-            )
-            self._m_spec_hist = self.metrics.histogram(
-                "serving_spec_accepted_tokens",
-                help="tokens emitted per verify step (accepted drafts + 1 bonus)",
-                buckets=[float(i) for i in range(1, self.draft_tokens + 2)],
-            )
+        # One `serve.request` span a request, submit() to its finish_reason, and one
+        # `serve.step` tree a step(): host-clock arithmetic (TPU112 lints the annotations).
+        self.tracer = tracer if tracer is not None else default_tracer()
+        self._request_spans: Dict[int, Any] = {}
+        # Slots admitted since the last chunk's dispatch, in order, whose first tokens
+        # are still on the device: the next `_Flight` takes the list (a step with no
+        # chunk reads the buffer itself). With when each was admitted and how many
+        # admissions of its step came before it, for `handed_back`.
+        self._fresh: List[Tuple[int, float, int]] = []
+        # Decode chunks dispatched and not yet read back, oldest first: two at
+        # most inside a step() that runs ahead, one at most when it returns.
+        self._flights: deque = deque()
+        # When a request's tokens last reached the host, by request id.
+        self._last_event: Dict[int, float] = {}
+        # Requests whose first token reached the host in the step() now running, with
+        # their `_fresh` entry and whether a chunk brought it (_hand_back).
+        self._first_tokens: List[Tuple[RequestResult, float, int, bool]] = []
 
     # ------------------------------------------------------------------ programs
+
+    def _register_instruments(self, table, buckets=None):
+        """Create (or find, in a shared registry) every instrument of `table`
+        and keep it as the attribute the table names: one instrument, or one a
+        label value in a dict. `buckets` are the table's histograms'."""
+        for attr, kind, name, help_text, *by in table:
+            make = getattr(self.metrics, kind)
+            extra = {} if buckets is None or kind != "histogram" else {"buckets": buckets}
+            if by:
+                label, values = by[0]
+                made = {v: make(name, help=help_text, labels={label: v}, **extra) for v in values}
+            else:
+                made = make(name, help=help_text, **extra)
+            setattr(self, attr, made)
+
+    def _build_programs(self, model, attention_impl: Optional[str]):
+        """The engine's modules and their programs' raw functions, from the
+        model's own module class under two configs: prefill rides the ORDINARY
+        decode-cache path on a batch-1 cache (shared scalar cache_index);
+        decode steps ride the per-row slot cache. Same logical cache capacity,
+        so the prefilled rows line up for the scatter into pool pages. What a
+        family's cache tree shows and the engine has not built is refused
+        here, by name."""
+        family, base = self._family, self.base_config
+        resolve = self._resolve = _params_resolver(model)
+        cache_len = self._padded_length
+        fields = family.fields(weight_dtype=self.weight_dtype) if self.weight_dtype != "bf16" else {}
+        prefill_module = type(model.module)(dataclasses.replace(base, decode_cache_length=cache_len, **fields))
+        self._cached_prefill_raw = make_cached_prefill_program(prefill_module, resolve)
+        # The dense batch-1 cache STRUCTURE the insert materializes by
+        # gathering pool pages (zero compute/compile: eval_shape only). The
+        # weight_autocast wrap matters even for eval_shape: int8 engines
+        # hold quantized kernel entries the raw Dense can't consume.
+        dummy = jnp.zeros((1, 1), jnp.int32)
+        with weight_autocast(self.weight_dtype):
+            self._dense_cache_struct = jax.eval_shape(
+                lambda p: prefill_module.apply(resolve(p), dummy, None, dummy, mutable=["cache"])[1]["cache"],
+                self.params,
+            )
+        # What a family keeps a request is read off its cache tree: page
+        # leaves (`cached_key` / `cached_value` / `cached_latent`) and, for a
+        # layer with a recurrence, BY-SLOT leaves (`recurrent_state`,
+        # `conv_state`: utils/operations) — a fixed state a slot that the
+        # insert writes whole and the decode chunk carries and updates.
+        self._state_bytes_per_slot = tree_slot_state_nbytes(self._dense_cache_struct)
+        if self._state_bytes_per_slot:
+            if self.speculative:
+                raise ValueError(
+                    f"speculative=True with {family.name}: its cache holds recurrent state by slot, "
+                    "which a verify block advances past drafts it may reject — speculative "
+                    "verify with a state roll-back (speculative.py) is not built; use "
+                    "speculative=False"
+                )
+            if self.tp > 1:
+                raise ValueError(
+                    f"tp={self.tp} with {family.name}: its cache holds recurrent state by slot, and "
+                    "the cache shardings (parallel/sharding.derive_tp_cache_shardings) place "
+                    "page pools by KV head only — a tensor-parallel layout for by-slot state is "
+                    "not built; use tp=1"
+                )
+            if self.use_prefix_cache:
+                self._disable_prefix_cache(
+                    f"{family.name} keeps recurrent state by slot, and a shared prefix hands back "
+                    "pages of tokens but not the state at that boundary (state snapshots are "
+                    "not built)"
+                )
+        if self.mesh is not None:
+            # The slot-decode modules carry the submesh so the Pallas page-walk
+            # kernels can shard_map over the KV-head grid; prefill stays
+            # mesh-free in config (its XLA paths partition off the sharded
+            # operands alone).
+            fields.update(family.fields(decode_tp_mesh=self.mesh))
+        if self.kv_cache_dtype != "bf16":
+            fields.update(family.fields(decode_kv_cache_dtype=self.kv_cache_dtype))
+        # What the paged read is sized from (`_live_page_counts`) and chosen by:
+        # the prefill cache is K as the model computes it — [1, length, KV
+        # heads, head_dim] in the compute dtype ([1, length, row] for a latent
+        # family), the very operands the read takes its block and its run from.
+        # "xla" is the loop over blocks of live pages (the parity oracle),
+        # "pallas_paged" the ops/paged_attention page-walk kernel, None the
+        # engine's choice between them (`ops.attention.slot_attention_impl`).
+        # Either way the ONE decode executable and the traced-operand page
+        # tables are unchanged — the impl only swaps the attention read inside
+        # the compiled program.
+        key = _cached_key_leaf(self._dense_cache_struct)
+        kv_heads = key.shape[2] if key.ndim == 4 else 1  # latent rows [1, length, row]: no head axis
+        self.attention_impl = attention_ops.slot_attention_impl(
+            None if attention_impl is None else str(attention_impl), platform=self._home_device.platform,
+            latent=family.latent, tp=self.tp, slots=self.num_slots,
+            pages_per_slot=self.pages_per_slot, page_size=self.page_size,
+            block=self.draft_tokens + 1 if self.speculative else 1,
+            heads=family.heads // self.tp, kv_heads=max(1, kv_heads // self.tp),
+            head_dim=key.shape[-1], itemsize=np.dtype(key.dtype).itemsize, kv_cache_dtype=self.kv_cache_dtype,
+        )
+        self._read_shape = (self.pages_per_slot, self.page_size, kv_heads, key.shape[-1],
+                            np.dtype(key.dtype).itemsize, family.heads // kv_heads,
+                            self.attention_impl, family.latent)
+        self._step_module = type(model.module)(dataclasses.replace(
+            base, decode_cache_length=cache_len, decode_slot_cache=True,
+            decode_page_size=self.page_size, decode_num_pages=self.num_pages,
+            decode_attention_impl=self.attention_impl, **fields,
+        ))
+        _, self._step_raw, self._verify_raw = make_causal_programs(
+            self._step_module, resolve, step_mask_operand=True, verify_block=True
+        )
+        self._chunk_fn = self._build_spec_chunk() if self.speculative else self._build_chunk()
 
     @property
     def params(self):
@@ -1047,7 +1130,7 @@ class ContinuousBatcher:
         """Zeroed `bool[num_slots, vocab]` seen-token rows of a penalty engine."""
         if not self.use_repetition_penalty:
             return None
-        return self._carried(jnp.zeros((self.num_slots, self.base_config.vocab_size), bool))
+        return self._carried(jnp.zeros((self.num_slots, self._family.vocab_size), bool))
 
     def _new_first_token(self):
         """The zeroed `int32[num_slots]` first-token buffer."""
@@ -1065,8 +1148,6 @@ class ContinuousBatcher:
         throwaway executable at engine construction) and materialize them as
         zeros. Correct because every slot's pages are overwritten by insert
         before they're ever attended."""
-        from .ops.quantization import weight_autocast
-
         S = self.num_slots
         module, resolve = self._step_module, self._resolve
         dummy = jnp.zeros((S, 1), jnp.int32)
@@ -1194,7 +1275,7 @@ class ContinuousBatcher:
         dense_struct = self._dense_cache_struct
         use_pen = self.use_repetition_penalty
         config = self._sample_config
-        V = self.base_config.vocab_size
+        V = self._family.vocab_size
         P = self.pages_per_slot
         mesh = self.mesh
         recurrent = bool(self._state_bytes_per_slot)
@@ -1552,7 +1633,7 @@ class ContinuousBatcher:
             # ~1 under a backlog, ~0 with an empty queue (step()).
             "chunks_ahead_share": float(self._m_chunks_ahead_share.value),
             "slot_chunks_lost_to_eos": int(self._m_lost_to_eos.value),
-            "device_starved": self._device_starved(),
+            "device_starved": self._starved.view(),
             "run_ahead": {
                 "enabled": self.run_ahead_disabled_reason is None,
                 "disabled_reason": self.run_ahead_disabled_reason,
@@ -1584,8 +1665,8 @@ class ContinuousBatcher:
             view["state_share_of_cache"] = float(self._m_state_share.value)
         if self._expert_layers:
             view["expert_load_max_over_mean"] = float(self._m_expert_load.value)
-        if self._hc_streams > 1:
-            view["residual_streams"] = self._hc_streams
+        if self._family.residual_streams > 1:
+            view["residual_streams"] = self._family.residual_streams
         view["prefix_cache"] = {
             "enabled": self.use_prefix_cache,
             "disabled_reason": self.prefix_cache_disabled_reason,
@@ -1597,28 +1678,6 @@ class ContinuousBatcher:
             "cached_pages": self.pool.pages_cached,
         }
         return view
-
-    def _device_starved(self) -> Dict[str, Optional[float]]:
-        """`stats["device_starved"]`: the seconds the engine knew the device
-        had nothing enqueued, by cause (`serving_device_starved_seconds_total`),
-        and `share`: the host's part of them — every cause but `no_work` —
-        over the wall since the first step(). A lower bound of the device's
-        idle share: what the device idles inside a wait (the readback's
-        latency, a launch's tail) the host cannot see."""
-        view: Dict[str, Optional[float]] = {cause: c.value for cause, c in self._m_starved.items()}
-        wall = time.perf_counter() - self._first_step_at if self._first_step_at is not None else 0.0
-        view["share"] = (sum(view.values()) - view["no_work"]) / wall if wall > 0 else None
-        return view
-
-    def _charge(self, cause: str, now: float):
-        """Charge `cause` the wall time since the mark, where the device was
-        EMPTY through it (`_empty_since`), and move the mark to `now`."""
-        if self._empty_since is None:
-            return
-        seconds = now - self._empty_since
-        self._empty_since = now
-        self._m_starved[cause].inc(seconds)
-        self._starved_in_step[cause] = self._starved_in_step.get(cause, 0.0) + seconds
 
     def _update_occupancy_gauges(self):
         """Refresh the point-in-time gauges (queue depth, slot occupancy) —
@@ -1721,7 +1780,7 @@ class ContinuousBatcher:
             condemned += [r for r in flight.tenants if r is not None and not r.finished]
             flight.span.annotate(error=repr(exc)).end()
         self._flights.clear()
-        self._empty_since = now  # nothing the engine enqueued is left to read
+        self._starved.all_read(now)
         self.tracer.event(
             "serve.blast_radius", category="serve",
             errored_requests=len({id(r) for r in condemned}),
@@ -1737,22 +1796,15 @@ class ContinuousBatcher:
         self._cache = self._init_cache()
         self._first_token = self._new_first_token()
         self._presence = self._new_presence()
-        # Every slot idle on the device too; nothing of the host's is ahead of it.
+        # Every slot idle on the device too, and on the host: a speculative
+        # engine's drafting contexts belonged to requests that just errored.
         self._carry = self._new_carry()
-        self._pos[:] = self._rem[:] = 0
-        self._active[:] = self._changed[:] = self._from_buffer[:] = False
-        if self.speculative:
-            # The speculative state dies with the cache: every slot's drafting
-            # context belonged to a request that just errored. Admissions
-            # reseed their own rows.
-            self._history[:] = 0
+        self._slots.reset()
         # The pool CONTENT died with the donated buffers: every refcount,
         # page-table row and — critically — prefix registration goes with
         # it (a stale hash->page mapping would serve zeroed KV as a
         # "cached" prefix to the next shared-prompt request).
         self.pool.reset()
-        self._page_table[:] = SCRATCH_PAGE
-        self._stale.add("table")
         self._slot_pages = [[] for _ in range(self.num_slots)]
         self._m_pages_in_use.set(0)
 
@@ -1769,23 +1821,14 @@ class ContinuousBatcher:
         has run is vacated at that dispatch (_predict), and the insert of its
         next tenant is enqueued behind the chunk, so the device's program
         order keeps the old tenant's last writes ahead of the new one's. The
-        next dispatch tells the device (`_changed`)."""
+        next dispatch tells the device (`_SlotMirror.vacate`)."""
         self._slot_request[slot] = None
-        self._active[slot] = False
-        # An idle slot sits at position 0: the XLA read takes a row's
-        # live pages from its position, and a released slot left at its
-        # last one would pass for that many pages of scratch.
-        self._pos[slot] = self._rem[slot] = 0
-        self._changed[slot], self._from_buffer[slot] = True, False
+        self._slots.vacate(slot, held_pages=bool(self._slot_pages[slot]))
         # Release the slot's page references (a shared prefix page
-        # drops to CACHED at refcount 0, private pages go free) and
-        # point the table row at the scratch page so any residual
-        # write for this row is discarded.
+        # drops to CACHED at refcount 0, private pages go free).
         if self._slot_pages[slot]:
             self.pool.release(self._slot_pages[slot])
             self._slot_pages[slot] = []
-            self._page_table[slot] = SCRATCH_PAGE
-            self._stale.add("table")
 
     def _finish(self, result: RequestResult, reason: str, now: Optional[float] = None,
                 slot: Optional[int] = None, error: Optional[str] = None):
@@ -1949,8 +1992,8 @@ class ContinuousBatcher:
                     request_id=int(req.request_id), slot=slot, bucket=int(bucket),
                     suffix_tokens=int(p - matched_len), prefix_hit_pages=int(matched_pages),
                     head_rows=1,
-                    **self._routed_pairs(bucket), **self._scan_chunks(bucket), **self._hc_rows(bucket),
-                    **self._attn_key_blocks(matched_len, bucket),
+                    **self._family.config.insert_span_counts(
+                        int(bucket), int(p - matched_len), int(matched_len), self._padded_length),
                 ):
                     fn = self._insert_fn(bucket)
                     self._first_token, self._cache, self._presence, self._rng = fn(
@@ -1968,11 +2011,10 @@ class ContinuousBatcher:
                         self._rng,
                         self._first_token,
                     )
-                # The device has work from here on: what it sat empty until now
-                # is the admission's (a step's FIRST insert alone can charge).
+                # What the device sat empty until now is the admission's (a
+                # step's FIRST insert alone can charge).
                 result.insert_dispatched_time = time.perf_counter()
-                self._charge("admit", result.insert_dispatched_time)
-                self._empty_since = None
+                self._starved.enqueued("admit", result.insert_dispatched_time)
             except Exception as exc:  # noqa: BLE001 — isolate, report, keep serving
                 self.pool.release(pages)
                 if self.trace_guard is not None:
@@ -2004,79 +2046,14 @@ class ContinuousBatcher:
             self._fresh.append((slot, admitted_at, len(self._fresh) - fresh_before))
             self._slot_request[slot] = result
             self._slot_pages[slot] = pages
-            # The device is told of the slot with the next dispatch: its state
-            # from the host, its token from the buffer.
-            self._changed[slot] = self._from_buffer[slot] = True
-            self._rem[slot] = req.max_new_tokens - 1
-            self._set_row("eos", self._eos, slot, -1 if req.eos_token_id is None else int(req.eos_token_id))
-            if self._rem[slot] > 0:
-                self._pos[slot] = p  # the first generated token's write position
-                self._active[slot] = True
-                self._set_row("temp", self._temp, slot, req.temperature)
-                self._set_row("pen", self._pen, slot, req.repetition_penalty)
-                if self.speculative:
-                    # Seed the drafter's context: full prompt (prefix-cache
-                    # hits included — the host has the whole prompt even when
-                    # the insert only saw the suffix). The chunk puts the
-                    # first token at [slot, p] on the device, _drain() here.
-                    self._history[slot, :p] = ids
-                    self._history[slot, p:] = 0
-                self._page_table[slot] = page_row
-                self._stale.add("table")
-            # else a one-token request: the chunk sees an idle slot (position
-            # 0, the scratch row); it is vacated when that chunk is dispatched
-            # and finished when its token is drained — a prefix it just
-            # registered stays CACHED for the next hit.
+            # A one-token request (nothing left to decode) is vacated when the
+            # next chunk is dispatched and finished when its token is drained —
+            # a prefix it just registered stays CACHED for the next hit.
+            self._slots.admit(
+                slot, ids, req.max_new_tokens - 1, -1 if req.eos_token_id is None else int(req.eos_token_id),
+                req.temperature, req.repetition_penalty, page_row,
+            )
         self._update_occupancy_gauges()
-
-    def _set_row(self, name: str, mirror: np.ndarray, slot: int, value):
-        """Write one slot's entry of a host-only operand's mirror; the device's
-        copy goes stale only where the value is new."""
-        value = mirror.dtype.type(value)
-        if mirror[slot] != value:
-            mirror[slot] = value
-            self._stale.add(name)
-
-    def _routed_pairs(self, bucket: int) -> Dict[str, int]:
-        """`routed_pairs` of an insert, for its span: the (token, expert) pairs
-        its bucket sends through the routed experts, pads included — dropless
-        routing computes every one. Nothing for a family without experts."""
-        if not self._expert_layers:
-            return {}
-        return {"routed_pairs": int(bucket) * int(self.base_config.num_experts_per_tok) * self._expert_layers}
-
-    def _hc_rows(self, rows: int) -> Dict[str, int]:
-        """`hc_streams` and `hc_rows` of an insert or a chunk, for its span: the
-        residual streams, and the rows their mixes process — the program's
-        rows (an insert's bucket, pads included; a chunk's busy slots times
-        its steps) times the sub-layers, two a layer. Nothing for a plain
-        residual."""
-        if not self._hc_sublayers:
-            return {}
-        return {"hc_streams": self._hc_streams, "hc_rows": int(rows) * self._hc_sublayers}
-
-    def _attn_key_blocks(self, matched_len: int, bucket: int) -> Dict[str, int]:
-        """`attn_key_blocks` and `attn_key_blocks_window` of an insert, for its
-        span: the key blocks its attention visits a layer and a head — those at
-        or before each block of query rows' causal frontier, behind
-        `matched_len` cached positions — and the blocks the slot's window holds
-        (host arithmetic over the kernel's block sizes). Nothing for a family
-        whose prefill scores the whole window under a mask: the config says
-        which it is (`prefill_key_blocks`), from what the module itself asks."""
-        count = getattr(self.base_config, "prefill_key_blocks", None)
-        blocks = count(int(matched_len), int(bucket), self._padded_length) if count else None
-        if blocks is None:
-            return {}
-        return {"attn_key_blocks": blocks[0], "attn_key_blocks_window": blocks[1]}
-
-    def _scan_chunks(self, bucket: int) -> Dict[str, int]:
-        """`scan_chunks` of an insert, for its span: the chunks its bucket is
-        for a layer's chunked recurrence, pads included — chunks of the served
-        family's own size (`decode_scan_chunk` of its config). Nothing for a
-        family without recurrent state."""
-        if not self._state_bytes_per_slot:
-            return {}
-        return {"scan_chunks": -(-int(bucket) // int(self.base_config.decode_scan_chunk))}
 
     def _hand_back(self):
         """Runs as step() returns, which is when a client gets the first token
@@ -2127,38 +2104,14 @@ class ContinuousBatcher:
         """The decode-chunk dispatch's operand list. All of it is on the
         device already — params, the donated cache/presence, the rng, the
         inserts' first tokens, the slot state its predecessor returned
-        (`_carry`), the host-only operands' copies (`_pushed`) — but what the
-        host changed since the last dispatch: the `_stale` operands are pushed
-        again, and the `_changed` slots' rows go in `update`
-        (`_merge_slot_updates`). A step that changed no slot pushes nothing."""
-        mirrors = {"eos": self._eos, "temp": self._temp, "pen": self._pen, "table": self._page_table}
-        for name in self._stale:
-            # A copy of the mirror: on a CPU the device array may alias the
-            # numpy buffer it was made from (jnp.array too), and the host
-            # writes the mirror in place while a chunk that reads this is
-            # still running.
-            self._pushed[name] = jnp.asarray(mirrors[name].copy())
-        self._stale.clear()
-        update = self._no_update
-        if self._changed.any():
-            update = jnp.asarray(np.stack(
-                [self._changed, self._from_buffer, self._pos, self._active, self._rem]
-            ).astype(np.int32))
-        args = [
-            self.params,
-            self._cache,
-            self._presence,
-            *self._carry,
-            self._pushed["eos"],
-            self._pushed["temp"],
-            self._pushed["pen"],
-            self._pushed["table"],
-            self._rng,
-            self._first_token,
-            update,
-        ]
-        if self.speculative:
-            args.append(jnp.asarray(self._history))
+        (`_carry`), the host-only operands' copies — but what the host
+        changed since the last dispatch (`_SlotMirror.operands`). A step that
+        changed no slot pushes nothing."""
+        host_only, update, history = self._slots.operands()
+        args = [self.params, self._cache, self._presence, *self._carry, *host_only,
+                self._rng, self._first_token, update]
+        if history is not None:
+            args.append(history)
         return args
 
     def lower_decode_chunk(self):
@@ -2230,52 +2183,18 @@ class ContinuousBatcher:
         readback returned, at most its own extent: one chunk and the inserts
         ahead of it either way (what `serving_chunk_seconds` observes).
 
-        **The account of a starved device.** The engine knows, without the
-        device, when nothing it enqueued is still unread: no chunk in flight
-        and no insert dispatched since the last readback returned. The wall
-        time it spends in that state goes to the cause that let it happen, at
-        the boundaries above (`serving_device_starved_seconds_total{cause}`,
-        `stats["device_starved"]`): `admit` (step start → the first insert's
-        dispatch call returns, or the end of an admission that admits
-        nothing), `push` then `dispatch` (→ the chunk's launch returns, where
-        no insert went out before it), `drain` (the readback's return →
-        step() returns, where it left nothing in flight) — `starved_admit_s`
-        / `_push_s` / `_dispatch_s` / `_drain_s`, summed in `starved_s`, at
-        most `host_s` — and between two steps `client` (work was pending when
-        the step before returned) or `no_work` (nothing was: the offered
-        load's idle, not the host's): `gap_s`, the whole gap before this step,
-        and `gap_cause`, who had it — or `"covered"`: a chunk was in flight
-        through it, nothing starved, nothing charged. A gap is its cause's
-        from its first instant: a request submitted into an engine that had
-        nothing pending waits in a `no_work` gap until the next step(). Time
-        inside the step's wait is never charged — the host cannot see when
-        the device finished — so the account is a LOWER BOUND of the device's
-        idle time, short by the readback's latency and the launch's tail. A
-        step that runs ahead charges nothing after its first dispatch, and one
-        that finds a chunk in flight and leaves one charges nothing at all:
-        in a steady backlog every cause reads 0. The step that ENDS a backlog
-        (it reads the last chunk in flight back and dispatches none) charges
-        its drain."""
+        The account of a starved device (`_StarvedAccount`) is kept at these
+        boundaries: `starved_s` and its four parts, `gap_s` and `gap_cause`."""
         if self._closed:
             return []
         tracer = self.tracer
         with tracer.span("serve.step", category="serve") as step_span:
-            # The gap since the last step() returned: covered by a chunk left in
-            # flight, or the device sat empty through it — the client's, or
-            # nobody's where nothing was pending.
-            began = time.perf_counter()
-            if self._first_step_at is None:
-                self._first_step_at = self._step_returned_at = self._empty_since = began
-            gap_s = began - self._step_returned_at
-            gap_cause = "covered" if self._empty_since is None else self._gap_cause
-            self._charge(gap_cause, began)  # nothing where a chunk covers the gap
-            self._starved_in_step = {}  # the step's own parts, from here
+            gap_s, gap_cause = self._starved.begin_step(time.perf_counter())
             waits_before = self._m_device_waits.value
             self._expire_deadlines()
             with tracer.span("serve.admit", category="serve", record=False) as admit_span:
                 self._admit()
-            if self._empty_since is not None:  # admitted nothing
-                self._charge("admit", time.perf_counter())
+            self._starved.charge("admit")  # where it admitted nothing: else its first insert moved the mark
             inserts = len(self._fresh)
             step_span.annotate(inserts=inserts,
                                admit_s=round(admit_span.duration_s, 6), push_s=0.0, dispatch_s=0.0)
@@ -2285,7 +2204,7 @@ class ContinuousBatcher:
             # predict its slots.
             older = self._flights[0] if self._flights else None
             run_ahead = bool(self._queue) and self.run_ahead_disabled_reason is None
-            decoding = bool(self._active.any()) and (older is None or run_ahead)
+            decoding = bool(self._slots.active.any()) and (older is None or run_ahead)
             newer = self._dispatch_chunk(step_span, ahead=older is not None) if decoding else None
             if decoding and newer is None:  # the dispatch failed: everything in flight errored
                 older = None
@@ -2294,19 +2213,14 @@ class ContinuousBatcher:
             if flight is not None or self._fresh:
                 self._m_dispatching_steps.inc()
                 drained, device_wait_s = self._read_back(flight)
-                if drained is not None and not self._flights:  # everything enqueued has been read
-                    self._empty_since = self._read_back_at
+                if drained is not None and not self._flights:
+                    self._starved.all_read()
                 with tracer.span("serve.drain", category="serve", record=False) as drain_span:
                     if drained is not None:
                         self._drain(events, flight, *drained)
                     self._hand_back()
                 drain_s = drain_span.duration_s
-            self._step_returned_at = time.perf_counter()
-            if self._empty_since is not None:
-                self._charge("drain", self._step_returned_at)
-                self._gap_cause = "client" if self.pending else "no_work"
-            starved = {cause: round(self._starved_in_step.get(cause, 0.0), 6)
-                       for cause in ("admit", "push", "dispatch", "drain")}
+            starved = self._starved.end_step(self.pending)
             step_span.annotate(
                 drain_s=round(drain_s, 6),
                 device_wait_s=round(device_wait_s, 6),
@@ -2314,10 +2228,7 @@ class ContinuousBatcher:
                 waits=int(self._m_device_waits.value - waits_before),
                 dispatched_ahead=inserts + decoding,
                 in_flight_at_return=len(self._flights),
-                starved_s=round(sum(starved.values()), 6),
-                starved_admit_s=starved["admit"], starved_push_s=starved["push"],
-                starved_dispatch_s=starved["dispatch"], starved_drain_s=starved["drain"],
-                gap_s=round(gap_s, 6), gap_cause=gap_cause,
+                gap_s=round(gap_s, 6), gap_cause=gap_cause, **starved,
             )
         return events
 
@@ -2336,14 +2247,13 @@ class ContinuousBatcher:
         values = (read, self._first_token if self._fresh else None)
         self._m_device_waits.inc()
         name = "serve.chunk.wait" if flight is not None else "serve.first_tokens.wait"
-        previous = self._read_back_at
         try:
             with self.tracer.span(name, category="serve", record=False) as wait_span:
                 host = jax.device_get(values)
         except Exception as exc:  # noqa: BLE001
             self._wait_failed(exc, "decode chunk readback" if flight is not None else "first-token readback")
             return None, 0.0
-        self._read_back_at = time.perf_counter()
+        since_previous = self._starved.read_returned()
         if flight is not None:
             self._flights.popleft()
             # One chunk's cadence: since the previous readback returned, at most
@@ -2351,8 +2261,8 @@ class ContinuousBatcher:
             # and the inserts ahead of it, where the span of a chunk dispatched
             # ahead also covers what was left of its predecessor.
             cadence_s = max(flight.span.duration_s, 0.0)
-            if previous is not None:
-                cadence_s = min(cadence_s, self._read_back_at - previous)
+            if since_previous is not None:
+                cadence_s = min(cadence_s, since_previous)
             flight.span.annotate(cadence_s=round(cadence_s, 6), **self._chunk_counts(host[0])).end()
             self._m_chunk_latency.observe(cadence_s)
         return host, wait_span.duration_s
@@ -2386,22 +2296,20 @@ class ContinuousBatcher:
         chunk_span = tracer.start_span(
             "serve.decode_chunk", category="serve",
             chunk_size=self.chunk_size,
-            active_slots=int(self._active.sum()),
+            active_slots=int(self._slots.active.sum()),
             pages_in_use=self.pool.pages_in_use,
             ahead=bool(ahead),
-            **self._live_page_counts(), **self._hc_rows(int(self._active.sum()) * self.chunk_size),
+            **self._live_page_counts(),
+            **self._family.config.chunk_span_counts(int(self._slots.active.sum()) * self.chunk_size),
         )
         try:
             with tracer.span("serve.chunk.push", category="serve", record=False) as push_span:
                 operands = self._chunk_operands()
-            if self._empty_since is not None:  # no insert went out before it
-                self._charge("push", time.perf_counter())
+            self._starved.charge("push")  # where no insert went out before it
             with tracer.span("serve.chunk.dispatch", category="serve", record=False) as dispatch_span:
                 carry, read = self._chunk_fn(*operands)
             chunk_span.annotate(read_impl=self.read_impl)  # known once the first dispatch has traced it
-            if self._empty_since is not None:
-                self._charge("dispatch", time.perf_counter())
-                self._empty_since = None  # the device has work from here on
+            self._starved.enqueued("dispatch")
         except Exception as exc:  # noqa: BLE001
             chunk_span.annotate(error=repr(exc)).end()
             self._wait_failed(exc, "decode chunk dispatch")
@@ -2410,37 +2318,30 @@ class ContinuousBatcher:
                            dispatch_s=round(dispatch_span.duration_s, 6))
         self._cache, self._presence, *slot_state, self._rng = carry
         self._carry = tuple(slot_state)
-        self._changed[:] = self._from_buffer[:] = False
         self._m_chunks.inc()
         self._m_chunks_ahead.inc(int(ahead))
         self._m_chunks_ahead_share.set(self._m_chunks_ahead.value / self._m_chunks.value)
         self._m_decode_steps.inc(self.chunk_size)
         # The host's state as the chunk was dispatched, before _predict() moves it on.
-        tenants, was_active, pos_before = list(self._slot_request), self._active.copy(), self._pos.copy()
+        slots = self._slots
+        tenants, was_active, pos_before = list(self._slot_request), slots.active.copy(), slots.pos.copy()
         flight = _Flight(
             read=read, span=chunk_span, tenants=tenants, fresh=self._fresh, was_active=was_active,
-            ends=self._predict(), eos=self._eos.copy(), pos_before=pos_before,
+            ends=self._predict(), eos=slots.eos.copy(), pos_before=pos_before,
         )
         self._fresh = []
         self._flights.append(flight)
         return flight
 
     def _predict(self) -> np.ndarray:
-        """Move the host's mirror of `pos`, `active`, `rem` past the chunk just
-        dispatched, without the device: an active slot streams one token a
-        decode step until its budget ends, so both are known — unless it stops
-        on its EOS, which only the drain sees. A slot whose request WILL have
+        """Move the host's mirror past the chunk just dispatched, without the
+        device (`_SlotMirror.dispatched`). A slot whose request WILL have
         ended when the chunk has run (its budget is at most the chunk's steps;
         a one-token request, which never decodes) is vacated now: the returned
         `bool[num_slots]` marks them, for the drain that finishes their
-        results. A speculative engine predicts nothing (a verified block's
-        length is the device's): its drain adopts the readback."""
-        if not self.speculative:
-            steps = np.where(self._active, np.minimum(self._rem, self.chunk_size), 0)
-            self._pos += steps
-            self._rem -= steps
-            self._active &= self._rem > 0
-        ends = np.asarray([r is not None for r in self._slot_request]) & ~self._active
+        results."""
+        self._slots.dispatched(None if self.speculative else self.chunk_size)
+        ends = np.asarray([r is not None for r in self._slot_request]) & ~self._slots.active
         for slot in np.nonzero(ends)[0]:
             self._vacate(int(slot))
         if ends.any():
@@ -2456,16 +2357,17 @@ class ContinuousBatcher:
         read's own module counts it (`ops.attention.read_blocks`: an idle
         slot is the one scratch page the device visits). A slot's pages grow
         inside the chunk: that is not counted."""
-        live = int((self._pos[self._active] // self.page_size + 1).sum())
+        pos, active = self._slots.pos, self._slots.active
+        live = int((pos[active] // self.page_size + 1).sum())
         window = self.num_slots * self.pages_per_slot
         self._m_kv_live_page_share.set(live / window)
         counts = {"live_pages": live, "window_pages": window,
-                  "read_blocks": attention_ops.read_blocks(np.where(self._active, self._pos, 0), *self._read_shape),
+                  "read_blocks": attention_ops.read_blocks(np.where(active, pos, 0), *self._read_shape),
                   "kv_row_values": self.kv_row_values}
         if self._state_bytes_per_slot:
             # A family with recurrent state: what the chunk's first step reads
             # and writes whatever the contexts, beside the pages it visits.
-            slots = int(self._active.sum())
+            slots = int(active.sum())
             state = slots * self._state_bytes_per_slot
             pages = live * self.page_size * self._kv_bytes_per_token
             self._m_state_share.set(state / (state + pages) if state else 0.0)
@@ -2539,15 +2441,15 @@ class ContinuousBatcher:
         if flight is not None:
             self._drain_chunk(events, flight, read, now)
         # Admissions of a step that dispatched no chunk. A one-token request
-        # ends here; any other keeps `_from_buffer`: the next chunk starts it
+        # ends here; any other keeps `from_buffer`: the next chunk starts it
         # from the buffer, where a first token that is its EOS ends it.
         for slot, *admission in self._fresh:
             result = self._slot_request[slot]
             if result is None:
                 continue
             self._first_token_to(events, result, int(first_token[slot]), now, *admission, rode_chunk=False)
-            if self._rem[slot] == 0:
-                self._finish(result, "eos" if result.tokens[-1] == self._eos[slot] else "length",
+            if self._slots.rem[slot] == 0:
+                self._finish(result, "eos" if result.tokens[-1] == self._slots.eos[slot] else "length",
                              now=now, slot=slot)
         self._fresh.clear()
 
@@ -2560,7 +2462,7 @@ class ContinuousBatcher:
             token = int(read["first"][slot])
             self._first_token_to(events, result, token, now, *admission, rode_chunk=True)
             if self.speculative and flight.was_active[slot]:
-                self._history[slot, flight.pos_before[slot]] = token  # as the chunk did on the device
+                self._slots.saw(slot, flight.pos_before[slot], [token])  # as the chunk did on the device
         per_slot: Dict[int, List[int]] = {}
         for slot, tok in read["packed"][: int(read["count"])]:
             per_slot.setdefault(int(slot), []).append(int(tok))
@@ -2573,8 +2475,7 @@ class ContinuousBatcher:
                 # Mirror the device-side history update (emitted token j of the
                 # chunk landed at history[pos_before + 1 + j]) so the next
                 # dispatch pushes an identical context.
-                start = int(flight.pos_before[slot]) + 1
-                self._history[slot, start : start + len(toks)] = toks
+                self._slots.saw(slot, int(flight.pos_before[slot]) + 1, toks)
             events.append((result.request_id, toks))
             # Inter-token latency: the host drains a request's tokens once per
             # chunk, so the per-token gap is the drain gap amortized over the
@@ -2603,7 +2504,7 @@ class ContinuousBatcher:
             # dispatch's live pages and the drafter's context are read off it.
             still = np.asarray([r is not None and self._slot_request[i] is r
                                 for i, r in enumerate(tenants)]) & np.asarray(read["active"])
-            self._pos[still], self._rem[still] = read["pos"][still], read["rem"][still]
+            self._slots.adopt(still, read["pos"], read["rem"])
 
     def run(self, requests: Optional[List[Request]] = None) -> Dict[int, np.ndarray]:
         """Drive to completion: submit `requests` (if given), loop `step()` until

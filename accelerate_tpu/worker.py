@@ -309,17 +309,6 @@ def _raise_from_reply(reply: Dict[str, Any]):
 
 
 # ------------------------------------------------------------------ model specs
-#: Flax module class name -> model-family key (`models.CREATE_BY_FAMILY`).
-#: Serving needs `decode_slot_cache`, so only the slot-cache families appear.
-_FAMILY_BY_MODULE = {
-    "LlamaForCausalLM": "llama",
-    "GPTNeoXForCausalLM": "gpt_neox",
-    "LatentMoEForCausalLM": "latent_moe",
-    "OlmoHybridForCausalLM": "olmo_hybrid",
-    "FalconH1ForCausalLM": "falcon_h1",
-}
-
-
 def spec_for_model(model, params_path: Optional[str] = None,
                    params_digest: Optional[str] = None) -> Dict[str, Any]:
     """Serialize a live Model bundle into a worker-buildable JSON spec: the
@@ -329,11 +318,15 @@ def spec_for_model(model, params_path: Optional[str] = None,
     (the file's SHA-256, PR 2 manifest machinery) makes the handoff safe
     across hosts: a worker on another machine verifies it read the exact
     bytes the controller wrote, not a torn or stale object at the same path."""
-    family = _FAMILY_BY_MODULE.get(type(model.module).__name__)
+    from . import models
+
+    # Serving needs the slot cache: only the families whose config says so (`ServedConfig`).
+    served = {f: cls for f, cls in models.CONFIG_BY_FAMILY.items() if issubclass(cls, models.ServedConfig)}
+    family = next((f for f, cls in served.items() if type(getattr(model.module, "config", None)) is cls), None)
     if family is None:
         raise ValueError(
             f"{type(model.module).__name__} has no subprocess-worker family mapping; "
-            f"known: {sorted(_FAMILY_BY_MODULE)}"
+            f"known: {sorted(served)}"
         )
     return {
         "family": family,
@@ -356,8 +349,7 @@ def build_model_from_spec(spec: Dict[str, Any]):
         create = models.CREATE_BY_FAMILY.get(family)
         if create is None:
             raise ValueError(f"unknown model family {family!r} in worker spec")
-        config_cls = type(models.MODEL_REGISTRY[f"{family.replace('_', '-')}-tiny"][1]())
-        config = config_cls(**spec["config"])
+        config = models.CONFIG_BY_FAMILY[family](**spec["config"])
         # Tiny init seq_len: the real params arrive via params_path below, so
         # the throwaway init should cost as little as possible.
         seq_len = int(spec.get("seq_len", 8))

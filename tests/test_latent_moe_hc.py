@@ -317,7 +317,7 @@ def test_one_stream_is_the_plain_residual_with_kimis_tree_and_programs():
     lowered = engine._chunk_fn.lower(*engine._chunk_operands()).as_text(debug_info=True)
     assert not any(scope in lowered for scope in ("hc_pre", "hc_post", "mla_q_lora")) and "mla_absorb" in lowered
     assert "residual_streams" not in engine.stats
-    assert "hc_rows" not in engine._hc_rows(16) and engine._hc_rows(16) == {}
+    assert "hc_rows" not in engine.base_config.chunk_span_counts(16) and engine.base_config.chunk_span_counts(16) == {}
 
 
 def test_the_four_stream_programs_hold_the_new_scopes(model):

@@ -425,7 +425,7 @@ def _decode_logits(engine):
     """One more decode step's logits off an engine's live state (its own
     un-jitted step program; nothing is donated or adopted)."""
     token, pos, _active, _rem = engine._carry  # the slot state lives on the device
-    args = [engine.params, engine._cache, token, pos, jnp.asarray(engine._page_table)]
+    args = [engine.params, engine._cache, token, pos, jnp.asarray(engine._slots.page_table)]
     logits, _cache = jax.jit(engine._step_raw)(*args)
     return np.asarray(logits, np.float32)
 
@@ -466,7 +466,7 @@ def test_paged_read_logits_match_oracle_and_contiguous(family, monkeypatch):
         return engine
 
     live = two_chunks()
-    assert live._pos[3] == 0 and not live._active[3]  # the idle slot
+    assert live._slots.pos[3] == 0 and not live._slots.active[3]  # the idle slot
     busy = np.arange(3)
     live_logits = _decode_logits(live)[busy]
     for i, p in enumerate(prompts):
@@ -478,7 +478,7 @@ def test_paged_read_logits_match_oracle_and_contiguous(family, monkeypatch):
     np.testing.assert_allclose(live_logits, _decode_logits(everything)[busy], atol=2e-5)
     for mine, theirs in zip(live._carry, everything._carry):
         np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
-    np.testing.assert_array_equal(live._pos, np.asarray(live._carry[1]))  # the host's prediction
+    np.testing.assert_array_equal(live._slots.pos, np.asarray(live._carry[1]))  # the host's prediction
 
 
 # ------------------------------------------------------------------ parity
@@ -531,7 +531,7 @@ def _assert_page_table_in_pool(engine):
     """What the "xla" read's unguarded gather (`mode="clip"`) relies on: every
     page-table entry is one of the pool's own ids, a live slot's row is its
     pages in order, and every unused entry is the scratch page."""
-    table = engine._page_table
+    table = engine._slots.page_table
     assert table.dtype == np.int32
     assert table.min() >= 0 and table.max() < engine.pool.num_pages, table
     for slot, pages in enumerate(engine._slot_pages):
@@ -571,7 +571,7 @@ def test_page_table_entries_stay_in_the_pool_through_churn():
         _assert_page_table_in_pool(engine)
         assert engine.pool.check_consistency() == []
         assert steps < 200
-    assert (engine._page_table == SCRATCH_PAGE).all()
+    assert (engine._slots.page_table == SCRATCH_PAGE).all()
     assert engine.pool.pages_in_use == 0
     assert engine.stats["prefix_cache"]["hits"] > 0 and engine.pool.evictions > 0
     for i, (p, m) in enumerate(zip(prompts, budgets)):

@@ -126,14 +126,14 @@ def test_released_slot_sits_at_position_zero_through_reuse():
     while paged.pending:
         paged.step()
         idle = [slot for slot, r in enumerate(paged._slot_request) if r is None]
-        assert not paged._pos[idle].any(), (paged._pos, idle)
-        assert not paged._page_table[idle].any()
+        assert not paged._slots.pos[idle].any(), (paged._slots.pos, idle)
+        assert not paged._slots.page_table[idle].any()
         shares.append(paged.stats["kv_live_page_share"])
     for i, (p, m) in enumerate(zip(prompts, budgets)):
         np.testing.assert_array_equal(
             np.asarray(paged.results[i].tokens), _static_reference(model, p, m)
         )
-    assert not paged._pos.any()  # drained: every slot was released to position 0
+    assert not paged._slots.pos.any()  # drained: every slot was released to position 0
     chunks = [r for r in recorder.records() if r["name"] == "serve.decode_chunk"]
     assert chunks and all(c["attrs"]["window_pages"] == 3 * 4 for c in chunks)
     # the first chunk: prompts of 12, 5 and 20 tokens, 8 a page -> 2 + 1 + 3 live pages
@@ -180,7 +180,7 @@ def test_chunk_span_read_blocks_is_the_reads_trip_count(monkeypatch, run_pages):
     pushed = []
     push = engine._chunk_operands
     monkeypatch.setattr(
-        engine, "_chunk_operands", lambda: (pushed.append(engine._pos.copy()), push())[1]
+        engine, "_chunk_operands", lambda: (pushed.append(engine._slots.pos.copy()), push())[1]
     )
     engine.step()
     engine.step()
@@ -442,7 +442,7 @@ def test_step_dispatches_everything_and_waits_once(monkeypatch, kind, admissions
         if kind == "speculative":  # the drafter's context, first token included
             slot, n = engine._slot_of(i), len(engine.results[i].tokens)
             np.testing.assert_array_equal(
-                engine._history[slot, : prompts[i].size + n],
+                engine._slots.history[slot, : prompts[i].size + n],
                 np.concatenate([prompts[i], engine.results[i].tokens]),
             )
     # stream order: the admissions' first tokens, in admission order, lead the step's events
@@ -653,7 +653,7 @@ def test_a_slot_predicted_free_is_given_away_before_its_last_tokens_are_drained(
     events = engine.step()  # chunk 2 — its last token — dispatched behind it; chunk 1 read
     assert [toks for rid, toks in events if rid == 0] == [engine.results[0].tokens[:1], engine.results[0].tokens[1:5]]
     assert engine.free_slots == 1 and engine.pool.pages_in_use == 0  # vacated on the prediction
-    assert not engine._pos.any() and not engine._page_table.any()
+    assert not engine._slots.pos.any() and not engine._slots.page_table.any()
     assert not engine.results[0].finished and len(engine.results[0].tokens) == 5
     with pytest.raises(ValueError, match="in flight"):
         engine.release(0)
@@ -793,7 +793,7 @@ def test_a_failing_chunk_condemns_the_successor_dispatched_behind_it(monkeypatch
         assert engine.results[rid].finish_reason == "error", rid
         assert "device halted" in engine.results[rid].error and engine.results[rid].tokens == []
     assert not engine.results[3].finished and not engine._flights and not engine._fresh
-    assert engine.free_slots == 2 and engine.pool.pages_in_use == 0 and not engine._active.any()
+    assert engine.free_slots == 2 and engine.pool.pages_in_use == 0 and not engine._slots.active.any()
     engine.submit(Request(4, prompts[1], max_new_tokens=6))
     outputs = engine.run()
     np.testing.assert_array_equal(outputs[3], _static_reference(model, prompts[3], 4))
@@ -1087,3 +1087,34 @@ def test_serving_soak_large_mixed_workload():
             outputs[req.request_id],
             _static_reference(model, np.asarray(req.input_ids), req.max_new_tokens),
         )
+
+
+# ------------------------------------------------------------- the slot mirror, alone
+
+def test_what_the_mirror_pushed_keeps_its_values_when_the_mirror_is_written(monkeypatch):
+    """ROADMAP D9: on a CPU `jnp.asarray(mirror)` may alias the numpy buffer
+    (it does where the buffer is 64-byte aligned), which the host writes in
+    place while a chunk that reads the pushed array runs. `_SlotMirror.operands()`
+    pushes copies — of every operand it makes. Held against the worst backend:
+    one whose `asarray` always aliases."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.serving import _SlotMirror
+
+    monkeypatch.setattr(serving.jnp, "asarray", lambda host: host)
+    mirror = _SlotMirror(num_slots=2, pages_per_slot=3, history_length=8)
+    mirror.admit(0, np.arange(1, 5, dtype=np.int32), budget=3, eos=7, temperature=0.5, penalty=1.25,
+                 page_row=np.array([4, 5, 0], np.int32))
+    host_only, update, history = mirror.operands()
+    pushed = [*host_only, update, history]
+    before = [np.array(x) for x in pushed]
+    assert before[0][0] == 7 and before[3][0].tolist() == [4, 5, 0] and before[5][0, :4].tolist() == [1, 2, 3, 4]
+    assert before[4].tolist() == [[1, 0], [1, 0], [4, 0], [1, 0], [3, 0]]  # changed, from_buffer, pos, active, rem
+    for host in (mirror.eos, mirror.temp, mirror.pen, mirror.page_table, mirror.history, mirror.pos, mirror.rem):
+        host[...] = 9
+    mirror.changed[:] = mirror.from_buffer[:] = mirror.active[:] = False
+    for then, now in zip(before, pushed):
+        np.testing.assert_array_equal(np.asarray(now), then)
+    # nothing changed by a slot operation since: the same device arrays, and the one array of zeros
+    mirror.dispatched(steps=None)
+    again, update, _ = mirror.operands()
+    assert all(a is b for a, b in zip(again, host_only)) and not np.asarray(update).any()
